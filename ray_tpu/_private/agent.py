@@ -29,7 +29,7 @@ import subprocess
 import sys
 import threading
 import time
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from . import clocks, diagnosis, loopmon, protocol, rpc
 from . import flight_recorder as frec
@@ -153,8 +153,9 @@ class WorkerHandle:
         self.lease_id: Optional[bytes] = None
         self.lease_resources: Dict[str, float] = {}
         self.lease_bundle: Optional[Tuple[bytes, int]] = None  # PG bundle key
-        self.needs_tpu = False        # pooled separately: TPU workers keep
-        self.is_actor = False         # the accelerator client initialized
+        self.needs_tpu = False        # never pooled: may hold a chip open
+        self.chip_ids: Tuple[int, ...] = ()   # real chips this process owns
+        self.is_actor = False
         self.has_env = False          # runtime-env workers never pool
         self.lease_owner_conn = None  # server conn that requested the lease
         self.actor_id: Optional[bytes] = None
@@ -182,7 +183,8 @@ class NodeAgent:
 
     def __init__(self, *, gcs_address, session_dir: str, node_id: bytes,
                  resources: Dict[str, float], labels: Dict[str, str],
-                 store_capacity: int, host: str = "127.0.0.1"):
+                 store_capacity: int, host: str = "127.0.0.1",
+                 tpu_chips: Sequence[int] = ()):
         self.gcs_address = tuple(gcs_address)
         self.session_dir = session_dir
         # Cluster epoch (GCS HA fencing token, docs/control_plane.md §8):
@@ -199,12 +201,21 @@ class NodeAgent:
         self.labels = labels
         self.resources_total = dict(resources)
         self.resources_available = dict(resources)
+        # Real chips on this host (empty when TPU counts were injected on
+        # a host without chips): a TPU worker is confined to the ids its
+        # lease takes from _free_chips and returns them when it EXITS.
+        self._host_chips: Tuple[int, ...] = tuple(sorted(tpu_chips))
+        if self._host_chips and \
+                resources.get("TPU", 0) > len(self._host_chips):
+            raise ValueError(
+                f"TPU={resources['TPU']} but this host exposes "
+                f"{len(self._host_chips)} chips")
+        self._free_chips: List[int] = list(self._host_chips)
         self.store_path = os.path.join(
             "/dev/shm", f"raytpu_{node_id.hex()[:12]}")
         self.store = ShmStore.create(self.store_path, store_capacity)
         self.workers: Dict[bytes, WorkerHandle] = {}
         self.idle_workers: List[WorkerHandle] = []      # CPU pool
-        self.idle_tpu_workers: List[WorkerHandle] = []  # TPU pool
         self.leases: Dict[bytes, WorkerHandle] = {}
         self.bundles: Dict[Tuple[bytes, int], Dict[str, float]] = {}
         self.pinned: Dict[bytes, int] = {}   # object_id -> pin count (owner pins)
@@ -548,6 +559,15 @@ class NodeAgent:
             "GCS rejected heartbeats for node %s (marked dead); "
             "re-registering as fresh node %s",
             old.hex()[:8], self.node_id.hex()[:8])
+        # The GCS buried (or restarted elsewhere) every actor of the dead
+        # identity, so it will never send their processes a kill: left
+        # alone they are orphans, and one that holds a chip holds it — and
+        # the TPU capacity of this node — for good.  No death report: the
+        # actor id may already name a live incarnation elsewhere.
+        for wh in self.workers.values():
+            if wh.is_actor:
+                wh.is_actor, wh.actor_id = False, None
+                wh.proc.terminate()
         await self._register_gcs(self.gcs)
 
     async def _report_loop(self):
@@ -910,8 +930,8 @@ class NodeAgent:
         self.workers.pop(wh.worker_id, None)
         if wh in self.idle_workers:
             self.idle_workers.remove(wh)
-        if wh in self.idle_tpu_workers:
-            self.idle_tpu_workers.remove(wh)
+        self._give_back_chips(wh.chip_ids)
+        wh.chip_ids = ()
         if wh.lease_id is not None:
             self._release_resources(self._settle_lease_release(wh),
                                     wh.lease_bundle)
@@ -979,18 +999,23 @@ class NodeAgent:
             pass
 
     # ------------------------------------------------------------- workers --
-    def _zygote_env(self) -> Dict[str, str]:
-        """Env for the fork-server: identical to a default CPU worker's
-        (sitecustomize stripped, CPU-only jax) so forked children need no
-        import-time env fixups."""
+    def _worker_env(self, env_extra: Dict[str, str] | None,
+                    needs_tpu: bool,
+                    chip_ids: Sequence[int] = ()) -> Dict[str, str]:
+        """A worker's base environment, with its JAX platform pinned
+        whatever the agent itself inherited: a worker holding real chips
+        sees those chips only and fails if it cannot open them; a worker
+        without chips is pinned to the CPU, because an unpinned JAX
+        auto-detects the TPU and takes it from the worker that leased it
+        (one process per chip).  Only a TPU lease on a host WITHOUT real
+        chips (injected counts) is left alone."""
         from .node import child_env
-        env = child_env(None)
-        strip = get_config().worker_pythonpath_strip_cpu
-        if strip:
-            parts = [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
-                     if p and strip not in p]
-            env["PYTHONPATH"] = os.pathsep.join(parts)
-        if env.get("JAX_PLATFORMS", "") not in ("", "cpu"):
+        env = child_env(env_extra)
+        if chip_ids:
+            from ..tpu.accelerator import TPUAcceleratorManager
+            env.update(TPUAcceleratorManager.worker_env(chip_ids,
+                                                        self._host_chips))
+        elif self._host_chips or not needs_tpu:
             env["JAX_PLATFORMS"] = "cpu"
         return env
 
@@ -1004,7 +1029,10 @@ class NodeAgent:
         try:
             self._zygote = subprocess.Popen(
                 [sys.executable, "-m", "ray_tpu._private.zygote"],
-                env=self._zygote_env(), stdin=subprocess.PIPE,
+                # A default CPU worker's env, so forked children need no
+                # import-time env fixups.
+                env=self._worker_env(None, needs_tpu=False),
+                stdin=subprocess.PIPE,
                 stdout=subprocess.PIPE, stderr=errf,
                 cwd=os.getcwd(), start_new_session=True)
         except OSError:
@@ -1024,25 +1052,12 @@ class NodeAgent:
 
     async def _spawn_worker(self, env_extra: Dict[str, str] | None = None,
                             needs_tpu: bool = False,
-                            cwd: str | None = None) -> WorkerHandle:
+                            cwd: str | None = None,
+                            chip_ids: Tuple[int, ...] = ()) -> WorkerHandle:
+        """`chip_ids` were taken from _free_chips by the caller; the new
+        handle owns them until its process exits (_on_worker_death)."""
         worker_id = WorkerID.from_random().binary()
-        from .node import child_env
-        env = child_env(env_extra)
-        if not needs_tpu:
-            # Strip accelerator site hooks (e.g. a sitecustomize that eagerly
-            # initializes the TPU client): CPU workers start in ~20ms instead
-            # of seconds and never touch chip state (reference analogue:
-            # workers outside TPU leases get no TPU_VISIBLE_CHIPS).
-            strip = get_config().worker_pythonpath_strip_cpu
-            if strip:
-                parts = [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
-                         if p and strip not in p]
-                env["PYTHONPATH"] = os.pathsep.join(parts)
-            # A worker with no TPU lease must never initialize the chip —
-            # one client per chip (reference analogue: no TPU_VISIBLE_CHIPS
-            # → no accelerator; jax_trainer.py:92-94 driver warning).
-            if env.get("JAX_PLATFORMS", "") not in ("", "cpu"):
-                env["JAX_PLATFORMS"] = "cpu"
+        env = self._worker_env(env_extra, needs_tpu, chip_ids)
         chaos_spec = get_config().rpc_chaos
         if chaos_spec:
             # Chaos must reach worker processes too (their config builds
@@ -1118,6 +1133,7 @@ class NodeAgent:
             self._worker_cgroup.add(proc.pid)
         wh = WorkerHandle(worker_id, proc)
         wh.needs_tpu = needs_tpu
+        wh.chip_ids = tuple(chip_ids)
         wh.has_env = bool(env_extra) or cwd is not None
         self.workers[worker_id] = wh
         return wh
@@ -1209,22 +1225,55 @@ class NodeAgent:
         wh.registered.set()
         return {"node_id": self.node_id}
 
+    def _take_chips(self, resources: Dict[str, float]) -> Tuple[int, ...]:
+        """Chip ids for a lease of `resources`: () unless it asks for TPU
+        on a host with real chips.  Raises when the request cannot be a
+        whole number of chips, or when the chips are still held by a
+        worker that has not finished exiting."""
+        n = resources.get("TPU", 0)
+        if not n or not self._host_chips:
+            return ()
+        if n != int(n):
+            raise rpc.RpcError(
+                f"{protocol.LEASE_REFUSED}: TPU={n} on a host with real chips — a "
+                "chip belongs to one process; ask for whole chips")
+        n = int(n)
+        if n > len(self._free_chips):
+            raise rpc.RpcError(
+                f"{n} chips wanted, {len(self._free_chips)} free: the "
+                "rest are held by workers that have not exited yet")
+        ids, self._free_chips = (tuple(self._free_chips[:n]),
+                                 self._free_chips[n:])
+        return ids
+
+    def _give_back_chips(self, ids: Sequence[int]) -> None:
+        self._free_chips = sorted([*self._free_chips, *ids])
+
     async def _pop_worker(self, env_extra=None,
-                          needs_tpu: bool = False,
-                          cwd: str | None = None) -> WorkerHandle:
+                          cwd: str | None = None,
+                          resources: Optional[Dict[str, float]] = None
+                          ) -> WorkerHandle:
         """Reuse an idle pooled worker or spawn one (reference:
         WorkerPool::PopWorker, worker_pool.h:55; reuse keyed by runtime env —
-        round 1 pools only default-env workers).  CPU and TPU workers pool
-        separately: CPU workers spawn without the accelerator client (fast
-        startup, no chip state); TPU workers keep it."""
-        if not env_extra and cwd is None:
-            pool = self.idle_tpu_workers if needs_tpu else self.idle_workers
+        round 1 pools only default-env workers).  TPU workers are never
+        pooled: a process that opened a chip holds it until it exits, so
+        each TPU lease gets a fresh process confined to the lease's
+        chips."""
+        resources = resources or {}
+        needs_tpu = _needs_tpu(resources)
+        if not env_extra and cwd is None and not needs_tpu:
+            pool = self.idle_workers
             while pool:
                 wh = pool.pop()
                 if wh.proc.poll() is None and wh.conn and not wh.conn.closed:
                     return wh
-        wh = await self._spawn_worker(env_extra, needs_tpu=needs_tpu,
-                                      cwd=cwd)
+        chips = self._take_chips(resources)
+        try:
+            wh = await self._spawn_worker(env_extra, needs_tpu=needs_tpu,
+                                          cwd=cwd, chip_ids=chips)
+        except BaseException:
+            self._give_back_chips(chips)
+            raise
         cfg = get_config()
         deadline = time.monotonic() + cfg.worker_register_timeout_s
         while True:
@@ -1416,8 +1465,8 @@ class NodeAgent:
         try:
             if p.get("env"):
                 env_extra.update(p["env"])
-            wh = await self._pop_worker(
-                env_extra or None, needs_tpu=_needs_tpu(resources), cwd=cwd)
+            wh = await self._pop_worker(env_extra or None, cwd=cwd,
+                                        resources=resources)
         except Exception as e:
             # A spawn failure must release the acquired resources.
             self._release_resources(resources, bundle_key)
@@ -1725,14 +1774,15 @@ class NodeAgent:
             pass
 
     def _recycle_worker(self, wh: WorkerHandle):
-        """Return a no-longer-leased worker to its idle pool, or
-        terminate it.  Runtime-env workers are never pooled: their
-        env_vars / PYTHONPATH / cwd would leak into default-env tasks."""
+        """Return a no-longer-leased worker to the idle pool, or terminate
+        it.  Runtime-env workers are never pooled: their env_vars /
+        PYTHONPATH / cwd would leak into default-env tasks.  TPU workers
+        are never pooled either (see _pop_worker)."""
         wh.last_idle = time.monotonic()
-        pool = self.idle_tpu_workers if wh.needs_tpu else self.idle_workers
         if (wh.proc.poll() is None and not wh.is_actor and not wh.has_env
-                and len(pool) < IDLE_WORKER_KEEP):
-            pool.append(wh)
+                and not wh.needs_tpu
+                and len(self.idle_workers) < IDLE_WORKER_KEEP):
+            self.idle_workers.append(wh)
         elif not wh.is_actor:
             wh.proc.terminate()
 
@@ -1754,15 +1804,37 @@ class NodeAgent:
         """Forcibly return a lease whose owner is gone.  Settles blocked-get
         CPU accounting (a blocked worker's CPU was already handed back by
         h_worker_blocked — returning the full grant would double-credit
-        the pool)."""
+        the pool).
+
+        A TPU worker's lease is credited back only once its process has
+        EXITED: until then it may hold the chip open, and a worker started
+        on the returned capacity would find the device busy.  The lease
+        stays charged to the handle; _on_worker_death settles it."""
         self.leases.pop(lease_id, None)
+        wh.lease_owner_conn = None
+        if wh.needs_tpu and wh.proc.poll() is None:
+            wh.proc.terminate()
+            rpc.spawn(self._settle_at_exit(wh))
+            return
         self._release_resources(self._settle_lease_release(wh),
                                 wh.lease_bundle)
         wh.lease_id = None
         wh.lease_resources = {}
         wh.lease_bundle = None
-        wh.lease_owner_conn = None
         self._recycle_worker(wh)
+
+    async def _settle_at_exit(self, wh: WorkerHandle,
+                              grace_s: float = 10.0) -> None:
+        """Wait for a terminated worker to exit (SIGKILL after `grace_s`)
+        and settle it at once instead of at the reap loop's next pass."""
+        deadline = time.monotonic() + grace_s
+        while wh.proc.poll() is None:
+            if time.monotonic() > deadline:
+                wh.proc.kill()
+                deadline = float("inf")
+            await asyncio.sleep(0.02)
+        if wh.worker_id in self.workers:
+            await self._on_worker_death(wh)
 
     async def h_return_lease(self, conn, p):
         # Returns are accepted under ANY epoch: refusing a release from a
@@ -1871,8 +1943,7 @@ class NodeAgent:
         env_extra, cwd = payload
         try:
             wh = await self._pop_worker(dict(env_extra) or None,
-                                        needs_tpu=_needs_tpu(resources),
-                                        cwd=cwd)
+                                        cwd=cwd, resources=resources)
         except Exception:
             self._release_resources(resources, bundle_key)
             raise
@@ -1885,19 +1956,19 @@ class NodeAgent:
         try:
             await wh.conn.call("actor_init", p, timeout=115)
         except (rpc.RpcError, asyncio.TimeoutError) as e:
-            self._release_resources(resources, bundle_key)
+            # The lease stays charged to the handle: _on_worker_death
+            # releases it once the process — and any chip it opened — is
+            # gone.  The ACTOR fields are cleared, or the death watcher
+            # races this raise with a generic actor_failed("exited with
+            # code 0") that masks the real __init__ error (e.g. an
+            # unimportable actor class) at the caller.
             self.leases.pop(wh.lease_id, None)
-            # Clear lease fields so _on_worker_death doesn't release again
-            # — and the ACTOR fields, or the death watcher races this
-            # raise with a generic actor_failed("exited with code 0")
-            # that masks the real __init__ error (e.g. an unimportable
-            # actor class) at the caller.
-            wh.lease_id = None
-            wh.lease_resources = {}
-            wh.lease_bundle = None
             wh.is_actor = False
             wh.actor_id = None
             wh.proc.terminate()
+            if isinstance(e, rpc.RemoteError):
+                # The constructor itself raised: no retry cures that.
+                raise rpc.RpcError(f"{protocol.ACTOR_INIT_RAISED}: {e}")
             raise rpc.RpcError(f"actor __init__ failed: {e}")
         return {"worker_addr": list(wh.address), "worker_id": wh.worker_id}
 
@@ -3781,6 +3852,7 @@ async def _amain(args):
         resources=json.loads(args.resources),
         labels=json.loads(args.labels),
         store_capacity=args.store_capacity,
+        tpu_chips=json.loads(args.tpu_chips),
     )
     addr = await agent.start()
     if args.ready_file:
@@ -3816,6 +3888,7 @@ def main():
     parser.add_argument("--node-id", required=True)
     parser.add_argument("--resources", default="{}")
     parser.add_argument("--labels", default="{}")
+    parser.add_argument("--tpu-chips", default="[]")
     parser.add_argument("--store-capacity", type=int, default=1 << 30)
     parser.add_argument("--system-config", default="")
     parser.add_argument("--ready-file", default="")

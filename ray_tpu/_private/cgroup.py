@@ -34,7 +34,7 @@ def _load():
     with _build_lock:
         if _lib is not None:
             return _lib
-        native_build.build_so(_SRC, _SO, fallback_to_stale=True)
+        native_build.build_so(_SRC, _SO)
         lib = ctypes.CDLL(_SO)
         lib.cg_available.restype = ctypes.c_int
         lib.cg_create.argtypes = [ctypes.c_char_p]

@@ -2581,10 +2581,12 @@ class CoreWorker:
                     self._pump(key, state)
                 return
             reason = res.get("reason") or ""
-            if "runtime env setup failed" in reason:
-                # A broken env spec (bad package, dead find_links) can
-                # never succeed by retrying — surface it on the tasks
-                # (reference: RuntimeEnvSetupError fails the task).
+            if "runtime env setup failed" in reason \
+                    or protocol.LEASE_REFUSED in reason:
+                # A broken env spec (bad package, dead find_links) or a
+                # shape the node refuses outright (a fraction of a real
+                # chip) can never succeed by retrying — surface it on the
+                # tasks (reference: RuntimeEnvSetupError fails the task).
                 state.pending_lease_requests -= 1
                 self._fail_queued_tasks(state, exc.RayError(reason))
                 return

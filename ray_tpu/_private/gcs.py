@@ -1579,10 +1579,22 @@ class GcsServer:
         cfg = get_config()
         period = cfg.health_check_period_ms / 1000.0
         threshold = cfg.health_check_failure_threshold
+        tick = time.monotonic()
         while True:
             await asyncio.sleep(period)
             try:
                 now = time.monotonic()
+                # A paused observer cannot judge silence.  When this tick
+                # itself is late — the loop was busy, or the whole host
+                # froze (creating a TPU client stalls every process on the
+                # machine for seconds) — the heartbeats sent meanwhile are
+                # still queued behind it, so the pause is taken out of
+                # every node's silence instead of being read as its death.
+                late, tick = now - tick - period, now
+                if late > period:
+                    for node in self.nodes.values():
+                        node.last_heartbeat = min(
+                            now, node.last_heartbeat + late)
                 for node in list(self.nodes.values()):
                     if node.alive and \
                             now - node.last_heartbeat > period * threshold:
@@ -2136,6 +2148,14 @@ class GcsServer:
                         # would livelock: each fresh install's "in
                         # progress" polls keep the deadline alive.
                         actor.death_cause = msg.split("\n")[0]
+                        return False
+                    if protocol.LEASE_REFUSED in msg or \
+                            protocol.ACTOR_INIT_RAISED in msg:
+                        # Likewise: a request the node can never grant
+                        # (a fraction of a real chip) or a constructor
+                        # that raised.  The whole remote traceback is the
+                        # cause — it names what the actor found.
+                        actor.death_cause = msg
                         return False
                     if "setup in progress" in msg:
                         # The node is actively materializing this actor's

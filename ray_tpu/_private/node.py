@@ -63,6 +63,10 @@ def dump_profile(*_a) -> None:
 
 
 def _wait_ready(path: str, proc: subprocess.Popen, timeout: float = 30.0) -> dict:
+    """The daemon's ready record.  A daemon that exits first, or is not
+    ready in time (it is then killed: a failed start leaves no process),
+    raises with the end of its stderr log — the caller may be on a machine
+    nobody can open the log on."""
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         if os.path.exists(path):
@@ -70,10 +74,21 @@ def _wait_ready(path: str, proc: subprocess.Popen, timeout: float = 30.0) -> dic
                 return json.load(f)
         if proc.poll() is not None:
             raise RuntimeError(
-                f"daemon exited with code {proc.returncode} before ready "
-                f"(logs in {os.path.dirname(path)})")
+                f"daemon exited with code {proc.returncode} before ready; "
+                + _err_tail(proc))
         time.sleep(0.02)
-    raise TimeoutError(f"daemon did not become ready: {path}")
+    proc.kill()
+    proc.wait()
+    raise TimeoutError(f"daemon not ready after {timeout:.0f}s ({path}); "
+                       + _err_tail(proc))
+
+
+def _err_tail(proc: subprocess.Popen, nbytes: int = 3000) -> str:
+    log = proc.err_log               # set by _spawn
+    with open(log, "rb") as f:
+        f.seek(max(0, os.path.getsize(log) - nbytes))
+        tail = f.read().decode(errors="replace").strip()
+    return f"its stderr ({log}) ends:\n{tail or '(empty)'}"
 
 
 def new_session_dir() -> str:
@@ -94,12 +109,16 @@ def _pkg_root() -> str:
 
 def child_env(extra: Optional[Dict[str, str]] = None) -> dict:
     """Environment for spawned daemons/workers: guarantees ray_tpu is
-    importable even when the driver added it to sys.path manually."""
+    importable even when the driver added it to sys.path manually, and
+    names the JAX compilation cache every child shares (compile_cache.py:
+    the variable if set, else the fixed in-checkout directory)."""
+    from .compile_cache import ENV as cache_env, compile_cache_dir
     env = dict(os.environ)
     root = _pkg_root()
     pp = env.get("PYTHONPATH", "")
     if root not in pp.split(os.pathsep):
         env["PYTHONPATH"] = root + (os.pathsep + pp if pp else "")
+    env[cache_env] = compile_cache_dir()
     env.update(extra or {})
     return env
 
@@ -109,8 +128,10 @@ def _spawn(args, session_dir: str, tag: str) -> subprocess.Popen:
     os.makedirs(log_dir, exist_ok=True)
     out = open(os.path.join(log_dir, f"{tag}.out"), "ab")
     err = open(os.path.join(log_dir, f"{tag}.err"), "ab")
-    return subprocess.Popen(args, stdout=out, stderr=err,
+    proc = subprocess.Popen(args, stdout=out, stderr=err,
                             start_new_session=True, env=child_env())
+    proc.err_log = err.name          # read back by _wait_ready on failure
+    return proc
 
 
 def start_gcs(session_dir: str, port: int = 0,
@@ -168,7 +189,16 @@ def start_agent(session_dir: str, gcs_address: tuple,
                 system_config: Optional[dict] = None,
                 node_id: Optional[bytes] = None,
                 ) -> Tuple[subprocess.Popen, tuple, str, bytes]:
+    """Spawn a node agent.  When `resources` holds TPU and this host
+    exposes real chips, the agent gets their ids so it can confine each
+    TPU worker to the chips of its lease; injected TPU counts on a host
+    without chips (tests) get none and workers' environments are left
+    alone."""
     node_id = node_id or NodeID.from_random().binary()
+    chips = []
+    if resources.get("TPU"):
+        from ..tpu.accelerator import TPUAcceleratorManager
+        chips = TPUAcceleratorManager.chip_ids()
     ready = os.path.join(session_dir,
                          f"agent_ready_{node_id.hex()[:8]}.json")
     proc = _spawn(
@@ -178,6 +208,7 @@ def start_agent(session_dir: str, gcs_address: tuple,
          "--node-id", node_id.hex(),
          "--resources", json.dumps(resources),
          "--labels", json.dumps(labels or {}),
+         "--tpu-chips", json.dumps(chips),
          "--store-capacity", str(store_capacity),
          "--system-config", json.dumps(system_config) if system_config else "",
          "--ready-file", ready],
@@ -192,16 +223,14 @@ def default_resources(num_cpus: Optional[int] = None,
                       ) -> Dict[str, float]:
     """Detect node resources (reference: _private/resource_spec.py +
     accelerator managers). TPU chips are detected via the accelerator
-    manager (ray_tpu/tpu/accelerator.py)."""
+    manager (ray_tpu/tpu/accelerator.py), which never initialises JAX; a
+    discovery error propagates rather than reading as "no chips"."""
     out: Dict[str, float] = dict(resources or {})
     out.setdefault("CPU", float(num_cpus if num_cpus is not None
                                 else os.cpu_count() or 1))
     if num_tpus is None:
-        try:
-            from ..tpu.accelerator import TPUAcceleratorManager
-            num_tpus = TPUAcceleratorManager.num_chips()
-        except Exception:
-            num_tpus = 0
+        from ..tpu.accelerator import TPUAcceleratorManager
+        num_tpus = TPUAcceleratorManager.num_chips()
     if num_tpus:
         out.setdefault("TPU", float(num_tpus))
     out.setdefault("memory", float(_available_memory()))

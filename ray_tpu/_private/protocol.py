@@ -250,6 +250,12 @@ EPOCH_NONE = 0
 # distinguish fencing from plain resource exhaustion.
 REJECT_STALE_EPOCH = "stale_epoch"
 
+# Reasons a lease / actor creation is refused for good: the submitter (or
+# the GCS actor scheduler) fails the work instead of retrying.  Matched as
+# substrings of the refusal text, like "runtime env setup failed".
+LEASE_REFUSED = "lease refused"
+ACTOR_INIT_RAISED = "actor __init__ raised"
+
 # GCS high-availability files, all under the session dir (the shared
 # path both the primary and the warm standby can reach):
 #   GCS_ADDRESS_FILE — the ADVERTISED address: {"address": [h, p],

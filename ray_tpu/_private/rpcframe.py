@@ -55,13 +55,11 @@ _failed = False
 
 
 def ensure_built() -> str:
-    """Build (or reuse) the shared object; raises only when there is
-    neither a buildable toolchain NOR an existing artifact.  A
-    compiler-less host whose checkout stamped the source newer than the
-    committed .so keeps the committed binary (rf_abi_version() in
-    _load() refuses a genuinely incompatible one)."""
+    """Build (or reuse) the shared object for the source as it reads now;
+    raises when it cannot be built (_load() then degrades to the
+    pure-Python framer, loudly)."""
     with _build_lock:
-        return native_build.build_so(_SRC, _SO, fallback_to_stale=True)
+        return native_build.build_so(_SRC, _SO)
 
 
 def _load():

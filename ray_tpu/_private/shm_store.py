@@ -10,9 +10,12 @@ round trip. The library is built on first use with g++ (no pip deps).
 from __future__ import annotations
 
 import ctypes
+import errno
 import mmap
 import os
+import resource
 import threading
+from typing import Optional
 
 from . import native_build
 
@@ -23,15 +26,26 @@ _SO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_shmstore.so")
 _build_lock = threading.Lock()
 _lib = None
 
+# What an arena file holds besides its data bytes: the header and the
+# default 65536-slot table (about 4 MiB), rounded up.
+ARENA_OVERHEAD_BYTES = 8 << 20
+
+
+def arena_bytes_limit() -> Optional[int]:
+    """The most data bytes an arena can have under this process's file-size
+    limit (RLIMIT_FSIZE, which children inherit), or None when there is no
+    limit.  The arena is ONE file in /dev/shm, so a harness's `ulimit -f`
+    bounds it like any other file."""
+    soft = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+    if soft == resource.RLIM_INFINITY:
+        return None
+    return max(0, soft - ARENA_OVERHEAD_BYTES)
+
 
 def _ensure_built() -> str:
-    # Load-bearing (the store IS the data plane): a failed rebuild with
-    # no usable committed artifact raises.  With one present, fall back
-    # to it — a compiler-less host must keep running on the committed
-    # binary even when checkout mtimes suggest staleness.
+    # Load-bearing (the store IS the data plane): a failed build raises.
     with _build_lock:
-        return native_build.build_so(_SRC, _SO, ldflags=("-lpthread",),
-                                     fallback_to_stale=True)
+        return native_build.build_so(_SRC, _SO, ldflags=("-lpthread",))
 
 
 def _load():
@@ -143,10 +157,22 @@ class ShmStore:
     # -- lifecycle -----------------------------------------------------------
     @classmethod
     def create(cls, path: str, capacity: int, table_slots: int = 1 << 16) -> "ShmStore":
+        limit = arena_bytes_limit()
+        fits = "" if limit is None else (
+            f"; this process's file-size limit (RLIMIT_FSIZE) leaves room "
+            f"for {limit} data bytes")
+        if capacity < 4096:
+            raise ShmObjectStoreError(
+                f"create failed: {capacity} data bytes is no arena{fits}")
         lib = _load()
         h = lib.rts_create(path.encode(), capacity, table_slots)
+        if h == -errno.EFBIG:
+            raise ShmObjectStoreError(
+                f"create failed: a {capacity}-byte arena is too large a "
+                f"file{fits}")
         if h < 0:
-            raise ShmObjectStoreError(f"create failed: errno {-h}")
+            raise ShmObjectStoreError(
+                f"create failed: errno {-h} ({os.strerror(-h)})")
         return cls(path, h)
 
     @classmethod

@@ -119,11 +119,16 @@ def init(address: Optional[str] = None, *,
         gcs_proc, gcs_addr = node_mod.start_gcs(
             rt.session_dir, system_config=_system_config)
         rt.procs.append(gcs_proc)
-        store_cap = object_store_memory or _auto_store_bytes(cfg)
-        res = node_mod.default_resources(num_cpus, num_tpus, resources)
-        agent_proc, agent_addr, store_path, node_id = node_mod.start_agent(
-            rt.session_dir, gcs_addr, res, labels=labels,
-            store_capacity=store_cap, system_config=_system_config)
+        try:
+            store_cap = object_store_memory or _auto_store_bytes(cfg)
+            res = node_mod.default_resources(num_cpus, num_tpus, resources)
+            agent_proc, agent_addr, store_path, node_id = \
+                node_mod.start_agent(
+                    rt.session_dir, gcs_addr, res, labels=labels,
+                    store_capacity=store_cap, system_config=_system_config)
+        except BaseException:
+            _stop_procs(rt.procs)       # a failed init leaves no daemon
+            raise
         rt.procs.append(agent_proc)
         rt.gcs_address = gcs_addr
     else:
@@ -169,10 +174,15 @@ def init(address: Optional[str] = None, *,
         node_id = bytes(n0["node_id"])
         rt.session_dir = n0.get("session_dir") or node_mod.new_session_dir()
 
-    core = CoreWorker(
-        mode="driver", gcs_address=rt.gcs_address, agent_address=agent_addr,
-        store_path=store_path, node_id=node_id, session_dir=rt.session_dir)
-    core.start_driver()
+    try:
+        core = CoreWorker(
+            mode="driver", gcs_address=rt.gcs_address,
+            agent_address=agent_addr, store_path=store_path,
+            node_id=node_id, session_dir=rt.session_dir)
+        core.start_driver()
+    except BaseException:
+        _stop_procs(rt.procs)
+        raise
     rt.core = core
     _runtime = rt
     from .usage import record_session
@@ -189,8 +199,19 @@ def _auto_store_bytes(cfg) -> int:
         avail = psutil.virtual_memory().available
     except Exception:
         avail = 8 * 1024**3
-    return int(min(avail * cfg.object_store_auto_fraction,
-                   cfg.object_store_max_auto_bytes))
+    cap = int(min(avail * cfg.object_store_auto_fraction,
+                  cfg.object_store_max_auto_bytes))
+    # The arena is one /dev/shm file: it must also fit the file-size limit
+    # the process runs under (a harness's `ulimit -f`), or the agent's
+    # ftruncate fails with EFBIG and the node never starts.
+    from .shm_store import arena_bytes_limit
+    limit = arena_bytes_limit()
+    if limit is not None and limit < cap:
+        logger.warning(
+            "object store arena sized to %d bytes instead of %d to fit "
+            "this process's file-size limit (RLIMIT_FSIZE)", limit, cap)
+        cap = limit
+    return cap
 
 
 def shutdown():
@@ -204,12 +225,17 @@ def shutdown():
             rt.core.shutdown()
         except Exception:
             pass
-    for proc in reversed(rt.procs):
+    _stop_procs(rt.procs)
+
+
+def _stop_procs(procs) -> None:
+    """Terminate the daemons this process started, newest first."""
+    for proc in reversed(procs):
         try:
             proc.terminate()
         except ProcessLookupError:
             pass
-    for proc in reversed(rt.procs):
+    for proc in reversed(procs):
         try:
             proc.wait(timeout=3)
         except subprocess.TimeoutExpired:

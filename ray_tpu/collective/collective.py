@@ -297,12 +297,11 @@ class XlaCollectiveGroup:
         if (op == "sum" and jax.local_device_count() == 1
                 and x.shape[0] % self.world_size == 0):
             from jax.experimental import multihost_utils
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import Mesh, PartitionSpec as P
             mesh = self._global_mesh()
             g = multihost_utils.host_local_array_to_global_array(
                 x, mesh, P("p"))
-            out = jax.jit(shard_map(
+            out = jax.jit(jax.shard_map(
                 lambda s: jax.lax.psum_scatter(
                     s, "p", scatter_dimension=0, tiled=True),
                 mesh=mesh, in_specs=P("p"), out_specs=P("p")))(g)

@@ -45,8 +45,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.transformer import apply_rope, rms_norm, rope_angles
-from ..ops.ring_attention import (ring_attention, shard_map_compat,
-                                  ulysses_attention)
+from ..ops.ring_attention import ring_attention, ulysses_attention
 
 __all__ = ["sp_mesh", "sp_prefill_fn", "sp_suffix_prefill_fn",
            "sp_stripe_pages", "StreamAttn", "validate_sp"]
@@ -243,11 +242,11 @@ def sp_suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     body_shard = functools.partial(_sp_suffix_shard, axis_name="sp",
                                    n_shards=n, scale=scale)
     spec = P(None, "sp", None, None)
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         body_shard, mesh=mesh,
         in_specs=(spec, spec, spec, P(None, None, None),
                   P(None, None, None), P()),
-        out_specs=spec)
+        out_specs=spec, check_vma=False)
 
     def body(x, layer):
         lp, pk, pv = layer                  # pk/pv: (N, page, KV, D)

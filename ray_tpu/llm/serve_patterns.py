@@ -30,6 +30,20 @@ from .. import serve
 from .serving import EngineReplica
 
 
+def _replica_options(num_cpus: float, num_tpus: int) -> dict:
+    """Actor options of one engine replica.  The engine is one process on
+    whole chips: a fraction of a chip is refused here, before anything is
+    deployed (the node agent refuses it too, for real chips)."""
+    if num_tpus != int(num_tpus) or num_tpus < 0:
+        raise ValueError(
+            f"num_tpus={num_tpus}: an engine replica holds whole chips "
+            "(one process per chip)")
+    opts = {"num_cpus": num_cpus}
+    if num_tpus:
+        opts["resources"] = {"TPU": int(num_tpus)}
+    return opts
+
+
 def build_llm_app(preset: str = "tiny", *, name: Optional[str] = None,
                   min_replicas: int = 0, max_replicas: int = 4,
                   target_load: float = 4.0,
@@ -39,7 +53,7 @@ def build_llm_app(preset: str = "tiny", *, name: Optional[str] = None,
                   prefix_cache: bool = True, max_queue: int = 64,
                   max_tokens: int = 16, temperature: float = 0.0,
                   eos_id: Optional[int] = None, seed: int = 0,
-                  num_cpus: float = 1.0, num_tpus: float = 0.0):
+                  num_cpus: float = 1.0, num_tpus: int = 0):
     """Autoscaled continuous-batching LLM app.
 
         handle = serve.run(build_llm_app("tiny"))
@@ -51,12 +65,9 @@ def build_llm_app(preset: str = "tiny", *, name: Optional[str] = None,
     Replica count follows each replica's ``__serve_load__`` (admission
     queue depth × page-pool occupancy): bursts scale 1→N, idle decays to
     ``min_replicas`` (0 = scale-to-zero; router demand revives it)."""
-    opts = {"num_cpus": num_cpus}
-    if num_tpus:
-        opts["resources"] = {"TPU": num_tpus}
     dep = serve.deployment(
         EngineReplica, name=name or f"llm-{preset}",
-        ray_actor_options=opts,
+        ray_actor_options=_replica_options(num_cpus, num_tpus),
         autoscaling_config={
             "min_replicas": min_replicas,
             "max_replicas": max_replicas,
@@ -75,19 +86,16 @@ def build_dp_deployment(preset: str = "tiny", *, num_replicas: int = 1,
                         max_batch: int = 4, max_len: int = 128,
                         max_tokens: int = 16, temperature: float = 0.0,
                         eos_id: Optional[int] = None, seed: int = 0,
-                        num_cpus: float = 1.0, num_tpus: float = 0.0,
+                        num_cpus: float = 1.0, num_tpus: int = 0,
                         prefix_cache: bool = True,
                         page_size: int = 16):
     """Fixed-size data-parallel LLM app: `serve.run(build_dp_deployment
     (...))`.  Each replica is a full continuous-batching engine —
     concurrent requests to one replica batch per decode tick instead of
     queueing behind a closed-loop generate call."""
-    opts = {"num_cpus": num_cpus}
-    if num_tpus:
-        opts["resources"] = {"TPU": num_tpus}
     dep = serve.deployment(
         EngineReplica, name=f"llm-{preset}", num_replicas=num_replicas,
-        ray_actor_options=opts)
+        ray_actor_options=_replica_options(num_cpus, num_tpus))
     return dep.bind(preset, max_batch=max_batch, max_len=max_len,
                     max_tokens=max_tokens, temperature=temperature,
                     eos_id=eos_id, seed=seed, prefix_cache=prefix_cache,

@@ -49,7 +49,7 @@ pull) — and rides the existing telemetry flush to the GCS sink.
 `run_open_loop` is the arrival-rate-driven (never closed-loop) load
 harness: it offers requests on a fixed schedule regardless of
 completions and reports p50/p99 TTFT, inter-token latency, and
-tokens/s/replica.  `bench.py` / ``perf --check`` gate on its numbers.
+tokens/s/replica.  ``perf --check`` gates on its numbers.
 """
 
 from __future__ import annotations
@@ -104,7 +104,15 @@ class EngineReplica:
                  kv_gather_window: int = 4, paged_span: int = 64):
         import concurrent.futures
 
+        from .._private.compile_cache import enable_compile_cache
         from ..models import PRESETS
+        from ..tpu.accelerator import (TPUAcceleratorManager,
+                                       require_tpu_backend)
+        enable_compile_cache()
+        if TPUAcceleratorManager.leased_chip_ids():
+            # Deployed on real chips: the engine below takes whatever
+            # jax.devices() gives, so make sure that is the TPU.
+            require_tpu_backend("EngineReplica")
         cfg = PRESETS[preset] if isinstance(preset, str) else preset
         # Cross-host KV gather plumbing: part handles are object-plane
         # refs into OTHER replicas' arenas (published through the
@@ -778,6 +786,12 @@ class EngineReplica:
     async def pid(self) -> int:
         import os
         return os.getpid()
+
+    async def device_info(self) -> Dict[str, Any]:
+        """Platform, device kind and count, peak device memory, leased
+        chip ids and compile-cache counts of this replica's process."""
+        from ..tpu.accelerator import device_report
+        return device_report()
 
 
 # ---------------------------------------------------------------------------

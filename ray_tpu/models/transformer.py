@@ -201,10 +201,28 @@ def _xla_attention(q, k, v, causal: bool = True):
     return reference_attention(q, k, v, causal=causal)
 
 
-def _attention(cfg: TransformerConfig, q, k, v, mesh: Optional[Mesh]):
+def _flash_attention(q, k, v, mesh: Optional[Mesh],
+                     rules: LogicalAxisRules):
+    """The Pallas kernel is a custom call the GSPMD partitioner cannot
+    split — left inside a sharded jit it gathers q/k/v onto every chip.
+    So on a multi-device mesh run it per shard over the batch and head
+    axes (attention is independent across both); every shard sees whole
+    sequences."""
+    from ..ops.flash_attention import flash_attention
+    attend = functools.partial(flash_attention, causal=True)
+    if mesh is None or mesh.size == 1:
+        return attend(q, k, v)
+    q_spec = rules.spec(("batch", None, "heads", None), mesh)
+    kv_spec = rules.spec(("batch", None, "kv_heads", None), mesh)
+    return jax.shard_map(attend, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
+
+
+def _attention(cfg: TransformerConfig, q, k, v, mesh: Optional[Mesh],
+               rules: LogicalAxisRules):
     if cfg.attention_impl == "flash":
-        from ..ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True)
+        return _flash_attention(q, k, v, mesh, rules)
     if cfg.attention_impl == "ring" and mesh is not None:
         from ..ops.ring_attention import ring_attention
         return ring_attention(q, k, v, mesh=mesh, axis_name="sp", causal=True)
@@ -271,7 +289,8 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
             o = _attention(cfg, q, k, v,
-                           mesh if constrain is not _no_constrain else None)
+                           mesh if constrain is not _no_constrain else None,
+                           rules)
             o = constrain(o, ("batch", "seq", "heads", "head_dim"))
             o = jnp.einsum("bshd,hde->bse", o,
                            lp["attn"]["wo"].astype(cfg.dtype))

@@ -1,14 +1,16 @@
-"""Flash attention: Pallas TPU kernel with online softmax + XLA fallback.
+"""Flash attention: Pallas TPU kernel with online softmax, plus the XLA
+reference it is checked against.
 
 The hot attention op for the model zoo (models/transformer.py selects it via
 TransformerConfig.attention_impl="flash").  Tiled over (batch*head, q-block,
-kv-block) with the kv dimension innermost so the running max/денom/accumulator
-live in VMEM scratch across kv steps — the standard flash recipe, written for
-the MXU/VMEM model of /opt/skills/guides/pallas_guide.md.
+kv-block) with the kv dimension innermost so the running max/denominator/
+accumulator live in VMEM scratch across kv steps — the standard flash recipe,
+written for the MXU/VMEM model of /opt/skills/guides/pallas_guide.md.
 
-Falls back to a fused-by-XLA reference implementation off-TPU or for shapes
-the kernel doesn't tile well (head_dim not multiple of 128-lane tiling, tiny
-sequences), so the same model code runs on the CPU test mesh.
+On a TPU `flash_attention` runs the kernel or raises naming the shape
+condition that failed; it never swaps in another implementation there.  Off
+the TPU (the CPU test mesh) the same call computes `reference_attention`,
+which is also the parity oracle for the kernel.
 """
 
 from __future__ import annotations
@@ -349,20 +351,27 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 1024, block_k: int = 1024):
     """Public entry: q (B,S,Hq,D), k/v (B,S,Hkv,D) → (B,S,Hq,D).
 
-    Dispatches to the Pallas kernel on TPU when shapes tile cleanly,
-    otherwise to the XLA reference path.  Fully differentiable: the TPU
-    path carries a custom VJP with Pallas dq and dk/dv kernels (the
-    standard flash backward — recompute p from saved logsumexp, one
-    rowwise delta = Σ do·o correction term).
+    On a TPU this is the Pallas kernel, fully differentiable: a custom VJP
+    with Pallas dq and dk/dv kernels (the standard flash backward —
+    recompute p from saved logsumexp, one rowwise delta = Σ do·o correction
+    term).  A shape the kernel cannot tile raises ValueError there.  On any
+    other platform it is `reference_attention`.
     """
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
-    on_tpu = jax.devices()[0].platform == "tpu"
-    bq, bk = min(block_q, S), min(block_k, S)
-    tiles_ok = (S % bq == 0 and S % bk == 0 and D % 128 == 0
-                and Hq % Hkv == 0)
-    if not (on_tpu and tiles_ok):
+    if jax.devices()[0].platform != "tpu":
         return reference_attention(q, k, v, causal=causal, scale=scale)
+    bq, bk = min(block_q, S), min(block_k, S)
+    unmet = [why for ok, why in (
+        (S % bq == 0 and S % bk == 0,
+         f"seq {S} is not a multiple of blocks ({bq}, {bk})"),
+        (D % 128 == 0, f"head_dim {D} is not a multiple of 128 lanes"),
+        (Hq % Hkv == 0, f"{Hq} query heads do not group over {Hkv} kv heads"),
+    ) if not ok]
+    if unmet:
+        raise ValueError(
+            "flash_attention cannot tile this shape on the TPU: "
+            + "; ".join(unmet) + ' (use attention_impl="xla")')
     return _flash_diff(q, k, v, causal, scale, bq, bk)

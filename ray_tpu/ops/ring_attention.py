@@ -31,20 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions: new releases expose it at the
-    top level with `check_vma`; older ones (<=0.4.x) only have
-    `jax.experimental.shard_map.shard_map` with `check_rep`.  Both knobs
-    mean the same thing here — skip the replication check, the bodies do
-    explicit collectives."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
 def _grouped_scores(q, k, scale):
     """q (B,Sq,Hkv,G,D), k (B,Sk,Hkv,D) → scores (B,Hkv,G,Sq,Sk) f32."""
     return jnp.einsum("bskgd,btkd->bkgst", q, k,
@@ -131,10 +117,10 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     body = functools.partial(_ring_attention_shard, axis_name=axis_name,
                              causal=causal, scale=scale, n_shards=n)
     spec = _qkv_specs(axis_name, batch_axes, heads_axis)
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec),
-        out_specs=spec,
+        out_specs=spec, check_vma=False,
     )(q, k, v)
 
 
@@ -168,5 +154,5 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
         return heads_to_seq(o)
 
     spec = _qkv_specs(axis_name, batch_axes, heads_axis)
-    return shard_map_compat(body, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -20,11 +20,14 @@ Canonical axis order (outer→inner, DCN→ICI):
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 AXES = ("pp", "dp", "fsdp", "sp", "tp")
 # Expert parallelism reuses the fsdp×sp submesh in MoE layers (same devices,
@@ -96,7 +99,12 @@ def build_mesh(spec: Optional[MeshSpec] = None,
             dev_array = mesh_utils.create_device_mesh(
                 shape, devices=devices,
                 allow_split_physical_axes=allow_split_physical_axes)
-        except (ValueError, NotImplementedError):
+        except (ValueError, NotImplementedError) as e:
+            logger.warning(
+                "build_mesh: no topology-aware layout for %s over %d %s "
+                "devices (%s); using enumeration order — logical axes may "
+                "not sit on neighbouring chips", dict(zip(AXES, shape)),
+                len(devices), devices[0].device_kind, e)
             dev_array = np.asarray(devices).reshape(shape)
     else:
         dev_array = np.asarray(devices).reshape(shape)

@@ -439,6 +439,11 @@ def run(app: Application, *, name: Optional[str] = None,
     controller = _get_or_create_controller()
     dep = app.deployment
     dep_name = name or dep.name
+    opts = dep.ray_actor_options or {}
+    tpus = opts.get("num_tpus") or (opts.get("resources") or {}).get("TPU")
+    if tpus:
+        from ray_tpu.tpu.accelerator import require_cluster_tpus
+        require_cluster_tpus(tpus, f"a replica of deployment {dep_name!r}")
     blob = cloudpickle.dumps(dep._target)
     ray_tpu.get(controller.deploy.remote(
         dep_name, blob, app.init_args, app.init_kwargs,

@@ -36,7 +36,11 @@ def reserve_tpu_slice(pod_type: Optional[str] = None,
     """
     mgr = TPUAcceleratorManager
     pod_type = pod_type or mgr.pod_type() or "local"
-    chips = chips_per_host or mgr.num_chips() or 1
+    chips = chips_per_host or mgr.num_chips()
+    if not chips:
+        raise RuntimeError(
+            "reserve_tpu_slice: this host exposes no TPU chip and no "
+            "chips_per_host was given")
     hosts = num_hosts or mgr.num_hosts_in_slice()
     if hosts <= 1:
         bundles = [{"TPU": float(chips)}]

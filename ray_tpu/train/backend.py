@@ -64,13 +64,13 @@ class _JaxBackend(Backend):
         self._initialized = False
 
     def _pin_local_devices(self, strict: bool) -> None:
-        """Pin this worker's platform + local device count before backend
-        init (reference: config.py:29-57 sets JAX_PLATFORMS per worker).
-        On TPU the host's chips define local devices; on CPU we must fix
+        """Pin this worker's local device count before backend init
+        (reference: config.py:29-57 sets JAX_PLATFORMS per worker).  With
+        use_tpu the agent already confined this process to its lease's
+        chips (JAX_PLATFORMS=tpu + TPU_VISIBLE_CHIPS); on CPU we must fix
         the per-process virtual device count explicitly."""
         import jax
         if self.config.use_tpu:
-            os.environ.setdefault("JAX_PLATFORMS", "tpu")
             return
         n = self.config.cpu_devices_per_process
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -81,12 +81,7 @@ class _JaxBackend(Backend):
             f"{flags} --xla_force_host_platform_device_count={n}").strip()
         try:
             jax.config.update("jax_platforms", "cpu")
-            try:
-                jax.config.update("jax_num_cpu_devices", n)
-            except AttributeError:
-                # Older jax has no jax_num_cpu_devices; the XLA_FLAGS
-                # device-count override above does the same job.
-                pass
+            jax.config.update("jax_num_cpu_devices", n)
         except RuntimeError as e:
             # Backend already initialized in this process — device count
             # can no longer change.  Only fatal if the count is wrong AND
@@ -102,17 +97,22 @@ class _JaxBackend(Backend):
 
     def on_start(self, worker_ctx: Dict[str, Any]) -> None:
         self._pin_local_devices(strict=worker_ctx["world_size"] > 1)
-        if worker_ctx["world_size"] <= 1:
-            # Single worker: jax works standalone; don't start a coordinator.
-            return
-        import jax
-        coordinator = (f"{worker_ctx['master_addr']}:"
-                       f"{worker_ctx['master_port']}")
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=worker_ctx["world_size"],
-            process_id=worker_ctx["world_rank"])
-        self._initialized = True
+        if worker_ctx["world_size"] > 1:
+            # (A single worker runs standalone: no coordinator.)
+            import jax
+            coordinator = (f"{worker_ctx['master_addr']}:"
+                           f"{worker_ctx['master_port']}")
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=worker_ctx["world_size"],
+                process_id=worker_ctx["world_rank"])
+            self._initialized = True
+        if self.config.use_tpu:
+            # use_tpu is a promise: a worker that lost its chip must fail
+            # here, not train on whatever platform JAX fell back to.
+            from ..tpu.accelerator import require_tpu_backend
+            require_tpu_backend("JaxTrainer(use_tpu=True) worker "
+                                f"rank {worker_ctx['world_rank']}")
 
     def on_shutdown(self) -> None:
         if self._initialized:

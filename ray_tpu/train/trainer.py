@@ -42,9 +42,11 @@ class ScalingConfig:
         if self.resources_per_worker:
             return dict(self.resources_per_worker)
         if self.use_tpu:
-            from ..tpu.accelerator import TPUAcceleratorManager
-            chips = TPUAcceleratorManager.num_chips() or 4
-            return {"TPU": float(chips)}
+            # One worker per TPU host, holding all of that host's chips.
+            # The count comes from what the cluster's nodes advertise —
+            # never from a guess, and never by touching JAX in the driver.
+            from ..tpu.accelerator import require_cluster_tpus
+            return {"TPU": require_cluster_tpus(1, "use_tpu=True")}
         return {"CPU": 1.0}
 
 

@@ -147,6 +147,10 @@ class WorkerGroup:
         self.workers: List[Any] = []
 
     def start(self, backend_config, timeout_s: float = 120.0) -> None:
+        if self.resources_per_worker.get("TPU"):
+            from ..tpu.accelerator import require_cluster_tpus
+            require_cluster_tpus(self.resources_per_worker["TPU"],
+                                 "a TrainWorker")
         if self.pg is None:
             bundles = [dict(self.resources_per_worker)
                        for _ in range(self.num_workers)]

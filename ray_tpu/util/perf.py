@@ -1532,13 +1532,13 @@ def _latest_committed_bench(repo_root: str = "."):
             host, _ = json.JSONDecoder().raw_decode(tail, mh.end())
         except json.JSONDecodeError:
             pass
-    # Entries are [value, vs_ref, ...] lists (bench.py compact form).
+    # Entries are [value, vs_ref, ...] lists (the --compact form).
     return path, ({k: (v[0] if isinstance(v, list) else v)
                    for k, v in table.items()}, host)
 
 
 def _host_fingerprint():
-    """Cheap host-class probe matching the fields bench.py records in
+    """Cheap host-class probe matching the fields a baseline records in
     micro_host: core count plus a ~0.15s memcpy-bandwidth sample (two
     hosts with the same core count can differ 5-10x in speed class —
     absolute ops/s gates are meaningless across that gap)."""
@@ -1889,8 +1889,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--min-time-s", type=float, default=2.0)
     ap.add_argument("--compact", action="store_true",
-                    help="print one JSON dict {name: [value, vs_ref]} "
-                         "(consumed by bench.py)")
+                    help="print one JSON dict {name: [value, vs_ref]}")
     ap.add_argument("--check", action="store_true",
                     help="CI gate: compare the control-plane metrics "
                          "against the last committed BENCH_*.json and exit "

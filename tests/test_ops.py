@@ -85,8 +85,7 @@ def test_ring_attention_in_model():
     params = init_params(cfg, jax.random.key(0))
     toks = jnp.asarray(np.random.default_rng(0).integers(
         1, cfg.vocab_size, (4, 32)), jnp.int32)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-            else mesh:
+    with jax.set_mesh(mesh):
         logits = jax.jit(lambda p, t: forward(p, t, cfg, mesh))(params, toks)
     ref_cfg = dataclasses.replace(cfg, attention_impl="xla")
     ref = jax.jit(lambda p, t: forward(p, t, ref_cfg, mesh))(params, toks)

@@ -560,41 +560,58 @@ def test_non_minimal_raw_header_is_safe_under_both_framers(mode):
     asyncio.run(main())
 
 
-def test_stale_source_mtime_keeps_committed_so(tmp_path, monkeypatch):
-    """Compiler-less host + checkout that stamped the source newer than
-    the committed .so: the committed artifact must keep loading (ABI
-    check still guards real incompatibility), not silently disable the
-    native framer."""
-    _skip_without_native()
-    import shutil
+def test_native_build_staleness_is_by_source_content(tmp_path, monkeypatch):
+    """A copy of the tree promises nothing about mtimes: the binary is
+    reused exactly while its source reads as it did at the last build,
+    rebuilt when the content changes, and a failed build raises instead
+    of loading a binary that no longer matches its source."""
     from ray_tpu._private import native_build
-    so = tmp_path / "_rpcframe.so"
-    shutil.copy(rpcframe._SO, so)
-    src = tmp_path / "rpcframe.cc"
-    src.write_text("// newer than the .so")
-    os.utime(so, (1, 1))                      # so mtime << src mtime
+    src = tmp_path / "lib.cc"
+    so = tmp_path / "_lib.so"
+    src.write_text('extern "C" int answer() { return 1; }\n')
+    calls = []
+
+    def fake_gxx(cmd, **_kw):
+        calls.append(cmd)
+        with open(cmd[cmd.index("-o") + 1], "wb") as f:
+            f.write(b"built from: " + src.read_bytes())
+
+    monkeypatch.setattr(native_build.subprocess, "run", fake_gxx)
+    assert native_build.build_so(str(src), str(so)) == str(so)
+    assert len(calls) == 1
+    # Same content, mtimes scrambled both ways: still current.
+    os.utime(src, (2_000_000_000, 2_000_000_000))
+    os.utime(so, (1, 1))
+    native_build.build_so(str(src), str(so))
+    assert len(calls) == 1
+    # New content, source made to look OLDER than the binary: rebuilt.
+    src.write_text('extern "C" int answer() { return 2; }\n')
+    os.utime(src, (1, 1))
+    native_build.build_so(str(src), str(so))
+    assert len(calls) == 2 and b"return 2" in so.read_bytes()
+    # New content and no compiler: raise, never keep the stale binary.
+    src.write_text('extern "C" int answer() { return 3; }\n')
     monkeypatch.setattr(native_build.subprocess, "run",
                         lambda *a, **k: (_ for _ in ()).throw(
                             FileNotFoundError("g++ not found")))
-    out = native_build.build_so(str(src), str(so),
-                                fallback_to_stale=True)
-    assert out == str(so)
     with pytest.raises(FileNotFoundError):
-        native_build.build_so(str(src), str(tmp_path / "missing.so"))
+        native_build.build_so(str(src), str(so))
 
 
 # ---------------------------------------------------------------- fallback --
 def test_corrupt_extension_falls_back_to_python(tmp_path, caplog):
     """A corrupt/missing .so must degrade to the pure-Python framer with
     one warning — never crash, never half-enable."""
+    from ray_tpu._private import native_build
     bad = tmp_path / "_rpcframe.so"
     bad.write_bytes(b"this is not an ELF")
-    # Point the loader at garbage (and a source file that's "older").
+    # Point the loader at garbage stamped as built from today's source.
+    (tmp_path / "_rpcframe.so.sha256").write_text(
+        native_build.source_digest(rpcframe._SRC))
     old_so, old_lib, old_failed = rpcframe._SO, rpcframe._lib, \
         rpcframe._failed
     try:
         rpcframe._reset_for_tests(str(bad))
-        os.utime(bad)
         assert not rpcframe.available()
         assert not rpcframe.available()     # second call: no second try
 
