@@ -317,7 +317,7 @@ _define("flight_recorder_capacity", 4096,
         "ray_tpu_flight_recorder_dropped_total)")
 _define("flight_recorder_categories", "",
         "comma-separated category gate for the flight recorder "
-        "(lease,transfer,sched,request,anomaly); empty = all "
+        "(lease,transfer,request,anomaly); empty = all "
         "categories on")
 _define("flight_recorder_sample_n", 1,
         "record 1 of every N instant events per category (spans are "

@@ -25,7 +25,11 @@ Three layers, all composed from primitives that already exist:
   `sys._current_frames`), tasks RUNNING past a multiple of their
   function's historical p95 (`TaskHangTracker`, fed by the existing
   task-event stream), leases granted-but-never-RUNNING (agent-side),
-  serving requests admitted-but-token-silent (serving-side).  Every
+  serving requests admitted-but-token-silent (serving-side).  A poll
+  that itself woke late by more than half the wedge threshold means the
+  PROCESS was not run: it records one `process_stalled` anomaly, with
+  no notify and so no bundle, and no detector runs for one wedge
+  threshold after the thaw.  Every
   firing goes through `record_anomaly()`: a typed `anomaly` recorder
   event + a `ray_tpu_anomaly_total{kind,...}` counter + an optional
   notify callback that forwards the anomaly to the GCS.
@@ -294,6 +298,7 @@ class Watchdog(threading.Thread):
         self.notify = notify
         self.poll_s = max(0.05, float(poll_s))
         self._stop_evt = threading.Event()
+        self._quiet_until = 0.0         # monotonic; see note_wake
         self.fired: List[dict] = []
 
     def stop(self) -> None:
@@ -317,8 +322,31 @@ class Watchdog(threading.Thread):
         return out
 
     def run(self) -> None:
-        while not self._stop_evt.wait(self.poll_s):
-            self.poll_once()
+        while True:
+            due = time.monotonic() + self.poll_s
+            if self._stop_evt.wait(self.poll_s):
+                return
+            if not self.note_wake(time.monotonic() - due):
+                self.poll_once()
+
+    def note_wake(self, late_s: float) -> bool:
+        """A wake `late_s` after it was due; returns whether this poll is
+        skipped.  Late by more than half the wedge threshold, this thread
+        itself was not run — the whole process stood still (a TPU client
+        starting stops every process on the machine for seconds), so
+        every loop's stamp is stale and none is wedged.  It records ONE
+        `process_stalled` instant with the length (no notify, so no
+        capture bundle), and no detector runs until a whole wedge
+        threshold has passed since the thaw: a loop that is still stale
+        then has been given that long to stamp afresh (an agent's loop
+        was still working off its backlog 0.8 s after an 8 s stop)."""
+        now = time.monotonic()
+        wedge_s = get_config().diagnosis_loop_wedge_s
+        if late_s > wedge_s / 2.0:
+            self._quiet_until = now + wedge_s
+            record_anomaly("process_stalled", daemon=self.daemon_name,
+                           node_id=self.node_id, stalled_s=round(late_s, 3))
+        return now < self._quiet_until
 
 
 # ---------------------------------------------------------------------------

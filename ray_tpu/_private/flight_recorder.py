@@ -20,13 +20,24 @@ Categories ("plane" granularity, gated via config
                prefetch), extending the existing PREFETCH task event
     transfer   object-plane timelines: pull start/commit, chunk-wave
                stream, hedge fired, swarm source set
-    sched      submit-side plane (reserved; SUBMITTED task events
-               already cover the per-task view)
-    request    LLM serving lifecycle (llm/serving.py + llm/engine.py):
-               request:admit, prefill (w/ cached_tokens), decode
-               (per tick, w/ batch), sample_sync, request:cancelled
+    request    LLM serving lifecycle (llm/serving.py + llm/engine.py).
+               Per request, sharing the engine's request id:
+               request:lock_wait (entry -> replica lock held and
+               enqueued), request:admit (enqueue -> first token fanned
+               out), prefill (w/ cached_tokens; `n` = the tick that
+               admitted it, `new_program` = 1 when the call compiled),
+               request:cancelled, request:kv_broken, sp:gather.
+               Per tick, sharing the tick number `n`
+               (llm/tick_phases.py; the same boundaries feed
+               EngineReplica.debug_stats()["tick"]): tick (lock held ->
+               fan-out done) and its pieces tick:expire, tick:hop,
+               step:admit (children prefill, sample_sync), step:chunk,
+               step:emit, decode (w/ batch; children decode:prep,
+               decode:dispatch, decode:wait), tick:fan_out; between
+               ticks tick:turn and tick:idle
     anomaly    diagnosis-plane detector firings (_private/diagnosis.py):
-               loop_wedged, task_hung, lease_stalled, serving_silent —
+               loop_wedged, task_hung, lease_stalled, serving_silent,
+               process_stalled (a watchdog that itself woke late) —
                rendered as global instant marks on the timeline
 
 Overflow drops the OLDEST record and counts it (`dropped`) — the
@@ -112,6 +123,14 @@ class FlightRecorder:
         if not self.active(cat):
             return
         self._push((t0_ns, clocks.mono_ns(), cat, name, id, args or None))
+
+    def span_at(self, cat: str, name: str, t0_ns: int, t1_ns: int,
+                id: bytes = b"", **args) -> None:
+        """A span whose two stamps the caller already took (mono-ns):
+        spans that share a boundary share the stamp, so they tile."""
+        if not self.active(cat):
+            return
+        self._push((t0_ns, t1_ns, cat, name, id, args or None))
 
     @contextmanager
     def span(self, cat: str, name: str, id: bytes = b"", **args):

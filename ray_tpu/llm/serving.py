@@ -40,11 +40,17 @@ from the loop's tick events:
     including scale-to-zero (see serve/_private/controller.py).
 
 Observability: every phase is stamped into the flight recorder under
-the ``request`` category — ``request:admit`` (enqueue → admitted, with
-queue depth and the count of requests already decoding), ``prefill``
-(with ``cached_tokens`` for prefix-cache hits), ``decode`` (per tick,
-with batch size) and ``sample_sync`` (the batched device→host sample
-pull) — and rides the existing telemetry flush to the GCS sink.
+the ``request`` category and rides the existing telemetry flush to the
+GCS sink.  Per request: ``request:lock_wait`` (entry → the replica's
+lock held and the request enqueued), ``request:admit`` (enqueue →
+admitted, with queue depth and the count of requests already decoding),
+``prefill`` (with ``cached_tokens`` for prefix-cache hits and the tick
+``n`` that admitted it).  Per tick: the phases of ``tick_phases.py`` —
+``tick`` and, tiling it and the stretch to the next one, ``tick:turn``,
+``tick:expire``, ``tick:hop``, ``step:admit``, ``decode`` (with batch
+size; ``decode:prep`` / ``:dispatch`` / ``:wait``), ``sample_sync`` (the
+batched device→host sample pull), ``step:emit``, ``tick:fan_out`` —
+whose cumulative nanoseconds ``debug_stats()["tick"]`` also serves.
 
 `run_open_loop` is the arrival-rate-driven (never closed-loop) load
 harness: it offers requests on a fixed schedule regardless of
@@ -147,6 +153,8 @@ class EngineReplica:
         # EMA of request wall time: the shed path's queue-wait estimate.
         self._req_s_ema = 0.25
         self._ticks = 0
+        self._phases = self.engine.phases
+        self._enqueued = 0              # since the last tick began
         self._max_active = 0
         self._shed = 0
         self._cancelled = 0
@@ -314,22 +322,32 @@ class EngineReplica:
         out to their streams.  Engine compute runs on an executor thread
         so this loop (and the whole worker runtime) stays responsive."""
         loop = asyncio.get_running_loop()
+        ph = self._phases
+        ph.to("turn")
         while True:
             try:
                 async with self._lock:
+                    enqueued, self._enqueued = self._enqueued, 0
+                    ph.tick_begin(enqueued, self.engine.active_requests,
+                                  self.engine.queue_depth)
                     self._expire_overdue()
                     if self.engine.has_unfinished():
+                        ph.to("hop")
                         done = await loop.run_in_executor(
                             None, self.engine.step)
+                        ph.to("fan_out")
                         self._ticks += 1
                         self._max_active = max(self._max_active,
                                                self.engine.active_requests
                                                + len(done))
                         self._fan_out(self.engine.take_tick_events(), done)
                         self._flush_gauges()
+                    ph.tick_end()
                 if not self.engine.has_unfinished():
                     self._wake.clear()
+                    ph.to("idle", enqueued=0)
                     await self._wake.wait()
+                    ph.to("turn")
                 else:
                     # One loop turn between ticks: lets freshly arrived
                     # requests enqueue (the lock is FIFO-fair) so they are
@@ -415,6 +433,21 @@ class EngineReplica:
                                             len(req.out)))
 
     # ------------------------------------------------------------ streams --
+    def _track(self, rid: int, deadline: Optional[float]) -> asyncio.Queue:
+        """Under the lock, right after the engine took request `rid`: its
+        stream's queue and metadata (`t0` opens its `request:admit`
+        span), and the decode loop woken."""
+        q: asyncio.Queue = asyncio.Queue()
+        self._waiters[rid] = q
+        self._meta[rid] = {"deadline": deadline,
+                           "t0": flight_recorder.recorder().begin(),
+                           "t_mono": time.monotonic(),
+                           "admitted": False, "finished": False}
+        self._enqueued += 1
+        self._ensure_loop()
+        self._wake.set()
+        return q
+
     async def _stream(self, prompt_tokens: Optional[Sequence[int]],
                       opts: Optional[dict], *, external: Optional[tuple]
                       = None, cache_prompt: Optional[Sequence[int]] = None
@@ -424,7 +457,7 @@ class EngineReplica:
         engine rejection) raise out of the first `anext`."""
         params = self._params(opts)
         deadline = deadlines.get()
-        rec = flight_recorder.recorder()
+        t_in = flight_recorder.recorder().begin()
         async with self._lock:
             # Shed check INSIDE the lock: concurrent arrivals during a
             # decode tick must each see the true queue depth, not a
@@ -436,13 +469,14 @@ class EngineReplica:
                     blob, first, params, prompt_tokens=cache_prompt)
             else:
                 rid = self.engine.add_request(list(prompt_tokens), params)
-            q: asyncio.Queue = asyncio.Queue()
-            self._waiters[rid] = q
-            self._meta[rid] = {"deadline": deadline, "t0": rec.begin(),
-                               "t_mono": time.monotonic(),
-                               "admitted": False, "finished": False}
-        self._ensure_loop()
-        self._wake.set()
+            # The wait for the tick that held the lock: the part of a
+            # client's time to first token that neither the engine's
+            # `request:admit` nor the serve library owns.
+            flight_recorder.recorder().end(
+                "request", "request:lock_wait", t_in,
+                id=rid.to_bytes(8, "little"),
+                queued=self.engine.queue_depth)
+            q = self._track(rid, deadline)
         try:
             while True:
                 item = await q.get()
@@ -595,19 +629,12 @@ class EngineReplica:
         blob = await self._resolve_handoff(handoff)
         params = self._params(handoff.get("opts"))
         deadline = deadlines.get()
-        rec = flight_recorder.recorder()
         async with self._lock:
             self._maybe_shed(deadline)
             rid = self.engine.add_external_request(
                 blob, handoff["first"], params,
                 prompt_tokens=handoff.get("prompt"))
-            q: asyncio.Queue = asyncio.Queue()
-            self._waiters[rid] = q
-            self._meta[rid] = {"deadline": deadline, "t0": rec.begin(),
-                               "t_mono": time.monotonic(),
-                               "admitted": False, "finished": False}
-        self._ensure_loop()
-        self._wake.set()
+            self._track(rid, deadline)
         return rid
 
     async def collect(self, rid: int) -> Dict[str, Any]:
@@ -745,19 +772,12 @@ class EngineReplica:
         node's pool pages."""
         params = self._params(handoff.get("opts"))
         deadline = deadlines.get()
-        rec = flight_recorder.recorder()
         async with self._lock:
             self._maybe_shed(deadline)
             rid = self.engine.add_paged_request(
                 handoff["parts"], handoff["len"], handoff["first"],
                 params, prompt_tokens=handoff.get("prompt"))
-            q: asyncio.Queue = asyncio.Queue()
-            self._waiters[rid] = q
-            self._meta[rid] = {"deadline": deadline, "t0": rec.begin(),
-                               "t_mono": time.monotonic(),
-                               "admitted": False, "finished": False}
-        self._ensure_loop()
-        self._wake.set()
+            self._track(rid, deadline)
         return rid
 
     async def decode_paged(self, handoff: dict) -> Dict[str, Any]:
@@ -781,7 +801,8 @@ class EngineReplica:
                 "kv_pages_total": e.kv_pages_total,
                 "load": self.__serve_load__(),
                 "prefix_cache": e.prefix_cache_stats(),
-                "kv_gather": e.kv_gather_stats()}
+                "kv_gather": e.kv_gather_stats(),
+                "tick": self._phases.snapshot()}
 
     async def pid(self) -> int:
         import os

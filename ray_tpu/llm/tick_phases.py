@@ -1,0 +1,143 @@
+"""Phases of the serving tick: where the host's time between two decode
+steps goes.
+
+Every instant of a running `EngineReplica` belongs to exactly one LEAF
+phase: the replica's decode loop and the engine's `step()` call
+`TickPhases.to(<leaf>)` at each boundary, which closes the open phase and
+opens the next at ONE monotonic stamp.  That one call feeds three sinks:
+
+  counters    cumulative ns per leaf (`snapshot()`, served as
+              `EngineReplica.debug_stats()["tick"]`): exact window totals
+              with no ring to overflow, counted whether or not the flight
+              recorder is on;
+  spans       the flight recorder's `request` category (the names in
+              `_SPAN` below; `_private/flight_recorder.py` lists them
+              all), every span of one tick carrying the tick number `n`;
+  annotation  a `jax.profiler.TraceAnnotation` named `ray_tpu/tick:<leaf>`
+              held open for as long as the phase is, so a profile of the
+              replica shows on its host plane, in the profiler's own
+              timebase, what the host did in each device gap.  (A TraceMe
+              costs well under a microsecond while no profile is taken,
+              and is recorded whole at its exit, on the exiting thread's
+              line: `hop` opens on one thread and closes on the other.)
+
+Leaves: `idle` (nothing unfinished, waiting for a request), `turn` (tick
+end -> the loop holds the replica's lock again: release, one loop turn,
+the `_stream` calls that enqueue meanwhile, other holders of the lock),
+`expire`, `hop` (the executor hand-off, there and back), `admit` and
+`chunk` (SELF time of `step:admit` / `step:chunk`: reserve, cache lookup,
+page tables), `prefill` and `sample_sync` (inside either), `prep`
+(key split and the uploads), `dispatch`, `wait` (the blocking read-back),
+`emit` (the emit/retire loops: requests finished at admission and
+paged-context slots before the decode step, every slot after it),
+`fan_out`.  Parent spans (`tick`, `step:admit`, `step:chunk`, `decode`)
+are stamped by their callers from the stamps `to()` returns.
+
+One thread at a time drives this object: the loop's thread, or — while the
+loop awaits `step()` — the executor's.  Engine work outside a tick
+(`prefill_only`, `sample_first`: another holder of the replica's lock)
+records its spans as before and counts as the loop's `turn`.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+from .._private import clocks, flight_recorder
+
+LEAVES = ("idle", "turn", "expire", "hop", "admit", "prefill",
+          "sample_sync", "chunk", "prep", "dispatch", "wait", "emit",
+          "fan_out")
+
+# Leaf -> the span a finished piece of it is recorded as.  The others are
+# self time of a parent span, or spans their caller records with the
+# request's id and its own arguments (`prefill`, `sample_sync`).
+_SPAN = {"idle": "tick:idle", "turn": "tick:turn", "expire": "tick:expire",
+         "hop": "tick:hop", "fan_out": "tick:fan_out",
+         "prep": "decode:prep", "dispatch": "decode:dispatch",
+         "wait": "decode:wait", "emit": "step:emit"}
+
+
+class TickPhases:
+
+    def __init__(self):
+        self.n = 0                      # ticks begun
+        self.ns: Dict[str, int] = dict.fromkeys(LEAVES, 0)
+        self.in_tick = False            # a replica's loop drives this tick
+        self.in_step = False            # inside LLMEngine.step()
+        # (leaf, since): replaced whole, so that `snapshot()` on another
+        # thread reads a consistent pair.
+        self._open: Tuple[Optional[str], int] = (None, 0)
+        self._note: Optional[TraceAnnotation] = None
+        self._tick: Tuple[int, int, int] = (0, 0, 0)
+
+    # ------------------------------------------------------------ leaves --
+    def to(self, leaf: Optional[str], **closing: Any) -> int:
+        """Close the open phase and open `leaf` (None: nothing) at one
+        stamp, which is returned.  `closing` are arguments of the span of
+        the phase that closes."""
+        now = clocks.mono_ns()
+        cur, since = self._open
+        if cur is not None:
+            self.ns[cur] += now - since
+            self._note.__exit__(None, None, None)
+            name = _SPAN.get(cur)
+            if name is not None:
+                if cur != "idle":
+                    closing["n"] = self.n
+                flight_recorder.recorder().span_at(
+                    "request", name, since, now, **closing)
+        self._open = (leaf, now)
+        if leaf is not None:
+            self._note = TraceAnnotation("ray_tpu/tick:" + leaf)
+            self._note.__enter__()
+        return now
+
+    def enter(self, leaf: str) -> Tuple[int, Optional[str]]:
+        """Start of work that is a leaf of its own inside `step()`
+        (`prefill`, `sample_sync`) and a plain span outside it.  Returns
+        the token `leave` takes."""
+        if not self.in_step:
+            return clocks.mono_ns(), None
+        back = self._open[0]
+        return self.to(leaf), back
+
+    def leave(self, token: Tuple[int, Optional[str]], name: str,
+              id: bytes = b"", **args: Any) -> None:
+        """End of it: back to the phase it interrupted, and the span
+        `name` over exactly its extent."""
+        t0, back = token
+        t1 = clocks.mono_ns() if back is None else self.to(back)
+        flight_recorder.recorder().span_at("request", name, t0, t1, id,
+                                           **args)
+
+    def span(self, name: str, t0: int, t1: int, **args: Any) -> None:
+        """A parent span of this tick, from stamps `to()` returned."""
+        flight_recorder.recorder().span_at("request", name, t0, t1,
+                                           n=self.n, **args)
+
+    # -------------------------------------------------------------- tick --
+    def tick_begin(self, enqueued: int, active: int, waiting: int) -> None:
+        """The loop holds the lock: `turn` closes under the NEW tick's
+        number (a turn that ended in `idle` kept the old one)."""
+        self.n += 1
+        self.in_tick = True
+        self._tick = (self.to("expire", enqueued=enqueued), active, waiting)
+
+    def tick_end(self) -> None:
+        t0, active, waiting = self._tick
+        self.in_tick = False
+        self.span("tick", t0, self.to("turn"), active=active,
+                  waiting=waiting)
+
+    # ---------------------------------------------------------- counters --
+    def snapshot(self) -> Dict[str, Any]:
+        """Ticks begun and cumulative ns per leaf, the open phase counted
+        up to now: two snapshots bracket a window exactly."""
+        ns = dict(self.ns)
+        cur, since = self._open
+        if cur is not None:
+            ns[cur] += clocks.mono_ns() - since
+        return {"n": self.n, "ns": ns}

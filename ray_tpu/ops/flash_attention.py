@@ -254,6 +254,7 @@ def _flash_forward_pallas(q, k, v, causal, scale, bq, bk):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_fwd",
     )(qh, kh, vh)
     o = out.reshape(B, Hq, S, D).transpose(0, 2, 1, 3)
     return o, (out, lse)        # heads-layout residuals
@@ -290,6 +291,7 @@ def _flash_backward_pallas(q, k, v, oh, lse, do, causal, scale, bq, bk):
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dq",
     )(qh, kh, vh, doh, lse, delta)
 
     # dk/dv per QUERY head (grid ki outer, qi inner), then the GQA group
@@ -318,6 +320,7 @@ def _flash_backward_pallas(q, k, v, oh, lse, do, causal, scale, bq, bk):
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dkv",
     )(qh, kh, vh, doh, lse, delta)
 
     dq = dqh.reshape(B, Hq, S, D).transpose(0, 2, 1, 3)

@@ -252,6 +252,74 @@ def test_watchdog_polls_detectors_and_survives_bad_ones():
     assert len(w.fired) <= 64
 
 
+class _StaleLoop:
+    """A loopmon entry 10 s stale on a live thread (this one), and a
+    private recorder to read the anomalies from."""
+
+    def __enter__(self):
+        loopmon._RATIOS["tst_stale"] = (0.0, time.monotonic() - 10.0,
+                                        threading.get_ident())
+        self.old = flight_recorder._recorder
+        self.rec = flight_recorder._recorder = \
+            flight_recorder.FlightRecorder()
+        return self
+
+    def __exit__(self, *exc):
+        loopmon._RATIOS.pop("tst_stale", None)
+        flight_recorder._recorder = self.old
+
+    def anomalies(self):
+        return [r["name"] for r in self.rec.drain() if r["cat"] == "anomaly"]
+
+
+def _run_watchdog(monkeypatch, oversleep_s):
+    """One poll of a real Watchdog thread body over a stale loop, with
+    the wedge threshold at 0.4 s; its wake comes `oversleep_s` late."""
+    from ray_tpu._private.config import get_config
+    monkeypatch.setitem(get_config()._values, "diagnosis_loop_wedge_s", 0.4)
+    notified = []
+    w = diagnosis.Watchdog(
+        daemon_name="t", node_id="n1", notify=notified.append, poll_s=0.05,
+        detectors=[diagnosis.loop_wedge_detector()])
+    waits = []
+
+    def wait(timeout):
+        waits.append(timeout)
+        if len(waits) > 1:
+            return True                     # stop after one poll
+        time.sleep(timeout + oversleep_s)
+        return False
+
+    w._stop_evt.wait = wait
+    w.run()
+    return w, notified
+
+
+def test_watchdog_that_woke_late_reports_process_stalled_not_wedged(
+        monkeypatch):
+    """The whole process stood still (a TPU client starting): every
+    loop's stamp is stale, none is wedged.  No detector runs on that
+    poll, nothing is forwarded (so no capture bundle), and one
+    `process_stalled` instant carries the length."""
+    with _StaleLoop() as env:
+        w, notified = _run_watchdog(monkeypatch, oversleep_s=0.5)
+        assert w.fired == [] and notified == []
+        assert env.anomalies() == ["anomaly:process_stalled"]
+        # the loops get one wedge threshold (0.4 s here) to stamp afresh
+        assert w.note_wake(0.0) is True
+        time.sleep(0.45)
+        assert w.note_wake(0.0) is False    # then the detectors run again
+        assert [a["kind"] for a in w.poll_once()] == ["loop_wedged"]
+
+
+def test_punctual_watchdog_still_fires_loop_wedged(monkeypatch):
+    with _StaleLoop() as env:
+        w, notified = _run_watchdog(monkeypatch, oversleep_s=0.0)
+        assert [a["kind"] for a in w.fired] == ["loop_wedged"]
+        assert notified and notified[0]["loop"] == "tst_stale"
+        assert env.anomalies() == ["anomaly:loop_wedged"]
+
+
 # ---------------------------------------------------------------------------
 # task-hang tracking
 # ---------------------------------------------------------------------------
