@@ -178,19 +178,37 @@ def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
     return pool_k, pool_v
 
 
-def _decode_fn(params, pool_k, pool_v, tables, last_tokens, lengths, active,
-               temps, rng, cfg: TransformerConfig, page: int, kv_sharding):
-    """One decode step for ALL slots against the paged pool.
+def _paged_attend(kv_sharding):
+    """The decode step's attention call for a pool placed as `kv_sharding`.
 
-    pool_k/pool_v (L, N, page, KV, D); tables (B, P) physical page ids
-    (page 0 = scratch for inactive slots); lengths (B,) = tokens already
-    in cache (the new token is written at index lengths); active (B,)
-    bool; temps (B,) f32 sampling temperatures (0 = greedy).
-    Returns (pool_k', pool_v', next_tokens (B,))."""
-    B = last_tokens.shape[0]
-    P = tables.shape[1]
-    T = P * page
-    groups = cfg.num_heads // cfg.num_kv_heads
+    The Pallas kernel is a custom call the GSPMD partitioner cannot split,
+    so on a mesh it runs per shard (training's flash kernel does the same,
+    models/transformer.py:_flash_attention): KV heads and their query
+    groups over `tp`, everything else whole on every device."""
+    from ..ops.paged_attention import paged_decode_attention
+    if kv_sharding is None:
+        return paged_decode_attention
+    from jax.sharding import PartitionSpec as P
+    spec = kv_sharding.spec
+    heads = P(None, "tp") if "tp" in spec else P()
+    return jax.shard_map(paged_decode_attention, mesh=kv_sharding.mesh,
+                         in_specs=(heads, spec, spec, P(), P(), P()),
+                         out_specs=heads, check_vma=False)
+
+
+def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
+                      active, cfg: TransformerConfig, page: int, kv_sharding):
+    """The model half of a decode step: every slot's last token through the
+    layers against the paged pool -> (pool_k', pool_v', logits (B, V) f32).
+
+    The pool is carried through the layer loop whole and written where the
+    new token lands; attention (ops/paged_attention.py) reads the pages a
+    slot holds.  Nothing in the step is sized by the pool or by
+    max_batch x max_len but the donated pool itself."""
+    # An inactive slot is one token on the scratch page: it costs one page
+    # and what it computes is dropped.
+    tables = jnp.where(active[:, None], tables, 0)
+    lengths = jnp.where(active, lengths, 0)
     x = params["embed"].astype(cfg.dtype)[last_tokens][:, None]   # (B,1,E)
     # Per-slot RoPE at each slot's own position.
     freqs = 1.0 / (cfg.rope_theta
@@ -201,8 +219,8 @@ def _decode_fn(params, pool_k, pool_v, tables, last_tokens, lengths, active,
     # Physical write position of the incoming token for every slot.
     write_page = jnp.take_along_axis(
         tables, (lengths // page)[:, None], axis=1)[:, 0]         # (B,)
-    write_page = jnp.where(active, write_page, 0)                 # scratch
     write_off = lengths % page
+    attend = _paged_attend(kv_sharding)
 
     def rope1(t):                       # t: (B, 1, H, D)
         t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
@@ -211,36 +229,44 @@ def _decode_fn(params, pool_k, pool_v, tables, last_tokens, lengths, active,
              t2 * cos[..., None, :] + t1 * sin[..., None, :]],
             -1).astype(t.dtype)
 
-    def body(x, layer):
-        lp, pk, pv = layer              # pk/pv: (N, page, KV, D)
+    def body(carry, layer):
+        x, pk, pv = carry               # pk/pv: the whole pool, in place
+        lp, li = layer
         h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
         q, k, v = _layer_qkv(lp, h, cfg)
         q, k = rope1(q), rope1(k)
-        pk = pk.at[write_page, write_off].set(k[:, 0])
-        pv = pv.at[write_page, write_off].set(v[:, 0])
-        # Gather each slot's pages: (B, P, page, KV, D) → (B, T, KV, D)
-        ck = pk[tables].reshape(B, T, -1, cfg.head_dim_)
-        cv = pv[tables].reshape(B, T, -1, cfg.head_dim_)
-        kr = jnp.repeat(ck, groups, axis=2)                       # (B,T,H,D)
-        vr = jnp.repeat(cv, groups, axis=2)
-        scores = jnp.einsum("bhd,bthd->bht", q[:, 0], kr) \
-            / jnp.sqrt(jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
-        valid = jnp.arange(T)[None] <= lengths[:, None]           # (B, T)
-        scores = jnp.where(valid[:, None], scores, -1e30)
-        p = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(q.dtype)
-        o = jnp.einsum("bht,bthd->bhd", p, vr)
+        pk = pk.at[li, write_page, write_off].set(k[:, 0])
+        pv = pv.at[li, write_page, write_off].set(v[:, 0])
+        o = attend(q[:, 0], pk, pv, tables, lengths, li)          # (B,H,D)
         o = jnp.einsum("bhd,hde->be", o, lp["attn"]["wo"].astype(cfg.dtype))
         x = _mlp(lp, x + o[:, None], cfg)
-        return x, (pk, pv)
+        return (x, pk, pv), None
 
-    x, (pool_k, pool_v) = jax.lax.scan(
-        body, x, (params["layers"], pool_k, pool_v))
+    (x, pool_k, pool_v), _ = jax.lax.scan(
+        body, (x, pool_k, pool_v),
+        (params["layers"], jnp.arange(pool_k.shape[0], dtype=jnp.int32)))
     if kv_sharding is not None:
         pool_k = jax.lax.with_sharding_constraint(pool_k, kv_sharding)
         pool_v = jax.lax.with_sharding_constraint(pool_v, kv_sharding)
     x = rms_norm(x[:, 0], params["ln_f"], cfg.rms_norm_eps)
     logits = jnp.einsum("be,ev->bv", x, params["lm_head"].astype(cfg.dtype),
                         preferred_element_type=jnp.float32)
+    return pool_k, pool_v, logits
+
+
+def _decode_fn(params, pool_k, pool_v, tables, last_tokens, lengths, active,
+               temps, rng, cfg: TransformerConfig, page: int, kv_sharding):
+    """One decode step for ALL slots against the paged pool.
+
+    pool_k/pool_v (L, N, page, KV, D); tables (B, P) physical page ids
+    (page 0 = scratch for inactive slots); lengths (B,) = tokens already
+    in cache (the new token is written at index lengths); active (B,)
+    bool; temps (B,) f32 sampling temperatures (0 = greedy).
+    Returns (pool_k', pool_v', next_tokens (B,))."""
+    B = last_tokens.shape[0]
+    pool_k, pool_v, logits = _decode_logits_fn(
+        params, pool_k, pool_v, tables, last_tokens, lengths, active, cfg,
+        page, kv_sharding)
     greedy = jnp.argmax(logits, -1).astype(jnp.int32)
     keys = jax.random.split(rng, B)
     sampled = jax.vmap(
@@ -777,6 +803,11 @@ class LLMEngine:
         self._temps = np.zeros(max_batch, np.float32)
         self._prefill_jit = {}
         self.phases = TickPhases()
+        # How much of what the tables address the batch decode step reads
+        # (ops/paged_attention.py reads live pages only), and by which path.
+        self._decode_steps = 0
+        self._pages_read = 0
+        self._step_pages_read = 0
         page, kv_shd = self.page, self._kv_shd
         # The decode step stays a lambda ON PURPOSE: the benchmark's
         # `decode_tick` and `decode_roofline` readers pick it out of a
@@ -1008,6 +1039,21 @@ class LLMEngine:
         layer; `refetches` > 0 means the gather window is smaller than a
         live request's part count (counted, never silent)."""
         return self._kv_window.stats()
+
+    def decode_stats(self) -> Dict[str, Any]:
+        """What the batch decode step read: pages the active slots held
+        (`lengths // page + 1` each) beside the pages their tables address,
+        over all steps and in the last one, and the attention path."""
+        from ..ops.paged_attention import decode_path
+        per_step = self.max_batch * self.pages_per_slot
+        return {"path": decode_path(
+                    (self.cfg.num_heads, self.cfg.head_dim_), self._pk.shape,
+                    self._tables.shape),
+                "steps": self._decode_steps,
+                "pages_read": self._pages_read,
+                "pages_addressable": self._decode_steps * per_step,
+                "step_pages_read": self._step_pages_read,
+                "step_pages_addressable": per_step}
 
     def prefix_cache_stats(self) -> Dict[str, Any]:
         if self._cache is None:
@@ -1466,6 +1512,10 @@ class LLMEngine:
             active[slot] = True
         before = len(done)
         t0 = ph.to("prep", retired=before)
+        pages = int((self._lengths[active] // self.page + 1).sum())
+        self._decode_steps += 1
+        self._pages_read += pages
+        self._step_pages_read = pages
         self._rng, key = jax.random.split(self._rng)
         tables, last = jnp.asarray(self._tables), jnp.asarray(self._last)
         lengths, active = jnp.asarray(self._lengths), jnp.asarray(active)
@@ -1476,7 +1526,7 @@ class LLMEngine:
             temps, key)
         ph.to("wait")
         nxt = np.asarray(nxt)
-        ph.span("decode", t0, ph.to("emit"), batch=len(batch))
+        ph.span("decode", t0, ph.to("emit"), batch=len(batch), pages=pages)
         for slot, req in list(self._slots.items()):
             if slot not in batch:
                 continue

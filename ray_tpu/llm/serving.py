@@ -802,6 +802,7 @@ class EngineReplica:
                 "load": self.__serve_load__(),
                 "prefix_cache": e.prefix_cache_stats(),
                 "kv_gather": e.kv_gather_stats(),
+                "decode": e.decode_stats(),
                 "tick": self._phases.snapshot()}
 
     async def pid(self) -> int:

@@ -1,0 +1,236 @@
+"""Paged decode attention: one query token per slot against a paged KV pool.
+
+The serving engine's decode step (llm/engine.py:_decode_fn) attends each
+slot's new token to the keys and values its page table holds.  On a TPU this
+is a Pallas kernel that moves only the pages a slot holds: the pool stays in
+HBM, each live page is copied to VMEM once, and all `H // KV` query heads of
+a KV head are computed against that one copy (GQA by grouping: no widened
+keys).  Softmax is online over chunks of pages, in float32.
+
+A page of one layer is `(page, KV, D)`; seen as rows it is `(page * KV, D)`
+with row `t * KV + h`.  The kernel multiplies all `H` query heads against
+all rows of a chunk on the MXU (the unit is idle in decode) and masks the
+rows of the other KV heads, so no key is ever regrouped in memory.
+
+`paged_decode_attention` runs the kernel on a TPU for shapes it tiles and
+`reference_paged_attention` everywhere else (the CPU test mesh, head widths
+under 128); the reference is also the kernel's parity oracle.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+# Rows (tokens x KV heads) of keys one chunk holds in VMEM: K and V chunks,
+# double-buffered, take 4 x _CHUNK_ROWS x D x 2 bytes (2 MiB at D = 128).
+_CHUNK_ROWS = 2048
+
+
+def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
+                              *, scale: Optional[float] = None):
+    """Plain `jax.numpy` form of `paged_decode_attention` (same signature):
+    gathers every slot's whole table and masks what is past its length."""
+    B, H, D = q.shape
+    page, KV = pool_k.shape[-3:-1]
+    T = tables.shape[1] * page
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    pages = (tables,) if layer is None else (layer, tables)
+    ck = pool_k[pages].reshape(B, T, KV, D)
+    cv = pool_v[pages].reshape(B, T, KV, D)
+    qg = q.reshape(B, KV, H // KV, D)
+    s = jnp.einsum("bkgd,btkd->bkgt", qg, ck,
+                   preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(T)[None] <= lengths[:, None]               # (B, T)
+    s = jnp.where(valid[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    # Dead rows may hold anything (NaN included): select, never multiply.
+    cv = jnp.where(valid[:, :, None, None], cv, 0)
+    o = jnp.einsum("bkgt,btkd->bkgd", p, cv,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, H, D).astype(q.dtype)
+
+
+def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
+                  q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, bias_scr, sem, *,
+                  scale: float, page: int, kv_heads: int, chunk_pages: int):
+    """All slots of one layer.  Work is the list of (slot, chunk) pairs in
+    order; while one chunk is computed the next one's pages are in flight,
+    across slot boundaries too."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, D = q_ref.shape
+    P = tables_ref.shape[0] // B
+    rows_page = page * kv_heads
+    R = chunk_pages * rows_page
+    groups = H // kv_heads
+    li = layer_ref[0]
+
+    def n_pages(b):
+        return lengths_ref[b] // page + 1
+
+    def n_chunks(b):
+        return (n_pages(b) + chunk_pages - 1) // chunk_pages
+
+    def page_copies(pid, buf, j):
+        dst = pl.ds(j * rows_page, rows_page)
+        return (pltpu.make_async_copy(k_hbm.at[li, pid], kbuf.at[buf, dst],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[li, pid], vbuf.at[buf, dst],
+                                      sem.at[1, buf]))
+
+    def start(b, c, buf):
+        """Start the chunk's page copies.  Past the slot's last page the
+        last one is fetched again: the buffer then holds live pages only,
+        and the repeated rows lie past `lengths[b]`, where the mask drops
+        them."""
+        last = n_pages(b) - 1
+        for j in range(chunk_pages):
+            pid = tables_ref[b * P + jnp.minimum(c * chunk_pages + j, last)]
+            for cp in page_copies(pid, buf, j):
+                cp.start()
+
+    def wait(buf):
+        for j in range(chunk_pages):
+            for cp in page_copies(0, buf, j):    # a wait reads no source
+                cp.wait()
+
+    # Row r of a chunk is token r // KV of KV head r % KV: query head j sees
+    # the rows of head j // groups alone.
+    row = jax.lax.broadcasted_iota(jnp.int32, (H, R), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, R), 0)
+    bias_scr[...] = jnp.where(row % kv_heads == head // groups,
+                              0.0, -1e30).astype(jnp.float32)
+    row_tok = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1) // kv_heads
+
+    start(0, 0, 0)
+
+    def slot_body(b, item):
+        q = q_ref[b]                                          # (H, D)
+        length = lengths_ref[b]
+        nc = n_chunks(b)
+
+        def chunk_body(c, carry):
+            m, l, acc, item = carry
+            buf = item % 2
+            more = c + 1 < nc
+            nb = jnp.where(more, b, b + 1)
+
+            @pl.when(nb < B)
+            def _prefetch():
+                start(nb, jnp.where(more, c + 1, 0), 1 - buf)
+
+            wait(buf)
+            k = kbuf[buf]                                     # (R, D)
+            v = vbuf[buf]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # (H, R)
+            s = s * scale + bias_scr[...]
+            live = row_tok + c * (chunk_pages * page) <= length
+            s = jnp.where(live, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # (H, D)
+            return m_new, l, acc, item + 1
+
+        m, l, acc, item = jax.lax.fori_loop(
+            0, nc, chunk_body,
+            (jnp.full((H, 1), -1e30, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32),
+             jnp.zeros((H, D), jnp.float32), item))
+        o_ref[b] = (acc / l).astype(o_ref.dtype)
+        return item
+
+    jax.lax.fori_loop(0, B, slot_body, jnp.int32(0))
+
+
+def _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
+                         interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, D = q.shape
+    L, N, page, KV, _ = pool_k.shape
+    rows_page = page * KV
+    chunk_pages = max(1, _CHUNK_ROWS // rows_page)
+    R = chunk_pages * rows_page
+    kernel = functools.partial(_paged_kernel, scale=scale, page=page,
+                               kv_heads=KV, chunk_pages=chunk_pages)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(),
+            in_specs=[vmem, hbm, hbm],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, R, D), pool_k.dtype),
+                pltpu.VMEM((2, R, D), pool_v.dtype),
+                pltpu.VMEM((H, R), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="paged_decode_attention",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      q, pool_k.reshape(L, N, rows_page, D), pool_v.reshape(L, N, rows_page, D))
+
+
+# Page tables ride in scalar memory (1 MiB on a v5e) beside the lengths.
+_TABLE_BYTES = 512 << 10
+
+
+def kernel_tiles(q_shape, pool_shape, tables_shape) -> bool:
+    """Whether the Pallas kernel can tile these shapes: a head of whole
+    128-lane rows, pages of whole bf16 sublane tiles, heads that group,
+    page tables that fit scalar memory."""
+    H, D = q_shape[-2:]
+    page, KV = pool_shape[-3:-1]
+    return D % 128 == 0 and page % 16 == 0 and H % KV == 0 \
+        and 4 * math.prod(tables_shape) <= _TABLE_BYTES
+
+
+def decode_path(q_shape, pool_shape, tables_shape) -> str:
+    """Which implementation `paged_decode_attention` runs for these shapes
+    in this process: "pallas" or "reference"."""
+    on_tpu = jax.devices()[0].platform == "tpu"
+    return "pallas" if on_tpu and kernel_tiles(
+        q_shape, pool_shape, tables_shape) else "reference"
+
+
+def paged_decode_attention(q, pool_k, pool_v, tables, lengths, layer=None, *,
+                           scale: Optional[float] = None):
+    """Attention of one new token per slot over the pages the slot holds.
+
+    q (B, H, D) after RoPE; pool_k / pool_v (N, page, KV, D), or the stacked
+    (L, N, page, KV, D) with `layer` the (traced) index to read, so that a
+    layer loop never slices the pool; tables (B, P) physical page ids;
+    lengths (B,) tokens already cached: positions 0..lengths[b] are attended
+    (the new token's key is at index lengths[b], written by the caller).
+    Slot b reads pages tables[b, 0 .. lengths[b] // page] and no other.
+    Returns (B, H, D) in q's dtype.
+
+    On a TPU, for shapes `kernel_tiles` accepts, this is the Pallas kernel;
+    otherwise `reference_paged_attention`."""
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if decode_path(q.shape, pool_k.shape, tables.shape) != "pallas":
+        return reference_paged_attention(q, pool_k, pool_v, tables, lengths,
+                                         layer, scale=scale)
+    if layer is None:
+        pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
+    return _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer,
+                                scale)

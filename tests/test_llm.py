@@ -262,3 +262,136 @@ def test_unserviceable_request_rejected_up_front():
                     page_size=16, kv_pages=2)
     with pytest.raises(ValueError, match="KV pages"):
         eng.add_request(list(range(1, 41)), SamplingParams(max_tokens=20))
+
+
+# ---- the decode step reads live pages only (ops/paged_attention.py) ------
+
+def _wide_head_cfg():
+    """128-wide heads (what the Pallas kernel tiles), small enough for the
+    CPU: 4 query heads over 2 KV heads, bf16 as it is served."""
+    import jax.numpy as jnp
+    from ray_tpu.models import TransformerConfig
+    return TransformerConfig(
+        vocab_size=256, hidden_size=256, intermediate_size=512, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim=128, max_seq_len=256,
+        dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("path", ["reference", "kernel"])
+def test_decode_logits_through_cache_match_float32_forward(path, monkeypatch):
+    """Prefill, then 2 x page + 3 decode steps through the paged cache: each
+    step's logits against a float32 forward pass over the whole sequence.
+    `tiny` takes the plain function; 128-wide heads take the Pallas kernel,
+    here interpreted (steered in the test: there is no TPU)."""
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.llm import engine as E
+    from ray_tpu.ops import paged_attention as pa
+
+    page = 16
+    if path == "kernel":
+        cfg, tol = _wide_head_cfg(), 0.08
+        monkeypatch.setattr(pa, "decode_path", lambda *shapes: "pallas")
+        monkeypatch.setattr(pa, "_paged_decode_pallas", functools.partial(
+            pa._paged_decode_pallas, interpret=True))
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", 2 * page * cfg.num_kv_heads)
+    else:
+        cfg, tol = CFG, 2e-4
+    steps = 2 * page + 3
+    eng = LLMEngine(cfg, max_batch=2, max_len=4 * page, page_size=page,
+                    seed=0)
+    prompt = [3, 17, 42, 7, 99, 5, 23, 11, 2]
+    eng.add_request(prompt, SamplingParams(max_tokens=steps + 2))
+    assert eng._admit() == 1                     # prefill; slot 0 holds it
+    slot = next(iter(eng._slots))
+    f32 = dataclasses.replace(cfg, dtype=jnp.float32)
+    params32 = jax.tree.map(lambda a: a.astype(jnp.float32), eng.params)
+    width = len(prompt) + steps + 1               # one program: causal
+    full = jax.jit(lambda toks, n: forward(params32, toks, f32)[0, n - 1])
+    decode = jax.jit(lambda pk, pv, tb, lt, ln, ac: E._decode_logits_fn(
+        eng.params, pk, pv, tb, lt, ln, ac, cfg, eng.page, None))
+    active = np.zeros(eng.max_batch, bool)
+    active[slot] = True
+    toks = prompt + [int(eng._last[slot])]
+    pk, pv, worst = eng._pk, eng._pv, 0.0
+    for i in range(steps):
+        lengths = eng._lengths.copy()
+        lengths[slot] = len(toks) - 1
+        last = eng._last.copy()
+        last[slot] = toks[-1]
+        pk, pv, logits = decode(pk, pv, jnp.asarray(eng._tables),
+                                jnp.asarray(last), jnp.asarray(lengths),
+                                jnp.asarray(active))
+        want = np.asarray(full(jnp.asarray(
+            [toks + [0] * (width - len(toks))], jnp.int32), len(toks)))
+        got = np.asarray(logits[slot])
+        worst = max(worst, np.abs(got - want).max())
+        assert worst < tol, (i, worst)
+        toks.append(int(np.argmax(want)))
+
+
+def test_tokens_do_not_depend_on_what_is_addressable():
+    """The same requests under max_len 256 and 1,024: the step reads what
+    is live, so the tokens are the same."""
+    prompts = [[5, 6, 7], list(range(9, 49)), [21, 22]]
+    sp = SamplingParams(max_tokens=24)
+    outs = [LLMEngine(CFG, max_batch=2, max_len=n, page_size=16,
+                      seed=0).generate(prompts, sp) for n in (256, 1024)]
+    assert outs[0] == outs[1]
+    assert all(len(o) == 24 for o in outs[0])
+
+
+def test_decode_step_writes_the_pool_in_place():
+    """The compiled decode step aliases both pools to its outputs and, where
+    the backend says, needs less temporary memory than one pool."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    eng = LLMEngine(CFG, max_batch=2, max_len=64, page_size=16,
+                    kv_pages=256, seed=0)
+    B = eng.max_batch
+    compiled = eng._decode_jit.lower(
+        eng.params, eng._pk, eng._pv, jnp.asarray(eng._tables),
+        jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
+        jnp.zeros(B, bool), jnp.zeros(B, jnp.float32),
+        jax.random.key(0)).compile()
+    n_params = len(jax.tree.leaves(eng.params))
+    head = compiled.as_text().split("\n", 1)[0]
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}", head))
+    assert aliases == {"0": str(n_params), "1": str(n_params + 1)}, head
+    mem = compiled.memory_analysis()
+    if mem is not None and mem.temp_size_in_bytes:
+        assert mem.temp_size_in_bytes < eng._pk.nbytes, mem
+
+
+def test_debug_stats_count_pages_read():
+    """`debug_stats()["decode"]`: pages the decode steps read (live ones:
+    lengths // page + 1 of each active slot) beside what the tables
+    address, and the path taken."""
+    import asyncio
+
+    from ray_tpu.llm.serving import EngineReplica
+
+    page, n_prompt, n_out = 16, 30, 12
+
+    async def run():
+        er = EngineReplica("tiny", max_batch=2, max_len=128, page_size=page,
+                           seed=0)
+        out = await er.generate(list(range(1, n_prompt + 1)),
+                                {"max_tokens": n_out})
+        assert len(out["tokens"]) == n_out
+        return await er.debug_stats()
+
+    st = asyncio.run(run())["decode"]
+    # Prefill gives the first token; decode step i attends n_prompt + i.
+    lengths = [n_prompt + i for i in range(n_out - 1)]
+    assert st["path"] == "reference"
+    assert st["steps"] == len(lengths)
+    assert st["pages_read"] == sum(n // page + 1 for n in lengths)
+    assert st["pages_addressable"] == len(lengths) * 2 * (128 // page)
+    assert st["step_pages_read"] == lengths[-1] // page + 1
+    assert st["step_pages_addressable"] == 2 * (128 // page)

@@ -259,7 +259,8 @@ def test_old_spans_keep_names_arguments_and_extents(run):
         assert {"tokens", "cached_tokens", "active"} <= set(r["args"])
         assert len(bytes(r["task_id"])) == 8
     assert all(set(r["args"]) == {"batch"} for r in rows["sample_sync"])
-    assert all(set(r["args"]) == {"batch", "n"} for r in rows["decode"])
+    assert all(set(r["args"]) == {"batch", "n", "pages"}
+               for r in rows["decode"])
     # `decode` still runs from before the key split to after the read-back
     # (now: from its first child's start to its last child's end), and a
     # `sample_sync` still follows the wave's last prefill in its tick

@@ -1,0 +1,287 @@
+"""Paged decode attention (ops/paged_attention.py): the Pallas kernel run in
+interpret mode on the CPU, against the module's plain function, against a
+float32 softmax written here; then the kernel and the engine's decode step
+compiled for a described v5e (no chip: the TPU's compiler is installed)."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import paged_attention as pa
+
+D = 128
+BF16_TOL = 2e-2     # outputs are O(1) averages of bf16 values rounded to bf16
+
+
+def _case(groups, page, lengths, *, kv=2, n_slots_pages=6, seed=0,
+          shared=(), dtype=jnp.bfloat16):
+    """q, pools, tables, lengths for `len(lengths)` slots of P pages each.
+    `shared`: pairs (a, b, n): slot b's first n pages are slot a's."""
+    B, P = len(lengths), n_slots_pages
+    H = kv * groups
+    N = 1 + B * P
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (B, H, D), jnp.float32).astype(dtype)
+    pk = jax.random.normal(ks[1], (N, page, kv, D), jnp.float32).astype(dtype)
+    pv = jax.random.normal(ks[2], (N, page, kv, D), jnp.float32).astype(dtype)
+    rng = np.random.default_rng(seed)
+    tables = rng.permutation(np.arange(1, N)).reshape(B, P).astype(np.int32)
+    for a, b, n in shared:
+        tables[b, :n] = tables[a, :n]
+    return q, pk, pv, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+
+
+def _oracle(q, pk, pv, tables, lengths):
+    """softmax(q K^T / sqrt(D)) V in float32, slot by slot, head by head,
+    over positions 0..length of the slot's own pages."""
+    q, pk, pv = (np.asarray(a, np.float32) for a in (q, pk, pv))
+    tables, lengths = np.asarray(tables), np.asarray(lengths)
+    B, H, _ = q.shape
+    page, kv = pk.shape[1:3]
+    out = np.zeros((B, H, D), np.float32)
+    for b in range(B):
+        n = lengths[b] + 1
+        k = pk[tables[b]].reshape(-1, kv, D)[:n]
+        v = pv[tables[b]].reshape(-1, kv, D)[:n]
+        for h in range(H):
+            s = k[:, h // (H // kv)] @ q[b, h] / math.sqrt(D)
+            p = np.exp(s - s.max())
+            out[b, h] = (p / p.sum()) @ v[:, h // (H // kv)]
+    return out
+
+
+def _kernel(q, pk, pv, tables, lengths, layer=None):
+    """The Pallas kernel itself, interpreted (on a TPU the public function
+    would choose it)."""
+    if layer is None:
+        pk, pv, layer = pk[None], pv[None], 0
+    return pa._paged_decode_pallas(q, pk, pv, tables, lengths, layer,
+                                   1 / math.sqrt(D), interpret=True)
+
+
+def _lengths(page, pages):
+    """0, page - 1, page, several pages, the table's last position."""
+    return [0, page - 1, page, 3 * page + 5, pages * page - 1]
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """Two pages a chunk, so a few pages already cross chunk boundaries
+    (at the real constant a chunk is 16 pages of the cells' shape)."""
+    def set_for(page, kv):
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", 2 * page * kv)
+    return set_for
+
+
+@pytest.mark.parametrize("page", [16, 64])
+@pytest.mark.parametrize("groups", [1, 4, 8])
+def test_kernel_matches_reference_and_float32(groups, page, small_chunks):
+    small_chunks(page, 2)
+    args = _case(groups, page, _lengths(page, 6))
+    got = np.asarray(_kernel(*args), np.float32)
+    ref = np.asarray(pa.reference_paged_attention(*args), np.float32)
+    want = _oracle(*args)
+    assert np.abs(got - want).max() < BF16_TOL
+    assert np.abs(ref - want).max() < BF16_TOL
+    assert np.abs(got - ref).max() < BF16_TOL
+
+
+@pytest.mark.parametrize("what", ["inactive", "shared", "stacked", "real_chunk",
+                                  "float32"])
+def test_kernel_cases(what, small_chunks):
+    page, kv, kwargs, layer = 16, 2, {}, None
+    lengths = _lengths(page, 6)
+    if what == "inactive":
+        # As the engine hands them over: table row 0 (the scratch page),
+        # length 0.  They cost one page and give a finite row.
+        lengths = [0, 40, 0, 17]
+    elif what == "shared":
+        # A prefix-cache hit: slots 1 and 2 read slot 0's first 3 pages.
+        kwargs["shared"] = [(0, 1, 3), (0, 2, 3)]
+        lengths = [70, 3 * page, 3 * page + 20]
+    elif what == "real_chunk":
+        kv = 8                    # the cells' page: 16 x 8 rows, 16 a chunk
+        kwargs["n_slots_pages"] = 20
+        lengths = [0, 15 * page + 3, 16 * page, 20 * page - 1]
+    elif what == "float32":
+        kwargs["dtype"] = jnp.float32
+    if what != "real_chunk":
+        small_chunks(page, kv)
+    q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv, **kwargs)
+    if what == "inactive":
+        tables = tables.at[0].set(0).at[2].set(0)
+    want = _oracle(q, pk, pv, tables, lens)
+    if what == "stacked":
+        # The engine's form: the whole (L, N, page, KV, D) pool and a
+        # traced layer index, so that no layer is ever sliced out.
+        other = jnp.full_like(pk, jnp.nan)
+        pk, pv, layer = (jnp.stack([other, pk, other]),
+                         jnp.stack([other, pv, other]), jnp.int32(1))
+    got = np.asarray(jax.jit(_kernel)(q, pk, pv, tables, lens, layer),
+                     np.float32)
+    ref = np.asarray(pa.reference_paged_attention(
+        q, pk, pv, tables, lens, layer), np.float32)
+    tol = 1e-4 if what == "float32" else BF16_TOL
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < tol
+    assert np.abs(ref - want).max() < tol
+
+
+@pytest.mark.parametrize("impl", ["kernel", "reference"])
+def test_reads_live_pages_only(impl, small_chunks):
+    """Every page no slot holds is NaN, and the rows of a slot's last page
+    past its length are huge: the output is what it was before.  (The old
+    gather multiplied dead values by zero and could not pass this.)"""
+    page, kv = 16, 2
+    small_chunks(page, kv)
+    lengths = [0, page - 1, page, 3 * page + 5, 6 * page - 1]
+    q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv)
+    fn = _kernel if impl == "kernel" else pa.reference_paged_attention
+    clean = np.asarray(fn(q, pk, pv, tables, lens), np.float32)
+    held = np.zeros(pk.shape[0], bool)
+    tail = np.zeros(pk.shape[:2], bool)
+    for b, n in enumerate(lengths):
+        last = n // page
+        held[np.asarray(tables)[b, :last + 1]] = True
+        tail[int(tables[b, last]), n % page + 1:] = True
+    poison = lambda pool: jnp.where(
+        jnp.asarray(tail)[:, :, None, None], 3e38,
+        jnp.where(jnp.asarray(held)[:, None, None, None], pool, jnp.nan)
+    ).astype(pool.dtype)
+    dirty = np.asarray(fn(q, poison(pk), poison(pv), tables, lens),
+                       np.float32)
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+def test_chooser_adapts_to_platform_and_shape():
+    """On the CPU of these tests every shape takes the plain function; on a
+    TPU the kernel takes what it can tile."""
+    pool, tables = (4, 9, 16, 8, 128), (16, 128)
+    assert pa.decode_path((32, 128), pool, tables) == "reference"  # no TPU
+    assert pa.kernel_tiles((32, 128), pool, tables)
+    assert pa.kernel_tiles((16, 128), (9, 64, 16, 128), (4, 4))
+    assert not pa.kernel_tiles((8, 16), (9, 16, 4, 16), tables)     # `tiny`
+    assert not pa.kernel_tiles((32, 128), (9, 8, 8, 128), tables)   # page 8
+    assert not pa.kernel_tiles((32, 128), pool, (256, 2048))  # scalar memory
+    args = _case(4, 16, [5, 40])
+    np.testing.assert_array_equal(
+        np.asarray(pa.paged_decode_attention(*args), np.float32),
+        np.asarray(pa.reference_paged_attention(*args), np.float32))
+
+
+# ---- compiled for the chip, without the chip -----------------------------
+# The topology is described inside a fixture of THIS file only: the process
+# that does so holds the TPU library until it exits (on-chip-measurement
+# guide, section 2).
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("shape", [
+    # B, P, page, KV, groups, dtype: serve_chat, serve_doc_reask, the
+    # engine's defaults with the `1b` preset's heads, float32
+    (16, 128, 16, 8, 4, jnp.bfloat16),
+    (8, 256, 16, 8, 4, jnp.bfloat16),
+    (4, 4, 64, 16, 1, jnp.bfloat16),
+    (4, 4, 64, 8, 4, jnp.float32),
+])
+def test_kernel_compiles_for_v5e(shape, topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+    B, P, page, KV, groups, dtype = shape
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    L, N = 3, 1 + B * P
+    compiled = jax.jit(
+        lambda q, pk, pv, tb, ln, li: pa._paged_decode_pallas(
+            q, pk, pv, tb, ln, li, 1 / math.sqrt(D))
+    ).lower(S((B, KV * groups, D), dtype), S((L, N, page, KV, D), dtype),
+            S((L, N, page, KV, D), dtype), S((B, P), jnp.int32),
+            S((B,), jnp.int32), S((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    # The pool reaches the kernel as it lies in HBM: no copy of it, no slice.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("mesh_axes", [None, {"tp": 2}, {"sp": 2}])
+def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
+                                               no_compile_cache):
+    """The engine's decode step at the cells' widths (two layers), on one
+    chip and under `shard_map` on a tp and an sp mesh: the kernel is in the
+    program, both pools alias their outputs, and nothing pool-sized is
+    made beside them."""
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+    from ray_tpu.llm import engine as E
+    from ray_tpu.models.transformer import (TransformerConfig, init_params,
+                                            param_logical_axes)
+    from ray_tpu.parallel.sharding import LogicalAxisRules, tree_shardings
+
+    monkeypatch.setattr(pa, "decode_path", lambda *shapes: "pallas")
+    cfg = TransformerConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=2048, rope_theta=1e6)
+    B, page, P_ = 16, 16, 128
+    if mesh_axes is None:
+        rep = SingleDeviceSharding(topo.devices[0])
+        kv_shd, par_shd, pool_shd = None, rep, rep
+    else:
+        (axis, n), = mesh_axes.items()
+        mesh = Mesh(np.array(topo.devices[:n]), (axis,))
+        rep = NamedSharding(mesh, P())
+        if axis == "tp":
+            kv_shd = NamedSharding(mesh, P(None, None, None, "tp"))
+            rules = LogicalAxisRules.default().with_overrides(
+                ("vocab", None), ("embed", None))
+            par_shd = tree_shardings(param_logical_axes(cfg), mesh, rules)
+        else:
+            kv_shd, par_shd = rep, rep
+        pool_shd = kv_shd
+    S = lambda s, t, shd=rep: jax.ShapeDtypeStruct(s, t, sharding=shd)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    if not isinstance(par_shd, dict):
+        par_shd = jax.tree.map(lambda _: par_shd, params)
+    params = jax.tree.map(lambda a, shd: S(a.shape, a.dtype, shd),
+                          params, par_shd)
+    pool = S((cfg.num_layers, 3073, page, 8, 128), cfg.dtype, pool_shd)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+
+    def decode_step(p, pk, pv, tb, lt, ln, ac, tp, rn):
+        return E._decode_fn(p, pk, pv, tb, lt, ln, ac, tp, rn, cfg, page,
+                            kv_shd)
+    compiled = jax.jit(decode_step, donate_argnums=(1, 2)).lower(
+        params, pool, pool, S((B, P_), jnp.int32), S((B,), jnp.int32),
+        S((B,), jnp.int32), S((B,), jnp.bool_), S((B,), jnp.float32),
+        S(key.shape, key.dtype)).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attention" in text
+    mem = compiled.memory_analysis()
+    per_device = math.prod(pool.shape) * 2 // (2 if mesh_axes == {"tp": 2}
+                                               else 1)
+    assert mem.alias_size_in_bytes >= 2 * per_device
+    assert mem.temp_size_in_bytes < per_device // 8
