@@ -31,6 +31,18 @@ def ttfts_ms(ctx: Dict[str, Any], since: str = "due") -> List[float]:
             for r in due_in_window(ctx)]
 
 
+def replies_ms(ctx: Dict[str, Any]) -> List[float]:
+    """Time to the whole reply of every request due in the window: from
+    when it was due to its last token.  A failed request, and one whose
+    reply was not whole when the harness stopped (it was then at least
+    `drain_s` old), counts as the window's length: both sort above every
+    whole reply, so they weigh on a band only through the ranks."""
+    whole = (ctx["window"][1] - ctx["window"][0]) * 1e3
+    return [whole if is_failed(r) or r["finish"] is None
+            else (r["token_times"][-1] - r["due"]) * 1e3
+            for r in due_in_window(ctx)]
+
+
 def gaps_ms(ctx: Dict[str, Any]) -> List[float]:
     """Every gap between consecutive output tokens of the requests due in
     the window, as far as they arrived before the harness stopped.  No gap

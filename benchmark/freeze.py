@@ -7,7 +7,10 @@ that long — sleeps in short ticks; a tick that wakes more than LIMIT_S late
 was a stall.  The watch only records: a window that held a stall is
 measured and reported like any other, and the stalls go into the result
 line (`stalls`) and the per-layer metric `host_stall_s`, so that a reader
-of a far-off run can see what it held."""
+of a far-off run can see what it held.  The watch also keeps every tick
+that woke over LATE_S late (`late`), the long stalls among them:
+`setup_net_s` is set-up less those (on PR 29's machines the short ones
+added 0.0-0.6 s to the one long stop)."""
 
 from __future__ import annotations
 
@@ -17,11 +20,13 @@ from typing import List, Tuple
 
 TICK_S = 0.05
 LIMIT_S = 1.0
+LATE_S = 0.1
 
 
 class FreezeWatch:
     def __init__(self) -> None:
         self.stalls: List[Tuple[float, float]] = []   # (began, ended), wall
+        self.late: List[Tuple[float, float]] = []     # the lateness alone
         self._halt = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
@@ -32,6 +37,8 @@ class FreezeWatch:
             now = time.time()
             if now - last - TICK_S > LIMIT_S:
                 self.stalls.append((last, now))
+            if now - last - TICK_S > LATE_S:
+                self.late.append((last + TICK_S, now))
             last = now
 
     def close(self) -> None:

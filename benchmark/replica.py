@@ -15,7 +15,7 @@ from typing import Any, Dict, List
 
 from ray_tpu.llm.serving import EngineReplica
 
-from . import families, trace
+from . import families, refcheck, trace
 
 class BenchReplica(EngineReplica):
 
@@ -43,48 +43,18 @@ class BenchReplica(EngineReplica):
     # --------------------------------------------------------- checking --
     async def bench_check(self, prompt: List[int], served: List[List[int]]
                           ) -> Dict[str, Any]:
-        """Hold what the engine served for `prompt` to the float32
-        reference: each of `served` (the tokens streamed for a cold
-        request, then for the same prompt as a prefix-cache hit) is
-        teacher-forced through the reference's full forward pass, and the
-        engine's own prefill logits are compared value by value."""
+        """Hold what the engine served for `prompt` (cold, then as a
+        prefix-cache hit) to the float32 reference: `refcheck.report`."""
         loop = asyncio.get_running_loop()
         async with self._lock:              # no tick while this computes
             return await loop.run_in_executor(
                 None, self._bench_check, prompt, served)
 
     def _bench_check(self, prompt, served) -> Dict[str, Any]:
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-        t0 = time.time()
-        family, config = self._bench_family, self._bench_config
-        tol = family.TOLERANCE
-        ref = jax.jit(lambda p, t: family.reference_logits(p, t, config))
-        n = len(prompt)
-        margins = []
-        ref_last = None
-        for out in served:
-            toks = jnp.asarray([list(prompt) + list(out[:-1])], jnp.int32)
-            logits = np.asarray(ref(self.engine.params, toks)[0])
-            at = logits[n - 1:]             # rows predicting out[0..]
-            margins.append(float(max(
-                row.max() - row[tok] for row, tok in zip(at, out))))
-            if ref_last is None:
-                ref_last = logits[n - 1]
-        got = np.asarray(self.engine._run_prefill(list(prompt))[0],
-                         np.float32)
-        diff = got - ref_last
-        report = {"prefill_logit_max": float(np.abs(diff).max()),
-                  "prefill_logit_rms": float(np.sqrt(np.mean(diff ** 2))),
-                  "ref_logit_std": float(ref_last.std()),
-                  "margins": margins, "tolerance": tol,
-                  "seconds": time.time() - t0}
-        report["ok"] = bool(
-            report["prefill_logit_max"] <= tol["logit_max"]
-            and report["prefill_logit_rms"] <= tol["logit_rms"]
-            and max(margins) <= tol["margin"])
-        return report
+        # `LLMEngine._run_prefill` (private) gives the plain comparison its
+        # logits; a family that owns its check gets the engine itself.
+        return refcheck.report(self.engine, self._bench_family,
+                               self._bench_config, prompt, served)
 
     async def bench_warm_sampler(self) -> int:
         """The engine samples the first tokens of every admission wave in

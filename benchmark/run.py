@@ -156,7 +156,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         "count": len(watch.stalls),
         "setup_s": freeze.seconds(watch.stalls, T_PROC, t0),
         "window_s": freeze.seconds(watch.stalls, t0, t1),
-        "each": watch.stalls}
+        "late_setup_s": freeze.seconds(watch.late, T_PROC, t0),
+        "late_window_s": freeze.seconds(watch.late, t0, t1),
+        "each": watch.stalls, "late": watch.late}
     if ctx["stalls"]["window_s"]:
         print(f"benchmark: this process stood still for "
               f"{ctx['stalls']['window_s']:.1f} s inside the window; the "

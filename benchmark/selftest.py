@@ -9,9 +9,13 @@
 `unit` holds BENCHMARK.json and the files it names to the rules a later PR
 leans on (every metric has its reader, every per-layer metric moves an
 end-to-end metric its cells report), shows that two seeds offer the same
-multiset of requests on the same schedule, and checks the trace reduction
-on a hand-made trace with known answers and on the recorded one under
-`fixtures/`.  `rehearse` runs `python -m benchmark.run --rehearse` for
+multiset of requests on the same schedule (a closed list with `stratum` 1:
+the same list in the same order), checks the trace reduction on a hand-made
+trace with known answers and on the recorded one under `fixtures/`, and
+holds the reference check to its own rules on a toy routed family
+(`tests/`: the plain check fails it on some seeds, the check it owns passes
+every seed and refuses fp8 weights) and on the dense family (the report is
+the parent's).  `rehearse` runs `python -m benchmark.run --rehearse` for
 every cell: tiny widths, every token length cut eightfold, CPU workers
 (four virtual devices for a four-chip cell) — control flow, not speed —
 and then shows that without `--rehearse` the command gives no result here.
@@ -19,6 +23,7 @@ and then shows that without `--rehearse` the command gives no result here.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import re
@@ -26,7 +31,7 @@ import subprocess
 import sys
 from typing import Any, Dict, List
 
-from . import freeze, stats, trace, traffic
+from . import client, freeze, stats, trace, traffic
 from .run import HERE, ROOT, load_cell
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -34,12 +39,16 @@ TINY = {"hidden_size": 128, "intermediate_size": 256, "vocab_size": 512,
         "num_attention_heads": 8, "num_key_value_heads": 4,
         "num_hidden_layers": 2, "torch_dtype": "float32"}
 CUT = 8
+ROUTED_SEEDS = range(14, 24)      # ten seeds; the plain check fails three
 
 
 def shrink(cell: Dict[str, Any]) -> None:
     """The rehearsal's cell: tiny widths, lengths cut by CUT, short
-    phases.  In place."""
+    phases.  In place.  TINY's keys are the dense decoder's; a family
+    whose configuration has other widths (latent ranks, expert counts)
+    names their rehearsal sizes in its own `TINY`, applied after."""
     cell["config"].update(TINY)
+    cell["config"].update(getattr(cell["family"], "TINY", {}))
     cell["config"].pop("head_dim", None)
     t = cell["traffic"]
     if "engine" in t:
@@ -96,51 +105,59 @@ def check_files() -> None:
           "reader; every `moves` is reported where its metric is")
 
 
-def check_traffic() -> None:
+def check_traffic_file(name: str) -> None:
     """Two seeds: the same multiset of request shapes, on the same
-    schedule (closed: the same list but for the order inside strata;
-    open_grid: one request per slot, the same shapes per stratum)."""
+    schedule.  closed: the same list but for the order inside strata, and
+    with `stratum` 1 the same list in the same order (which request misses,
+    and which misses meet in one admission, is then the layout's); the
+    token ids differ.  open_grid: one request per slot, the same shapes per
+    stratum."""
     seeds = (7, 2 ** 31 + 11)
+    spec = traffic.load(name)
+    if spec["kind"] == "train_steps":
+        return
+    a, b = (traffic.requests(spec, s, 45.0) for s in seeds)
+    assert sorted(r.shape() for r in a) == sorted(r.shape() for r in b)
+    size = spec.get("stratum") or spec["stratum_slots"]
+    same = [(r.shape(), r.doc) for r in a] == [(r.shape(), r.doc) for r in b]
+    assert same == (size == 1), \
+        f"{name}: the seed {'permutes' if size == 1 else 'leaves'} the order"
+    if spec["kind"] == "closed":
+        for i in range(0, len(a), size):
+            assert sorted(r.shape() for r in a[i:i + size]) == \
+                sorted(r.shape() for r in b[i:i + size])
+        assert [r.doc for r in a if r.doc < 0] == []
+    else:
+        rate = spec["rate_hz"]
+        starts = {"ramp": 0.0, "window": spec["ramp_s"],
+                  "tail": spec["ramp_s"] + 45.0}
+        for reqs in (a, b):
+            for phase, start in starts.items():
+                slots = [int((r.due - start) * rate + 1e-9)
+                         for r in reqs if r.phase == phase]
+                assert slots == list(range(len(slots))), \
+                    f"{name}: not one request per slot in the {phase}"
+        for phase in ("ramp", "window", "tail"):
+            pa = [r for r in a if r.phase == phase]
+            pb = [r for r in b if r.phase == phase]
+            for i in range(0, len(pa), size):
+                sa = pa[i:i + size], pb[i:i + size]
+                assert sorted(r.prompt_len for r in sa[0]) == \
+                    sorted(r.prompt_len for r in sa[1])
+                assert sorted(r.output_len for r in sa[0]) == \
+                    sorted(r.output_len for r in sa[1])
+    traffic.fill_tokens(a[:8], seeds[0], 512)
+    traffic.fill_tokens(b[:8], seeds[1], 512)
+    assert all(len(r.tokens) == r.prompt_len for r in a[:8] + b[:8])
+    assert a[0].tokens != b[0].tokens
+    print(f"traffic {name}: {len(a)} requests, same multiset and schedule "
+          f"under two seeds; contents differ, order "
+          f"{'the same' if size == 1 else 'differs inside strata'}")
+
+
+def check_traffic() -> None:
     for name in sorted(os.listdir(os.path.join(HERE, "traffic"))):
-        spec = traffic.load(name[:-5])
-        if spec["kind"] == "train_steps":
-            continue
-        a, b = (traffic.requests(spec, s, 45.0) for s in seeds)
-        assert sorted(r.shape() for r in a) == sorted(r.shape() for r in b)
-        assert [r.shape() for r in a] != [r.shape() for r in b], \
-            f"{name}: the seed does not permute the order"
-        size = spec.get("stratum") or spec["stratum_slots"]
-        if spec["kind"] == "closed":
-            for i in range(0, len(a), size):
-                assert sorted(r.shape() for r in a[i:i + size]) == \
-                    sorted(r.shape() for r in b[i:i + size])
-            assert [r.doc for r in a if r.doc < 0] == []
-        else:
-            rate = spec["rate_hz"]
-            starts = {"ramp": 0.0, "window": spec["ramp_s"],
-                      "tail": spec["ramp_s"] + 45.0}
-            for reqs in (a, b):
-                for phase, start in starts.items():
-                    slots = [int((r.due - start) * rate + 1e-9)
-                             for r in reqs if r.phase == phase]
-                    assert slots == list(range(len(slots))), \
-                        f"{name}: not one request per slot in the {phase}"
-            for phase in ("ramp", "window", "tail"):
-                pa = [r for r in a if r.phase == phase]
-                pb = [r for r in b if r.phase == phase]
-                for i in range(0, len(pa), size):
-                    sa = pa[i:i + size], pb[i:i + size]
-                    assert sorted(r.prompt_len for r in sa[0]) == \
-                        sorted(r.prompt_len for r in sa[1])
-                    assert sorted(r.output_len for r in sa[0]) == \
-                        sorted(r.output_len for r in sa[1])
-        traffic.fill_tokens(a[:8], seeds[0], 512)
-        traffic.fill_tokens(b[:8], seeds[1], 512)
-        assert all(len(r.tokens) == r.prompt_len for r in a[:8] + b[:8])
-        assert a[0].tokens != b[0].tokens or a[0].shape() != b[0].shape()
-        print(f"traffic {name}: {len(a)} requests, same multiset and "
-              "schedule under two seeds; contents and order in strata "
-              "differ")
+        check_traffic_file(name[:-5])
 
 
 def check_stats() -> None:
@@ -151,7 +168,15 @@ def check_stats() -> None:
     assert stats.percentile(v, 50) == 50 and stats.median([1, 3]) == 2
     assert stats.gaps_ms([1.0, 1.5, 1.75]) == [500.0, 250.0]
     assert freeze.seconds([(1.0, 3.0), (5.0, 9.0)], 2.0, 6.0) == 2.0
-    print("stats: band means, percentiles, gaps and stall seconds as "
+    # Replies: due -> last token; failed or not whole = the window's length.
+    rec = dict(error=None, cut=False, finish={"n_tokens": 2})
+    ctx = {"window": [10.0, 20.0], "records": [
+        dict(rec, due=11.0, token_times=[11.5, 12.25]),
+        dict(rec, due=19.0, token_times=[19.5], finish=None, cut=True),
+        dict(rec, due=12.0, token_times=[], error="x"),
+        dict(rec, due=9.0, token_times=[9.5, 10.5])]}
+    assert client.replies_ms(ctx) == [1250.0, 10000.0, 10000.0]
+    print("stats: band means, percentiles, gaps, replies and stall seconds as "
           "defined")
 
 
@@ -215,11 +240,106 @@ def check_trace() -> None:
     print(f"trace: reduction agrees on {', '.join(seen)}")
 
 
+@functools.lru_cache(maxsize=None)
+def routed_report(seed: int, weights: str = "bfloat16", flip=None,
+                  family=None) -> Dict[str, Any]:
+    """`refcheck.report` on the toy routed family (`tests/toy_routed.py`):
+    seeded weights, a seeded prompt, eight greedy tokens served twice."""
+    import numpy as np
+
+    from . import refcheck
+    from .tests import toy_routed as toy
+    engine = toy.ToyEngine(toy.init_params(seed), weights=weights, flip=flip)
+    prompt = np.random.default_rng([seed, 5]).integers(
+        1, toy.CONFIG["vocab_size"], 40).tolist()
+    out = engine.generate(prompt, 8)
+    return refcheck.report(engine, family or toy, toy.CONFIG, prompt,
+                           [out, out])
+
+
+def check_routed(seeds=ROUTED_SEEDS) -> None:
+    sound = [routed_report(s) for s in seeds]
+    assert all(r["ok"] for r in sound), [r for r in sound if not r["ok"]]
+    plain_fails = [s for s, r in zip(seeds, sound) if not r["plain"]["ok"]]
+    assert plain_fails, "the plain check held a routed family on every seed"
+    control = [routed_report(s, weights="float8_e4m3fn") for s in seeds]
+    assert not any(r["ok"] for r in control), control
+    print(f"reference check, toy routed family, {len(sound)} seeds: the "
+          f"plain check fails seeds {plain_fails}; the family's own passes "
+          f"all (logit max <= {max(r['logit_max'] for r in sound):.3f}, rms "
+          f"<= {max(r['logit_rms'] for r in sound):.4f}, forced share <= "
+          f"{max(r['forced_share'] for r in sound):.3f}) and refuses fp8 "
+          f"weights on all (rms >= "
+          f"{min(r['logit_rms'] for r in control):.3f})")
+
+
+def check_dense_report() -> None:
+    """A family without `check`: every key and value of the report is what
+    the parent's `_bench_check` wrote (but the seconds it took)."""
+    import numpy as np
+
+    from ray_tpu.llm.engine import LLMEngine, SamplingParams
+
+    from . import refcheck
+    from .tests import parent_check
+    cell = load_cell("serve_chat")
+    shrink(cell)
+    family, config = cell["family"], cell["config"]
+    engine = LLMEngine(family.program_config(config, max_seq_len=128),
+                       max_batch=2, max_len=128, seed=2 ** 31 + 5,
+                       page_size=16)
+    rng = np.random.default_rng([2 ** 31 + 5, 5])
+    prompt = rng.integers(1, config["vocab_size"], 75).tolist()
+    served = engine.generate([prompt], SamplingParams(max_tokens=8)) * 2
+    want = parent_check.bench_check(engine, family, config, prompt, served)
+    got = refcheck.report(engine, family, config, prompt, served)
+    want.pop("seconds"), got.pop("seconds")
+    assert got == want and list(got) == list(want) and got["ok"], (got, want)
+
+    # The same report says no when the path under it is broken: a served
+    # token altered where it is produced, a prefill logit moved.
+    worst = int(np.argmin(np.asarray(engine._run_prefill(prompt)[0])))
+    altered = [[worst] + served[0][1:], served[1]]
+    assert not refcheck.report(engine, family, config, prompt, altered)["ok"]
+
+    class Moved:
+        params = engine.params
+
+        def _run_prefill(self, p):
+            logits, *rest = engine._run_prefill(p)
+            return (logits.at[7].add(1.0), *rest)
+    assert not refcheck.report(Moved(), family, config, prompt, served)["ok"]
+    print(f"reference check, dense family: the report is the parent's "
+          f"({sorted(got)}), and fails on an altered token or a moved logit")
+
+
+def check_shrink(tiny=None) -> None:
+    """`shrink` applies the family's `TINY` after its own, if it has one."""
+    import types
+    cell = load_cell("serve_chat")
+    if tiny is not None:
+        cell["family"] = types.SimpleNamespace(TINY=tiny)
+    shrink(cell)
+    for key, value in {**TINY, **(tiny or {})}.items():
+        assert cell["config"][key] == value, (key, cell["config"][key])
+    assert cell["traffic"]["engine"]["max_len"] == 2048 // CUT
+
+
+def check_refcheck() -> None:
+    check_routed()
+    check_dense_report()
+    from .tests import toy_routed
+    check_shrink()
+    check_shrink(toy_routed.TINY)
+    print("shrink: the family's TINY is applied after the dense keys")
+
+
 def unit() -> None:
     check_files()
     check_traffic()
     check_stats()
     check_trace()
+    check_refcheck()
 
 
 # ------------------------------------------------------------ rehearse ----

@@ -27,8 +27,10 @@ STREAM = dict(stream=True, method_name="stream_generate")
 # ------------------------------------------------------- one request ------
 
 def _offer(handle, req: traffic.Request, due_wall: float, stop,
-           records: List[dict], lock) -> None:
-    """Send one request and stamp every token's arrival (client clock)."""
+           records: List[dict], lock, at_token=None) -> None:
+    """Send one request and stamp every token's arrival (client clock).
+    `at_token` = (n, event): set the event when the n-th token is here, or
+    when the stream ends without it."""
     rec: Dict[str, Any] = {
         "index": req.index, "phase": req.phase, "due": due_wall,
         "sent": time.time(), "prompt_len": req.prompt_len,
@@ -44,33 +46,53 @@ def _offer(handle, req: traffic.Request, due_wall: float, stop,
                 rec["finish"] = item
                 break
             rec["token_times"].append(time.time())
+            if at_token and len(rec["token_times"]) == at_token[0]:
+                at_token[1].set()
             if stop.is_set():
                 rec["cut"] = True
                 stream.cancel()
                 break
     except Exception as e:              # the harness reports, never dies
         rec["error"] = repr(e)
+    if at_token:
+        at_token[1].set()
     rec["end"] = time.time()
 
 
-def _closed_loop(handle, reqs, callers: int, stagger_s: float, stop,
+def _closed_loop(handle, reqs, callers: int, stagger_tokens: int, stop,
                  records, lock) -> List[threading.Thread]:
     """`callers` threads over one list: each takes the list's next request
-    when its last answer ends, so a request is due when it is sent.  Caller
-    i starts i * stagger_s into the ramp: callers that start together get
-    equal output lengths in lockstep — one admission wave, then 64 ticks
-    with no arrival — which no set of independent callers does."""
+    when its last answer ends, so a request is due when it is sent.
+
+    With equal output lengths a caller keeps its PHASE for the whole run:
+    the tick, modulo the ticks a request takes, in which it is admitted.
+    Callers that start together, or while another's whole-prompt prefill
+    holds the engine, are admitted in one tick or in neighbouring ones and
+    stay so: every miss of one then waits for, or stalls, the other, and
+    which pairs stick is decided by a millisecond in the ramp (PERF.md §6,
+    PR 29).  So the phases are laid down by the engine's own clock: caller
+    i+1 sends its first request when caller i's first answer has its
+    `stagger_tokens`-th token, which puts the callers that many ticks (and
+    a round trip) apart whatever a tick lasts."""
     it = iter(reqs)
+    go = [threading.Event() for _ in range(callers + 1)]
+    go[0].set()
 
     def caller(i: int):
-        if stop.wait(i * stagger_s):
-            return
+        while not go[i].wait(0.05):
+            if stop.is_set():
+                return
+        first = True
         while not stop.is_set():
             with lock:
                 req = next(it, None)
             if req is None:
-                return
-            _offer(handle, req, time.time(), stop, records, lock)
+                break
+            _offer(handle, req, time.time(), stop, records, lock,
+                   (min(stagger_tokens, req.output_len), go[i + 1])
+                   if first else None)
+            first = False
+        go[i + 1].set()
 
     threads = [threading.Thread(target=caller, args=(i,), daemon=True)
                for i in range(callers)]
@@ -170,6 +192,7 @@ def run(cell: Dict[str, Any], *, seed: int, seconds: float, traced: bool,
     with cluster.runtime(
             cell["chips"], out_dir, require_tpu,
             config["deployment"].get("runtime_config")) as rt:
+        ctx["init_s"] = time.time() - t_proc    # runtime up; detail only
         dep = serve.deployment(
             BenchReplica, name=APP, num_replicas=1,
             ray_actor_options={"num_cpus": 1.0, **(
@@ -191,7 +214,8 @@ def run(cell: Dict[str, Any], *, seed: int, seconds: float, traced: bool,
         t_ramp = time.time()
         if spec["kind"] == "closed":
             workers = _closed_loop(handle, reqs, spec["callers"],
-                                   spec["stagger_s"], stop, records, lock)
+                                   spec["stagger_tokens"], stop, records,
+                                   lock)
         else:
             workers = [_open_loop(handle, reqs, t_ramp, stop, records,
                                   lock, pool)]

@@ -10,7 +10,9 @@ file) seeds everything structural; `--seed` never reaches it.
 
 Kinds:
   closed       N callers over one fixed list of requests; each caller takes
-               the list's next request when its last answer ends.
+               the list's next request when its last answer ends.  The
+               callers start `stagger_tokens` tokens of the one before
+               apart (serve_cell._closed_loop).
   open_grid    one request per slot of 1/rate seconds, at an offset inside
                the slot drawn from the seed: an open loop whose load per
                stratum is the same in every run.
@@ -140,9 +142,11 @@ def _closed(spec: Dict[str, Any], seed: int) -> List[Request]:
                 slots[place][1] = left - 1
     order = _permute_strata(n, spec["stratum"],
                             np.random.default_rng([seed, 1]))
-    # Moving requests inside a stratum changes no load: with stratum <=
-    # live (requests() refuses otherwise) a stratum holds at most two asks
-    # of one document, and whichever comes first is the one that misses.
+    # Moving requests inside a stratum keeps the multiset, not the load's
+    # timing: of two asks of one document in a stratum the first is the one
+    # that misses, so the order decides which request pays a whole-prompt
+    # prefill and which misses meet.  A list like that takes stratum 1 (the
+    # identity), and the seed is left the token ids (README.md).
     out = [reqs[j] for j in order]
     for i, r in enumerate(out):
         r.index = i

@@ -38,6 +38,7 @@ def train_loop(c: Dict[str, Any]) -> None:
 
     from . import families, trace as trace_mod
 
+    loop_wall = time.time()
     enable_compile_cache()
     config, spec, seed = c["config"], c["traffic"], c["seed"]
     family = families.load(config["family"])
@@ -143,7 +144,8 @@ def train_loop(c: Dict[str, Any]) -> None:
 
     train.report({
         "done": True, "device": device_report(), "check": check,
-        "ready_wall": ready_wall, "window": [t0, ends[-1]],
+        "loop_wall": loop_wall, "ready_wall": ready_wall,
+        "window": [t0, ends[-1]],
         "step_ends": ends, "losses": losses, "raised": raised,
         "warm_losses": warm_losses, "warm_s": warm_s,
         "tokens_per_step": B * S, "pallas_in_step": pallas,
@@ -161,6 +163,7 @@ def run(cell: Dict[str, Any], *, seed: int, seconds: float, traced: bool,
     with cluster.runtime(
             chips, out_dir, require_tpu,
             cell["config"]["deployment"].get("runtime_config")) as rt:
+        init_s = time.time() - t_proc           # runtime up; detail only
         result = JaxTrainer(
             train_loop,
             train_loop_config={
@@ -190,6 +193,8 @@ def run(cell: Dict[str, Any], *, seed: int, seconds: float, traced: bool,
                                    "path")
     t0, t1 = final["window"]
     return {**final, "kind": "train_steps", "chips": chips,
-            "seconds": seconds, "ready_s": final["ready_wall"] - t_proc,
+            "seconds": seconds, "init_s": init_s,
+            "loop_s": final["loop_wall"] - t_proc,
+            "ready_s": final["ready_wall"] - t_proc,
             "setup_s": t0 - t_proc, "session": rt.session_dir,
             "reports": len(result.metrics_history)}
