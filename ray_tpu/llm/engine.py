@@ -15,7 +15,14 @@ stack, so the engine is native JAX on the in-tree flagship transformer
     dense (max_batch, max_len) cache could not.  Pages are reserved at
     admission (no mid-flight exhaustion, no preemption machinery).
   - Prefill is compiled per prompt-length *bucket* (pow-2 padding) —
-    a handful of compilations total, amortized across all requests.
+    a handful of compilations total, amortized across all requests.  The
+    attention form is the bucket's: on a TPU, with 128-wide heads, a
+    whole-prompt bucket of 1,024 rows or more and a suffix bucket (a
+    prefix-cache hit, a chunk) of 128 or more run the blocked kernel
+    (ops/prefill_attention.py: no S x S scores, nothing run past the
+    prompt's real length, the prefix read from its pages); smaller
+    buckets, other head widths and the CPU build the scores in XLA.
+    `prefill_stats()` says which form the prefills took.
   - KV pool lives on device between steps (no host round-trips in the
     decode loop); only sampled token ids come back per step.
   - Tensor parallelism via GSPMD: pass ``mesh=`` and the engine shards
@@ -119,7 +126,40 @@ def _mlp(lp, x, cfg):
                           lp["mlp"]["w_down"].astype(dt))
 
 
-def _prefill_fn(params, tokens, length, cfg: TransformerConfig):
+def _prefill_path(cfg: TransformerConfig, rows: int, kv_sharding,
+                  page: Optional[int] = None, table_len: int = 0) -> str:
+    """The attention form a prefill of `rows` padded rows takes: "kernel"
+    (ops/prefill_attention.py) or "xla" (the expression in the prefill
+    bodies below).  Decided from the platform and the shapes alone; under a
+    `tp` mesh the kernel runs per shard, so a shard's heads decide."""
+    from ..ops.prefill_attention import prefill_path
+    tp = 1
+    if kv_sharding is not None and "tp" in kv_sharding.spec:
+        tp = kv_sharding.mesh.shape["tp"]
+    if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+        return "xla"
+    return prefill_path((rows, cfg.num_heads // tp, cfg.head_dim_),
+                        cfg.num_kv_heads // tp, cfg.dtype, page=page,
+                        table_len=table_len)
+
+
+def _prefill_attend(kv_sharding, paged: bool):
+    """The prefill bodies' kernel call for a pool placed as `kv_sharding`:
+    per shard on a mesh, as the decode step's (`_paged_attend`)."""
+    from ..ops.prefill_attention import prefill_attention
+    if kv_sharding is None:
+        return prefill_attention
+    from jax.sharding import PartitionSpec as P
+    spec = kv_sharding.spec
+    heads = P(None, "tp") if "tp" in spec else P()
+    pool = (spec, spec, P(), P(), P()) if paged else ()
+    return jax.shard_map(prefill_attention, mesh=kv_sharding.mesh,
+                         in_specs=(heads, heads, heads, P()) + pool,
+                         out_specs=heads, check_vma=False)
+
+
+def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
+                kv_sharding=None):
     """tokens (1, Sb) padded prompt → (last_logits (V,), k, v (L, Sb, KV, D)).
 
     Positions ≥ length produce garbage cache rows; decode masks them out
@@ -129,20 +169,27 @@ def _prefill_fn(params, tokens, length, cfg: TransformerConfig):
     x = params["embed"].astype(cfg.dtype)[tokens]
     cos, sin = rope_angles(S, cfg.head_dim_, cfg.rope_theta)
     groups = cfg.num_heads // cfg.num_kv_heads
+    kernel = _prefill_path(cfg, S, kv_sharding) == "kernel"
+    attend = _prefill_attend(kv_sharding, paged=False) if kernel else None
 
     def body(x, lp):
         h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
         q, k, v = _layer_qkv(lp, h, cfg)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        kr = jnp.repeat(k, groups, axis=2)
-        vr = jnp.repeat(v, groups, axis=2)
-        scores = jnp.einsum("bshd,bthd->bhst", q, kr) \
-            / jnp.sqrt(jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
-        mask = jnp.tril(jnp.ones((S, S), bool))
-        scores = jnp.where(mask[None, None], scores, -1e30)
-        p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhst,bthd->bshd", p, vr)
+        if kernel:
+            # Blocked, no S x S scores, nothing run past `length`.
+            o = attend(q[0], k[0], v[0], length)[None]
+        else:
+            kr = jnp.repeat(k, groups, axis=2)
+            vr = jnp.repeat(v, groups, axis=2)
+            scores = jnp.einsum("bshd,bthd->bhst", q, kr) / jnp.sqrt(
+                jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
+            mask = jnp.tril(jnp.ones((S, S), bool))
+            scores = jnp.where(mask[None, None], scores, -1e30)
+            p = jax.nn.softmax(scores.astype(jnp.float32),
+                               axis=-1).astype(q.dtype)
+            o = jnp.einsum("bhst,bthd->bshd", p, vr)
         o = jnp.einsum("bshd,hde->bse", o,
                        lp["attn"]["wo"].astype(cfg.dtype))
         x = _mlp(lp, x + o, cfg)
@@ -278,7 +325,8 @@ def _decode_fn(params, pool_k, pool_v, tables, last_tokens, lengths, active,
 
 
 def _suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
-                       length, cfg: TransformerConfig, page: int):
+                       length, cfg: TransformerConfig, page: int,
+                       kv_sharding=None):
     """Suffix half of a prefix-cache hit: run the transformer over ONLY
     tokens[prefix_len:] while attending to the cached KV of
     tokens[:prefix_len] already resident in the pool's shared pages.
@@ -307,30 +355,42 @@ def _suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     qpos = jnp.arange(Sb)
     valid = (tpos[None, :] < prefix_len) | (
         (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
+    kernel = _prefill_path(cfg, Sb, kv_sharding, page, P) == "kernel"
+    attend = _prefill_attend(kv_sharding, paged=True) if kernel else None
 
     def body(x, layer):
-        lp, pk, pv = layer                  # pk/pv: (N, page, KV, D)
+        lp, *at = layer
         h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
         q, k, v = _layer_qkv(lp, h, cfg)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        ck = pk[pages].reshape(T, -1, cfg.head_dim_)
-        cv = pv[pages].reshape(T, -1, cfg.head_dim_)
-        kk = jnp.concatenate([ck[None], k], axis=1)   # (1, T+Sb, KV, D)
-        vv = jnp.concatenate([cv[None], v], axis=1)
-        kr = jnp.repeat(kk, groups, axis=2)
-        vr = jnp.repeat(vv, groups, axis=2)
-        scores = jnp.einsum("bshd,bthd->bhst", q, kr) \
-            / jnp.sqrt(jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
-        scores = jnp.where(valid[None, None], scores, -1e30)
-        p = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(q.dtype)
-        o = jnp.einsum("bhst,bthd->bshd", p, vr)
+        if kernel:
+            # The whole pool goes in as it lies; the kernel copies the
+            # pages below `prefix_len` of layer `at[0]` and no other.
+            o = attend(q[0], k[0], v[0], length, pool_k, pool_v, pages,
+                       prefix_len, at[0])[None]
+        else:
+            pk, pv = at                     # (N, page, KV, D)
+            ck = pk[pages].reshape(T, -1, cfg.head_dim_)
+            cv = pv[pages].reshape(T, -1, cfg.head_dim_)
+            kk = jnp.concatenate([ck[None], k], axis=1)   # (1, T+Sb, KV, D)
+            vv = jnp.concatenate([cv[None], v], axis=1)
+            kr = jnp.repeat(kk, groups, axis=2)
+            vr = jnp.repeat(vv, groups, axis=2)
+            scores = jnp.einsum("bshd,bthd->bhst", q, kr) / jnp.sqrt(
+                jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
+            scores = jnp.where(valid[None, None], scores, -1e30)
+            p = jax.nn.softmax(scores.astype(jnp.float32),
+                               -1).astype(q.dtype)
+            o = jnp.einsum("bhst,bthd->bshd", p, vr)
         o = jnp.einsum("bshd,hde->bse", o,
                        lp["attn"]["wo"].astype(cfg.dtype))
         x = _mlp(lp, x + o, cfg)
         return x, (k[0], v[0])
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], pool_k, pool_v))
+    at = (jnp.arange(pool_k.shape[0], dtype=jnp.int32),) if kernel \
+        else (pool_k, pool_v)
+    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], *at))
     x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
     last = x[0, length - 1]
     logits = jnp.einsum("e,ev->v", last, params["lm_head"].astype(cfg.dtype),
@@ -808,6 +868,12 @@ class LLMEngine:
         self._decode_steps = 0
         self._pages_read = 0
         self._step_pages_read = 0
+        # Which attention form the prefills took (ops/prefill_attention.py)
+        # and how many key blocks they ran beside what S x S covers; the
+        # last one's, as its `prefill` span carries them.
+        self._prefill_stats = {"path": "", "kernel_calls": 0, "xla_calls": 0,
+                               "kv_blocks_run": 0, "kv_blocks_dense": 0}
+        self._prefill_ran: Dict[str, Any] = {}
         page, kv_shd = self.page, self._kv_shd
         # The decode step stays a lambda ON PURPOSE: the benchmark's
         # `decode_tick` and `decode_roofline` readers pick it out of a
@@ -1055,6 +1121,31 @@ class LLMEngine:
                 "step_pages_read": self._step_pages_read,
                 "step_pages_addressable": per_step}
 
+    def prefill_stats(self) -> Dict[str, Any]:
+        """The attention form of the last prefill (`path`: "kernel" or
+        "xla"), how many prefills took each, and the key blocks they ran
+        beside the blocks of the dense S x S form (`kv_blocks_dense`)."""
+        return dict(self._prefill_stats)
+
+    def _count_prefill(self, rows: int, padded: int,
+                       prefix_len: Optional[int] = None) -> None:
+        """Host-side count of one prefill of `rows` real rows in a
+        `padded` bucket (`prefix_len` given: the suffix form), from the
+        shapes alone: nothing is read back."""
+        from ..ops.prefill_attention import kv_blocks
+        row = () if prefix_len is None else (self.page, self.pages_per_slot)
+        path = _prefill_path(self.cfg, padded, self._kv_shd, *row) \
+            if self.sp_degree == 1 else "xla"
+        run, dense = kv_blocks(rows, padded, prefix_len or 0,
+                               math.prod(row) if row else 0)
+        self._prefill_ran = {"path": path,
+                             "kv_blocks": run if path == "kernel" else dense}
+        st = self._prefill_stats
+        st["path"] = path
+        st[path + "_calls"] += 1
+        st["kv_blocks_run"] += self._prefill_ran["kv_blocks"]
+        st["kv_blocks_dense"] += dense
+
     def prefix_cache_stats(self) -> Dict[str, Any]:
         if self._cache is None:
             return {"enabled": False}
@@ -1095,11 +1186,14 @@ class LLMEngine:
                     return self._sp.sp_prefill_fn(p, t, n, cfg, mesh, strat)
                 self._prefill_jit[key] = jax.jit(sp_prefill)
             else:
+                kv_shd = self._kv_shd
+
                 def prefill(p, t, n):
-                    return _prefill_fn(p, t, n, cfg)
+                    return _prefill_fn(p, t, n, cfg, kv_shd)
                 self._prefill_jit[key] = jax.jit(prefill)
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = prompt
+        self._count_prefill(S, Sb)
         return self._prefill_jit[key](self.params, jnp.asarray(toks), S)
 
     # ------------------------------------------------------ page refcounts --
@@ -1289,12 +1383,15 @@ class LLMEngine:
                         p, pk, pv, pg, t, pl, n, cfg, page, mesh)
                 self._prefill_jit[key] = jax.jit(sp_suffix_prefill)
             else:
+                kv_shd = self._kv_shd
+
                 def suffix_prefill(p, pk, pv, pg, t, pl, n):
                     return _suffix_prefill_fn(
-                        p, pk, pv, pg, t, pl, n, cfg, page)
+                        p, pk, pv, pg, t, pl, n, cfg, page, kv_shd)
                 self._prefill_jit[key] = jax.jit(suffix_prefill)
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = suf
+        self._count_prefill(S, Sb, prefix_len)
         return self._prefill_jit[key](
             self.params, self._pk, self._pv, jnp.asarray(pages_row),
             jnp.asarray(toks), prefix_len, S)
@@ -1338,10 +1435,11 @@ class LLMEngine:
             else:
                 logits, ks, vs = self._run_prefill(req.prompt)
                 self._install(req.slot, ks, vs)
+            ran = {} if req.kv_blob is not None else self._prefill_ran
             ph.leave(t0, "prefill", req.req_id.to_bytes(8, "little"),
                      tokens=S, cached_tokens=req.prefix_len,
                      active=active_before, n=ph.n,
-                     new_program=len(self._prefill_jit) - programs)
+                     new_program=len(self._prefill_jit) - programs, **ran)
             if self._cache is not None and not req.no_cache:
                 self._cache.insert(req.prompt, self._tables[req.slot],
                                    self._incref)
@@ -1566,7 +1664,8 @@ class LLMEngine:
             ph.leave(t0, "prefill", req.req_id.to_bytes(8, "little"),
                      tokens=nxt, cached_tokens=req.prefilled, chunked=True,
                      active=len(self._slots), n=ph.n,
-                     new_program=len(self._prefill_jit) - programs)
+                     new_program=len(self._prefill_jit) - programs,
+                     **self._prefill_ran)
             req.prefilled = nxt
             if nxt >= S:
                 del self._prefilling[slot]

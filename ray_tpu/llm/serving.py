@@ -803,6 +803,7 @@ class EngineReplica:
                 "prefix_cache": e.prefix_cache_stats(),
                 "kv_gather": e.kv_gather_stats(),
                 "decode": e.decode_stats(),
+                "prefill": e.prefill_stats(),
                 "tick": self._phases.snapshot()}
 
     async def pid(self) -> int:

@@ -386,7 +386,12 @@ def test_debug_stats_count_pages_read():
         assert len(out["tokens"]) == n_out
         return await er.debug_stats()
 
-    st = asyncio.run(run())["decode"]
+    stats = asyncio.run(run())
+    # One whole-prompt prefill of 30 tokens in a 32 bucket, on the CPU.
+    assert stats["prefill"] == {"path": "xla", "kernel_calls": 0,
+                                "xla_calls": 1, "kv_blocks_run": 1,
+                                "kv_blocks_dense": 1}
+    st = stats["decode"]
     # Prefill gives the first token; decode step i attends n_prompt + i.
     lengths = [n_prompt + i for i in range(n_out - 1)]
     assert st["path"] == "reference"
@@ -395,3 +400,110 @@ def test_debug_stats_count_pages_read():
     assert st["pages_addressable"] == len(lengths) * 2 * (128 // page)
     assert st["step_pages_read"] == lengths[-1] // page + 1
     assert st["step_pages_addressable"] == 2 * (128 // page)
+
+
+# ---- prefill without the S x S scores (ops/prefill_attention.py) ---------
+
+@pytest.fixture
+def prefill_kernel(monkeypatch):
+    """Call it to steer the prefill bodies onto the kernel, interpreted, in
+    blocks of 128 from 128 padded rows up (there is no TPU here; the chooser
+    itself is in tests/test_prefill_attention.py)."""
+    import functools
+
+    from ray_tpu.ops import prefill_attention as pfa
+
+    def steer():
+        monkeypatch.setattr(pfa, "_BLOCK", 128)
+        monkeypatch.setattr(
+            pfa, "prefill_path", lambda q, kv, dtype, **kw: "kernel"
+            if q[0] >= 128 and pfa.kernel_tiles(q, kv, dtype, **kw)
+            else "xla")
+        monkeypatch.setattr(pfa, "_prefill_attention_pallas",
+                            functools.partial(pfa._prefill_attention_pallas,
+                                              interpret=True))
+    return steer
+
+
+def _tiny_wide():
+    """`tiny` with 128-wide heads, which the prefill kernel tiles; float32
+    as `tiny` is, so the two forms agree to rounding."""
+    import dataclasses
+    return dataclasses.replace(CFG, head_dim=128, max_seq_len=512)
+
+
+@pytest.mark.parametrize("form", ["whole", "suffix"])
+def test_prefill_bodies_through_the_kernel_match_xla(form, prefill_kernel):
+    """`_prefill_fn` / `_suffix_prefill_fn` with the kernel against their
+    XLA expression: last-token logits and the rows to install below
+    `length` (rows past it are garbage under either)."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.llm import engine as E
+
+    cfg, page, rows, length = _tiny_wide(), 16, 256, 150
+    rng = np.random.default_rng(0)
+    eng = LLMEngine(cfg, max_batch=2, max_len=512, page_size=page, seed=0)
+    toks = np.zeros((1, rows), np.int32)
+    toks[0, :length] = rng.integers(1, cfg.vocab_size, length)
+    if form == "whole":
+        fn = lambda: jax.jit(lambda p, t, n: E._prefill_fn(p, t, n, cfg))(
+            eng.params, jnp.asarray(toks), length)
+    else:
+        # Slot 0 holds a 100-token prompt; its first 4 pages are the prefix.
+        eng.add_request(rng.integers(1, cfg.vocab_size, 100).tolist(),
+                        SamplingParams(max_tokens=4))
+        assert eng._admit() == 1
+        row = jnp.asarray(eng._tables[next(iter(eng._slots))])
+        fn = lambda: jax.jit(
+            lambda p, pk, pv, pg, t, pl, n: E._suffix_prefill_fn(
+                p, pk, pv, pg, t, pl, n, cfg, page))(
+            eng.params, eng._pk, eng._pv, row, jnp.asarray(toks), 4 * page,
+            length)
+    want = [np.asarray(a) for a in fn()]
+    prefill_kernel()
+    assert E._prefill_path(cfg, rows, None, *(
+        (page, eng.pages_per_slot) if form == "suffix" else ())) == "kernel"
+    got = [np.asarray(a) for a in fn()]
+    assert all(np.isfinite(a).all() for a in got)
+    assert np.abs(got[0] - want[0]).max() < 2e-4 * np.abs(want[0]).max()
+    for g, w in zip(got[1:], want[1:]):                    # ks, vs
+        assert g.shape == w.shape == (cfg.num_layers, rows,
+                                      cfg.num_kv_heads, 128)
+        assert np.abs(g[:, :length] - w[:, :length]).max() < 1e-4
+
+
+def test_prefix_hit_through_the_kernel_gives_the_whole_prompts_tokens(
+        prefill_kernel):
+    """Greedy tokens of a prefix-cache hit (suffix form over 4 cached pages)
+    equal those of the same prompt prefilled whole, both through the
+    kernel; `prefill_stats()` (served as `debug_stats()["prefill"]`) counts
+    the calls of each form and the key blocks run beside the dense ones."""
+    cfg, page = _tiny_wide(), 16
+    rng = np.random.default_rng(1)
+    first = rng.integers(1, cfg.vocab_size, 200).tolist()
+    second = first[:4 * page] + rng.integers(1, cfg.vocab_size, 150).tolist()
+    sp = SamplingParams(max_tokens=6)
+    xla = LLMEngine(cfg, max_batch=2, max_len=512, page_size=page,
+                    seed=0).generate([second], sp)[0]
+    prefill_kernel()
+    whole = LLMEngine(cfg, max_batch=2, max_len=512, page_size=page, seed=0)
+    assert whole.generate([second], sp)[0] == xla
+    assert whole.prefill_stats() == {
+        "path": "kernel", "kernel_calls": 1, "xla_calls": 0,
+        "kv_blocks_run": 3, "kv_blocks_dense": 4}   # 214 rows in 2 x 128
+    hit = LLMEngine(cfg, max_batch=2, max_len=512, page_size=page, seed=0,
+                    prefix_cache=True)
+    hit.generate([first], sp)
+    assert hit.generate([second], sp)[0] == xla
+    assert hit.prefix_cache_stats()["hit_pages"] == 4
+    st = hit.prefill_stats()
+    # 200 rows whole: 3 of 4; 150 suffix rows after 64 cached tokens: 3 + 2
+    # of the 2 x (512 + 256) / 128 a gathered page row would cover.
+    assert (st["path"], st["kernel_calls"], st["xla_calls"]) == \
+        ("kernel", 2, 0)
+    assert (st["kv_blocks_run"], st["kv_blocks_dense"]) == (3 + 5, 4 + 12)
+    hit.generate([[5, 6, 7]], sp)                       # an 8-row bucket
+    st = hit.prefill_stats()
+    assert (st["path"], st["kernel_calls"], st["xla_calls"]) == ("xla", 2, 1)
+    assert st["kv_blocks_run"] <= st["kv_blocks_dense"]

@@ -227,26 +227,20 @@ def test_kernel_compiles_for_v5e(shape, topo, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("mesh_axes", [None, {"tp": 2}, {"sp": 2}])
-def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
-                                               no_compile_cache):
-    """The engine's decode step at the cells' widths (two layers), on one
-    chip and under `shard_map` on a tp and an sp mesh: the kernel is in the
-    program, both pools alias their outputs, and nothing pool-sized is
-    made beside them."""
+def _cell(topo, mesh_axes, page=16):
+    """The serving cells' widths (two layers) placed on one described chip
+    or a two-chip `mesh_axes` mesh: cfg, kv_sharding, a ShapeDtypeStruct
+    maker, the params' shapes and the pool's."""
     from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                               SingleDeviceSharding)
-    from ray_tpu.llm import engine as E
     from ray_tpu.models.transformer import (TransformerConfig, init_params,
                                             param_logical_axes)
     from ray_tpu.parallel.sharding import LogicalAxisRules, tree_shardings
 
-    monkeypatch.setattr(pa, "decode_path", lambda *shapes: "pallas")
     cfg = TransformerConfig(
         vocab_size=32768, hidden_size=4096, intermediate_size=14336,
         num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
         max_seq_len=2048, rope_theta=1e6)
-    B, page, P_ = 16, 16, 128
     if mesh_axes is None:
         rep = SingleDeviceSharding(topo.devices[0])
         kv_shd, par_shd, pool_shd = None, rep, rep
@@ -269,6 +263,21 @@ def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
     params = jax.tree.map(lambda a, shd: S(a.shape, a.dtype, shd),
                           params, par_shd)
     pool = S((cfg.num_layers, 3073, page, 8, 128), cfg.dtype, pool_shd)
+    return cfg, kv_shd, S, params, pool
+
+
+@pytest.mark.parametrize("mesh_axes", [None, {"tp": 2}, {"sp": 2}])
+def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
+                                               no_compile_cache):
+    """The engine's decode step at the cells' widths (two layers), on one
+    chip and under `shard_map` on a tp and an sp mesh: the kernel is in the
+    program, both pools alias their outputs, and nothing pool-sized is
+    made beside them."""
+    from ray_tpu.llm import engine as E
+
+    monkeypatch.setattr(pa, "decode_path", lambda *shapes: "pallas")
+    B, page, P_ = 16, 16, 128
+    cfg, kv_shd, S, params, pool = _cell(topo, mesh_axes, page)
     key = jax.eval_shape(lambda: jax.random.key(0))
 
     def decode_step(p, pk, pv, tb, lt, ln, ac, tp, rn):
@@ -285,3 +294,80 @@ def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
                                                else 1)
     assert mem.alias_size_in_bytes >= 2 * per_device
     assert mem.temp_size_in_bytes < per_device // 8
+
+
+# ---- the prefill kernel (ops/prefill_attention.py) ------------------------
+# Its interpreted cases are in tests/test_prefill_attention.py; what needs
+# the described chip is here, in the one file that describes it.
+
+@pytest.mark.parametrize("shape", [
+    # rows, KV, groups, dtype, prefix in pages: serve_doc_reask's bucket,
+    # the reference check's, the suffix form at its largest and smallest,
+    # float32 and one KV head
+    (4096, 8, 4, jnp.bfloat16, False),
+    (1024, 8, 4, jnp.bfloat16, False),
+    (4096, 8, 4, jnp.bfloat16, True),
+    (128, 8, 4, jnp.bfloat16, True),
+    (512, 4, 2, jnp.float32, True),
+    (256, 1, 8, jnp.bfloat16, True),
+])
+def test_prefill_kernel_compiles_for_v5e(shape, topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import prefill_attention as pfa
+    rows, KV, groups, dtype, paged = shape
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    assert pfa.kernel_tiles((rows, KV * groups, D), KV, dtype,
+                            **(dict(page=16, table_len=256) if paged else {}))
+    args = [S((rows, KV * groups, D), dtype), S((rows, KV, D), dtype),
+            S((rows, KV, D), dtype), S((), jnp.int32)]
+    if paged:
+        pool = S((3, 3073, 16, KV, D), dtype)
+        args += [pool, pool, S((256,), jnp.int32), S((), jnp.int32),
+                 S((), jnp.int32)]
+    compiled = jax.jit(lambda *a: pfa._prefill_attention_pallas(
+        *a, scale=1 / math.sqrt(D))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "prefill_attention" in text
+    # The pool reaches the kernel as it lies in HBM: no copy of it, no slice.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("mesh_axes", [None, {"tp": 2}])
+@pytest.mark.parametrize("form", ["whole", "suffix"])
+def test_prefill_bodies_compile_for_v5e_without_scores(
+        form, mesh_axes, topo, monkeypatch, no_compile_cache):
+    """The engine's two prefill bodies at the cells' widths (two layers) and
+    the 4,096 bucket, on one chip and under `shard_map` on a tp mesh: the
+    kernel is in the program, and no (H, S, S) or (H, Sb, T + Sb) array, no
+    widened K or V and nothing pool-sized is made."""
+    import re
+    from ray_tpu.llm import engine as E
+
+    # `prefill_path` asks the platform: let it see the described chips.
+    monkeypatch.setattr(jax, "devices", lambda *a: topo.devices)
+    rows, page, P_ = 4096, 16, 256
+    cfg, kv_shd, S, params, pool = _cell(topo, mesh_axes, page)
+    assert E._prefill_path(cfg, rows, kv_shd) == "kernel"
+    assert E._prefill_path(cfg, 128, kv_shd, page, P_) == "kernel"
+    assert E._prefill_path(cfg, 512, kv_shd) == "xla"       # under MIN_ROWS
+    toks = S((1, rows), jnp.int32)
+    if form == "whole":
+        def prefill(p, t, n):
+            return E._prefill_fn(p, t, n, cfg, kv_shd)
+        lowered = jax.jit(prefill).lower(params, toks, S((), jnp.int32))
+    else:
+        def suffix_prefill(p, pk, pv, pg, t, pl, n):
+            return E._suffix_prefill_fn(p, pk, pv, pg, t, pl, n, cfg, page,
+                                        kv_shd)
+        lowered = jax.jit(suffix_prefill).lower(
+            params, pool, pool, S((P_,), jnp.int32), toks,
+            S((), jnp.int32), S((), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "prefill_attention" in text
+    assert not re.search(r"\[(1,)?(32|16),4096,(4096|8192)\]", text)
+    assert not re.search(r"[\[,]8192[\],]", text)    # no [row's keys | new]
+    # 145 MiB (q, k, v and the MLP's rows); with the scores the whole form
+    # took 1.07 GiB and the suffix form, with its page row's keys, 2.1.
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
