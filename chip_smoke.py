@@ -5,8 +5,8 @@ from a driver that never initialises a JAX backend:
 
   train:  ray_tpu.init() -> JaxTrainer(use_tpu=True) -> one TrainWorker actor
           holds the host's chips and takes optimizer steps with
-          make_train_step on the configuration bench.py measures on a 16 GB
-          chip (hidden 2048, 16 heads x 128, 10 layers, batch 8 x 2048, bf16,
+          make_train_step on a configuration sized for a 16 GB chip
+          (hidden 2048, 16 heads x 128, 10 layers, batch 8 x 2048, bf16,
           Pallas flash attention), reporting through train.report.
   serve:  as soon as that worker has exited, serve.run(build_llm_app("1b",
           num_tpus=1, ...)) -> streamed requests through
@@ -34,7 +34,7 @@ import sys
 import time
 from typing import Any, Dict, List, Sequence, Union
 
-# The configuration bench.py picks for a 16 GB chip (bench.py pick_config).
+# Sized for a 16 GB chip with its optimizer state.
 MODEL = dict(vocab_size=32000, hidden_size=2048, intermediate_size=5504,
              num_layers=10, num_heads=16, num_kv_heads=16, max_seq_len=2048)
 # Mesh of the one TrainWorker, by the number of chips it holds.

@@ -52,8 +52,10 @@ import numpy as np
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
-from ..models.transformer import (TransformerConfig, apply_rope, init_params,
-                                  param_logical_axes, rms_norm, rope_angles)
+from ..models.transformer import (TransformerConfig, decoder_block,
+                                  embed_tokens, init_params, lm_logits,
+                                  param_logical_axes, rope_angles,
+                                  scan_blocks)
 from .tick_phases import TickPhases
 
 
@@ -109,29 +111,12 @@ class _Request:
 # Pure compiled pieces
 # --------------------------------------------------------------------------
 
-def _layer_qkv(lp, h, cfg):
-    dt = cfg.dtype
-    q = jnp.einsum("bse,ehd->bshd", h, lp["attn"]["wq"].astype(dt))
-    k = jnp.einsum("bse,ekd->bskd", h, lp["attn"]["wk"].astype(dt))
-    v = jnp.einsum("bse,ekd->bskd", h, lp["attn"]["wv"].astype(dt))
-    return q, k, v
-
-
-def _mlp(lp, x, cfg):
-    dt = cfg.dtype
-    h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
-    g = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_gate"].astype(dt))
-    u = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_up"].astype(dt))
-    return x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                          lp["mlp"]["w_down"].astype(dt))
-
-
 def _prefill_path(cfg: TransformerConfig, rows: int, kv_sharding,
                   page: Optional[int] = None, table_len: int = 0) -> str:
     """The attention form a prefill of `rows` padded rows takes: "kernel"
-    (ops/prefill_attention.py) or "xla" (the expression in the prefill
-    bodies below).  Decided from the platform and the shapes alone; under a
-    `tp` mesh the kernel runs per shard, so a shard's heads decide."""
+    (ops/prefill_attention.py) or "xla" (`_xla_prefill_attention`).
+    Decided from the platform and the shapes alone; under a `tp` mesh the
+    kernel runs per shard, so a shard's heads decide."""
     from ..ops.prefill_attention import prefill_path
     tp = 1
     if kv_sharding is not None and "tp" in kv_sharding.spec:
@@ -143,19 +128,88 @@ def _prefill_path(cfg: TransformerConfig, rows: int, kv_sharding,
                         table_len=table_len)
 
 
-def _prefill_attend(kv_sharding, paged: bool):
-    """The prefill bodies' kernel call for a pool placed as `kv_sharding`:
-    per shard on a mesh, as the decode step's (`_paged_attend`)."""
-    from ..ops.prefill_attention import prefill_attention
+def _per_shard(kernel, kv_sharding, args: str):
+    """A Pallas attention kernel as it runs beside a pool placed as
+    `kv_sharding`.  The kernel is a custom call the GSPMD partitioner cannot
+    split, so on a mesh it runs per shard (training's flash kernel does the
+    same, models/transformer.py:_flash_attention): KV heads and their query
+    groups over `tp`, everything else whole on every device.  `args` names
+    the kernel's positional arguments: "h" one split by heads, "p" a pool as
+    it lies, "." one every device holds whole."""
     if kv_sharding is None:
-        return prefill_attention
+        return kernel
     from jax.sharding import PartitionSpec as P
     spec = kv_sharding.spec
-    heads = P(None, "tp") if "tp" in spec else P()
-    pool = (spec, spec, P(), P(), P()) if paged else ()
-    return jax.shard_map(prefill_attention, mesh=kv_sharding.mesh,
-                         in_specs=(heads, heads, heads, P()) + pool,
-                         out_specs=heads, check_vma=False)
+    by = {"h": P(None, "tp") if "tp" in spec else P(), "p": spec, ".": P()}
+    return jax.shard_map(kernel, mesh=kv_sharding.mesh,
+                         in_specs=tuple(by[a] for a in args),
+                         out_specs=by["h"], check_vma=False)
+
+
+def _xla_prefill_attention(q, k, v, mask, cfg: TransformerConfig):
+    """A prefill's attention with the scores built: q (1, Sb, H, D) over
+    k, v (1, T, KV, D), key t open to query s where mask[s, t]."""
+    groups = cfg.num_heads // cfg.num_kv_heads
+    kr = jnp.repeat(k, groups, axis=2)
+    vr = jnp.repeat(v, groups, axis=2)
+    scores = jnp.einsum("bshd,bthd->bhst", q, kr) / jnp.sqrt(
+        jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
+    scores = jnp.where(mask[None, None], scores, -1e30)
+    p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhst,bthd->bshd", p, vr)
+
+
+def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
+                    cached=None):
+    """The one place a prefill's attention form is chosen (`_prefill_path`).
+    For `rows` padded rows of which `length` are real and, in the suffix
+    form, `cached` = (pool_k, pool_v, pages, prefix_len, page) — the slot's
+    page row, whose first `prefix_len` tokens precede row 0 — returns
+    (attend, per_layer) for `scan_blocks`: `attend(q, k, v, *at)` gives
+    (o, the layer's new cache rows (k[0], v[0]))."""
+    pool = per_layer = ()
+    if cached is None:
+        path = _prefill_path(cfg, rows, kv_sharding)
+    else:
+        pool_k, pool_v, pages, prefix_len, page = cached
+        T = pages.shape[0] * page
+        path = _prefill_path(cfg, rows, kv_sharding, page, pages.shape[0])
+    if path == "kernel":
+        # Blocked, no S x S scores, nothing run past `length`.  The whole
+        # pool goes in as it lies; the kernel copies the pages below
+        # `prefix_len` of layer `li` and no other.
+        from ..ops.prefill_attention import prefill_attention
+        kernel = _per_shard(prefill_attention, kv_sharding,
+                            "hhh.pp..." if cached else "hhh.")
+        if cached:
+            pool = (pool_k, pool_v, pages, prefix_len)
+            per_layer = (jnp.arange(pool_k.shape[0], dtype=jnp.int32),)
+
+        def scores(q, k, v, *li):
+            return kernel(q[0], k[0], v[0], length, *pool, *li)[None]
+    elif cached is None:
+        def scores(q, k, v):
+            mask = jnp.tril(jnp.ones((rows, rows), bool))
+            return _xla_prefill_attention(q, k, v, mask, cfg)
+    else:
+        # Key t (over [cached T | suffix Sb]) is valid for suffix query s
+        # iff it is a REAL cached prefix position or a suffix position <= s.
+        tpos = jnp.arange(T + rows)
+        qpos = jnp.arange(rows)
+        mask = (tpos[None, :] < prefix_len) | (
+            (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
+        per_layer = (pool_k, pool_v)
+
+        def scores(q, k, v, pk, pv):        # pk, pv: (N, page, KV, D)
+            ck = pk[pages].reshape(T, -1, cfg.head_dim_)
+            cv = pv[pages].reshape(T, -1, cfg.head_dim_)
+            return _xla_prefill_attention(
+                q, jnp.concatenate([ck[None], k], axis=1),
+                jnp.concatenate([cv[None], v], axis=1), mask, cfg)
+
+    def attend(q, k, v, *at):
+        return scores(q, k, v, *at), (k[0], v[0])   # drop the B=1 dim
+    return attend, per_layer
 
 
 def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
@@ -165,41 +219,13 @@ def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
     Positions ≥ length produce garbage cache rows; decode masks them out
     via per-slot lengths, and the last-real-token logits only attend
     backwards (causal), so padding never leaks into results."""
-    B, S = tokens.shape
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    cos, sin = rope_angles(S, cfg.head_dim_, cfg.rope_theta)
-    groups = cfg.num_heads // cfg.num_kv_heads
-    kernel = _prefill_path(cfg, S, kv_sharding) == "kernel"
-    attend = _prefill_attend(kv_sharding, paged=False) if kernel else None
-
-    def body(x, lp):
-        h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-        q, k, v = _layer_qkv(lp, h, cfg)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if kernel:
-            # Blocked, no S x S scores, nothing run past `length`.
-            o = attend(q[0], k[0], v[0], length)[None]
-        else:
-            kr = jnp.repeat(k, groups, axis=2)
-            vr = jnp.repeat(v, groups, axis=2)
-            scores = jnp.einsum("bshd,bthd->bhst", q, kr) / jnp.sqrt(
-                jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
-            mask = jnp.tril(jnp.ones((S, S), bool))
-            scores = jnp.where(mask[None, None], scores, -1e30)
-            p = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-            o = jnp.einsum("bhst,bthd->bshd", p, vr)
-        o = jnp.einsum("bshd,hde->bse", o,
-                       lp["attn"]["wo"].astype(cfg.dtype))
-        x = _mlp(lp, x + o, cfg)
-        return x, (k[0], v[0])              # drop the B=1 dim for the cache
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    last = x[0, length - 1]
-    logits = jnp.einsum("e,ev->v", last, params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
-    return logits, ks, vs
+    S = tokens.shape[1]
+    x = embed_tokens(params, tokens, cfg)
+    cos, sin = rope_angles(jnp.arange(0, S, dtype=jnp.float32), cfg)
+    attend, per_layer = _prefill_attend(cfg, S, length, kv_sharding)
+    x, (ks, vs) = scan_blocks(params["layers"], x, cos, sin, attend, cfg,
+                              per_layer)
+    return lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
 def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
@@ -225,24 +251,6 @@ def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
     return pool_k, pool_v
 
 
-def _paged_attend(kv_sharding):
-    """The decode step's attention call for a pool placed as `kv_sharding`.
-
-    The Pallas kernel is a custom call the GSPMD partitioner cannot split,
-    so on a mesh it runs per shard (training's flash kernel does the same,
-    models/transformer.py:_flash_attention): KV heads and their query
-    groups over `tp`, everything else whole on every device."""
-    from ..ops.paged_attention import paged_decode_attention
-    if kv_sharding is None:
-        return paged_decode_attention
-    from jax.sharding import PartitionSpec as P
-    spec = kv_sharding.spec
-    heads = P(None, "tp") if "tp" in spec else P()
-    return jax.shard_map(paged_decode_attention, mesh=kv_sharding.mesh,
-                         in_specs=(heads, spec, spec, P(), P(), P()),
-                         out_specs=heads, check_vma=False)
-
-
 def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
                       active, cfg: TransformerConfig, page: int, kv_sharding):
     """The model half of a decode step: every slot's last token through the
@@ -252,41 +260,31 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
     new token lands; attention (ops/paged_attention.py) reads the pages a
     slot holds.  Nothing in the step is sized by the pool or by
     max_batch x max_len but the donated pool itself."""
+    from ..ops.paged_attention import paged_decode_attention
     # An inactive slot is one token on the scratch page: it costs one page
     # and what it computes is dropped.
     tables = jnp.where(active[:, None], tables, 0)
     lengths = jnp.where(active, lengths, 0)
-    x = params["embed"].astype(cfg.dtype)[last_tokens][:, None]   # (B,1,E)
+    x = embed_tokens(params, last_tokens, cfg)[:, None]           # (B,1,E)
     # Per-slot RoPE at each slot's own position.
-    freqs = 1.0 / (cfg.rope_theta
-                   ** (jnp.arange(0, cfg.head_dim_, 2, jnp.float32)
-                      / cfg.head_dim_))
-    ang = lengths.astype(jnp.float32)[:, None] * freqs[None]      # (B, D/2)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]       # (B,1,D/2)
+    cos, sin = rope_angles(lengths, cfg)                          # (B, D/2)
+    cos, sin = cos[:, None], sin[:, None]                         # (B,1,D/2)
     # Physical write position of the incoming token for every slot.
     write_page = jnp.take_along_axis(
         tables, (lengths // page)[:, None], axis=1)[:, 0]         # (B,)
     write_off = lengths % page
-    attend = _paged_attend(kv_sharding)
-
-    def rope1(t):                       # t: (B, 1, H, D)
-        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate(
-            [t1 * cos[..., None, :] - t2 * sin[..., None, :],
-             t2 * cos[..., None, :] + t1 * sin[..., None, :]],
-            -1).astype(t.dtype)
+    paged = _per_shard(paged_decode_attention, kv_sharding, "hpp...")
 
     def body(carry, layer):
         x, pk, pv = carry               # pk/pv: the whole pool, in place
         lp, li = layer
-        h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-        q, k, v = _layer_qkv(lp, h, cfg)
-        q, k = rope1(q), rope1(k)
-        pk = pk.at[li, write_page, write_off].set(k[:, 0])
-        pv = pv.at[li, write_page, write_off].set(v[:, 0])
-        o = attend(q[:, 0], pk, pv, tables, lengths, li)          # (B,H,D)
-        o = jnp.einsum("bhd,hde->be", o, lp["attn"]["wo"].astype(cfg.dtype))
-        x = _mlp(lp, x + o[:, None], cfg)
+
+        def attend(q, k, v):
+            wk = pk.at[li, write_page, write_off].set(k[:, 0])
+            wv = pv.at[li, write_page, write_off].set(v[:, 0])
+            o = paged(q[:, 0], wk, wv, tables, lengths, li)       # (B,H,D)
+            return o[:, None], (wk, wv)
+        x, (pk, pv) = decoder_block(lp, x, cos, sin, attend, cfg)
         return (x, pk, pv), None
 
     (x, pool_k, pool_v), _ = jax.lax.scan(
@@ -295,10 +293,7 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
     if kv_sharding is not None:
         pool_k = jax.lax.with_sharding_constraint(pool_k, kv_sharding)
         pool_v = jax.lax.with_sharding_constraint(pool_v, kv_sharding)
-    x = rms_norm(x[:, 0], params["ln_f"], cfg.rms_norm_eps)
-    logits = jnp.einsum("be,ev->bv", x, params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
-    return pool_k, pool_v, logits
+    return pool_k, pool_v, lm_logits(params, x[:, 0], cfg)
 
 
 def _decode_fn(params, pool_k, pool_v, tables, last_tokens, lengths, active,
@@ -337,65 +332,15 @@ def _suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     tokens: (1, Sb) the PADDED suffix; length = real suffix length.
     Returns (last-token logits, suffix ks, vs (L, Sb, KV, D)) — the same
     contract as _prefill_fn, so the install path is shared."""
-    B, Sb = tokens.shape
-    P = pages.shape[0]
-    T = P * page
-    groups = cfg.num_heads // cfg.num_kv_heads
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    Sb = tokens.shape[1]
+    x = embed_tokens(params, tokens, cfg)
     # RoPE at absolute positions prefix_len + i.
-    freqs = 1.0 / (cfg.rope_theta
-                   ** (jnp.arange(0, cfg.head_dim_, 2, jnp.float32)
-                      / cfg.head_dim_))
-    pos = prefix_len + jnp.arange(Sb, dtype=jnp.int32)
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    # Key t (over [cached T | suffix Sb]) is valid for suffix query s iff
-    # it is a REAL cached prefix position or a suffix position <= s.
-    tpos = jnp.arange(T + Sb)
-    qpos = jnp.arange(Sb)
-    valid = (tpos[None, :] < prefix_len) | (
-        (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
-    kernel = _prefill_path(cfg, Sb, kv_sharding, page, P) == "kernel"
-    attend = _prefill_attend(kv_sharding, paged=True) if kernel else None
-
-    def body(x, layer):
-        lp, *at = layer
-        h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-        q, k, v = _layer_qkv(lp, h, cfg)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if kernel:
-            # The whole pool goes in as it lies; the kernel copies the
-            # pages below `prefix_len` of layer `at[0]` and no other.
-            o = attend(q[0], k[0], v[0], length, pool_k, pool_v, pages,
-                       prefix_len, at[0])[None]
-        else:
-            pk, pv = at                     # (N, page, KV, D)
-            ck = pk[pages].reshape(T, -1, cfg.head_dim_)
-            cv = pv[pages].reshape(T, -1, cfg.head_dim_)
-            kk = jnp.concatenate([ck[None], k], axis=1)   # (1, T+Sb, KV, D)
-            vv = jnp.concatenate([cv[None], v], axis=1)
-            kr = jnp.repeat(kk, groups, axis=2)
-            vr = jnp.repeat(vv, groups, axis=2)
-            scores = jnp.einsum("bshd,bthd->bhst", q, kr) / jnp.sqrt(
-                jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
-            scores = jnp.where(valid[None, None], scores, -1e30)
-            p = jax.nn.softmax(scores.astype(jnp.float32),
-                               -1).astype(q.dtype)
-            o = jnp.einsum("bhst,bthd->bshd", p, vr)
-        o = jnp.einsum("bshd,hde->bse", o,
-                       lp["attn"]["wo"].astype(cfg.dtype))
-        x = _mlp(lp, x + o, cfg)
-        return x, (k[0], v[0])
-
-    at = (jnp.arange(pool_k.shape[0], dtype=jnp.int32),) if kernel \
-        else (pool_k, pool_v)
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], *at))
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    last = x[0, length - 1]
-    logits = jnp.einsum("e,ev->v", last, params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
-    return logits, ks, vs
+    cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
+    attend, per_layer = _prefill_attend(
+        cfg, Sb, length, kv_sharding, (pool_k, pool_v, pages, prefix_len, page))
+    x, (ks, vs) = scan_blocks(params["layers"], x, cos, sin, attend, cfg,
+                              per_layer)
+    return lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
 class _PrefixCache:

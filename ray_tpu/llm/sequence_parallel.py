@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.transformer import apply_rope, rms_norm, rope_angles
+from ..models import transformer as model
 from ..ops.ring_attention import ring_attention, ulysses_attention
 
 __all__ = ["sp_mesh", "sp_prefill_fn", "sp_suffix_prefill_fn",
@@ -121,34 +121,23 @@ def sp_prefill_fn(params, tokens, length, cfg, mesh: Mesh,
     axis.  Sb must be divisible by the sp size (pow-2 buckets are).
     Heads ride a ``tp`` axis if the mesh has one; only the sequence
     axis communicates."""
-    from .engine import _layer_qkv, _mlp
-    B, S = tokens.shape
-    dt = cfg.dtype
+    S = tokens.shape[1]
     tokens = jax.lax.with_sharding_constraint(tokens,
                                               _seq_sharding(mesh, 2))
-    x = params["embed"].astype(dt)[tokens]
+    x = model.embed_tokens(params, tokens, cfg)
     x = jax.lax.with_sharding_constraint(x, _seq_sharding(mesh, 3))
-    cos, sin = rope_angles(S, cfg.head_dim_, cfg.rope_theta)
+    cos, sin = model.rope_angles(jnp.arange(0, S, dtype=jnp.float32), cfg)
     scale = 1.0 / math.sqrt(cfg.head_dim_)
     attn = ring_attention if strategy == "ring" else ulysses_attention
 
-    def body(x, lp):
-        h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-        q, k, v = _layer_qkv(lp, h, cfg)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+    def attend(q, k, v):
         o = attn(q, k, v, mesh, axis_name="sp", causal=True, scale=scale,
                  batch_axes=(), heads_axis="tp")
-        o = jnp.einsum("bshd,hde->bse", o, lp["attn"]["wo"].astype(dt))
-        x = _mlp(lp, x + o, cfg)
-        return x, (k[0], v[0])
+        return o, (k[0], v[0])
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    last = x[0, length - 1]
-    logits = jnp.einsum("e,ev->v", last, params["lm_head"].astype(dt),
-                        preferred_element_type=jnp.float32)
-    return logits, ks, vs
+    x, (ks, vs) = model.scan_blocks(params["layers"], x, cos, sin, attend,
+                                    cfg)
+    return model.lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
 def _sp_suffix_shard(q, k, v, ck, cv, prefix_len, *, axis_name: str,
@@ -220,24 +209,17 @@ def sp_suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     prefix pages replicated, ring rotation over the suffix KV.  Always
     ring — Ulysses would have to split the resident prefix's KV heads
     across shards, which buys nothing for a memory-resident prefix."""
-    from .engine import _layer_qkv, _mlp
-    B, Sb = tokens.shape
-    Pn = pages.shape[0]
-    T = Pn * page
-    dt = cfg.dtype
+    Sb = tokens.shape[1]
+    T = pages.shape[0] * page
     n = mesh.shape["sp"]
     scale = 1.0 / math.sqrt(cfg.head_dim_)
     tokens = jax.lax.with_sharding_constraint(tokens,
                                               _seq_sharding(mesh, 2))
-    x = params["embed"].astype(dt)[tokens]
+    x = model.embed_tokens(params, tokens, cfg)
     x = jax.lax.with_sharding_constraint(x, _seq_sharding(mesh, 3))
     # RoPE at absolute positions prefix_len + i (prefix_len is traced).
-    freqs = 1.0 / (cfg.rope_theta
-                   ** (jnp.arange(0, cfg.head_dim_, 2, jnp.float32)
-                      / cfg.head_dim_))
-    pos = prefix_len + jnp.arange(Sb, dtype=jnp.int32)
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    cos, sin = model.rope_angles(
+        prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
 
     body_shard = functools.partial(_sp_suffix_shard, axis_name="sp",
                                    n_shards=n, scale=scale)
@@ -248,25 +230,14 @@ def sp_suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
                   P(None, None, None), P()),
         out_specs=spec, check_vma=False)
 
-    def body(x, layer):
-        lp, pk, pv = layer                  # pk/pv: (N, page, KV, D)
-        h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-        q, k, v = _layer_qkv(lp, h, cfg)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+    def attend(q, k, v, pk, pv):            # pk/pv: (N, page, KV, D)
         ck = pk[pages].reshape(T, -1, cfg.head_dim_)
         cv = pv[pages].reshape(T, -1, cfg.head_dim_)
-        o = shard(q, k, v, ck, cv, prefix_len)
-        o = jnp.einsum("bshd,hde->bse", o, lp["attn"]["wo"].astype(dt))
-        x = _mlp(lp, x + o, cfg)
-        return x, (k[0], v[0])
+        return shard(q, k, v, ck, cv, prefix_len), (k[0], v[0])
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], pool_k, pool_v))
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    last = x[0, length - 1]
-    logits = jnp.einsum("e,ev->v", last, params["lm_head"].astype(dt),
-                        preferred_element_type=jnp.float32)
-    return logits, ks, vs
+    x, (ks, vs) = model.scan_blocks(params["layers"], x, cos, sin, attend,
+                                    cfg, (pool_k, pool_v))
+    return model.lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
 # ---------------------------------------------------------------------------
@@ -303,6 +274,13 @@ def _stream_block_fn(q, k_blk, v_blk, k_valid, q_pos0, k_pos0, m, l, acc,
     pv = jnp.einsum("kgst,tkd->kgsd", p.astype(v_blk.dtype), v_blk,
                     preferred_element_type=jnp.float32)
     return m_new, l_new, alpha * acc + pv
+
+
+def _layer(layers, i):
+    """Layer `i` (traced) of the stacked layer parameters."""
+    return jax.tree_util.tree_map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
+        layers)
 
 
 class StreamAttn:
@@ -347,7 +325,7 @@ class StreamAttn:
         cfg = self.cfg
 
         def make():
-            return jax.jit(lambda p, t: p["embed"].astype(cfg.dtype)[t])
+            return jax.jit(lambda p, t: model.embed_tokens(p, t, cfg))
         return self._get(("embed", tokens.shape[1]), make)(
             params, jnp.asarray(tokens))
 
@@ -357,21 +335,10 @@ class StreamAttn:
 
         def make():
             def fn(layers, i, x, pos0):
-                lp = jax.tree_util.tree_map(
-                    lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
-                    layers)
-                from .engine import _layer_qkv
-                h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-                q, k, v = _layer_qkv(lp, h, cfg)
-                Sq = x.shape[1]
-                freqs = 1.0 / (cfg.rope_theta
-                               ** (jnp.arange(0, cfg.head_dim_, 2,
-                                              jnp.float32) / cfg.head_dim_))
-                pos = pos0 + jnp.arange(Sq, dtype=jnp.int32)
-                ang = pos.astype(jnp.float32)[:, None] * freqs[None]
-                cos, sin = jnp.cos(ang), jnp.sin(ang)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
+                cos, sin = model.rope_angles(
+                    pos0 + jnp.arange(x.shape[1], dtype=jnp.int32), cfg)
+                q, k, v = model.block_qkv(_layer(layers, i), x, cos, sin,
+                                          cfg)
                 return q[0], k[0], v[0]
             return jax.jit(fn)
         return self._get(("qkv", x.shape[1]), make)(
@@ -391,17 +358,10 @@ class StreamAttn:
 
         def make():
             def fn(layers, i, x, l, acc):
-                lp = jax.tree_util.tree_map(
-                    lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
-                    layers)
-                from .engine import _mlp
                 o = acc / jnp.maximum(l, 1e-30)        # (KV, G, Sq, D)
-                Sq = x.shape[1]
                 o = o.transpose(2, 0, 1, 3).reshape(
-                    1, Sq, -1, cfg.head_dim_).astype(cfg.dtype)
-                o = jnp.einsum("bshd,hde->bse", o,
-                               lp["attn"]["wo"].astype(cfg.dtype))
-                return _mlp(lp, x + o, cfg)
+                    1, x.shape[1], -1, cfg.head_dim_).astype(cfg.dtype)
+                return model.block_out(_layer(layers, i), x, o, cfg)
             return jax.jit(fn)
         return self._get(("finish", x.shape[1]), make)(
             layers, jnp.int32(li), x, l, acc)
@@ -411,11 +371,7 @@ class StreamAttn:
 
         def make():
             def fn(params, x, idx):
-                xx = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-                last = xx[0, idx]
-                return jnp.einsum("e,ev->v", last,
-                                  params["lm_head"].astype(cfg.dtype),
-                                  preferred_element_type=jnp.float32)
+                return model.lm_logits(params, x[0, idx], cfg)
             return jax.jit(fn)
         return self._get(("logits", x.shape[1]), make)(
             params, x, jnp.int32(idx))

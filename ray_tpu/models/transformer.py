@@ -60,13 +60,6 @@ class TransformerConfig:
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
-    def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Approximate train FLOPs/token (fwd+bwd = 6*N + attention term)."""
-        s = seq_len or self.max_seq_len
-        n_params = self.param_count()
-        attn = 12 * self.num_layers * self.hidden_size * s
-        return 6 * n_params + attn
-
     def param_count(self) -> int:
         h, v, l = self.hidden_size, self.vocab_size, self.num_layers
         d = self.head_dim_
@@ -176,22 +169,102 @@ def rms_norm(x, scale, eps):
     return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def rope_angles(seq_len: int, head_dim: int, theta: float,
-                offset: int = 0) -> Tuple[jax.Array, jax.Array]:
-    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                             / head_dim))
-    pos = jnp.arange(offset, offset + seq_len, dtype=jnp.float32)
-    ang = pos[:, None] * freqs[None, :]           # (S, D/2)
+def rope_angles(positions, cfg: TransformerConfig
+                ) -> Tuple[jax.Array, jax.Array]:
+    """cos, sin (N, D/2) float32 of the rotary angles at `positions` (N,),
+    which may be traced (a suffix after cached tokens, each slot's own
+    length in a decode step)."""
+    d = cfg.head_dim_
+    freqs = 1.0 / (cfg.rope_theta
+                   ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]
     return jnp.cos(ang), jnp.sin(ang)
 
 
 def apply_rope(x, cos, sin):
-    """x: (B, S, H, D); rotate-half formulation."""
+    """x: (B, S, H, D); rotate-half formulation.  cos, sin: (S, D/2) where
+    the batch shares its positions, (B, S, D/2) where each row has its own."""
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    c = cos[None, :, None, :]
-    s = sin[None, :, None, :]
+    c, s = (t[None, :, None, :] if t.ndim == 2 else t[:, :, None, :]
+            for t in (cos, sin))
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
                            axis=-1).astype(x.dtype)
+
+
+def embed_tokens(params, tokens, cfg: TransformerConfig):
+    """tokens (...) int32 -> their rows of the table (..., E)."""
+    return params["embed"].astype(cfg.dtype)[tokens]
+
+
+def lm_logits(params, x, cfg: TransformerConfig):
+    """The head: final norm and output projection of rows x (..., E) ->
+    logits (..., V), accumulated and returned in float32."""
+    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
+    return jnp.einsum("...e,ev->...v", x, params["lm_head"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# The decoder block: two halves around an attention that is passed in
+# ---------------------------------------------------------------------------
+
+def _unconstrained(x, axes):
+    return x
+
+
+def block_qkv(lp, x, cos, sin, cfg: TransformerConfig,
+              constrain=_unconstrained):
+    """First half of the block: norm, the three projections, RoPE.
+    x (B, S, E) -> q (B, S, H, D), k, v (B, S, KV, D)."""
+    dt = cfg.dtype
+    h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
+    q = jnp.einsum("bse,ehd->bshd", h, lp["attn"]["wq"].astype(dt))
+    k = jnp.einsum("bse,ekd->bskd", h, lp["attn"]["wk"].astype(dt))
+    v = jnp.einsum("bse,ekd->bskd", h, lp["attn"]["wv"].astype(dt))
+    q = constrain(q, ("batch", "seq", "heads", "head_dim"))
+    k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
+    """Second half: output projection of the attention's o (B, S, H, D),
+    residual, norm, SwiGLU, residual -> x (B, S, E)."""
+    dt = cfg.dtype
+    o = constrain(o, ("batch", "seq", "heads", "head_dim"))
+    o = jnp.einsum("bshd,hde->bse", o, lp["attn"]["wo"].astype(dt))
+    x = x + constrain(o, ("batch", "seq", "embed"))
+    h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
+    g = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_gate"].astype(dt))
+    u = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_up"].astype(dt))
+    g = constrain(g, ("batch", "seq", "mlp"))
+    d = jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
+                   lp["mlp"]["w_down"].astype(dt))
+    return x + constrain(d, ("batch", "seq", "embed"))
+
+
+def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
+                  constrain=_unconstrained):
+    """One layer.  `attend(q, k, v) -> (o, kept)` is the caller's: training's
+    causal attention, a prefill's (kernel or scores), a decode step's read of
+    the paged pool; `kept` is what the caller's scan collects or carries (a
+    prefill's new cache rows, the decode step's written pool, nothing).
+    `constrain(x, logical_axes)` places activations on a training mesh.
+    Returns (x, kept)."""
+    q, k, v = block_qkv(lp, x, cos, sin, cfg, constrain)
+    o, kept = attend(q, k, v)
+    return block_out(lp, x, o, cfg, constrain), kept
+
+
+def scan_blocks(layers, x, cos, sin, attend, cfg: TransformerConfig,
+                per_layer=()):
+    """`lax.scan` of the block over the stacked `layers`, each layer's
+    `attend(q, k, v, *at)` given its slice `at` of the arrays in `per_layer`
+    (a pool's layer, a layer index).  Returns (x, every layer's `kept`)."""
+    def body(x, layer):
+        lp, *at = layer
+        return decoder_block(lp, x, cos, sin,
+                             lambda q, k, v: attend(q, k, v, *at), cfg)
+    return jax.lax.scan(body, x, (layers, *per_layer))
 
 
 def _xla_attention(q, k, v, causal: bool = True):
@@ -270,46 +343,22 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
         one_hot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
         x = jnp.einsum("bsv,ve->bse", one_hot, table)
     else:
-        x = params["embed"].astype(cfg.dtype)[tokens]
+        x = embed_tokens(params, tokens, cfg)
     x = constrain(x, ("batch", "seq", "embed"))
     S = tokens.shape[1]
-    cos, sin = rope_angles(S, cfg.head_dim_, cfg.rope_theta)
+    cos, sin = rope_angles(jnp.arange(0, S, dtype=jnp.float32), cfg)
 
     def _make_layer_body(constrain):
+        # The mesh-bound attention variants are training's alone, and off
+        # inside the pp region with the constraints.
+        amesh = mesh if constrain is not _unconstrained else None
+
+        def attend(q, k, v):
+            return _attention(cfg, q, k, v, amesh, rules), None
+
         def layer_body(x, lp):
-            h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-            q = jnp.einsum("bse,ehd->bshd", h,
-                           lp["attn"]["wq"].astype(cfg.dtype))
-            k = jnp.einsum("bse,ekd->bskd", h,
-                           lp["attn"]["wk"].astype(cfg.dtype))
-            v = jnp.einsum("bse,ekd->bskd", h,
-                           lp["attn"]["wv"].astype(cfg.dtype))
-            q = constrain(q, ("batch", "seq", "heads", "head_dim"))
-            k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            o = _attention(cfg, q, k, v,
-                           mesh if constrain is not _no_constrain else None,
-                           rules)
-            o = constrain(o, ("batch", "seq", "heads", "head_dim"))
-            o = jnp.einsum("bshd,hde->bse", o,
-                           lp["attn"]["wo"].astype(cfg.dtype))
-            x = x + constrain(o, ("batch", "seq", "embed"))
-
-            h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
-            g = jnp.einsum("bse,em->bsm", h,
-                           lp["mlp"]["w_gate"].astype(cfg.dtype))
-            u = jnp.einsum("bse,em->bsm", h,
-                           lp["mlp"]["w_up"].astype(cfg.dtype))
-            g = constrain(g, ("batch", "seq", "mlp"))
-            d = jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                           lp["mlp"]["w_down"].astype(cfg.dtype))
-            x = x + constrain(d, ("batch", "seq", "embed"))
-            return x, None
+            return decoder_block(lp, x, cos, sin, attend, cfg, constrain)
         return layer_body
-
-    def _no_constrain(v, axes):
-        return v
 
     pp = dict(mesh.shape).get("pp", 1) if mesh is not None else 1
     if pp > 1:
@@ -320,7 +369,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
         # propagates shardings through the auto axes.
         from ..parallel.pipeline import pipeline_spmd, split_stages
 
-        sbody = _make_layer_body(_no_constrain)
+        sbody = _make_layer_body(_unconstrained)
         if cfg.remat:
             sbody = jax.checkpoint(
                 sbody,
@@ -343,10 +392,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
                 .dots_with_no_batch_dims_saveable)
 
         x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    logits = jnp.einsum("bse,ev->bsv", x,
-                        params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+    logits = lm_logits(params, x, cfg)
     return constrain(logits, ("batch", "seq", "vocab"))
 
 
