@@ -24,7 +24,10 @@ stack, so the engine is native JAX on the in-tree flagship transformer
     buckets, other head widths and the CPU build the scores in XLA.
     `prefill_stats()` says which form the prefills took.
   - KV pool lives on device between steps (no host round-trips in the
-    decode loop); only sampled token ids come back per step.
+    decode loop); only sampled token ids come back per step.  So does the
+    step's own state (page tables, last tokens, lengths, active mask,
+    temperatures, sampling key): the step advances it, and the host
+    writes to it only the slots it changed (`_decode_fn`).
   - Tensor parallelism via GSPMD: pass ``mesh=`` and the engine shards
     weights (heads/kv_heads/mlp over tp, Megatron layout) and the KV pool
     (kv_heads over tp) with NamedShardings; XLA inserts the collectives in
@@ -49,6 +52,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
@@ -296,27 +300,85 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
     return pool_k, pool_v, lm_logits(params, x[:, 0], cfg)
 
 
-def _decode_fn(params, pool_k, pool_v, tables, last_tokens, lengths, active,
-               temps, rng, cfg: TransformerConfig, page: int, kv_sharding):
-    """One decode step for ALL slots against the paged pool.
-
-    pool_k/pool_v (L, N, page, KV, D); tables (B, P) physical page ids
-    (page 0 = scratch for inactive slots); lengths (B,) = tokens already
-    in cache (the new token is written at index lengths); active (B,)
-    bool; temps (B,) f32 sampling temperatures (0 = greedy).
-    Returns (pool_k', pool_v', next_tokens (B,))."""
-    B = last_tokens.shape[0]
-    pool_k, pool_v, logits = _decode_logits_fn(
-        params, pool_k, pool_v, tables, last_tokens, lengths, active, cfg,
-        page, kv_sharding)
+def _sample_fn(logits, active, temps, key):
+    """Every slot's next token from its logits (B, V): greedy where its
+    temperature is 0, else drawn with its own split of `key`; 0 for an
+    inactive slot."""
     greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-    keys = jax.random.split(rng, B)
+    keys = jax.random.split(key, logits.shape[0])
     sampled = jax.vmap(
         lambda key, lg, t: jax.random.categorical(
             key, lg / jnp.maximum(t, 1e-6)))(keys, logits, temps)
     nxt = jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
-    nxt = jnp.where(active, nxt, 0)
-    return pool_k, pool_v, nxt
+    return jnp.where(active, nxt, 0)
+
+
+# The decode step's resident state is `slots`, one int32 row a slot, and the
+# sampling key.  A row is the slot's page-table row (P physical page ids)
+# and then these columns (the temperature as its float32 bits); the packed
+# update the host sends has one column more, `take`: the device is to
+# accept the row.
+_COL_LAST, _COL_LENGTH, _COL_ACTIVE, _COL_TEMP = range(4)
+_COLS = 4
+
+
+def _pack_rows(tables, last, lengths, active, temps, take) -> np.ndarray:
+    """Host side: every slot's row as the host's mirrors have it, (B, P + 5)
+    int32, with `take` marking the slots the device is to accept."""
+    P = tables.shape[1]
+    rows = np.empty((tables.shape[0], P + _COLS + 1), np.int32)
+    rows[:, :P] = tables
+    rows[:, P + _COL_LAST] = last
+    rows[:, P + _COL_LENGTH] = lengths
+    rows[:, P + _COL_ACTIVE] = active
+    rows[:, P + _COL_TEMP] = np.asarray(temps, np.float32).view(np.int32)
+    rows[:, -1] = take
+    return rows
+
+
+def _accept_rows(slots, update):
+    """Device side: the rows a packed update marks replace the state's; an
+    update that marks none leaves it as it is."""
+    return jnp.where(update[:, -1:] != 0, update[:, :-1], slots)
+
+
+def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
+               page: int, kv_sharding):
+    """One decode step for ALL slots against the paged pool, on state that
+    stays on the device.
+
+    pool_k/pool_v (L, N, page, KV, D).  `state` = {"slots": (B, P + 4)
+    int32, "rng": the sampling key} is RESIDENT: the step takes it, advances
+    it and returns it, donated like the two pools, so between two steps the
+    host uploads nothing and runs no program.  A slot's row holds its page
+    table (page 0 = scratch for inactive slots), its last token, the tokens
+    it has in cache (the new token is written at that index), whether it is
+    active, and its temperature (0 = greedy).  The step first accepts
+    `update` (`_pack_rows`), the one packed upload through which the host
+    writes the slots IT changed (a reservation, an admission, a
+    retirement); then it splits the key as the host would (`rng, key =
+    split(rng)`: the same two keys), samples, and advances what it owns:
+    last token <- next token and length + 1 for the active slots.  On a
+    mesh the state is replicated.
+    Returns (pool_k', pool_v', state', next_tokens (B,))."""
+    slots = _accept_rows(state["slots"], update)
+    P = slots.shape[1] - _COLS
+    tables, last, lengths = (slots[:, :P], slots[:, P + _COL_LAST],
+                             slots[:, P + _COL_LENGTH])
+    active = slots[:, P + _COL_ACTIVE] != 0
+    temps = jax.lax.bitcast_convert_type(slots[:, P + _COL_TEMP], jnp.float32)
+    rng, key = jax.random.split(state["rng"])
+    pool_k, pool_v, logits = _decode_logits_fn(
+        params, pool_k, pool_v, tables, last, lengths, active, cfg, page,
+        kv_sharding)
+    nxt = _sample_fn(logits, active, temps, key)
+    slots = slots.at[:, P + _COL_LAST].set(jnp.where(active, nxt, last))
+    slots = slots.at[:, P + _COL_LENGTH].add(active)
+    state = {"slots": slots, "rng": rng}
+    if kv_sharding is not None:
+        state = jax.lax.with_sharding_constraint(
+            state, NamedSharding(kv_sharding.mesh, PartitionSpec()))
+    return pool_k, pool_v, state, nxt
 
 
 def _suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
@@ -764,7 +826,6 @@ class LLMEngine:
         pool_shape = (L, self.n_pages, self.page, kvh, d)
         self._pk = jnp.zeros(pool_shape, cfg.dtype, device=self._kv_shd)
         self._pv = jnp.zeros(pool_shape, cfg.dtype, device=self._kv_shd)
-        self._rng = jax.random.key(seed + 1)
         self._free_slots = list(range(max_batch))
         self._free_pages = list(range(1, self.n_pages))
         # page -> holder count (requests + cache entries); a page leaves
@@ -803,9 +864,24 @@ class LLMEngine:
         self._requests: Dict[int, _Request] = {}
         self._tick_events: List[Tuple[int, int, bool]] = []
         self._next_id = 0
+        # The scheduler's truth about every slot, on the host.  The decode
+        # step works on its own copy on the device (`self._dev`), which it
+        # advances itself; `_touched` marks the slots whose host side has
+        # changed since the device last accepted them.
         self._last = np.zeros(max_batch, np.int32)
         self._lengths = np.zeros(max_batch, np.int32)
         self._temps = np.zeros(max_batch, np.float32)
+        self._touched = np.zeros(max_batch, bool)
+        self._state_shd = None if mesh is None else NamedSharding(
+            mesh, PartitionSpec())
+        idle = np.zeros(max_batch, bool)
+        none = _pack_rows(self._tables, self._last, self._lengths, idle,
+                          self._temps, idle)
+        self._dev = jax.device_put(
+            {"slots": none[:, :-1], "rng": jax.random.key(seed + 1)},
+            self._state_shd)
+        # The update of a step before which no slot was touched: marks none.
+        self._no_rows = jax.device_put(none, self._state_shd)
         self._prefill_jit = {}
         self.phases = TickPhases()
         # How much of what the tables address the batch decode step reads
@@ -813,6 +889,11 @@ class LLMEngine:
         self._decode_steps = 0
         self._pages_read = 0
         self._step_pages_read = 0
+        # How often the host wrote slot state to the device, and how many
+        # slot rows: a step no slot was touched before writes none.
+        self._state_syncs = 0
+        self._state_rows = 0
+        self._step_state_rows = 0
         # Which attention form the prefills took (ops/prefill_attention.py)
         # and how many key blocks they ran beside what S x S covers; the
         # last one's, as its `prefill` span carries them.
@@ -829,9 +910,9 @@ class LLMEngine:
         # `decode_step` when a benchmark PR points the readers at that
         # name (ROADMAP).
         self._decode_jit = jax.jit(
-            lambda p, pk, pv, tb, lt, ln, ac, tp, rn: _decode_fn(
-                p, pk, pv, tb, lt, ln, ac, tp, rn, cfg, page, kv_shd),
-            donate_argnums=(1, 2))
+            lambda p, pk, pv, state, update: _decode_fn(
+                p, pk, pv, state, update, cfg, page, kv_shd),
+            donate_argnums=(1, 2, 3))
 
         def install_kv(pk, pv, ks, vs, pages):
             return _install_fn(pk, pv, ks, vs, pages, page, kv_shd)
@@ -1054,7 +1135,11 @@ class LLMEngine:
     def decode_stats(self) -> Dict[str, Any]:
         """What the batch decode step read: pages the active slots held
         (`lengths // page + 1` each) beside the pages their tables address,
-        over all steps and in the last one, and the attention path."""
+        over all steps and in the last one, and the attention path.  And
+        what the host wrote into the step's resident state: `state_syncs`
+        counts the steps before which it wrote slot rows (one packed
+        upload), `state_rows` the rows (`step_state_rows`: the last
+        step's); `steps - state_syncs` steps uploaded nothing."""
         from ..ops.paged_attention import decode_path
         per_step = self.max_batch * self.pages_per_slot
         return {"path": decode_path(
@@ -1064,7 +1149,10 @@ class LLMEngine:
                 "pages_read": self._pages_read,
                 "pages_addressable": self._decode_steps * per_step,
                 "step_pages_read": self._step_pages_read,
-                "step_pages_addressable": per_step}
+                "step_pages_addressable": per_step,
+                "state_syncs": self._state_syncs,
+                "state_rows": self._state_rows,
+                "step_state_rows": self._step_state_rows}
 
     def prefill_stats(self) -> Dict[str, Any]:
         """The attention form of the last prefill (`path`: "kernel" or
@@ -1283,6 +1371,7 @@ class LLMEngine:
         row[:len(shared)] = shared
         row[len(shared):total] = req.pages
         self._tables[req.slot] = row
+        self._touched[req.slot] = True
         return True
 
     def _install(self, slot: int, ks, vs):
@@ -1353,11 +1442,8 @@ class LLMEngine:
                 # External paged context: nothing to prefill — the
                 # parts stay wherever they live (possibly remote); the
                 # reserved pages are the decode tail.
-                self._lengths[req.slot] = 0
-                self._temps[req.slot] = req.params.temperature
-                self._slots[req.slot] = req
-                self._last[req.slot] = req.first_token
-                self._emit(req, int(req.first_token))
+                self._activate(req, 0)
+                self._emit_first(req, req.first_token)
                 continue
             S = len(req.prompt)
             if self.prefill_chunk and req.kv_blob is None \
@@ -1403,23 +1489,36 @@ class LLMEngine:
                     req.sp_stripes = self._sp.sp_stripe_pages(
                         self._tables[req.slot], S, self.sp_degree,
                         self.page, padded=self._bucket(S))
-            self._lengths[req.slot] = S
-            self._temps[req.slot] = req.params.temperature
-            self._slots[req.slot] = req
+            self._activate(req, S)
             if req.kv_blob is not None:
                 req.kv_blob = None          # release the host copy
-                self._last[req.slot] = req.first_token
-                self._emit(req, int(req.first_token))
+                self._emit_first(req, req.first_token)
             else:
                 admitted.append((req, logits))
         if admitted:
             firsts = self._sample_batch([lg for _, lg in admitted],
                                         [r.params for r, _ in admitted])
             for (req, _), first in zip(admitted, firsts):
-                self._last[req.slot] = first
-                self._emit(req, int(first))
+                self._emit_first(req, first)
         self._report_pool_pressure()
         return taken
+
+    def _activate(self, req: _Request, length: int) -> None:
+        """The reserved slot joins the running set with `length` tokens in
+        cache: the decode step's device state takes its row before the
+        next step."""
+        slot = req.slot
+        self._lengths[slot] = length
+        self._temps[slot] = req.params.temperature
+        self._slots[slot] = req
+        self._touched[slot] = True
+
+    def _emit_first(self, req: _Request, token: int) -> None:
+        """A request's first token, which no decode step produced: the
+        next one starts from it."""
+        self._last[req.slot] = token
+        self._touched[req.slot] = True
+        self._emit(req, int(token))
 
     def _install_external(self, req: _Request):
         """Install a shipped KV blob; on a prefix-cache hit only the
@@ -1444,7 +1543,10 @@ class LLMEngine:
                            np.float32)
         greedy = jnp.argmax(lg, -1).astype(jnp.int32)
         if (temps > 0).any():
-            self._rng, key = jax.random.split(self._rng)
+            # The one key stream, shared with the decode step, which
+            # splits it on the device: this split's first half goes back
+            # into the resident state.
+            self._dev["rng"], key = jax.random.split(self._dev["rng"])
             keys = jax.random.split(key, len(params_list))
             tj = jnp.asarray(temps)
             sampled = jax.vmap(
@@ -1495,6 +1597,16 @@ class LLMEngine:
         requests.  Returns requests finished in this step (vllm
         engine.step parity).
 
+        The decode step's per-slot state is resident on the device
+        (`self._dev`, see `_decode_fn`) and advanced by the step itself.
+        The host's mirrors (`_tables`, `_last`, `_lengths`, `_temps`)
+        remain the scheduler's truth and are advanced here from the tokens
+        read back, every tick; a slot the host itself changed (reserved,
+        activated, freed) is marked in `_touched`, and the marked rows
+        ride to the device as ONE packed upload in the next step's `prep`.
+        A step before which nothing was touched uploads nothing and runs
+        no program but the decode step (`decode_stats()`).
+
         Every instant of the call belongs to one phase of
         `tick_phases.TickPhases` (self.phases): `admit`, `chunk`, `emit`,
         then the decode step's `prep`, `dispatch` and `wait`, then `emit`
@@ -1543,7 +1655,7 @@ class LLMEngine:
                 req.finish_reason = "error"
                 done.append(self._retire(slot))
                 continue
-            self._last[slot] = tok
+            self._last[slot] = tok      # host only: never in the batch
             self._emit(req, tok)
             if req.finished:
                 done.append(self._retire(slot))
@@ -1559,17 +1671,23 @@ class LLMEngine:
         self._decode_steps += 1
         self._pages_read += pages
         self._step_pages_read = pages
-        self._rng, key = jax.random.split(self._rng)
-        tables, last = jnp.asarray(self._tables), jnp.asarray(self._last)
-        lengths, active = jnp.asarray(self._lengths), jnp.asarray(active)
-        temps = jnp.asarray(self._temps)
+        update, synced = self._no_rows, int(self._touched.sum())
+        if synced:
+            update = jax.device_put(_pack_rows(
+                self._tables, self._last, self._lengths, active, self._temps,
+                self._touched), self._state_shd)
+            self._touched[:] = False
+            self._state_syncs += 1
+            self._state_rows += synced
+        self._step_state_rows = synced
         ph.to("dispatch")
-        self._pk, self._pv, nxt = self._decode_jit(
-            self.params, self._pk, self._pv, tables, last, lengths, active,
-            temps, key)
+        self._pk, self._pv, self._dev, nxt = self._decode_jit(
+            self.params, self._pk, self._pv, self._dev, update)
         ph.to("wait")
         nxt = np.asarray(nxt)
-        ph.span("decode", t0, ph.to("emit"), batch=len(batch), pages=pages)
+        ph.span("decode", t0, ph.to("emit"), batch=len(batch), pages=pages,
+                synced=synced)
+        # The host advances its mirrors as the step advanced the device's.
         for slot, req in list(self._slots.items()):
             if slot not in batch:
                 continue
@@ -1621,12 +1739,9 @@ class LLMEngine:
                 # whole-prompt stripe attribution would lie; chunked
                 # cross-host handoffs carry exact spans via the paged
                 # parts path instead.
-                self._lengths[slot] = S
-                self._temps[slot] = req.params.temperature
-                self._slots[slot] = req
-                first = self._sample_batch([logits], [req.params])[0]
-                self._last[slot] = first
-                self._emit(req, int(first))
+                self._activate(req, S)
+                self._emit_first(
+                    req, self._sample_batch([logits], [req.params])[0])
             break                       # one chunk per tick, total
 
     def _retire(self, slot: int) -> _Request:
@@ -1649,6 +1764,7 @@ class LLMEngine:
             self._kv_window.drop([p["key"] for p in req.ext_parts])
         self._tables[slot] = 0
         self._lengths[slot] = 0
+        self._touched[slot] = True
         self._requests.pop(req.req_id, None)
 
     # ------------------------------------------- streamed cross-host KV ----

@@ -48,7 +48,8 @@ admitted, with queue depth and the count of requests already decoding),
 ``n`` that admitted it).  Per tick: the phases of ``tick_phases.py`` —
 ``tick`` and, tiling it and the stretch to the next one, ``tick:turn``,
 ``tick:expire``, ``tick:hop``, ``step:admit``, ``decode`` (with batch
-size; ``decode:prep`` / ``:dispatch`` / ``:wait``), ``sample_sync`` (the
+size and ``synced``, the slot rows written to the device before it;
+``decode:prep`` / ``:dispatch`` / ``:wait``), ``sample_sync`` (the
 batched device→host sample pull), ``step:emit``, ``tick:fan_out`` —
 whose cumulative nanoseconds ``debug_stats()["tick"]`` also serves.
 
@@ -789,6 +790,10 @@ class EngineReplica:
 
     # ------------------------------------------------------------- introspect
     async def debug_stats(self) -> Dict[str, Any]:
+        """Counters of this replica and its engine.  `decode` is
+        `LLMEngine.decode_stats()`: the pages the decode steps read, and how
+        often the host had to write slot rows into the step's
+        device-resident state (`state_syncs` of `steps`, `state_rows`)."""
         e = self.engine
         return {"ticks": self._ticks, "max_active": self._max_active,
                 "shed": self._shed, "cancelled": self._cancelled,

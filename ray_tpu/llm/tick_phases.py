@@ -27,7 +27,8 @@ the `_stream` calls that enqueue meanwhile, other holders of the lock),
 `expire`, `hop` (the executor hand-off, there and back), `admit` and
 `chunk` (SELF time of `step:admit` / `step:chunk`: reserve, cache lookup,
 page tables), `prefill` and `sample_sync` (inside either), `prep`
-(key split and the uploads), `dispatch`, `wait` (the blocking read-back),
+(the step's bookkeeping and, when the host touched a slot since the last
+step, the one packed upload of the touched rows), `dispatch`, `wait` (the blocking read-back),
 `emit` (the emit/retire loops: requests finished at admission and
 paged-context slots before the decode step, every slot after it),
 `fan_out`.  Parent spans (`tick`, `step:admit`, `step:chunk`, `decode`)

@@ -345,27 +345,189 @@ def test_tokens_do_not_depend_on_what_is_addressable():
 
 
 def test_decode_step_writes_the_pool_in_place():
-    """The compiled decode step aliases both pools to its outputs and, where
-    the backend says, needs less temporary memory than one pool."""
+    """The compiled decode step aliases both pools and its resident state
+    (the slots' rows: tables, last tokens, lengths, active mask,
+    temperatures; and the key) to its outputs, the packed update to none,
+    and, where the backend says, needs less temporary memory than one
+    pool."""
     import re
 
     import jax
-    import jax.numpy as jnp
     eng = LLMEngine(CFG, max_batch=2, max_len=64, page_size=16,
                     kv_pages=256, seed=0)
-    B = eng.max_batch
     compiled = eng._decode_jit.lower(
-        eng.params, eng._pk, eng._pv, jnp.asarray(eng._tables),
-        jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
-        jnp.zeros(B, bool), jnp.zeros(B, jnp.float32),
-        jax.random.key(0)).compile()
+        eng.params, eng._pk, eng._pv, eng._dev, eng._no_rows).compile()
     n_params = len(jax.tree.leaves(eng.params))
+    n_state = len(jax.tree.leaves(eng._dev))
+    assert n_state == 2                       # the slots' rows and the key
     head = compiled.as_text().split("\n", 1)[0]
-    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}", head))
-    assert aliases == {"0": str(n_params), "1": str(n_params + 1)}, head
+    aliases = {int(o): int(i) for o, i in
+               re.findall(r"\{(\d+)\}: \((\d+), \{\}", head)}
+    # Outputs: pool_k, pool_v, the state's leaves, next tokens (not aliased).
+    assert aliases == {o: n_params + o for o in range(2 + n_state)}, head
     mem = compiled.memory_analysis()
     if mem is not None and mem.temp_size_in_bytes:
         assert mem.temp_size_in_bytes < eng._pk.nbytes, mem
+
+
+# ---- the decode step's resident state (llm/engine.py:_decode_fn) ---------
+
+def _engine(mesh_axes, **kw):
+    """An engine on one device, or on a forced-CPU mesh of `mesh_axes`."""
+    if mesh_axes is None:
+        return LLMEngine(CFG, **kw)
+    import jax
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    n = int(np.prod(list(mesh_axes.values())))
+    assert len(jax.devices()) >= n
+    return LLMEngine(CFG, mesh=build_mesh(MeshSpec(**mesh_axes),
+                                          devices=jax.devices()[:n]), **kw)
+
+
+def _check_against_host_rebuilt_steps(eng, seed):
+    """Wrap the engine's decode step: before each one, the parent's step is
+    run beside it, on inputs rebuilt from the HOST's mirrors and with the
+    key split on the host, and the tokens must agree; after it, the state
+    on the device must be what the mirrors say.  Returns the list the
+    checked steps are counted in."""
+    import jax
+    from ray_tpu.llm import engine as E
+
+    cfg, page, kv_shd = eng.cfg, eng.page, eng._kv_shd
+    parent_step = jax.jit(
+        lambda p, pk, pv, tb, lt, ln, ac, tp, key: E._sample_fn(
+            E._decode_logits_fn(p, pk, pv, tb, lt, ln, ac, cfg, page,
+                                kv_shd)[2], ac, tp, key))
+    host = {"rng": jax.random.key(seed + 1)}
+    resident, first_tokens, checked = eng._decode_jit, eng._sample_batch, []
+
+    def sample_batch(logits_list, params_list):
+        if any(p.temperature > 0 for p in params_list):   # the parent's rule
+            host["rng"], _ = jax.random.split(host["rng"])
+        return first_tokens(logits_list, params_list)
+
+    def step(params, pk, pv, state, update):
+        active = np.zeros(eng.max_batch, bool)
+        active[[s for s, r in eng._slots.items() if not r.kv_paged]] = True
+        host["rng"], key = jax.random.split(host["rng"])
+        want = np.asarray(parent_step(
+            params, pk, pv, eng._tables, eng._last, eng._lengths, active,
+            eng._temps, key))
+        mirrors = E._pack_rows(
+            eng._tables, np.where(active, want, eng._last),
+            eng._lengths + active, active, eng._temps, False)[:, :-1]
+        pk, pv, state, nxt = resident(params, pk, pv, state, update)
+        np.testing.assert_array_equal(np.asarray(nxt), want)
+        np.testing.assert_array_equal(np.asarray(state["slots"]), mirrors)
+        np.testing.assert_array_equal(
+            jax.random.key_data(state["rng"]),
+            jax.random.key_data(host["rng"]))
+        checked.append(int(active.sum()))
+        return pk, pv, state, nxt
+
+    eng._decode_jit, eng._sample_batch = step, sample_batch
+    return checked
+
+
+@pytest.mark.parametrize("mesh_axes", [None, {"tp": 2}], ids=["one", "tp2"])
+@pytest.mark.parametrize("temps", [(0.0,), (0.8, 0.0, 1.3)],
+                         ids=["greedy", "sampled"])
+def test_resident_decode_state_gives_the_host_rebuilt_steps_tokens(
+        temps, mesh_axes):
+    """Admissions, retirements, slot reuse, a prefix-cache hit, a cancelled
+    request and a chunked prefill: in every decode step the tokens from the
+    state that lives on the device equal those of the parent's step on
+    inputs rebuilt from the host's mirrors."""
+    seed, page = 3, 16
+    eng = _engine(mesh_axes, max_batch=3, max_len=128, page_size=page,
+                  prefix_cache=True, prefill_chunk=2 * page, seed=seed)
+    checked = _check_against_host_rebuilt_steps(eng, seed)
+    rng = np.random.default_rng(0)
+    doc = rng.integers(1, CFG.vocab_size, 3 * page).tolist()
+    prompts = [rng.integers(1, CFG.vocab_size, n).tolist()
+               for n in (5, 5 * page + 3, 9, 12, 7, 20)]
+    prompts[2] = doc + prompts[2]            # leaves the document's pages
+    prompts[3] = doc + prompts[3]            # ... for this one to hit
+    arrive = {0: [0, 1, 2], 3: [3, 4], 9: [5]}
+    ids, outs, chunked, steps, cancelled = {}, {}, 0, 0, False
+    while steps == 0 or eng.has_unfinished():
+        for i in arrive.get(steps, ()):
+            ids[i] = eng.add_request(prompts[i], SamplingParams(
+                max_tokens=6 + 3 * i, temperature=temps[i % len(temps)]))
+        req = eng._requests.get(ids.get(4))
+        if req is not None and len(req.out) == 3:   # mid-decode, slot live
+            assert eng._slots.get(req.slot) is req
+            cancelled = eng.cancel_request(ids[4])
+        chunked += bool(eng._prefilling)
+        for req in eng.step():
+            outs[req.req_id] = req.out
+        steps += 1
+        assert steps < 200
+    assert cancelled and chunked >= 2 and eng._cache.hits >= 1
+    assert sorted(outs) == sorted(ids[i] for i in (0, 1, 2, 3, 5))
+    assert all(len(outs[ids[i]]) == 6 + 3 * i for i in (0, 1, 2, 3, 5))
+    st = eng.decode_stats()
+    assert st["steps"] == len(checked) > 20 and max(checked) == 3
+    # Six requests took a slot, five of them retired and one was cancelled:
+    # each is a row to write (one row, where a slot was freed and taken
+    # between two steps), and most steps write none.
+    assert 6 <= st["state_syncs"] < st["steps"] // 2
+    assert st["state_syncs"] <= st["state_rows"] <= 12
+    assert not eng._touched.any() or not eng._slots
+
+
+def test_decode_state_accepts_only_the_marked_rows():
+    """`_pack_rows` -> `_accept_rows`: the rows the host marks replace the
+    device's, bit for bit (a temperature too); the others stay."""
+    import jax
+    from ray_tpu.llm import engine as E
+    B, P = 5, 7
+    rng = np.random.default_rng(1)
+    draw = lambda: (rng.integers(0, 99, (B, P)), rng.integers(0, 99, B),
+                    rng.integers(0, 99, B), rng.random(B) < 0.5,
+                    rng.random(B).astype(np.float32) * 2)
+    old, new = draw(), draw()
+    take = np.array([True, False, False, True, False])
+    slots = E._pack_rows(*old, False)[:, :-1]
+    got = np.asarray(jax.jit(E._accept_rows)(slots, E._pack_rows(*new, take)))
+    assert got.dtype == np.int32 and got.shape == (B, P + 4)
+    want = [np.where(take.reshape((B,) + (1,) * (o.ndim - 1)), n, o)
+            for o, n in zip(old, new)]
+    np.testing.assert_array_equal(got[:, :P], want[0])
+    for col, w in zip((E._COL_LAST, E._COL_LENGTH, E._COL_ACTIVE), want[1:]):
+        np.testing.assert_array_equal(got[:, P + col], w)
+    np.testing.assert_array_equal(
+        got[:, P + E._COL_TEMP].view(np.float32), want[4])
+
+
+@pytest.mark.parametrize("mesh_axes", [None, {"tp": 2}], ids=["one", "tp2"])
+def test_step_with_no_slot_touched_uploads_nothing(mesh_axes):
+    """A decode step before which the host touched no slot moves nothing
+    from host to device; a step after an admission or a retirement writes
+    the touched rows, and only them; neither kind compiles again."""
+    import jax
+    from ray_tpu._private.compile_cache import compile_cache_stats
+    eng = _engine(mesh_axes, max_batch=4, max_len=64, page_size=16, seed=0)
+    count = lambda: [eng.decode_stats()[k] for k in (
+        "steps", "state_syncs", "state_rows", "step_state_rows")]
+    sampled = SamplingParams(max_tokens=12, temperature=0.9)
+    eng.add_request([1, 2, 3], SamplingParams(max_tokens=3))
+    eng.step()
+    assert count() == [1, 1, 1, 1]
+    eng.add_request([4, 5, 6, 7], sampled)
+    assert [len(r.out) for r in eng.step()] == [3]   # the first one retires
+    assert count() == [2, 2, 2, 1]
+    eng.step()                      # its freed slot is a row to write
+    assert count() == [3, 3, 3, 1]
+    compiles = compile_cache_stats()["requests"]
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        for _ in range(4):
+            eng.step()
+    assert count() == [7, 3, 3, 0]
+    eng.add_request([8, 9, 10], sampled)
+    eng.step()
+    assert count() == [8, 4, 4, 1]
+    assert compile_cache_stats()["requests"] == compiles
 
 
 def test_debug_stats_count_pages_read():

@@ -259,9 +259,13 @@ def test_old_spans_keep_names_arguments_and_extents(run):
         assert {"tokens", "cached_tokens", "active"} <= set(r["args"])
         assert len(bytes(r["task_id"])) == 8
     assert all(set(r["args"]) == {"batch"} for r in rows["sample_sync"])
-    assert all(set(r["args"]) == {"batch", "n", "pages"}
+    assert all(set(r["args"]) == {"batch", "n", "pages", "synced"}
                for r in rows["decode"])
-    # `decode` still runs from before the key split to after the read-back
+    # `synced` = the slot rows the host wrote into the step's resident state
+    # before it: some steps carry an admission or a retirement, most none
+    synced = [r["args"]["synced"] for r in rows["decode"]]
+    assert 0 < sum(s > 0 for s in synced) < len(synced) / 2
+    # `decode` still runs from where the key was split to after the read-back
     # (now: from its first child's start to its last child's end), and a
     # `sample_sync` still follows the wave's last prefill in its tick
     for t0, t1, _, _, a in _spans(run, "decode"):

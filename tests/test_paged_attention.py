@@ -271,24 +271,29 @@ def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
                                                no_compile_cache):
     """The engine's decode step at the cells' widths (two layers), on one
     chip and under `shard_map` on a tp and an sp mesh: the kernel is in the
-    program, both pools alias their outputs, and nothing pool-sized is
-    made beside them."""
+    program, both pools and the step's resident state alias their outputs,
+    and nothing pool-sized is made beside them."""
+    import re
+
     from ray_tpu.llm import engine as E
 
     monkeypatch.setattr(pa, "decode_path", lambda *shapes: "pallas")
     B, page, P_ = 16, 16, 128
     cfg, kv_shd, S, params, pool = _cell(topo, mesh_axes, page)
     key = jax.eval_shape(lambda: jax.random.key(0))
+    state = {"slots": S((B, P_ + 4), jnp.int32),
+             "rng": S(key.shape, key.dtype)}
 
-    def decode_step(p, pk, pv, tb, lt, ln, ac, tp, rn):
-        return E._decode_fn(p, pk, pv, tb, lt, ln, ac, tp, rn, cfg, page,
-                            kv_shd)
-    compiled = jax.jit(decode_step, donate_argnums=(1, 2)).lower(
-        params, pool, pool, S((B, P_), jnp.int32), S((B,), jnp.int32),
-        S((B,), jnp.int32), S((B,), jnp.bool_), S((B,), jnp.float32),
-        S(key.shape, key.dtype)).compile()
+    def decode_step(p, pk, pv, state, update):
+        return E._decode_fn(p, pk, pv, state, update, cfg, page, kv_shd)
+    compiled = jax.jit(decode_step, donate_argnums=(1, 2, 3)).lower(
+        params, pool, pool, state, S((B, P_ + 5), jnp.int32)).compile()
     text = compiled.as_text()
     assert "paged_decode_attention" in text
+    n_params, n_state = len(jax.tree.leaves(params)), 2
+    aliases = {int(o): int(i) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", text.split("\n", 1)[0])}
+    assert aliases == {o: n_params + o for o in range(2 + n_state)}
     mem = compiled.memory_analysis()
     per_device = math.prod(pool.shape) * 2 // (2 if mesh_axes == {"tp": 2}
                                                else 1)
