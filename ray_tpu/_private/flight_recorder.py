@@ -25,16 +25,21 @@ Categories ("plane" granularity, gated via config
                request:lock_wait (entry -> replica lock held and
                enqueued), request:admit (enqueue -> first token fanned
                out), prefill (w/ cached_tokens; `n` = the tick that
-               admitted it, `new_program` = 1 when the call compiled),
+               admitted it, `new_program` = 1 when the call compiled;
+               a model with recurrent layers: `recomputed` = tokens run
+               again behind a state checkpoint, `checkpoints` = rows
+               this prefill kept),
                request:cancelled, request:kv_broken, sp:gather.
                Per tick, sharing the tick number `n`
                (llm/tick_phases.py; the same boundaries feed
                EngineReplica.debug_stats()["tick"]): tick (lock held ->
                fan-out done) and its pieces tick:expire, tick:hop,
                step:admit (children prefill, sample_sync), step:chunk,
-               step:emit, decode (w/ batch; children decode:prep,
-               decode:dispatch, decode:wait), tick:fan_out; between
-               ticks tick:turn and tick:idle
+               step:emit, decode (w/ batch, and `experts` = held
+               experts the routed layers touched; children decode:prep,
+               decode:dispatch, decode:wait), step:ahead (the next
+               decode step sent off before `step()` returns),
+               tick:fan_out; between ticks tick:turn and tick:idle
     anomaly    diagnosis-plane detector firings (_private/diagnosis.py):
                loop_wedged, task_hung, lease_stalled, serving_silent,
                process_stalled (a watchdog that itself woke late) —

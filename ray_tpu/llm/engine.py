@@ -47,7 +47,7 @@ import math
 import os
 import tempfile
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,10 +56,11 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
+from ..models.mamba2 import zero_state
 from ..models.transformer import (TransformerConfig, decoder_block,
                                   embed_tokens, init_params, lm_logits,
                                   param_logical_axes, rope_angles,
-                                  scan_blocks)
+                                  run_pattern, scan_blocks)
 from .tick_phases import TickPhases
 
 
@@ -87,6 +88,13 @@ class _Request:
     shared_pages: List[int] = dataclasses.field(default_factory=list)
     prefix_len: int = 0
     no_cache: bool = False
+    # A model with recurrent layers: the checkpoint row its prefill starts
+    # from (0: from nothing), the tokens of its hit that lie past that
+    # checkpoint and run again, and the rows reserved for the checkpoints it
+    # will pass, by boundary (tokens).
+    from_row: int = 0
+    recomputed: int = 0
+    new_rows: Dict[int, int] = dataclasses.field(default_factory=dict)
     # P/D external admission: a shipped KV blob installed at admission
     # instead of running prefill (add_external_request).
     kv_blob: Optional[dict] = None
@@ -109,6 +117,18 @@ class _Request:
     # SP accounting: shard i's stripe of the slot's pages (which pages a
     # sequence-parallel prefill shard installed / would hand off).
     sp_stripes: Optional[List[List[int]]] = None
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A decode step that has been dispatched and not read yet: what it
+    returns (the next tokens, a pattern's routed counts after them), whom
+    it ran for (slot -> request), and what its `decode` span carries."""
+    nxt: Any
+    batch: Dict[int, _Request]
+    t0: int
+    pages: int
+    synced: int
 
 
 # --------------------------------------------------------------------------
@@ -232,6 +252,44 @@ def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
     return lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
+def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
+                      length, ckpt, row, cfg: TransformerConfig, page: int,
+                      every: int):
+    """A prefill of a pattern with recurrent layers: ONE form for a whole
+    prompt and for a suffix, since both run the recurrence from a given
+    state.  The rows `tokens` (1, Sb), of which `length` are real, follow
+    `prefix_len` tokens whose keys and values lie in `pages` (as
+    `_suffix_prefill_fn` has it) and whose recurrent state is row `row` of
+    the checkpoint pool `ckpt` (row 0: the state of having read nothing,
+    with prefix_len 0).  Returns (last-token logits, the attention layers'
+    ks, vs (nA, Sb, KV, D), the state after `length` rows, the state after
+    every `every` rows (`mamba2.mixer`), the experts every row chose
+    (nE, Sb, K))."""
+    Sb = tokens.shape[1]
+    x = embed_tokens(params, tokens, cfg)
+    cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
+    attend, per_layer = _prefill_attend(
+        cfg, Sb, length, None, (pool_k, pool_v, pages, prefix_len, page))
+    rec = [{k: c[k][row][None] for k in c} for c in ckpt]
+    x, (ks, vs), rec, kept, _, chosen = run_pattern(
+        params["layers"], x, cos, sin, attend, cfg, rec, per_layer,
+        length=length, every=every)
+    return (lm_logits(params, x[0, length - 1], cfg), ks, vs, rec, kept,
+            chosen)
+
+
+def _install_state_fn(rec, ckpt, slot, end, kept, rows):
+    """Write a prefill's recurrent state into slot `slot` of the resident
+    per-slot state `rec`, and the checkpoints it passed into rows `rows`
+    (n,) of the pool `ckpt`; a checkpoint nobody keeps goes to row 1, the
+    scratch row."""
+    rec = [{k: r[k].at[slot].set(e[k][0]) for k in r}
+           for r, e in zip(rec, end)]
+    ckpt = [{k: c[k].at[rows].set(kp[k][0]) for k in c}
+            for c, kp in zip(ckpt, kept)]
+    return rec, ckpt
+
+
 def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
     """Write a prefill's (L, Sb, KV, D) kv into the slot's reserved pages.
 
@@ -256,9 +314,13 @@ def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
 
 
 def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
-                      active, cfg: TransformerConfig, page: int, kv_sharding):
+                      active, cfg: TransformerConfig, page: int, kv_sharding,
+                      rec=()):
     """The model half of a decode step: every slot's last token through the
-    layers against the paged pool -> (pool_k', pool_v', logits (B, V) f32).
+    layers against the paged pool -> (pool_k', pool_v', logits (B, V) f32),
+    and for a pattern three more: the recurrent layers' per-slot state `rec`
+    advanced for the active slots, the routed layers' counts (n, 2) and
+    their chosen experts (n, B, 1, K).
 
     The pool is carried through the layer loop whole and written where the
     new token lands; attention (ops/paged_attention.py) reads the pages a
@@ -278,6 +340,18 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
         tables, (lengths // page)[:, None], axis=1)[:, 0]         # (B,)
     write_off = lengths % page
     paged = _per_shard(paged_decode_attention, kv_sharding, "hpp...")
+
+    if cfg.pattern:
+        pools = [pool_k, pool_v]        # written layer by layer, in place
+
+        def attend(q, k, v, li):
+            pools[0] = pools[0].at[li, write_page, write_off].set(k[:, 0])
+            pools[1] = pools[1].at[li, write_page, write_off].set(v[:, 0])
+            return paged(q[:, 0], *pools, tables, lengths, li)[:, None], None
+        x, _, rec, _, counts, chosen = run_pattern(
+            params["layers"], x, cos, sin, attend, cfg, rec,
+            (jnp.arange(pool_k.shape[0], dtype=jnp.int32),), live=active)
+        return (*pools, lm_logits(params, x[:, 0], cfg), rec, counts, chosen)
 
     def body(carry, layer):
         x, pk, pv = carry               # pk/pv: the whole pool, in place
@@ -360,7 +434,13 @@ def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
     split(rng)`: the same two keys), samples, and advances what it owns:
     last token <- next token and length + 1 for the active slots.  On a
     mesh the state is replicated.
-    Returns (pool_k', pool_v', state', next_tokens (B,))."""
+    A pattern with recurrent layers keeps their state there too, under
+    "rec": one {"ssm", "tail"} for each `M` layer, a row a slot, advanced
+    by the step for the active slots; the host writes a slot's row when it
+    installs a prefill (`_install_state_fn`) and at no other time.
+    Returns (pool_k', pool_v', state', out): `out` the next tokens (B,),
+    and after them a pattern's routed counts, flattened (held experts
+    touched and rows computed, for each `E` layer): one read-back."""
     slots = _accept_rows(state["slots"], update)
     P = slots.shape[1] - _COLS
     tables, last, lengths = (slots[:, :P], slots[:, P + _COL_LAST],
@@ -368,13 +448,16 @@ def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
     active = slots[:, P + _COL_ACTIVE] != 0
     temps = jax.lax.bitcast_convert_type(slots[:, P + _COL_TEMP], jnp.float32)
     rng, key = jax.random.split(state["rng"])
-    pool_k, pool_v, logits = _decode_logits_fn(
+    pool_k, pool_v, logits, *pattern = _decode_logits_fn(
         params, pool_k, pool_v, tables, last, lengths, active, cfg, page,
-        kv_sharding)
+        kv_sharding, state.get("rec", ()))
     nxt = _sample_fn(logits, active, temps, key)
     slots = slots.at[:, P + _COL_LAST].set(jnp.where(active, nxt, last))
     slots = slots.at[:, P + _COL_LENGTH].add(active)
     state = {"slots": slots, "rng": rng}
+    if pattern:
+        state["rec"], counts, _ = pattern
+        nxt = jnp.concatenate([nxt, counts.reshape(-1)])
     if kv_sharding is not None:
         state = jax.lax.with_sharding_constraint(
             state, NamedSharding(kv_sharding.mesh, PartitionSpec()))
@@ -418,16 +501,43 @@ class _PrefixCache:
     Pages are ref-counted by the engine: cache membership holds one ref
     per entry, each active request one — a page returns to the free
     list only when the last holder lets go, so evicting an entry out
-    from under an in-flight request is safe."""
+    from under an in-flight request is safe.
 
-    def __init__(self, page: int, tag: bytes = b""):
+    STATE CHECKPOINTS (`every` > 0: a model with recurrent layers).  Cached
+    keys and values are then half of what a prefix left behind: the other
+    half is the recurrent state after it, which is kept only at every
+    `every`-th token (a row of the engine's checkpoint pool, keyed like the
+    page that ends there).  An entry can be used from the last such
+    boundary at or before it: `lookup` cuts the hit back to there and the
+    prefill recomputes the tokens between (`recomputed` counts them).  An
+    entry holds a reference to every checkpoint row at or before its own
+    boundary, as it does to its pages, so evicting it frees pages and rows
+    together and a row outlives every entry that could use it.  The rows
+    are this cache's to hand out (`free_rows`): nothing else holds one."""
+
+    def __init__(self, page: int, tag: bytes = b"", every: int = 0,
+                 rows: Sequence[int] = ()):
         self.page = page
+        self.every = every
+        self.free_rows: List[int] = list(rows)
+        self.n_rows = len(self.free_rows)
+        # boundary key -> checkpoint row, and back; row -> entries holding
+        # it; entry key -> the rows it holds
+        self._rows: Dict[bytes, int] = {}
+        self._row_key: Dict[int, bytes] = {}
+        self._row_refs: Dict[int, int] = {}
+        self._held: Dict[bytes, List[int]] = {}
+        self.recomputed = 0         # tokens recomputed behind a checkpoint
+        self.hit_tokens = 0         # prompt tokens of the requests that hit
+        self.rows_kept = 0
+        self.rows_evicted = 0
         # Key namespace tag: sequence-parallel engines key their pages
         # per SP layout (tag = b"sp<degree>") so pages cached under one
         # shard→stripe mapping can never alias pages cached under
         # another — the per-shard half of "prefix-cache keys become
         # per-shard" (the other half is _Request.sp_stripes).
         self.tag = tag
+        self._memo: Tuple[Any, List[bytes]] = (None, [])
         # rolling-hash key -> page ids covering the whole prefix
         self._entries: "OrderedDict[bytes, List[int]]" = OrderedDict()
         self.hits = 0
@@ -436,42 +546,101 @@ class _PrefixCache:
         self.evictions = 0
 
     def _keys(self, prompt: Sequence[int], upto: int) -> List[bytes]:
-        """Rolling hash at every page boundary 1..upto."""
+        """Rolling hash at every page boundary 1..upto.  One admission asks
+        three times (`lookup`, `boundaries`, `insert`) about one prompt:
+        the last prompt's keys are kept, by the list's identity."""
+        memo, keys = self._memo
+        if memo is prompt and len(keys) >= upto:
+            return keys[:upto]
+        full = max(upto, len(prompt) // self.page)
+        data = np.asarray(prompt[:full * self.page], np.int32).tobytes()
         h = hashlib.blake2b(digest_size=16)
         h.update(self.tag)
-        out = []
-        for k in range(1, upto + 1):
-            h.update(np.asarray(prompt[(k - 1) * self.page: k * self.page],
-                                np.int32).tobytes())
-            out.append(h.copy().digest())
-        return out
+        keys, step = [], 4 * self.page
+        for k in range(full):
+            h.update(data[k * step:(k + 1) * step])
+            keys.append(h.copy().digest())
+        self._memo = (prompt, keys)
+        return keys[:upto]
 
-    def lookup(self, prompt: Sequence[int]) -> Tuple[int, List[int]]:
+    def lookup(self, prompt: Sequence[int]) -> Tuple[int, List[int], int]:
         """Longest cached prefix usable by this prompt: (token count,
-        page ids).  Capped at S-1 tokens — the last prompt token's
-        logits must be computed, so at least a one-token suffix always
-        runs through prefill."""
+        page ids, checkpoint row).  Capped at S-1 tokens — the last prompt
+        token's logits must be computed, so at least a one-token suffix
+        always runs through prefill.  With state checkpoints the hit is cut
+        back to the last boundary that kept one (row 0 with no tokens: a
+        miss); without, the row is 0 and means nothing."""
         usable = (len(prompt) - 1) // self.page
         if usable <= 0:
-            return 0, []
+            return 0, [], 0
         keys = self._keys(prompt, usable)
         for k in range(usable, 0, -1):
             pages = self._entries.get(keys[k - 1])
-            if pages is not None:
-                self._entries.move_to_end(keys[k - 1])
-                self.hits += 1
-                self.hit_pages += k
-                return k * self.page, list(pages)
+            if pages is None:
+                continue
+            row, found = 0, k
+            if self.every:
+                per = self.every // self.page
+                k -= k % per
+                while k and keys[k - 1] not in self._rows:
+                    k -= per
+                if not k:
+                    break               # cached pages, but no state to go on
+                row = self._rows[keys[k - 1]]
+                self.recomputed += (found - k) * self.page
+            self._entries.move_to_end(keys[found - 1])
+            self.hits += 1
+            self.hit_pages += k
+            self.hit_tokens += len(prompt)
+            return k * self.page, list(pages[:k]), row
         self.misses += 1
-        return 0, []
+        return 0, [], 0
 
-    def insert(self, prompt: Sequence[int], table_row, incref) -> None:
+    def boundaries(self, prompt: Sequence[int], after: int) -> List[int]:
+        """The checkpoint boundaries (token counts) of `prompt` past
+        `after` that its full pages cover and no row is kept for yet."""
+        if not self.every:
+            return []
+        full = len(prompt) // self.page * self.page
+        marks = range(after + self.every, full + 1, self.every)
+        if not marks:
+            return []
+        keys = self._keys(prompt, full // self.page)
+        return [b for b in marks if keys[b // self.page - 1] not in self._rows]
+
+    def hold_row(self, row: int, by: int = 1) -> None:
+        """A prefill that starts from `row` holds it (`by` 1) until it has
+        run (`by` -1); row 0, the state of nothing read, is nobody's."""
+        if row:
+            self._row_refs[row] += by
+            if not self._row_refs[row]:
+                self._drop_row(row)
+                self.rows_evicted += 1
+
+    def _drop_row(self, row: int) -> None:
+        del self._rows[self._row_key.pop(row)], self._row_refs[row]
+        self.free_rows.append(row)
+
+    def insert(self, prompt: Sequence[int], table_row, incref,
+               rows: Optional[Dict[int, int]] = None) -> None:
         """Register every full prompt page of a freshly admitted request
-        (decode writes land strictly after them, so they are immutable)."""
+        (decode writes land strictly after them, so they are immutable).
+        `rows`: boundary (tokens) -> the checkpoint row (taken from
+        `free_rows`) this prefill wrote for it; each new entry takes a
+        reference to every row at or before its boundary, and a row no
+        entry took goes back."""
         full = len(prompt) // self.page
         if full <= 0:
+            for row in (rows or {}).values():
+                self.free_rows.append(row)
             return
         keys = self._keys(prompt, full)
+        for b, row in (rows or {}).items():
+            self._rows[keys[b // self.page - 1]] = row
+            self._row_key[row] = keys[b // self.page - 1]
+            self._row_refs[row] = 0
+            self.rows_kept += 1
+        per = self.every // self.page if self.every else 0
         for k in range(1, full + 1):
             key = keys[k - 1]
             if key in self._entries:
@@ -481,10 +650,21 @@ class _PrefixCache:
             self._entries[key] = pages
             for p in pages:
                 incref(p)
+            if per:
+                self._held[key] = held = [
+                    self._rows[keys[j - 1]] for j in range(per, k + 1, per)
+                    if keys[j - 1] in self._rows]
+                for r in held:
+                    self._row_refs[r] += 1
+        for row in (rows or {}).values():
+            if not self._row_refs[row]:
+                self._drop_row(row)
+                self.rows_kept -= 1
 
     def evict_lru(self, decref, demote=None) -> bool:
         """Drop the least-recently-used entry; True if one was dropped.
-        Pages still held by active requests stay allocated (ref > 0).
+        Pages still held by active requests stay allocated (ref > 0); a
+        checkpoint row whose last holder this entry was is free again.
         `demote(key, pages)` — when given — runs BEFORE the refs drop,
         so the hook can copy the page contents out of the pool while
         they are still guaranteed unrecycled (after decref the pages
@@ -497,6 +677,11 @@ class _PrefixCache:
             demote(key, pages)
         for p in pages:
             decref(p)
+        for r in self._held.pop(key, ()):
+            self._row_refs[r] -= 1
+            if not self._row_refs[r]:
+                self._drop_row(r)
+                self.rows_evicted += 1
         return True
 
 
@@ -710,6 +895,16 @@ def _default_kv_fetch(handle):
 # Engine
 # --------------------------------------------------------------------------
 
+# A pattern with recurrent layers keeps a state checkpoint every this many
+# chunks of its scan (4 x 128 = 512 tokens for the published chunk), and
+# pads no prefill below `_MIN_STATE_ROWS` rows: under that a prefill's time
+# is the weights' read, and a bucket fewer is a program fewer to warm (a
+# warm-up that reaches the suffix programs through one shared page of 16
+# tokens starts at 24 rows).
+_CKPT_CHUNKS = 4
+_MIN_STATE_ROWS = 32
+
+
 class LLMEngine:
     """Continuous-batching engine (reference concept: vllm engine wrapped
     by python/ray/llm/_internal/serve/engines/vllm/; here native JAX with
@@ -753,7 +948,22 @@ class LLMEngine:
         # page 0 is scratch (inactive-slot writes land there); never handed out
         self.n_pages = 1 + (kv_pages if kv_pages is not None
                             else max_batch * self.pages_per_slot)
-        L, kvh, d = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
+        # The pool has rows for the layers that attend: all of the dense
+        # decoder's, the `*` layers of a pattern.
+        L, kvh, d = cfg.count("*") or cfg.num_layers, cfg.num_kv_heads, \
+            cfg.head_dim_
+        # State checkpoints, every `_every` tokens (0: no recurrent layer).
+        self._every = _CKPT_CHUNKS * cfg.mamba.chunk if cfg.count("M") else 0
+        if cfg.pattern:
+            if mesh is not None or prefill_chunk or (sp_degree or 1) > 1 \
+                    or getattr(cfg, "sp_degree", 1) > 1:
+                raise ValueError(
+                    "a pattern of layer kinds is served on one device, whole "
+                    "prompts at a time: no mesh, sp_degree or prefill_chunk")
+            if self._every % self.page:
+                raise ValueError(
+                    f"page_size {self.page} does not divide the state "
+                    f"checkpoints' spacing of {self._every} tokens")
 
         from . import sequence_parallel as _sp
         deg = sp_degree if sp_degree is not None \
@@ -832,8 +1042,17 @@ class LLMEngine:
         # _free_pages with count 1 and returns when the count hits 0.
         self._page_refs: Dict[int, int] = {}
         cache_tag = (b"sp%d" % self.sp_degree) if self.sp_degree > 1 else b""
-        self._cache = _PrefixCache(self.page, cache_tag) \
+        # One checkpoint row for every `_every` tokens the page pool holds,
+        # so that the page pool is the one thing an operator sizes.  Row 0
+        # is the state of having read nothing and row 1 takes the
+        # checkpoints nobody keeps; neither is handed out.
+        n_rows = (self.n_pages - 1) * self.page // self._every \
+            if self._every and prefix_cache else 0
+        self._cache = _PrefixCache(self.page, cache_tag, self._every,
+                                   range(2, 2 + n_rows)) \
             if prefix_cache else None
+        self._ckpt = [zero_state(cfg.mamba, 2 + n_rows, cfg.dtype)
+                      for _ in range(cfg.count("M"))]
         # KV offload tier: LRU-evicted prefix-cache pages demote into a
         # bounded host window (NVMe overflow) instead of being freed;
         # hits promote back via device_put.  Pool squeezes (mem_chaos)
@@ -854,7 +1073,8 @@ class LLMEngine:
                 _demo_dir = os.path.join(
                     tempfile.gettempdir(),
                     "ray_tpu_kv_demote_%d" % os.getpid())
-            if _demo_on:
+            if _demo_on and not self._every:
+                # (Demoted pages would leave their state checkpoints behind.)
                 self._demote = _KVDemoteStore(_demo_lim, _demo_dir)
         self._tables = np.zeros((max_batch, self.pages_per_slot), np.int32)
         self._slots: Dict[int, _Request] = {}
@@ -872,6 +1092,14 @@ class LLMEngine:
         self._lengths = np.zeros(max_batch, np.int32)
         self._temps = np.zeros(max_batch, np.float32)
         self._touched = np.zeros(max_batch, bool)
+        # The next decode step, where the last `step()` already sent it off
+        # (`_next_batch_if_ahead`); and the owner's word that someone is waiting
+        # to hand the engine work (a replica: its lock has waiters), which
+        # keeps the next step back.  None: no owner who could tell, and no
+        # step leaves ahead (a request added between two calls joins the
+        # very next step, as ever).
+        self._ahead: Optional[_Flight] = None
+        self.hold_ahead: Optional[Callable[[], bool]] = None
         self._state_shd = None if mesh is None else NamedSharding(
             mesh, PartitionSpec())
         idle = np.zeros(max_batch, bool)
@@ -880,6 +1108,15 @@ class LLMEngine:
         self._dev = jax.device_put(
             {"slots": none[:, :-1], "rng": jax.random.key(seed + 1)},
             self._state_shd)
+        if self._every:
+            # Per slot, the recurrent layers' state: resident with the rest.
+            self._dev["rec"] = [zero_state(cfg.mamba, max_batch, cfg.dtype)
+                                for _ in range(cfg.count("M"))]
+        # What the routed layers' decode steps touched, a row a layer:
+        # held experts that got a row, (token, expert) rows computed;
+        # cumulative, and the last step's.
+        self._routed = np.zeros((cfg.count("E"), 2), np.int64)
+        self._step_routed = np.zeros((cfg.count("E"), 2), np.int64)
         # The update of a step before which no slot was touched: marks none.
         self._no_rows = jax.device_put(none, self._state_shd)
         self._prefill_jit = {}
@@ -918,6 +1155,10 @@ class LLMEngine:
             return _install_fn(pk, pv, ks, vs, pages, page, kv_shd)
         self._install_jit = jax.jit(install_kv, donate_argnums=(0, 1))
 
+        self._install_state_jit = jax.jit(_install_state_fn,
+                                          donate_argnums=(0, 1))
+        self._trace_jit = None          # `trace_logits` builds it
+
         # Chunked in-pool prefill: chunk size is a page multiple so every
         # chunk boundary is a page boundary (the suffix path requires a
         # page-aligned resident prefix).
@@ -953,6 +1194,12 @@ class LLMEngine:
                                         donate_argnums=(0, 1))
 
     # ------------------------------------------------------------ requests --
+    def _dense_only(self, what: str) -> None:
+        if self.cfg.pattern:
+            raise ValueError(
+                f"{what}: keys and values shipped or streamed from elsewhere "
+                "are not the whole cache of a pattern with other layer kinds")
+
     def _pages_needed(self, req: _Request) -> int:
         if req.kv_paged:
             # External context: only the decode tail lives in the pool.
@@ -989,6 +1236,7 @@ class LLMEngine:
         prompt tokens are supplied — prefix cache as locally-prefilled
         requests, so deadline expiry, pool pressure and cancellation
         behave identically."""
+        self._dense_only("add_external_request")
         params = params or SamplingParams()
         S = int(kv_blob["len"])
         if S >= self.max_len:
@@ -1052,6 +1300,7 @@ class LLMEngine:
         gather window; a part whose host is lost mid-decode fails THIS
         request typed (KVGatherError → StreamBrokenError upstream),
         never emitting a wrong token."""
+        self._dense_only("add_paged_request")
         params = params or SamplingParams()
         S = int(length)
         req = _Request(self._next_id,
@@ -1154,6 +1403,42 @@ class LLMEngine:
                 "state_rows": self._state_rows,
                 "step_state_rows": self._step_state_rows}
 
+    def state_stats(self) -> Dict[str, Any]:
+        """A model with recurrent layers: the checkpoint rows in use and in
+        all, checkpoints kept and evicted, the prompt tokens recomputed
+        behind a checkpoint beside the prompt tokens of the requests that
+        hit, and the bytes of one row and of one slot's state."""
+        if not self._every:
+            return {"enabled": False}
+        c = self._cache
+        row = self.cfg.count("M") * self.cfg.mamba.state_bytes(
+            jnp.dtype(self.cfg.dtype).itemsize)
+        out = {"enabled": True, "every": self._every, "row_bytes": row,
+               "slots": self.max_batch, "rows_total": 0, "rows_in_use": 0}
+        if c is not None:
+            out.update(rows_total=c.n_rows,
+                       rows_in_use=c.n_rows - len(c.free_rows),
+                       checkpoints_kept=c.rows_kept,
+                       checkpoints_evicted=c.rows_evicted,
+                       tokens_recomputed=c.recomputed,
+                       hit_prompt_tokens=c.hit_tokens)
+        return out
+
+    def routed_stats(self) -> Dict[str, Any]:
+        """What the routed layers' DECODE steps touched, a number a layer:
+        distinct held experts that got a row and (token, expert) rows
+        computed, summed over `steps` decode steps and in the last one.
+        A step reads the weights of the experts it touched and no others."""
+        if not len(self._routed):
+            return {"enabled": False}
+        r = self.cfg.routed
+        return {"enabled": True, "steps": self._decode_steps,
+                "held": r.held, "experts": r.experts, "top_k": r.top_k,
+                "touched": self._routed[:, 0].tolist(),
+                "rows": self._routed[:, 1].tolist(),
+                "step_touched": self._step_routed[:, 0].tolist(),
+                "step_rows": self._step_routed[:, 1].tolist()}
+
     def prefill_stats(self) -> Dict[str, Any]:
         """The attention form of the last prefill (`path`: "kernel" or
         "xla"), how many prefills took each, and the key blocks they ran
@@ -1197,7 +1482,7 @@ class LLMEngine:
     def _bucket(self, n: int) -> int:
         # Floor at sp_degree (both pow-2): a short prompt's bucket must
         # still split over every sequence-parallel shard.
-        b = max(8, self.sp_degree)
+        b = max(_MIN_STATE_ROWS if self._every else 8, self.sp_degree)
         while b < n:
             b *= 2
         return min(b, self.max_len)
@@ -1207,6 +1492,9 @@ class LLMEngine:
         prefill half; returns (last_logits, ks, vs).  With sp_degree > 1
         dispatches to the sequence-parallel path (ring/Ulysses over the
         mesh's sp axis) — exact parity with the single-device kernel."""
+        if self.cfg.pattern:
+            return self._run_suffix(
+                prompt, 0, np.zeros(self.pages_per_slot, np.int32))
         S = len(prompt)
         Sb = self._bucket(S)
         key = ("sp", Sb) if self.sp_degree > 1 else Sb
@@ -1343,22 +1631,38 @@ class LLMEngine:
         under pool pressure before giving up."""
         if not self._free_slots:
             return False
-        c, shared = 0, []
-        if self._cache is not None and not req.no_cache:
-            c, shared = self._cache.lookup(req.prompt)
+        c, shared, marks = 0, [], []
+        caching = self._cache is not None and not req.no_cache
+        if caching:
+            before = self._cache.recomputed
+            c, shared, req.from_row = self._cache.lookup(req.prompt)
+            req.recomputed = self._cache.recomputed - before
+            marks = self._cache.boundaries(req.prompt, c)
         total = self._pages_needed(req)
         need = total - len(shared)
-        # Hold the shared pages before any eviction can touch them.
+        # Hold the shared pages, and the checkpoint row the prefill starts
+        # from, before any eviction can touch them.
         for p in shared:
             self._incref(p)
+        if caching:
+            self._cache.hold_row(req.from_row)
         demote = self._demote_entry if self._demote is not None else None
-        while len(self._free_pages) < need and self._cache is not None \
+        def short():                # of pages, or of rows for `marks`
+            return len(self._free_pages) < need or (
+                marks and len(self._cache.free_rows) < len(marks))
+        while short() and self._cache is not None \
                 and self._cache.evict_lru(self._decref, demote):
             pass
         if len(self._free_pages) < need:
             for p in shared:
                 self._decref(p)
+            if caching:
+                self._cache.hold_row(req.from_row, -1)
             return False
+        # Rows for the checkpoints this prefill passes; one that finds none
+        # free is not kept.
+        req.new_rows = {b: self._cache.free_rows.pop()
+                        for b in marks if self._cache.free_rows}
         if self._demote is not None and not req.no_cache \
                 and not req.kv_paged and len(self._demote):
             c, shared = self._try_promote(req, c, shared, total)
@@ -1388,6 +1692,18 @@ class LLMEngine:
         self._pk, self._pv = self._install_jit(
             self._pk, self._pv, ks, vs, jnp.asarray(pages))
 
+    def _install_state(self, req: _Request, end, kept) -> None:
+        """A prefill's recurrent state into the request's slot, and the
+        checkpoints it passed into the rows reserved for them
+        (`_reserve`); the prefill's bucket may hold boundaries past the
+        prompt, which go to the scratch row."""
+        rows = np.ones(kept[0]["ssm"].shape[1], np.int32)
+        for b, row in req.new_rows.items():
+            rows[(b - req.prefix_len) // self._every - 1] = row
+        self._dev["rec"], self._ckpt = self._install_state_jit(
+            self._dev["rec"], self._ckpt, req.slot, end, kept,
+            jnp.asarray(rows))
+
     def _install_new_pages(self, req: _Request, ks, vs):
         """Install suffix KV into the request's NEWLY reserved pages (the
         suffix starts page-aligned at prefix_len, so it maps exactly onto
@@ -1396,12 +1712,16 @@ class LLMEngine:
         self._install_pages(req.pages, ks, vs)
 
     def _run_suffix(self, prompt: Sequence[int], prefix_len: int,
-                    pages_row, upto: Optional[int] = None):
+                    pages_row, upto: Optional[int] = None, from_row: int = 0):
         """Jit-cached suffix prefill against resident prefix pages.
         `upto` bounds the suffix (chunked prefill: one chunk per call).
         With sp_degree > 1 the suffix attention runs sequence-parallel
         (ring over the suffix KV, accumulator seeded by the resident
-        prefix) so prefix-cache hits keep their compute skip under SP."""
+        prefix) so prefix-cache hits keep their compute skip under SP.
+        A pattern of kinds runs every prefill through here, a whole prompt
+        as the suffix of nothing, from checkpoint row `from_row`
+        (`_state_prefill_fn`: two results more, the state and its
+        checkpoints)."""
         suf = prompt[prefix_len:upto]
         S = len(suf)
         Sb = self._bucket(S)
@@ -1409,7 +1729,14 @@ class LLMEngine:
         key = ("sp-suffix", Sb) if sp else ("suffix", Sb)
         if key not in self._prefill_jit:
             cfg, page = self.cfg, self.page
-            if sp:
+            if cfg.pattern:
+                every = self._every
+
+                def state_prefill(p, pk, pv, pg, t, pl, n, ckpt, row):
+                    return _state_prefill_fn(p, pk, pv, pg, t, pl, n, ckpt,
+                                             row, cfg, page, every)
+                self._prefill_jit[key] = jax.jit(state_prefill)
+            elif sp:
                 mesh = self.mesh
 
                 def sp_suffix_prefill(p, pk, pv, pg, t, pl, n):
@@ -1426,9 +1753,27 @@ class LLMEngine:
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = suf
         self._count_prefill(S, Sb, prefix_len)
+        state = (self._ckpt, from_row) if self.cfg.pattern else ()
         return self._prefill_jit[key](
             self.params, self._pk, self._pv, jnp.asarray(pages_row),
-            jnp.asarray(toks), prefix_len, S)
+            jnp.asarray(toks), prefix_len, S, *state)
+
+    def _prefill_slot(self, req: _Request):
+        """Run a reserved request's prefill and install what it leaves:
+        keys and values into its pages, a pattern's recurrent state into
+        its slot and the checkpoints it passed into their rows.  Returns
+        (last-token logits, the experts every row chose or None)."""
+        if not (req.prefix_len or self.cfg.pattern):
+            logits, ks, vs = self._run_prefill(req.prompt)
+            self._install(req.slot, ks, vs)
+            return logits, None
+        logits, ks, vs, *state = self._run_suffix(
+            req.prompt, req.prefix_len, self._tables[req.slot],
+            from_row=req.from_row)
+        self._install_new_pages(req, ks, vs)
+        if self._every:
+            self._install_state(req, *state[:2])
+        return logits, state[2] if state else None
 
     def _admit(self) -> int:
         """Admit what fits; returns how many requests took a slot."""
@@ -1459,21 +1804,20 @@ class LLMEngine:
             t0 = ph.enter("prefill")
             if req.kv_blob is not None:
                 self._install_external(req)
-            elif req.prefix_len:
-                logits, ks, vs = self._run_suffix(
-                    req.prompt, req.prefix_len, self._tables[req.slot])
-                self._install_new_pages(req, ks, vs)
             else:
-                logits, ks, vs = self._run_prefill(req.prompt)
-                self._install(req.slot, ks, vs)
+                logits, _ = self._prefill_slot(req)
             ran = {} if req.kv_blob is not None else self._prefill_ran
+            if self._every:
+                ran = dict(ran, checkpoints=len(req.new_rows),
+                           recomputed=req.recomputed)
             ph.leave(t0, "prefill", req.req_id.to_bytes(8, "little"),
                      tokens=S, cached_tokens=req.prefix_len,
                      active=active_before, n=ph.n,
                      new_program=len(self._prefill_jit) - programs, **ran)
             if self._cache is not None and not req.no_cache:
+                self._cache.hold_row(req.from_row, -1)
                 self._cache.insert(req.prompt, self._tables[req.slot],
-                                   self._incref)
+                                   self._incref, req.new_rows)
             if self.sp_degree > 1:
                 # Which pages each sequence-parallel shard installed —
                 # the stripe accounting the cross-host handoff consumes.
@@ -1605,12 +1949,21 @@ class LLMEngine:
         activated, freed) is marked in `_touched`, and the marked rows
         ride to the device as ONE packed upload in the next step's `prep`.
         A step before which nothing was touched uploads nothing and runs
-        no program but the decode step (`decode_stats()`).
+        no program but the decode step (`decode_stats()`).  Such a step
+        needs nothing of the host: where the call can see that the next
+        one is of that kind and no request stands between
+        (`_next_batch_if_ahead`) it sends it off before it returns, and
+        the device runs it while the caller hands this step's tokens on
+        and comes back; the next call reads it (`_ahead`) and dispatches
+        nothing.  What a call returns is what it returned before: one
+        token for every active slot.
 
         Every instant of the call belongs to one phase of
         `tick_phases.TickPhases` (self.phases): `admit`, `chunk`, `emit`,
         then the decode step's `prep`, `dispatch` and `wait`, then `emit`
-        again; it hands back to the replica's loop in `hop`."""
+        again (and `ahead`, where the next step leaves now: that call's
+        `prep` and `dispatch` are then empty); it hands back to the
+        replica's loop in `hop`."""
         ph = self.phases
         ph.in_step = True
         done: List[_Request] = []
@@ -1659,18 +2012,58 @@ class LLMEngine:
             self._emit(req, tok)
             if req.finished:
                 done.append(self._retire(slot))
-        batch = {s for s, r in self._slots.items() if not r.kv_paged}
-        if not batch:
-            return 0
-        active = np.zeros(self.max_batch, bool)
-        for slot in batch:
-            active[slot] = True
         before = len(done)
-        t0 = ph.to("prep", retired=before)
-        pages = int((self._lengths[active] // self.page + 1).sum())
+        flight, self._ahead = self._ahead, None
+        if flight is None:
+            batch = {s: r for s, r in self._slots.items() if not r.kv_paged}
+            if not batch:
+                return 0
+            flight = self._dispatch_decode(ph, batch, retired=before)
+        else:
+            # Sent off by the last call (`ahead`): nothing to prepare.
+            flight.t0 = ph.to("prep", retired=before)
+            ph.to("dispatch")
+        ph.to("wait")
+        nxt = np.asarray(flight.nxt)
         self._decode_steps += 1
-        self._pages_read += pages
-        self._step_pages_read = pages
+        self._pages_read += flight.pages
+        self._step_pages_read = flight.pages
+        self._step_state_rows = flight.synced
+        extra = {}
+        if len(self._routed):
+            # After the tokens, what the routed layers touched (`_decode_fn`).
+            self._step_routed = nxt[self.max_batch:].reshape(-1, 2)
+            self._routed += self._step_routed
+            extra["experts"] = int(self._step_routed[:, 0].sum())
+        ph.span("decode", flight.t0, ph.to("emit"), batch=len(flight.batch),
+                pages=flight.pages, synced=flight.synced, **extra)
+        # The host advances its mirrors as the step advanced the device's.
+        for slot, req in flight.batch.items():
+            if self._slots.get(slot) is not req:
+                continue                # cancelled while the step was out
+            self._lengths[slot] += 1          # the token we just attended
+            tok = int(nxt[slot])
+            self._last[slot] = tok
+            self._emit(req, tok)
+            if req.finished:
+                done.append(self._retire(slot))
+        batch = self._next_batch_if_ahead()
+        if batch:
+            self._ahead = self._dispatch_decode(ph, batch, ahead=True)
+            ph.to("emit")
+        return before
+
+    def _dispatch_decode(self, ph: TickPhases, batch: Dict[int, _Request],
+                         ahead: bool = False, **closing) -> _Flight:
+        """One decode step for `batch` (slot -> request) leaves for the
+        device: `prep` (the one packed upload of the rows the host
+        touched, if any) and `dispatch`; both in the one leaf `ahead` for
+        a step sent off at the end of a call, before the caller asks for
+        it.  What comes back is read in `_step`."""
+        active = np.zeros(self.max_batch, bool)
+        active[list(batch)] = True
+        t0 = ph.to("ahead" if ahead else "prep", **closing)
+        pages = int((self._lengths[active] // self.page + 1).sum())
         update, synced = self._no_rows, int(self._touched.sum())
         if synced:
             update = jax.device_put(_pack_rows(
@@ -1679,25 +2072,34 @@ class LLMEngine:
             self._touched[:] = False
             self._state_syncs += 1
             self._state_rows += synced
-        self._step_state_rows = synced
-        ph.to("dispatch")
+        if not ahead:
+            ph.to("dispatch")
         self._pk, self._pv, self._dev, nxt = self._decode_jit(
             self.params, self._pk, self._pv, self._dev, update)
-        ph.to("wait")
-        nxt = np.asarray(nxt)
-        ph.span("decode", t0, ph.to("emit"), batch=len(batch), pages=pages,
-                synced=synced)
-        # The host advances its mirrors as the step advanced the device's.
-        for slot, req in list(self._slots.items()):
-            if slot not in batch:
-                continue
-            self._lengths[slot] += 1          # the token we just attended
-            tok = int(nxt[slot])
-            self._last[slot] = tok
-            self._emit(req, tok)
-            if req.finished:
-                done.append(self._retire(slot))
-        return before
+        return _Flight(nxt, batch, t0, pages, synced)
+
+    def _next_batch_if_ahead(self) -> Dict[int, _Request]:
+        """Whom the NEXT decode step is for, if it may leave now, at the
+        end of this call, so that the device runs it while the caller fans
+        this step's tokens out and comes back (`_step` then finds it in
+        `_ahead` and only reads it); nobody if it may not.  It may when
+        the next call would dispatch exactly this step: every slot is the
+        batch step's, nothing waits for admission, the engine's owner says
+        that nobody is about to hand it work (`hold_ahead`), whose prefill
+        would otherwise queue behind the step, and no row is touched:
+        nobody retired in this call.  A caller whose answer has just ended
+        comes back with its next request within the tick that follows, and
+        that tick is left as long as it ever was: cut short by a step sent
+        ahead, it ends a millisecond before a closed loop's request
+        arrives about once in ten, the request joins a tick late, and
+        callers laid ticks apart walk into each other's prefills
+        (PERF.md §6, PR 38)."""
+        if (self.hold_ahead is None or self._waiting or self._prefilling
+                or self._touched.any()
+                or any(r.kv_paged for r in self._slots.values())
+                or self.hold_ahead()):
+            return {}
+        return dict(self._slots)
 
     def _advance_prefilling(self) -> None:
         """Advance chunked prefills by AT MOST one chunk per tick: the
@@ -1862,6 +2264,7 @@ class LLMEngine:
         sequence-parallel prefill shards: each shard computes its
         stripe and publishes it into ITS OWN node's arena, so no single
         node's pool (or arena) ever holds the whole context."""
+        self._dense_only("prefill_paged_chunk")
         sa = self._stream_attn
         Sc = len(chunk_tokens)
         if not (0 < Sc <= span):
@@ -2008,6 +2411,63 @@ class LLMEngine:
                 results[req.req_id] = req.out
         return [results[i] for i in ids]
 
+    def trace_logits(self, prompt: Sequence[int], tokens: Sequence[int] = (),
+                     cached: bool = False) -> Dict[str, Any]:
+        """For a reference check that needs the path's own logits and what
+        it decided (a model with routed experts): `prompt` prefilled into a
+        free slot — cold, or with `cached` as a request would be, from
+        whatever the prefix cache holds of it — then each of `tokens`
+        decoded through the pool and the slot's state by the serving step's
+        own model half (`_decode_logits_fn`).  Returns {"logits": (1 +
+        len(tokens), V) float32, the prompt's last position and then each
+        token's; "from": the cached tokens the prefill started after;
+        "chosen": (routed layers, len(prompt) - from + len(tokens), K) the
+        experts every computed position chose, or None}.  Needs a free slot
+        and the pages; leaves nothing behind and adds no cache entry."""
+        req = _Request(-1, list(prompt),
+                       SamplingParams(max_tokens=len(tokens) + 1))
+        req.no_cache = not cached
+        if not self._reserve(req):
+            raise RuntimeError("trace_logits: no free slot or pages")
+        try:
+            S, slot, picks = len(prompt), req.slot, []
+            if self._cache is not None and cached:  # keeps no checkpoint
+                self._cache.free_rows.extend(req.new_rows.values())
+                req.new_rows = {}
+            logits, chosen = self._prefill_slot(req)
+            if chosen is not None:
+                picks.append(chosen[:, 0, :S - req.prefix_len])
+            if self._cache is not None and cached:
+                self._cache.hold_row(req.from_row, -1)
+            if self._trace_jit is None:
+                cfg, page, kv_shd = self.cfg, self.page, self._kv_shd
+                def decode_logits(p, pk, pv, tb, lt, ln, ac, rec):
+                    return _decode_logits_fn(p, pk, pv, tb, lt, ln, ac, cfg,
+                                             page, kv_shd, rec)
+                self._trace_jit = jax.jit(decode_logits,
+                                          donate_argnums=(1, 2, 7))
+            rows = [logits]
+            active = np.zeros(self.max_batch, bool)
+            active[slot] = True
+            at = np.arange(self.max_batch) == slot
+            for i, tok in enumerate(tokens):
+                # (Fresh arrays a step: the CPU backend may read a numpy
+                # argument in place after the call has returned.)
+                self._pk, self._pv, lg, *pattern = self._trace_jit(
+                    self.params, self._pk, self._pv, self._tables.copy(),
+                    np.where(at, tok, 0).astype(np.int32),
+                    np.where(at, S + i, 0).astype(np.int32), active.copy(),
+                    self._dev.get("rec", ()))
+                if pattern:
+                    self._dev["rec"], _, chosen = pattern
+                    if chosen is not None:
+                        picks.append(chosen[:, slot])
+                rows.append(lg[slot])
+        finally:
+            self._free_slot(req)
+        return {"logits": jnp.stack(rows), "from": req.prefix_len,
+                "chosen": jnp.concatenate(picks, axis=1) if picks else None}
+
     # ------------------------------------------- prefill/decode disaggregation
     def prefill_only(self, prompt_tokens: Sequence[int],
                      params: Optional[SamplingParams] = None):
@@ -2021,6 +2481,7 @@ class LLMEngine:
         back to a host gather there, counted as fallback bytes).  With
         the prefix cache on, a hit computes only the suffix and gathers
         the shared span straight out of the resident pages."""
+        self._dense_only("prefill_only")
         params = params or SamplingParams()
         S = len(prompt_tokens)
         if S >= self.max_len:
@@ -2030,7 +2491,7 @@ class LLMEngine:
         t0 = rec.begin()
         c, shared = 0, []
         if self._cache is not None:
-            c, shared = self._cache.lookup(prompt)
+            c, shared, _ = self._cache.lookup(prompt)
         if c:
             row = np.zeros(self.pages_per_slot, np.int32)
             row[:len(shared)] = shared

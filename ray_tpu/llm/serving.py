@@ -50,8 +50,9 @@ admitted, with queue depth and the count of requests already decoding),
 ``tick:expire``, ``tick:hop``, ``step:admit``, ``decode`` (with batch
 size and ``synced``, the slot rows written to the device before it;
 ``decode:prep`` / ``:dispatch`` / ``:wait``), ``sample_sync`` (the
-batched device→host sample pull), ``step:emit``, ``tick:fan_out`` —
-whose cumulative nanoseconds ``debug_stats()["tick"]`` also serves.
+batched device→host sample pull), ``step:emit``, ``step:ahead`` (the
+next decode step sent off before ``step()`` returns, while no caller waits
+for the lock), ``tick:fan_out`` — whose cumulative nanoseconds ``debug_stats()["tick"]`` also serves.
 
 `run_open_loop` is the arrival-rate-driven (never closed-loop) load
 harness: it offers requests on a fixed schedule regardless of
@@ -88,6 +89,22 @@ class _StreamEnd:
     def __init__(self, finish_reason: str, n_tokens: int):
         self.finish_reason = finish_reason
         self.n_tokens = n_tokens
+
+
+class _EngineLock(asyncio.Lock):
+    """The replica's lock, which also knows how many callers wait for it
+    (`waiting`; read from the engine's thread, a plain int)."""
+
+    def __init__(self):
+        super().__init__()
+        self.waiting = 0
+
+    async def acquire(self):
+        self.waiting += 1
+        try:
+            return await super().acquire()
+        finally:
+            self.waiting -= 1
 
 
 class EngineReplica:
@@ -145,7 +162,10 @@ class EngineReplica:
                                        temperature=temperature,
                                        eos_id=eos_id)
         self.max_queue = int(max_queue)
-        self._lock = asyncio.Lock()        # serializes ALL engine access
+        self._lock = _EngineLock()         # serializes ALL engine access
+        # A caller waiting for the lock is about to hand the engine work:
+        # the tick does not send its next decode step off ahead of it.
+        self.engine.hold_ahead = lambda: self._lock.waiting > 0
         self._wake = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
         # req_id -> consumer queue / metadata for in-flight streams.
@@ -793,7 +813,10 @@ class EngineReplica:
         """Counters of this replica and its engine.  `decode` is
         `LLMEngine.decode_stats()`: the pages the decode steps read, and how
         often the host had to write slot rows into the step's
-        device-resident state (`state_syncs` of `steps`, `state_rows`)."""
+        device-resident state (`state_syncs` of `steps`, `state_rows`);
+        `state` and `routed` are `LLMEngine.state_stats()` (recurrent-state
+        checkpoints) and `routed_stats()` (experts the decode steps
+        touched), `{"enabled": False}` for a model without such layers."""
         e = self.engine
         return {"ticks": self._ticks, "max_active": self._max_active,
                 "shed": self._shed, "cancelled": self._cancelled,
@@ -809,6 +832,8 @@ class EngineReplica:
                 "kv_gather": e.kv_gather_stats(),
                 "decode": e.decode_stats(),
                 "prefill": e.prefill_stats(),
+                "state": e.state_stats(),
+                "routed": e.routed_stats(),
                 "tick": self._phases.snapshot()}
 
     async def pid(self) -> int:

@@ -31,7 +31,10 @@ page tables), `prefill` and `sample_sync` (inside either), `prep`
 step, the one packed upload of the touched rows), `dispatch`, `wait` (the blocking read-back),
 `emit` (the emit/retire loops: requests finished at admission and
 paged-context slots before the decode step, every slot after it),
-`fan_out`.  Parent spans (`tick`, `step:admit`, `step:chunk`, `decode`)
+`ahead` (the NEXT step's `prep` and `dispatch`, where `step()` sends it
+off before it returns: the device then runs it through `hop`, `fan_out`,
+`turn` and the next tick's `expire`, `hop` and `admit`, and that tick's
+own `prep` and `dispatch` are empty), `fan_out`.  Parent spans (`tick`, `step:admit`, `step:chunk`, `decode`)
 are stamped by their callers from the stamps `to()` returns.
 
 One thread at a time drives this object: the loop's thread, or — while the
@@ -50,7 +53,7 @@ from .._private import clocks, flight_recorder
 
 LEAVES = ("idle", "turn", "expire", "hop", "admit", "prefill",
           "sample_sync", "chunk", "prep", "dispatch", "wait", "emit",
-          "fan_out")
+          "ahead", "fan_out")
 
 # Leaf -> the span a finished piece of it is recorded as.  The others are
 # self time of a parent span, or spans their caller records with the
@@ -58,7 +61,7 @@ LEAVES = ("idle", "turn", "expire", "hop", "admit", "prefill",
 _SPAN = {"idle": "tick:idle", "turn": "tick:turn", "expire": "tick:expire",
          "hop": "tick:hop", "fan_out": "tick:fan_out",
          "prep": "decode:prep", "dispatch": "decode:dispatch",
-         "wait": "decode:wait", "emit": "step:emit"}
+         "wait": "decode:wait", "emit": "step:emit", "ahead": "step:ahead"}
 
 
 class TickPhases:

@@ -1,0 +1,170 @@
+"""Latent routed-expert layer (the `E` layers of a hybrid stack,
+models/transformer.py: `routed_block`), dropless, told which experts it
+holds.
+
+The router scores ALL experts of the model: `s = sigmoid(x W_r)` in float32;
+the `top_k` experts with the largest `s + bias` are chosen, their weights
+are their `s` normalised to sum 1 over the chosen and scaled.  The experts
+work in a latent space: `u = x W_down`, expert e gives `relu(u W1_e)^2
+W2_e`, the weighted sum goes back through `W_up`.  One shared expert works
+on x itself and is added.
+
+This process holds experts [held_from, held_from + held) of every layer (a
+chip of an expert-parallel group holds its share) and computes THEIR part of
+the weighted sum for the tokens routed to them; what the experts held
+elsewhere would add is left out, and nothing stands in for the exchange.
+The chosen weights are normalised over all chosen experts, held or not.
+No token is dropped: the (token, expert) rows are sorted by expert and each
+projection is ONE grouped product over the sorted rows (on a TPU the
+`megablox` grouped matmul, whose grid covers only the row tiles of experts
+that have rows, so a decode step reads the weights of the experts its batch
+touched and no others; elsewhere `jax.lax.ragged_dot`).  No [N, K, E]
+one-hot is built.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedDims:
+    experts: int = 512          # the router's outputs: every expert of the model
+    held: int = 128             # experts whose weights this process holds
+    held_from: int = 0          # the first of them
+    top_k: int = 22
+    latent: int = 1024
+    width: int = 2688           # an expert's hidden width
+    shared_width: int = 5376
+    scale: float = 5.0          # routed_scaling_factor
+
+    def expert_params(self) -> int:
+        return 2 * self.latent * self.width
+
+    def shared_params(self, hidden: int) -> int:
+        """A layer's parameters outside its routed experts."""
+        return (hidden * self.experts + self.experts + 2 * hidden * self.latent
+                + 2 * hidden * self.shared_width)
+
+
+def scores(lp, x):
+    """x (..., E) -> every expert's score (..., X) in float32."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "...e,ex->...x", x, lp["router"].astype(x.dtype),
+        preferred_element_type=jnp.float32))
+
+
+def route(lp, x, dims: RoutedDims):
+    """x (N, E) -> the chosen experts idx (N, K) int32 and their weights
+    (N, K) float32."""
+    s = scores(lp, x)
+    _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
+                           dims.top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / jnp.sum(w, -1, keepdims=True) * dims.scale
+    return idx.astype(jnp.int32), w
+
+
+def _tile(width: int) -> int:
+    """A tile of the grouped product along a width: all of it up to 1,024,
+    and a divisor of it beyond where 896 (7 x 128) is one."""
+    return width if width <= 1024 else 896 if width % 896 == 0 else 1024
+
+
+def grouped_path() -> str:
+    """How `_grouped` multiplies in this process: "megablox" on a TPU,
+    "ragged_dot" elsewhere."""
+    return "megablox" if jax.devices()[0].platform == "tpu" else "ragged_dot"
+
+
+def _grouped(rows, weights, sizes):
+    """rows (M, k) sorted by group, weights (G, k, n), sizes (G,) -> (M, n):
+    each group's rows times its own matrix.  Rows past the groups' total
+    are not computed and hold anything."""
+    if grouped_path() == "megablox":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        k, n = weights.shape[1:]
+        tile = (128, _tile(k), _tile(n))
+        pad = -rows.shape[0] % tile[0]
+        out = gmm(jnp.pad(rows, ((0, pad), (0, 0))), weights, sizes,
+                  preferred_element_type=rows.dtype, tiling=tile)
+        return out[:rows.shape[0]]
+    return jax.lax.ragged_dot(rows, weights, sizes,
+                              preferred_element_type=rows.dtype)
+
+
+def held_experts(lp, u, idx, w, dims: RoutedDims, real=None):
+    """The held experts' part of the weighted sum: u (N, latent), idx and w
+    (N, K) from `route` -> (out (N, latent), counts (2,) int32: distinct
+    held experts that got a row, and (token, expert) rows computed).  A row
+    of u that is not `real` (N,) (padding, a slot that is not live) goes to
+    no expert: it costs no product and touches no weights."""
+    N, K = idx.shape
+    local = idx.reshape(-1) - dims.held_from
+    here = (local >= 0) & (local < dims.held)
+    if real is not None:
+        here = here & jnp.repeat(real, K)
+    group = jnp.where(here, local, dims.held)           # elsewhere: sorts last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=dims.held + 1)[:dims.held] \
+        .astype(jnp.int32)
+    rows = u[order // K]                                # (N K, latent)
+    hid = _grouped(rows, lp["w1"].astype(u.dtype), sizes)
+    hid = jnp.square(jax.nn.relu(hid))
+    out = _grouped(hid, lp["w2"].astype(u.dtype), sizes)
+    # Back to (token, choice) order; a row of an expert held elsewhere was
+    # not computed and counts nothing.
+    back = jnp.argsort(order)
+    out = jnp.where(here[:, None], out[back], 0).reshape(N, K, -1)
+    weight = jnp.where(here.reshape(N, K), w, 0.0)
+    out = jnp.einsum("nkl,nk->nl", out.astype(jnp.float32), weight)
+    counts = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes)]).astype(jnp.int32)
+    return out.astype(u.dtype), counts
+
+
+def _relu2_mlp(x, w1, w2):
+    h = jnp.einsum("ne,em->nm", x, w1.astype(x.dtype))
+    return jnp.einsum("nm,me->ne", jnp.square(jax.nn.relu(h)),
+                      w2.astype(x.dtype))
+
+
+def mixer(lp, x, dims: RoutedDims, real=None):
+    """The layer on normalised rows x (B, S, E) -> (y (B, S, E), counts (2,)
+    int32 as `held_experts` gives them, the chosen experts (B, S, K));
+    `real` (B, S) bool: the rows that are not get no routed expert."""
+    B, S, E = x.shape
+    x = x.reshape(B * S, E)
+    idx, w = route(lp, x, dims)
+    u = jnp.einsum("ne,el->nl", x, lp["w_down"].astype(x.dtype))
+    mix, counts = held_experts(lp, u, idx, w, dims,
+                               None if real is None else real.reshape(-1))
+    y = jnp.einsum("nl,le->ne", mix, lp["w_up"].astype(x.dtype))
+    y = y + _relu2_mlp(x, lp["ws1"], lp["ws2"])
+    return y.reshape(B, S, E), counts, idx.reshape(B, S, -1)
+
+
+def init_layer(key, hidden: int, dims: RoutedDims, dtype):
+    """Seeded weights of one layer, normal / sqrt(fan_in); the router's
+    correction bias small and not zero, so that choosing by `s + bias` and
+    weighting by `s` differ (a stack's `init_params` then sets it to balance
+    the experts: `models/transformer.py:balance_routers`)."""
+    ks = jax.random.split(key, 8)
+
+    def dense(k, shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32)
+                / jnp.sqrt(fan_in)).astype(dtype)
+    return {"router": dense(ks[0], (hidden, dims.experts), hidden),
+            "router_bias": 0.02 * jax.random.normal(
+                ks[1], (dims.experts,), jnp.float32),
+            "w_down": dense(ks[2], (hidden, dims.latent), hidden),
+            "w1": dense(ks[3], (dims.held, dims.latent, dims.width),
+                        dims.latent),
+            "w2": dense(ks[4], (dims.held, dims.width, dims.latent),
+                        dims.width),
+            "w_up": dense(ks[5], (dims.latent, hidden), dims.latent),
+            "ws1": dense(ks[6], (hidden, dims.shared_width), hidden),
+            "ws2": dense(ks[7], (dims.shared_width, hidden),
+                         dims.shared_width)}

@@ -30,6 +30,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..parallel.sharding import LogicalAxisRules, with_logical_constraint
+from . import mamba2, routed
+from .mamba2 import Mamba2Dims
+from .routed import RoutedDims
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,18 +58,42 @@ class TransformerConfig:
     # axis (ring attention / Ulysses).  Must be a power of two; the
     # engine builds a local sp mesh when none is passed.  1 = off.
     sp_degree: int = 1
+    # The stack as a pattern of block kinds, one letter a layer (KINDS):
+    # "" = every layer the dense block, which is what the fields above
+    # describe and the only pattern training, meshes and the streamed paths
+    # know.  A pattern with `M` or `E` layers brings their sizes in `mamba`
+    # and `routed`; `rope` off = the attention layers rotate nothing
+    # (position is carried by the recurrent layers).
+    pattern: str = ""
+    mamba: Optional[Mamba2Dims] = None
+    routed: Optional[RoutedDims] = None
+    rope: bool = True
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    @property
+    def kinds(self) -> str:
+        """One letter a layer."""
+        return self.pattern or "D" * self.num_layers
+
+    def count(self, kind: str) -> int:
+        return self.kinds.count(kind)
+
     def param_count(self) -> int:
-        h, v, l = self.hidden_size, self.vocab_size, self.num_layers
+        h, v = self.hidden_size, self.vocab_size
         d = self.head_dim_
         qkv = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d)
         o = self.num_heads * d * h
         mlp = 3 * h * self.intermediate_size
-        return v * h + l * (qkv + o + mlp + 2 * h) + h + v * h
+        per = {"D": qkv + o + mlp + 2 * h, "*": qkv + o + h}
+        if self.mamba:
+            per["M"] = self.mamba.param_count(h) + h
+        if self.routed:
+            per["E"] = (self.routed.shared_params(h) + h
+                        + self.routed.held * self.routed.expert_params())
+        return v * h + sum(per[k] for k in self.kinds) + h + v * h
 
 
 PRESETS: Dict[str, TransformerConfig] = {
@@ -98,6 +125,8 @@ PRESETS: Dict[str, TransformerConfig] = {
 
 def param_logical_axes(cfg: TransformerConfig):
     """Pytree (same structure as init params) of logical-axis tuples."""
+    if cfg.pattern:
+        raise ValueError("a pattern of layer kinds has no sharding rules yet")
     layer = {
         "attn": {
             "wq": ("layer", "embed", "heads", "head_dim"),
@@ -125,15 +154,48 @@ def param_logical_axes(cfg: TransformerConfig):
 # Init
 # ---------------------------------------------------------------------------
 
+def _dense(key, shape, fan_in, dt):
+    return (jax.random.normal(key, shape, jnp.float32)
+            * (1.0 / math.sqrt(fan_in))).astype(dt)
+
+
+def _init_pattern_layer(kind: str, key, cfg: TransformerConfig):
+    """One layer of a pattern, unstacked (`run_pattern` walks them)."""
+    h, dt = cfg.hidden_size, cfg.dtype
+    ln = jnp.ones((h,), jnp.float32)
+    if kind == "M":
+        return {"ln": ln, **mamba2.init_layer(key, h, cfg.mamba, dt)}
+    if kind == "E":
+        return {"ln": ln, **routed.init_layer(key, h, cfg.routed, dt)}
+    if kind != "*":
+        raise ValueError(f"layer kind {kind!r} is none of {KINDS}")
+    nh, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    ks = jax.random.split(key, 4)
+    return {"ln_attn": ln, "attn": {
+        "wq": _dense(ks[0], (h, nh, d), h, dt),
+        "wk": _dense(ks[1], (h, nkv, d), h, dt),
+        "wv": _dense(ks[2], (h, nkv, d), h, dt),
+        "wo": _dense(ks[3], (nh, d, h), nh * d, dt)}}
+
+
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     h, d = cfg.hidden_size, cfg.head_dim_
     nh, nkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
     k = iter(jax.random.split(key, 16))
     dt = cfg.dtype
 
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32)
-                * (1.0 / math.sqrt(fan_in))).astype(dt)
+    dense = functools.partial(_dense, dt=dt)
+
+    if cfg.pattern:
+        if len(cfg.pattern) != L:
+            raise ValueError(f"pattern {cfg.pattern!r} has not {L} layers")
+        keys = jax.random.split(next(k), L)
+        params = {"embed": dense(next(k), (cfg.vocab_size, h), h),
+                  "layers": tuple(_init_pattern_layer(kind, keys[i], cfg)
+                                  for i, kind in enumerate(cfg.pattern)),
+                  "ln_f": jnp.ones((h,), jnp.float32),
+                  "lm_head": dense(next(k), (h, cfg.vocab_size), h)}
+        return balance_routers(params, cfg, next(k)) if cfg.routed else params
 
     params = {
         "embed": dense(next(k), (cfg.vocab_size, h), h),
@@ -223,16 +285,23 @@ def block_qkv(lp, x, cos, sin, cfg: TransformerConfig,
     v = jnp.einsum("bse,ekd->bskd", h, lp["attn"]["wv"].astype(dt))
     q = constrain(q, ("batch", "seq", "heads", "head_dim"))
     k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
+    if not cfg.rope:
+        return q, k, v
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def attn_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
+    """Output projection of the attention's o (B, S, H, D), and residual."""
+    o = constrain(o, ("batch", "seq", "heads", "head_dim"))
+    o = jnp.einsum("bshd,hde->bse", o, lp["attn"]["wo"].astype(cfg.dtype))
+    return x + constrain(o, ("batch", "seq", "embed"))
 
 
 def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
     """Second half: output projection of the attention's o (B, S, H, D),
     residual, norm, SwiGLU, residual -> x (B, S, E)."""
     dt = cfg.dtype
-    o = constrain(o, ("batch", "seq", "heads", "head_dim"))
-    o = jnp.einsum("bshd,hde->bse", o, lp["attn"]["wo"].astype(dt))
-    x = x + constrain(o, ("batch", "seq", "embed"))
+    x = attn_out(lp, x, o, cfg, constrain)
     h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
     g = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_gate"].astype(dt))
     u = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_up"].astype(dt))
@@ -253,6 +322,113 @@ def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
     q, k, v = block_qkv(lp, x, cos, sin, cfg, constrain)
     o, kept = attend(q, k, v)
     return block_out(lp, x, o, cfg, constrain), kept
+
+
+# The kinds of block a pattern is made of.  Each is defined once, here, and
+# every path calls it: `D` the dense block above (attention and SwiGLU),
+# `*` attention alone, `M` a Mamba-2 mixer, `E` a latent routed-expert
+# layer; each is x + mixer(rms_norm(x)).
+KINDS = "D*ME"
+
+
+def attention_block(lp, x, cos, sin, attend, cfg: TransformerConfig):
+    """`*`: the dense block's attention half and nothing after it."""
+    q, k, v = block_qkv(lp, x, cos, sin, cfg)
+    o, kept = attend(q, k, v)
+    return attn_out(lp, x, o, cfg), kept
+
+
+def mamba_block(lp, x, state, cfg: TransformerConfig, **how):
+    """`M`: x (B, S, E) from the layer's recurrent `state` -> (x, state',
+    checkpoints); `how` is `mamba2.mixer`'s (length, live, every)."""
+    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
+    y, state, kept = mamba2.mixer(lp, h, state, cfg.mamba, **how)
+    return x + y, state, kept
+
+
+def routed_block(lp, x, cfg: TransformerConfig, real=None):
+    """`E`: -> (x, counts (2,) int32: held experts touched and rows
+    computed, the experts each row chose (B, S, K)); rows that are not
+    `real` (B, S) go to no routed expert."""
+    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
+    y, counts, chosen = routed.mixer(lp, h, cfg.routed, real)
+    return x + y, counts, chosen
+
+
+def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
+                per_layer=(), length=None, live=None, every: int = 0):
+    """A pattern of kinds, layer by layer (`layers`: one tree a layer).
+    `attend(q, k, v, *at)` as `scan_blocks` takes it, `at` the i-th slice of
+    `per_layer` for the i-th attention layer; `rec` the recurrent state, one
+    {"ssm", "tail"} for each `M` layer in order.  Which rows are real:
+    the first `length` (a prefill's padded bucket), the slots that are
+    `live` (B,) (a decode step); the others move no state and meet no
+    routed expert.  `every`: `mamba2.mixer`'s checkpoints.  Returns (x, the
+    attention layers' `kept` stacked, rec', the `M` layers' checkpoints, the
+    `E` layers' counts (n, 2) and chosen experts (n, B, S, K))."""
+    kept, new, ckpts, counts, chosen = [], [], [], [], []
+    real = None
+    if length is not None:
+        real = jnp.broadcast_to(jnp.arange(x.shape[1]) < length, x.shape[:2])
+    if live is not None:
+        real = jnp.broadcast_to(live[:, None], x.shape[:2])
+    for kind, lp in zip(cfg.pattern, layers):
+        if kind == "*":
+            at = tuple(a[len(kept)] for a in per_layer)
+            x, k = attention_block(
+                lp, x, cos, sin, lambda q, k, v: attend(q, k, v, *at), cfg)
+            kept.append(k)
+        elif kind == "M":
+            x, state, ck = mamba_block(lp, x, rec[len(new)], cfg,
+                                       length=length, live=live, every=every)
+            new.append(state)
+            ckpts.append(ck)
+        else:
+            x, c, ch = routed_block(lp, x, cfg, real)
+            counts.append(c)
+            chosen.append(ch)
+    kept = jax.tree.map(lambda *a: jnp.stack(a), *kept) \
+        if kept and kept[0] is not None else None
+    if not counts:
+        return x, kept, new, ckpts, None, None
+    return x, kept, new, ckpts, jnp.stack(counts), jnp.stack(chosen)
+
+
+def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
+                    seq: int = 2048):
+    """Seeded weights have no training behind them, and a router that nobody
+    balanced loads its experts unevenly (some several times the mean, on
+    these stacks' own activations): a chip's share of the experts would see
+    a load that the SEED decides.  The published training keeps the experts
+    equally loaded through `e_score_correction_bias`; this sets that bias to
+    the same end, layer by layer, on the stack's activations for seeded
+    tokens: an expert's bias is minus the score it exceeds for a `top_k /
+    experts` share of the tokens, so that every expert clears a common bar
+    equally often.  Returns the parameters with each routed layer's
+    `router_bias` set so."""
+    r = cfg.routed
+    tokens = jax.random.randint(key, (batch, min(seq, cfg.max_seq_len)), 0,
+                                cfg.vocab_size)
+    x = embed_tokens(params, tokens, cfg)
+    cos, sin = rope_angles(jnp.arange(tokens.shape[1]), cfg)
+    layers = []
+    for kind, lp in zip(cfg.pattern, params["layers"]):
+        if kind == "E":
+            h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
+            s = routed.scores(lp, h)
+            bar = jnp.quantile(s.reshape(-1, r.experts),
+                               1.0 - r.top_k / r.experts, axis=0)
+            lp = dict(lp, router_bias=jnp.mean(bar) - bar)
+            x = routed_block(lp, x, cfg)[0]
+        elif kind == "M":
+            x = mamba_block(lp, x, mamba2.zero_state(
+                cfg.mamba, batch, cfg.dtype), cfg)[0]
+        else:
+            x = attention_block(
+                lp, x, cos, sin, lambda q, k, v: (_xla_attention(q, k, v),
+                                                  None), cfg)[0]
+        layers.append(lp)
+    return dict(params, layers=tuple(layers))
 
 
 def scan_blocks(layers, x, cos, sin, attend, cfg: TransformerConfig,
@@ -318,6 +494,10 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
     (train_step.make_train_step threads its rules through here). With a
     pp>1 mesh axis the layer stack runs as a collective pipeline
     (parallel/pipeline.py) over `num_microbatches` (default: pp)."""
+    if cfg.pattern:
+        raise ValueError(
+            "forward() trains the dense decoder; a pattern of layer kinds is "
+            "run by the serving engine (llm/engine.py, `run_pattern`)")
     rules = rules or LogicalAxisRules.default()
 
     def constrain(x, axes):

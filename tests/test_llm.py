@@ -530,6 +530,54 @@ def test_step_with_no_slot_touched_uploads_nothing(mesh_axes):
     assert compile_cache_stats()["requests"] == compiles
 
 
+def _script(eng, events, steps):
+    """Drive `eng` by a script: at call i, `events.get(i)` is run with the
+    engine before `step()`.  Returns every request's tokens, by id."""
+    outs = {}
+    for i in range(steps):
+        if i in events:
+            events[i](eng)
+        for r in eng.step():
+            outs[r.req_id] = list(r.out)
+    assert not eng.has_unfinished()
+    return outs
+
+
+def test_a_step_sent_ahead_changes_no_token():
+    """With an owner who can say that nobody waits (`hold_ahead`), `step()`
+    sends the next decode step off before it returns where that step needs
+    nothing of the host.  Requests that end by length and by eos, one that
+    arrives while a step is out, one cancelled while a step is out and its
+    slot taken by the next: every token is the one the engine without an
+    owner gives, and the stats count the steps that were read."""
+    make = lambda: _engine(None, max_batch=2, max_len=64, page_size=16,
+                           seed=0)
+    first = make().generate([[5, 6, 7]], SamplingParams(max_tokens=9))[0]
+    eos = SamplingParams(max_tokens=9, eos_id=first[4])
+    events = {
+        0: lambda e: (e.add_request([5, 6, 7], eos),
+                      e.add_request([1, 2, 3, 4], SamplingParams(max_tokens=14))),
+        3: lambda e: e.add_request([9, 8, 7], SamplingParams(max_tokens=6)),
+        8: lambda e: e.cancel_request(2),
+        9: lambda e: e.add_request([2, 4, 6, 8], SamplingParams(max_tokens=5)),
+    }
+    plain = make()
+    want = _script(plain, events, 24)
+    ahead = make()
+    ahead.hold_ahead = lambda: False
+    got = _script(ahead, events, 24)
+    assert got == want and len(want) == 3 and len(want[0]) == 5
+    ns = ahead.phases.snapshot()["ns"]
+    assert ns["ahead"] > 0
+    steps = plain.decode_stats()["steps"]
+    assert ahead.decode_stats()["steps"] >= steps
+    held = make()
+    held.hold_ahead = lambda: True          # someone always waits
+    assert _script(held, events, 24) == want
+    assert held.phases.snapshot()["ns"]["ahead"] == 0
+    assert held.decode_stats() == plain.decode_stats()
+
+
 def test_debug_stats_count_pages_read():
     """`debug_stats()["decode"]`: pages the decode steps read (live ones:
     lengths // page + 1 of each active slot) beside what the tables

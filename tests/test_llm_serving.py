@@ -199,6 +199,31 @@ def test_engine_replica_streams_batches_and_cancels():
     asyncio.run(main())
 
 
+def test_a_caller_waiting_for_the_lock_holds_the_next_step_back():
+    """The replica tells its engine whether anyone waits for the lock (a
+    request about to be enqueued, a cancellation): while someone does, no
+    decode step leaves ahead of the call that asks for it, so the waiting
+    caller's prefill never queues behind one."""
+
+    async def main():
+        er = EngineReplica("tiny", max_batch=2, max_len=64, page_size=8,
+                           max_tokens=4)
+        hold = er.engine.hold_ahead
+        assert hold() is False
+        async with er._lock:
+            waiter = asyncio.ensure_future(er._lock.acquire())
+            await asyncio.sleep(0)
+            assert hold() is True
+        await waiter
+        assert hold() is False
+        er._lock.release()
+        out = await er.generate([1, 2, 3], {"max_tokens": 4})
+        assert len(out["tokens"]) == 4
+        assert (await er.debug_stats())["tick"]["ns"]["ahead"] > 0
+
+    asyncio.run(main())
+
+
 def test_queued_deadline_expires_typed():
     """A request whose deadline passes while parked in the admission
     queue fails typed (DeadlineExceededError) without occupying a slot,

@@ -28,7 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LEAF_OF = {**{span: leaf for leaf, span in _SPAN.items()},
            "prefill": "prefill", "sample_sync": "sample_sync"}
 PARENTS = {"tick": ("tick:expire", "tick:hop", "step:admit", "step:chunk",
-                    "step:emit", "decode", "tick:fan_out"),
+                    "step:emit", "decode", "step:ahead", "tick:fan_out"),
            "step:admit": ("prefill", "sample_sync"),
            "step:chunk": ("prefill", "sample_sync"),
            "decode": ("decode:prep", "decode:dispatch", "decode:wait")}

@@ -1,8 +1,9 @@
-"""Pipeline parallelism (pp axis) + MoE expert parallelism (ep axes).
+"""Pipeline parallelism (pp axis).
 
-Reference model: these exceed the reference — it ships PP only as aDAG /
-vLLM scaffolding (SURVEY §2.4) and EP only as a serving pattern; here both
-are first-class SPMD compute paths (parallel/pipeline.py, models/moe.py).
+Reference model: this exceeds the reference — it ships PP only as aDAG /
+vLLM scaffolding (SURVEY §2.4); here it is a first-class SPMD compute path
+(parallel/pipeline.py).  The routed-expert layer and its held share are in
+tests/test_hybrid_model.py (models/routed.py).
 Runs on the virtual 8-device CPU mesh from conftest.
 """
 
@@ -13,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import PRESETS, forward, init_params
-from ray_tpu.models.moe import MoEConfig, init_moe_params, moe_layer
 from ray_tpu.parallel import MeshSpec, build_mesh
 from ray_tpu.parallel.pipeline import (merge_stages, pipeline_spmd,
                                        split_stages)
@@ -86,57 +86,6 @@ def test_transformer_forward_pp_parity(cpu_mesh_devices):
                                        num_microbatches=2))(params, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-2, atol=2e-2)
-
-
-def test_moe_layer_shapes_and_losses():
-    cfg = MoEConfig(d_model=16, d_ff=32, num_experts=4,
-                    num_experts_per_token=2, dtype=jnp.float32)
-    params = init_moe_params(cfg, jax.random.key(0))
-    x = jax.random.normal(jax.random.key(1), (2, 8, 16))
-    y, aux = jax.jit(lambda p, x: moe_layer(p, x, cfg))(params, x)
-    assert y.shape == x.shape
-    assert float(aux["moe_load_balance_loss"]) > 0
-    assert float(aux["moe_router_z_loss"]) >= 0
-    assert 0.0 <= float(aux["moe_fraction_dropped"]) <= 1.0
-
-
-def test_moe_single_expert_matches_dense_ffn():
-    """E=1, K=1, ample capacity: MoE must equal the plain silu-gated FFN."""
-    cfg = MoEConfig(d_model=8, d_ff=16, num_experts=1,
-                    num_experts_per_token=1, capacity_factor=2.0,
-                    dtype=jnp.float32)
-    params = init_moe_params(cfg, jax.random.key(0))
-    x = jax.random.normal(jax.random.key(1), (2, 4, 8))
-    y, aux = moe_layer(params, x, cfg)
-    assert float(aux["moe_fraction_dropped"]) == 0.0
-    xf = x.reshape(-1, 8)
-    g = xf @ params["w_gate"][0]
-    u = xf @ params["w_up"][0]
-    dense = ((jax.nn.silu(g) * u) @ params["w_down"][0]).reshape(x.shape)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(dense),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_moe_sharded_over_ep_axes(cpu_mesh_devices):
-    """Expert dim sharded over the fsdp×sp submesh compiles and runs
-    (XLA inserts the dispatch all-to-alls)."""
-    _need_devices(8)
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = build_mesh(MeshSpec(fsdp=2, sp=2, tp=2),
-                      devices=jax.devices()[:8])
-    cfg = MoEConfig(d_model=16, d_ff=32, num_experts=4,
-                    num_experts_per_token=2, dtype=jnp.float32)
-    params = init_moe_params(cfg, jax.random.key(0))
-    expert_sharding = NamedSharding(mesh, P(("fsdp", "sp")))
-    params = {
-        "router": jax.device_put(params["router"], NamedSharding(mesh, P())),
-        "w_gate": jax.device_put(params["w_gate"], expert_sharding),
-        "w_up": jax.device_put(params["w_up"], expert_sharding),
-        "w_down": jax.device_put(params["w_down"], expert_sharding),
-    }
-    x = jax.random.normal(jax.random.key(1), (4, 8, 16))
-    y, aux = jax.jit(lambda p, x: moe_layer(p, x, cfg))(params, x)
-    assert y.shape == x.shape and np.isfinite(np.asarray(y)).all()
 
 
 def test_pipeline_rejects_bad_microbatching(cpu_mesh_devices):
