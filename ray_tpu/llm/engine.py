@@ -57,10 +57,10 @@ from jax.sharding import NamedSharding, PartitionSpec
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
 from ..models.mamba2 import zero_state
-from ..models.transformer import (TransformerConfig, decoder_block,
-                                  embed_tokens, init_params, lm_logits,
-                                  param_logical_axes, rope_angles,
-                                  run_pattern, scan_blocks)
+from ..models.transformer import (ROW_BLOCK, TransformerConfig,
+                                  decoder_block, embed_tokens, init_params,
+                                  lm_logits, param_logical_axes, rope_angles,
+                                  row_blocks, run_pattern, scan_blocks)
 from .tick_phases import TickPhases
 
 
@@ -237,18 +237,20 @@ def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
 
 
 def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
-                kv_sharding=None):
+                kv_sharding=None, row_block: int = ROW_BLOCK):
     """tokens (1, Sb) padded prompt → (last_logits (V,), k, v (L, Sb, KV, D)).
 
-    Positions ≥ length produce garbage cache rows; decode masks them out
-    via per-slot lengths, and the last-real-token logits only attend
-    backwards (causal), so padding never leaks into results."""
+    Cache rows at positions ≥ length are padding's, or zeros where the
+    bucket is run by row blocks (`decoder_block`: those past the last block
+    that holds a real row); decode masks them out via per-slot lengths, and
+    the last-real-token logits only attend backwards (causal), so padding
+    never leaks into results.  `row_block`: the tests'."""
     S = tokens.shape[1]
     x = embed_tokens(params, tokens, cfg)
     cos, sin = rope_angles(jnp.arange(0, S, dtype=jnp.float32), cfg)
     attend, per_layer = _prefill_attend(cfg, S, length, kv_sharding)
     x, (ks, vs) = scan_blocks(params["layers"], x, cos, sin, attend, cfg,
-                              per_layer)
+                              per_layer, length, row_block)
     return lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
@@ -466,7 +468,7 @@ def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
 
 def _suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
                        length, cfg: TransformerConfig, page: int,
-                       kv_sharding=None):
+                       kv_sharding=None, row_block: int = ROW_BLOCK):
     """Suffix half of a prefix-cache hit: run the transformer over ONLY
     tokens[prefix_len:] while attending to the cached KV of
     tokens[:prefix_len] already resident in the pool's shared pages.
@@ -484,7 +486,7 @@ def _suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     attend, per_layer = _prefill_attend(
         cfg, Sb, length, kv_sharding, (pool_k, pool_v, pages, prefix_len, page))
     x, (ks, vs) = scan_blocks(params["layers"], x, cos, sin, attend, cfg,
-                              per_layer)
+                              per_layer, length, row_block)
     return lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
@@ -1132,10 +1134,13 @@ class LLMEngine:
         self._state_rows = 0
         self._step_state_rows = 0
         # Which attention form the prefills took (ops/prefill_attention.py)
-        # and how many key blocks they ran beside what S x S covers; the
-        # last one's, as its `prefill` span carries them.
+        # and how many key blocks they ran beside what S x S covers; how
+        # many row blocks a layer's halves ran beside the bucket's
+        # (models/transformer.py:row_blocks); the last one's, as its
+        # `prefill` span carries them.
         self._prefill_stats = {"path": "", "kernel_calls": 0, "xla_calls": 0,
-                               "kv_blocks_run": 0, "kv_blocks_dense": 0}
+                               "kv_blocks_run": 0, "kv_blocks_dense": 0,
+                               "row_blocks_run": 0, "row_blocks_dense": 0}
         self._prefill_ran: Dict[str, Any] = {}
         page, kv_shd = self.page, self._kv_shd
         # The decode step stays a lambda ON PURPOSE: the benchmark's
@@ -1441,8 +1446,10 @@ class LLMEngine:
 
     def prefill_stats(self) -> Dict[str, Any]:
         """The attention form of the last prefill (`path`: "kernel" or
-        "xla"), how many prefills took each, and the key blocks they ran
-        beside the blocks of the dense S x S form (`kv_blocks_dense`)."""
+        "xla"), how many prefills took each, the key blocks they ran
+        beside the blocks of the dense S x S form (`kv_blocks_dense`), and
+        the row blocks a layer's two halves ran beside those of the padded
+        bucket (`row_blocks_dense`; the same below 2,048 padded rows)."""
         return dict(self._prefill_stats)
 
     def _count_prefill(self, rows: int, padded: int,
@@ -1456,13 +1463,20 @@ class LLMEngine:
             if self.sp_degree == 1 else "xla"
         run, dense = kv_blocks(rows, padded, prefix_len or 0,
                                math.prod(row) if row else 0)
+        # The halves go by row blocks where `scan_blocks` is given the
+        # length: not a pattern's prefill, not a sequence-parallel one.
+        by_rows = self.sp_degree == 1 and not self.cfg.pattern
+        rows_run, rows_dense = row_blocks(rows if by_rows else None, padded)
         self._prefill_ran = {"path": path,
-                             "kv_blocks": run if path == "kernel" else dense}
+                             "kv_blocks": run if path == "kernel" else dense,
+                             "row_blocks": rows_run}
         st = self._prefill_stats
         st["path"] = path
         st[path + "_calls"] += 1
         st["kv_blocks_run"] += self._prefill_ran["kv_blocks"]
         st["kv_blocks_dense"] += dense
+        st["row_blocks_run"] += rows_run
+        st["row_blocks_dense"] += rows_dense
 
     def prefix_cache_stats(self) -> Dict[str, Any]:
         if self._cache is None:

@@ -311,17 +311,106 @@ def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
     return x + constrain(d, ("batch", "seq", "embed"))
 
 
+# A prefill's bucket is padded to a power of two, and the block's two halves
+# are row-wise: what they compute for a row past the prompt's real length is
+# thrown away.  Told that length, `decoder_block` runs each half over blocks
+# of ROW_BLOCK rows (the prefill kernel's own block) and over those alone
+# that hold a real row.  Decided from the shapes, on any platform: a bucket
+# of at least MIN_ROW_BLOCKS whole blocks (2,048 rows).  A bucket is more
+# than half full, so under four blocks none can be left out and the loop
+# only costs (a 1,024-row bucket always ran both of its two: +2.0 ms of
+# 45.5 on a v5e, PERF.md PR 39).  Below that, and for every caller that
+# gives no length, the halves take all rows at once.
+ROW_BLOCK = 512
+MIN_ROW_BLOCKS = 4
+
+
+def by_row_blocks(length, rows: int, row_block: int = ROW_BLOCK) -> bool:
+    """Whether the halves of a `rows`-row bucket with `length` real rows go
+    by row blocks: a length, and MIN_ROW_BLOCKS whole blocks or more."""
+    return length is not None and rows % row_block == 0 \
+        and rows // row_block >= MIN_ROW_BLOCKS
+
+
+def row_blocks(length, rows: int, row_block: int = ROW_BLOCK):
+    """(row blocks the halves of one layer run, row blocks of the bucket) for
+    `length` real rows (an int, or traced) of a `rows`-row bucket; the same
+    where the halves take all rows at once."""
+    dense = max(1, rows // row_block)
+    if not by_row_blocks(length, rows, row_block):
+        return dense, dense
+    return -(-length // row_block), dense
+
+
+def _over_rows(half, ins, outs, blocks, row_block: int):
+    """`half(*arrays of ins)` -> a tuple of arrays shaped as `outs` [(shape,
+    dtype)], rows on axis 1.  `ins` pairs each array with its row axis.
+    `blocks` None: all rows at once.  Else (traced) a loop runs the half on
+    the first `blocks` blocks of rows, a block a trip, and leaves ZEROS in
+    the rows of the others, so that whatever reads them (a softmax over
+    masked scores, a decode step's read of a page's tail) meets nothing
+    that is not finite.  A loop traces its body once."""
+    if blocks is None:
+        return half(*(a for a, _ in ins))
+
+    def body(i, bufs):
+        at = i * row_block
+        got = half(*(jax.lax.dynamic_slice_in_dim(a, at, row_block, ax)
+                     for a, ax in ins))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(b, g, at, 1)
+                     for b, g in zip(bufs, got))
+    return jax.lax.fori_loop(
+        0, blocks, body, tuple(jnp.zeros(s, dt) for s, dt in outs))
+
+
+def _layer_of(stack, layer, pin=None):
+    """Layer `layer` (traced) of the stacked weights `stack` (None: `stack`
+    is the layer's own), indexed where it is used: inside a loop over row
+    blocks.  XLA would hoist the slice out of that loop as invariant, and a
+    slice that is a loop's operand is a COPY (the MLP's and wo's 385 MB a
+    layer: +15 ms a prefill, measured) where inside it is an offset fused
+    into the product, as in the plain scan.  `pin`, the block's rows, ties
+    the index to the loop's own data so that it stays; the first half goes
+    without (its projections are copied to another layout a layer either
+    way, and pinned XLA would turn the whole stack at the program's start:
+    0.8 GB of scratch)."""
+    if layer is None:
+        return stack
+    if pin is not None:
+        layer, _ = jax.lax.optimization_barrier((layer, pin))
+    return jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
+        w, layer, keepdims=False), stack)
+
+
 def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
-                  constrain=_unconstrained):
+                  constrain=_unconstrained, length=None,
+                  row_block: int = ROW_BLOCK, layer=None):
     """One layer.  `attend(q, k, v) -> (o, kept)` is the caller's: training's
     causal attention, a prefill's (kernel or scores), a decode step's read of
     the paged pool; `kept` is what the caller's scan collects or carries (a
     prefill's new cache rows, the decode step's written pool, nothing).
     `constrain(x, logical_axes)` places activations on a training mesh.
+    `length`: a prefill's real rows, the first of x's padded S; the halves
+    then run over the row blocks that hold them (`row_blocks`), and q, k, v
+    and the new x are zeros past the last block that ran.  With `layer`,
+    `lp` is the whole stack and `layer` this layer's index (`_layer_of`).
+    `row_block` is an argument for the tests' small buckets alone.
     Returns (x, kept)."""
-    q, k, v = block_qkv(lp, x, cos, sin, cfg, constrain)
+    B, S, _ = x.shape
+    blocks = row_blocks(length, S, row_block)[0] \
+        if by_row_blocks(length, S, row_block) else None
+    weights = functools.partial(_layer_of, lp, layer)
+    heads = [((B, S, n, cfg.head_dim_), x.dtype)
+             for n in (cfg.num_heads, cfg.num_kv_heads, cfg.num_kv_heads)]
+    q, k, v = _over_rows(
+        lambda x, cos, sin: block_qkv(weights(), x, cos, sin, cfg, constrain),
+        [(x, 1), (cos, cos.ndim - 2), (sin, sin.ndim - 2)], heads, blocks,
+        row_block)
     o, kept = attend(q, k, v)
-    return block_out(lp, x, o, cfg, constrain), kept
+    x, = _over_rows(
+        lambda x, o: (block_out(weights(x), x, o, cfg, constrain),),
+        [(x, 1), (o, 1)], [(x.shape, x.dtype)], blocks, row_block)
+    return x, kept
 
 
 # The kinds of block a pattern is made of.  Each is defined once, here, and
@@ -432,15 +521,24 @@ def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
 
 
 def scan_blocks(layers, x, cos, sin, attend, cfg: TransformerConfig,
-                per_layer=()):
+                per_layer=(), length=None, row_block: int = ROW_BLOCK):
     """`lax.scan` of the block over the stacked `layers`, each layer's
     `attend(q, k, v, *at)` given its slice `at` of the arrays in `per_layer`
-    (a pool's layer, a layer index).  Returns (x, every layer's `kept`)."""
+    (a pool's layer, a layer index); `length` and `row_block` as
+    `decoder_block` takes them: where the halves go by row blocks the scan
+    is over the layers' indices and the block indexes the stack itself.
+    Returns (x, every layer's `kept`)."""
+    by_rows = by_row_blocks(length, x.shape[1], row_block)
+
     def body(x, layer):
-        lp, *at = layer
-        return decoder_block(lp, x, cos, sin,
-                             lambda q, k, v: attend(q, k, v, *at), cfg)
-    return jax.lax.scan(body, x, (layers, *per_layer))
+        lp, *at = layer                     # by rows: the layer's index
+        return decoder_block(layers if by_rows else lp, x, cos, sin,
+                             lambda q, k, v: attend(q, k, v, *at), cfg,
+                             length=length, row_block=row_block,
+                             layer=lp if by_rows else None)
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return jax.lax.scan(
+        body, x, (jnp.arange(n) if by_rows else layers, *per_layer))
 
 
 def _xla_attention(q, k, v, causal: bool = True):

@@ -80,6 +80,15 @@ def _prefill():
     return E._prefill_fn
 
 
+def _prefill_by_rows():
+    # Four row blocks of the bucket: the halves inside a loop each.
+    params, _, toks, _, n = _shapes()
+    jax.eval_shape(lambda p, t, n: E._prefill_fn(p, t, n, CFG,
+                                                 row_block=ROWS // 4),
+                   params, toks, n)
+    return E._prefill_fn
+
+
 def _suffix_prefill():
     params, pool, toks, pages, n = _shapes()
     jax.eval_shape(
@@ -130,8 +139,9 @@ def _streamed():
 
 
 @pytest.mark.parametrize("trace", [
-    _forward, _prefill, _suffix_prefill, _decode, _sp_prefill,
-    _sp_suffix_prefill, _streamed], ids=lambda f: f.__name__.strip("_"))
+    _forward, _prefill, _prefill_by_rows, _suffix_prefill, _decode,
+    _sp_prefill, _sp_suffix_prefill, _streamed],
+    ids=lambda f: f.__name__.strip("_"))
 def test_every_path_runs_the_one_block(trace, halves):
     entry = trace()
     # Once: a scan traces its body once, and the streamed path's per-layer

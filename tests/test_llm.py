@@ -600,7 +600,8 @@ def test_debug_stats_count_pages_read():
     # One whole-prompt prefill of 30 tokens in a 32 bucket, on the CPU.
     assert stats["prefill"] == {"path": "xla", "kernel_calls": 0,
                                 "xla_calls": 1, "kv_blocks_run": 1,
-                                "kv_blocks_dense": 1}
+                                "kv_blocks_dense": 1, "row_blocks_run": 1,
+                                "row_blocks_dense": 1}
     st = stats["decode"]
     # Prefill gives the first token; decode step i attends n_prompt + i.
     lengths = [n_prompt + i for i in range(n_out - 1)]
@@ -701,7 +702,8 @@ def test_prefix_hit_through_the_kernel_gives_the_whole_prompts_tokens(
     assert whole.generate([second], sp)[0] == xla
     assert whole.prefill_stats() == {
         "path": "kernel", "kernel_calls": 1, "xla_calls": 0,
-        "kv_blocks_run": 3, "kv_blocks_dense": 4}   # 214 rows in 2 x 128
+        "kv_blocks_run": 3, "kv_blocks_dense": 4,   # 214 rows in 2 x 128
+        "row_blocks_run": 1, "row_blocks_dense": 1}
     hit = LLMEngine(cfg, max_batch=2, max_len=512, page_size=page, seed=0,
                     prefix_cache=True)
     hit.generate([first], sp)
