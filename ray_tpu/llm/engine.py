@@ -56,11 +56,11 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
-from ..models.mamba2 import zero_state
-from ..models.transformer import (ROW_BLOCK, TransformerConfig,
+from ..models.transformer import (ROW_BLOCK, STATEFUL, TransformerConfig,
                                   decoder_block, embed_tokens, init_params,
                                   lm_logits, param_logical_axes, rope_angles,
-                                  row_blocks, run_pattern, scan_blocks)
+                                  row_blocks, run_pattern, scan_blocks,
+                                  state_bytes, state_chunk, zero_state)
 from .tick_phases import TickPhases
 
 
@@ -265,8 +265,8 @@ def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     the checkpoint pool `ckpt` (row 0: the state of having read nothing,
     with prefix_len 0).  Returns (last-token logits, the attention layers'
     ks, vs (nA, Sb, KV, D), the state after `length` rows, the state after
-    every `every` rows (`mamba2.mixer`), the experts every row chose
-    (nE, Sb, K))."""
+    every `every` rows (the stateful mixers' `every`), the experts every row
+    chose (nE, Sb, K))."""
     Sb = tokens.shape[1]
     x = embed_tokens(params, tokens, cfg)
     cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
@@ -437,7 +437,7 @@ def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
     last token <- next token and length + 1 for the active slots.  On a
     mesh the state is replicated.
     A pattern with recurrent layers keeps their state there too, under
-    "rec": one {"ssm", "tail"} for each `M` layer, a row a slot, advanced
+    "rec": one tree for each stateful layer, a row a slot, advanced
     by the step for the active slots; the host writes a slot's row when it
     installs a prefill (`_install_state_fn`) and at no other time.
     Returns (pool_k', pool_v', state', out): `out` the next tokens (B,),
@@ -897,8 +897,9 @@ def _default_kv_fetch(handle):
 # Engine
 # --------------------------------------------------------------------------
 
-# A pattern with recurrent layers keeps a state checkpoint every this many
-# chunks of its scan (4 x 128 = 512 tokens for the published chunk), and
+# A pattern with stateful layers keeps a state checkpoint every this many
+# of their chunks (`state_chunk`: 4 x 128 = 512 tokens for Mamba-2's
+# published scan chunk, and for the short convolution's), and
 # pads no prefill below `_MIN_STATE_ROWS` rows: under that a prefill's time
 # is the weights' read, and a bucket fewer is a program fewer to warm (a
 # warm-up that reaches the suffix programs through one shared page of 16
@@ -955,7 +956,7 @@ class LLMEngine:
         L, kvh, d = cfg.count("*") or cfg.num_layers, cfg.num_kv_heads, \
             cfg.head_dim_
         # State checkpoints, every `_every` tokens (0: no recurrent layer).
-        self._every = _CKPT_CHUNKS * cfg.mamba.chunk if cfg.count("M") else 0
+        self._every = _CKPT_CHUNKS * state_chunk(cfg)
         if cfg.pattern:
             if mesh is not None or prefill_chunk or (sp_degree or 1) > 1 \
                     or getattr(cfg, "sp_degree", 1) > 1:
@@ -1053,8 +1054,8 @@ class LLMEngine:
         self._cache = _PrefixCache(self.page, cache_tag, self._every,
                                    range(2, 2 + n_rows)) \
             if prefix_cache else None
-        self._ckpt = [zero_state(cfg.mamba, 2 + n_rows, cfg.dtype)
-                      for _ in range(cfg.count("M"))]
+        stateful = [k for k in cfg.kinds if k in STATEFUL]
+        self._ckpt = [zero_state(cfg, k, 2 + n_rows) for k in stateful]
         # KV offload tier: LRU-evicted prefix-cache pages demote into a
         # bounded host window (NVMe overflow) instead of being freed;
         # hits promote back via device_put.  Pool squeezes (mem_chaos)
@@ -1112,8 +1113,8 @@ class LLMEngine:
             self._state_shd)
         if self._every:
             # Per slot, the recurrent layers' state: resident with the rest.
-            self._dev["rec"] = [zero_state(cfg.mamba, max_batch, cfg.dtype)
-                                for _ in range(cfg.count("M"))]
+            self._dev["rec"] = [zero_state(cfg, k, max_batch)
+                                for k in stateful]
         # What the routed layers' decode steps touched, a row a layer:
         # held experts that got a row, (token, expert) rows computed;
         # cumulative, and the last step's.
@@ -1416,9 +1417,8 @@ class LLMEngine:
         if not self._every:
             return {"enabled": False}
         c = self._cache
-        row = self.cfg.count("M") * self.cfg.mamba.state_bytes(
-            jnp.dtype(self.cfg.dtype).itemsize)
-        out = {"enabled": True, "every": self._every, "row_bytes": row,
+        out = {"enabled": True, "every": self._every,
+               "row_bytes": state_bytes(self.cfg),
                "slots": self.max_batch, "rows_total": 0, "rows_in_use": 0}
         if c is not None:
             out.update(rows_total=c.n_rows,
@@ -1711,7 +1711,7 @@ class LLMEngine:
         checkpoints it passed into the rows reserved for them
         (`_reserve`); the prefill's bucket may hold boundaries past the
         prompt, which go to the scratch row."""
-        rows = np.ones(kept[0]["ssm"].shape[1], np.int32)
+        rows = np.ones(jax.tree.leaves(kept[0])[0].shape[1], np.int32)
         for b, row in req.new_rows.items():
             rows[(b - req.prefix_len) // self._every - 1] = row
         self._dev["rec"], self._ckpt = self._install_state_jit(

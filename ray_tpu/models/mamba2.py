@@ -146,6 +146,31 @@ def _conv(lp, xbc, tail, dims: Mamba2Dims):
     return jax.nn.silu(acc).astype(xbc.dtype), ext
 
 
+def tail_after(ext, prev, K: int, length=None, live=None):
+    """The convolution's carried tail after the last real row.  `ext` (B,
+    K - 1 + S, C) is the input with the previous tail `prev` first, so row
+    t + K - 1 of it is input row t and the K - 1 rows before row `n` start
+    at ext row n.  `length`: the real rows (all S without); slots that are
+    not `live` (B,) keep `prev`.  In `prev`'s type."""
+    if length is None:
+        tail = ext[:, ext.shape[1] - (K - 1):]
+    else:
+        tail = jax.lax.dynamic_slice_in_dim(ext, length, K - 1, axis=1)
+    if live is not None:
+        tail = jnp.where(live[:, None, None], tail, prev.astype(ext.dtype))
+    return tail.astype(prev.dtype)
+
+
+def tails_every(ext, prev, K: int, every: int):
+    """The tails after every `every` rows of `ext` (as `tail_after` has it):
+    (B, S // every, K - 1, C) in `prev`'s type."""
+    B, S = ext.shape[0], ext.shape[1] - (K - 1)
+    tails = jnp.stack([ext[:, b:b + K - 1]
+                       for b in range(every, S + 1, every)], axis=1) \
+        if S >= every else jnp.zeros((B, 0, K - 1, ext.shape[-1]), ext.dtype)
+    return tails.astype(prev.dtype)
+
+
 def mixer(lp, u, state, dims: Mamba2Dims, length=None, live=None,
           every: int = 0):
     """The Mamba-2 mixer on normalised rows u (B, S, E) from `state`.
@@ -184,21 +209,10 @@ def mixer(lp, u, state, dims: Mamba2Dims, length=None, live=None,
     y = (yg.reshape(B, S, dims.inner) * lp["norm"].astype(f32)).astype(dt_)
     out = jnp.einsum("bsf,fe->bse", y, lp["w_out"].astype(dt_))
 
-    # The convolution's tail after the last real row: `ext` row t + K - 1 is
-    # input row t, so the K - 1 rows before row `n` start at ext row n.
-    if length is None:
-        tail = ext[:, S:]
-    else:
-        tail = jax.lax.dynamic_slice_in_dim(ext, length, K - 1, axis=1)
-    if live is not None:
-        tail = jnp.where(live[:, None, None], tail, state["tail"].astype(dt_))
-    new = {"ssm": ssm, "tail": tail.astype(state["tail"].dtype)}
+    new = {"ssm": ssm, "tail": tail_after(ext, state["tail"], K, length, live)}
     ckpt = None
     if every:
-        tails = jnp.stack([ext[:, b:b + K - 1]
-                           for b in range(every, S + 1, every)], axis=1) \
-            if S >= every else jnp.zeros((B, 0, K - 1, ext.shape[-1]), ext.dtype)
-        ckpt = {"ssm": kept, "tail": tails.astype(state["tail"].dtype)}
+        ckpt = {"ssm": kept, "tail": tails_every(ext, state["tail"], K, every)}
     return out, new, ckpt
 
 

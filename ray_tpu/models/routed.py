@@ -1,13 +1,19 @@
-"""Latent routed-expert layer (the `E` layers of a hybrid stack,
-models/transformer.py: `routed_block`), dropless, told which experts it
-holds.
+"""Routed-expert layer (the `E` blocks of a pattern, models/
+transformer.py: `routed_block`), dropless, told which experts it holds.
 
 The router scores ALL experts of the model: `s = sigmoid(x W_r)` in float32;
 the `top_k` experts with the largest `s + bias` are chosen, their weights
-are their `s` normalised to sum 1 over the chosen and scaled.  The experts
-work in a latent space: `u = x W_down`, expert e gives `relu(u W1_e)^2
-W2_e`, the weighted sum goes back through `W_up`.  One shared expert works
-on x itself and is added.
+are their `s` normalised to sum 1 over the chosen and scaled.  ONE layer in
+two published forms, told apart by its sizes (`RoutedDims`):
+
+- latent, ungated, with a shared expert (Nemotron-H): the experts work in a
+  latent space, `u = x W_down`, expert e gives `relu(u W1_e)^2 W2_e`, the
+  weighted sum goes back through `W_up`; one shared expert works on x
+  itself and is added.
+- on the hidden width, gated, no shared expert (LFM2: `latent` 0,
+  `shared_width` 0, `gated`): expert e gives `(silu(x W1_e) * (x W3_e))
+  W2_e`; W1 and W3 lie side by side in `w1` (k, 2 x width), so a layer is
+  two grouped products in either form.
 
 This process holds experts [held_from, held_from + held) of every layer (a
 chip of an expert-parallel group holds its share) and computes THEIR part of
@@ -36,13 +42,14 @@ class RoutedDims:
     held: int = 128             # experts whose weights this process holds
     held_from: int = 0          # the first of them
     top_k: int = 22
-    latent: int = 1024
+    latent: int = 1024          # 0: the experts act on the hidden width
     width: int = 2688           # an expert's hidden width
-    shared_width: int = 5376
+    shared_width: int = 5376    # 0: no shared expert
     scale: float = 5.0          # routed_scaling_factor
+    gated: bool = False         # silu(x W1) * (x W3), not relu(x W1)^2
 
-    def expert_params(self) -> int:
-        return 2 * self.latent * self.width
+    def expert_params(self, hidden: int) -> int:
+        return (3 if self.gated else 2) * (self.latent or hidden) * self.width
 
     def shared_params(self, hidden: int) -> int:
         """A layer's parameters outside its routed experts."""
@@ -70,8 +77,11 @@ def route(lp, x, dims: RoutedDims):
 
 def _tile(width: int) -> int:
     """A tile of the grouped product along a width: all of it up to 1,024,
-    and a divisor of it beyond where 896 (7 x 128) is one."""
-    return width if width <= 1024 else 896 if width % 896 == 0 else 1024
+    beyond that its largest divisor that is a multiple of 128 and no more
+    than 1,024 (896 of 2,688, 768 of 1,536, 1,024 of 2,048 and 3,072)."""
+    if width <= 1024:
+        return width
+    return max(t for t in range(128, 1025, 128) if width % t == 0)
 
 
 def grouped_path() -> str:
@@ -97,8 +107,8 @@ def _grouped(rows, weights, sizes):
 
 
 def held_experts(lp, u, idx, w, dims: RoutedDims, real=None):
-    """The held experts' part of the weighted sum: u (N, latent), idx and w
-    (N, K) from `route` -> (out (N, latent), counts (2,) int32: distinct
+    """The held experts' part of the weighted sum: u (N, latent or hidden),
+    idx and w (N, K) from `route` -> (out like u, counts (2,) int32: distinct
     held experts that got a row, and (token, expert) rows computed).  A row
     of u that is not `real` (N,) (padding, a slot that is not live) goes to
     no expert: it costs no product and touches no weights."""
@@ -113,7 +123,11 @@ def held_experts(lp, u, idx, w, dims: RoutedDims, real=None):
         .astype(jnp.int32)
     rows = u[order // K]                                # (N K, latent)
     hid = _grouped(rows, lp["w1"].astype(u.dtype), sizes)
-    hid = jnp.square(jax.nn.relu(hid))
+    if dims.gated:
+        gate, up = jnp.split(hid, 2, axis=-1)
+        hid = jax.nn.silu(gate) * up
+    else:
+        hid = jnp.square(jax.nn.relu(hid))
     out = _grouped(hid, lp["w2"].astype(u.dtype), sizes)
     # Back to (token, choice) order; a row of an expert held elsewhere was
     # not computed and counts nothing.
@@ -138,11 +152,14 @@ def mixer(lp, x, dims: RoutedDims, real=None):
     B, S, E = x.shape
     x = x.reshape(B * S, E)
     idx, w = route(lp, x, dims)
-    u = jnp.einsum("ne,el->nl", x, lp["w_down"].astype(x.dtype))
-    mix, counts = held_experts(lp, u, idx, w, dims,
-                               None if real is None else real.reshape(-1))
-    y = jnp.einsum("nl,le->ne", mix, lp["w_up"].astype(x.dtype))
-    y = y + _relu2_mlp(x, lp["ws1"], lp["ws2"])
+    u = jnp.einsum("ne,el->nl", x, lp["w_down"].astype(x.dtype)) \
+        if dims.latent else x
+    y, counts = held_experts(lp, u, idx, w, dims,
+                             None if real is None else real.reshape(-1))
+    if dims.latent:
+        y = jnp.einsum("nl,le->ne", y, lp["w_up"].astype(x.dtype))
+    if dims.shared_width:
+        y = y + _relu2_mlp(x, lp["ws1"], lp["ws2"])
     return y.reshape(B, S, E), counts, idx.reshape(B, S, -1)
 
 
@@ -152,19 +169,22 @@ def init_layer(key, hidden: int, dims: RoutedDims, dtype):
     weighting by `s` differ (a stack's `init_params` then sets it to balance
     the experts: `models/transformer.py:balance_routers`)."""
     ks = jax.random.split(key, 8)
+    inner = dims.latent or hidden       # the width the experts act on
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 / jnp.sqrt(fan_in)).astype(dtype)
-    return {"router": dense(ks[0], (hidden, dims.experts), hidden),
-            "router_bias": 0.02 * jax.random.normal(
-                ks[1], (dims.experts,), jnp.float32),
-            "w_down": dense(ks[2], (hidden, dims.latent), hidden),
-            "w1": dense(ks[3], (dims.held, dims.latent, dims.width),
-                        dims.latent),
-            "w2": dense(ks[4], (dims.held, dims.width, dims.latent),
-                        dims.width),
-            "w_up": dense(ks[5], (dims.latent, hidden), dims.latent),
-            "ws1": dense(ks[6], (hidden, dims.shared_width), hidden),
-            "ws2": dense(ks[7], (dims.shared_width, hidden),
-                         dims.shared_width)}
+    lp = {"router": dense(ks[0], (hidden, dims.experts), hidden),
+          "router_bias": 0.02 * jax.random.normal(
+              ks[1], (dims.experts,), jnp.float32),
+          "w1": dense(ks[3], (dims.held, inner,
+                              (2 if dims.gated else 1) * dims.width), inner),
+          "w2": dense(ks[4], (dims.held, dims.width, inner), dims.width)}
+    if dims.latent:
+        lp.update(w_down=dense(ks[2], (hidden, dims.latent), hidden),
+                  w_up=dense(ks[5], (dims.latent, hidden), dims.latent))
+    if dims.shared_width:
+        lp.update(ws1=dense(ks[6], (hidden, dims.shared_width), hidden),
+                  ws2=dense(ks[7], (dims.shared_width, hidden),
+                            dims.shared_width))
+    return lp

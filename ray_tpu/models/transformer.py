@@ -30,9 +30,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..parallel.sharding import LogicalAxisRules, with_logical_constraint
-from . import mamba2, routed
+from . import mamba2, routed, shortconv
 from .mamba2 import Mamba2Dims
 from .routed import RoutedDims
+from .shortconv import ShortConvDims
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,16 +59,26 @@ class TransformerConfig:
     # axis (ring attention / Ulysses).  Must be a power of two; the
     # engine builds a local sp mesh when none is passed.  1 = off.
     sp_degree: int = 1
-    # The stack as a pattern of block kinds, one letter a layer (KINDS):
+    # The stack as a pattern of block kinds, one letter a block (KINDS):
     # "" = every layer the dense block, which is what the fields above
     # describe and the only pattern training, meshes and the streamed paths
-    # know.  A pattern with `M` or `E` layers brings their sizes in `mamba`
-    # and `routed`; `rope` off = the attention layers rotate nothing
-    # (position is carried by the recurrent layers).
+    # know.  Without spaces every letter is a layer ("MEM*E"); a model whose
+    # layer is an operator AND a feed-forward, each a residual half behind
+    # its own norm, spells a layer as two letters and parts the layers with
+    # spaces ("CF *E CE": `num_layers` 3, six blocks, one attention layer
+    # in the page pool).  A pattern with `M`, `E` or `C` blocks brings their
+    # sizes in `mamba`, `routed` and `conv`; `rope` off = the attention
+    # layers rotate nothing (position is carried by the recurrent layers);
+    # `qk_norm` = an RMS norm with a learned scale over each q and k head
+    # before the rotation; `tie_embeddings` = the head is the embedding
+    # table, held once.
     pattern: str = ""
     mamba: Optional[Mamba2Dims] = None
     routed: Optional[RoutedDims] = None
+    conv: Optional[ShortConvDims] = None
     rope: bool = True
+    qk_norm: bool = False
+    tie_embeddings: bool = False
 
     @property
     def head_dim_(self) -> int:
@@ -75,8 +86,15 @@ class TransformerConfig:
 
     @property
     def kinds(self) -> str:
-        """One letter a layer."""
-        return self.pattern or "D" * self.num_layers
+        """One letter a block, in order."""
+        return self.pattern.replace(" ", "") or "D" * self.num_layers
+
+    @property
+    def pattern_layers(self) -> int:
+        """The layers `pattern` spells: its words, or its letters where it
+        has no spaces."""
+        return len(self.pattern.split() if " " in self.pattern
+                   else self.pattern)
 
     def count(self, kind: str) -> int:
         return self.kinds.count(kind)
@@ -87,13 +105,18 @@ class TransformerConfig:
         qkv = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d)
         o = self.num_heads * d * h
         mlp = 3 * h * self.intermediate_size
-        per = {"D": qkv + o + mlp + 2 * h, "*": qkv + o + h}
+        qk = 2 * d if self.qk_norm else 0
+        per = {"D": qkv + o + mlp + 2 * h, "*": qkv + o + h + qk,
+               "F": mlp + h}
         if self.mamba:
             per["M"] = self.mamba.param_count(h) + h
         if self.routed:
             per["E"] = (self.routed.shared_params(h) + h
-                        + self.routed.held * self.routed.expert_params())
-        return v * h + sum(per[k] for k in self.kinds) + h + v * h
+                        + self.routed.held * self.routed.expert_params(h))
+        if self.conv:
+            per["C"] = self.conv.param_count(h) + h
+        head = 0 if self.tie_embeddings else v * h
+        return v * h + sum(per[k] for k in self.kinds) + h + head
 
 
 PRESETS: Dict[str, TransformerConfig] = {
@@ -167,15 +190,26 @@ def _init_pattern_layer(kind: str, key, cfg: TransformerConfig):
         return {"ln": ln, **mamba2.init_layer(key, h, cfg.mamba, dt)}
     if kind == "E":
         return {"ln": ln, **routed.init_layer(key, h, cfg.routed, dt)}
+    if kind == "C":
+        return {"ln": ln, **shortconv.init_layer(key, h, cfg.conv, dt)}
+    ks = jax.random.split(key, 4)
+    if kind == "F":
+        m = cfg.intermediate_size
+        return {"ln_mlp": ln, "mlp": {
+            "w_gate": _dense(ks[0], (h, m), h, dt),
+            "w_up": _dense(ks[1], (h, m), h, dt),
+            "w_down": _dense(ks[2], (m, h), m, dt)}}
     if kind != "*":
         raise ValueError(f"layer kind {kind!r} is none of {KINDS}")
     nh, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
-    ks = jax.random.split(key, 4)
-    return {"ln_attn": ln, "attn": {
-        "wq": _dense(ks[0], (h, nh, d), h, dt),
-        "wk": _dense(ks[1], (h, nkv, d), h, dt),
-        "wv": _dense(ks[2], (h, nkv, d), h, dt),
-        "wo": _dense(ks[3], (nh, d, h), nh * d, dt)}}
+    attn = {"wq": _dense(ks[0], (h, nh, d), h, dt),
+            "wk": _dense(ks[1], (h, nkv, d), h, dt),
+            "wv": _dense(ks[2], (h, nkv, d), h, dt),
+            "wo": _dense(ks[3], (nh, d, h), nh * d, dt)}
+    if cfg.qk_norm:
+        attn.update(q_norm=jnp.ones((d,), jnp.float32),
+                    k_norm=jnp.ones((d,), jnp.float32))
+    return {"ln_attn": ln, "attn": attn}
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
@@ -187,14 +221,16 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     dense = functools.partial(_dense, dt=dt)
 
     if cfg.pattern:
-        if len(cfg.pattern) != L:
+        if cfg.pattern_layers != L:
             raise ValueError(f"pattern {cfg.pattern!r} has not {L} layers")
-        keys = jax.random.split(next(k), L)
+        keys = jax.random.split(next(k), len(cfg.kinds))
         params = {"embed": dense(next(k), (cfg.vocab_size, h), h),
                   "layers": tuple(_init_pattern_layer(kind, keys[i], cfg)
-                                  for i, kind in enumerate(cfg.pattern)),
-                  "ln_f": jnp.ones((h,), jnp.float32),
-                  "lm_head": dense(next(k), (h, cfg.vocab_size), h)}
+                                  for i, kind in enumerate(cfg.kinds)),
+                  "ln_f": jnp.ones((h,), jnp.float32)}
+        head = next(k)
+        if not cfg.tie_embeddings:
+            params["lm_head"] = dense(head, (h, cfg.vocab_size), h)
         return balance_routers(params, cfg, next(k)) if cfg.routed else params
 
     params = {
@@ -262,6 +298,9 @@ def lm_logits(params, x, cfg: TransformerConfig):
     """The head: final norm and output projection of rows x (..., E) ->
     logits (..., V), accumulated and returned in float32."""
     x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
+    if cfg.tie_embeddings:
+        return jnp.einsum("...e,ve->...v", x, params["embed"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
     return jnp.einsum("...e,ev->...v", x, params["lm_head"].astype(cfg.dtype),
                       preferred_element_type=jnp.float32)
 
@@ -276,7 +315,8 @@ def _unconstrained(x, axes):
 
 def block_qkv(lp, x, cos, sin, cfg: TransformerConfig,
               constrain=_unconstrained):
-    """First half of the block: norm, the three projections, RoPE.
+    """First half of the block: norm, the three projections, with `qk_norm`
+    each q and k head's own norm, RoPE.
     x (B, S, E) -> q (B, S, H, D), k, v (B, S, KV, D)."""
     dt = cfg.dtype
     h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
@@ -285,6 +325,9 @@ def block_qkv(lp, x, cos, sin, cfg: TransformerConfig,
     v = jnp.einsum("bse,ekd->bskd", h, lp["attn"]["wv"].astype(dt))
     q = constrain(q, ("batch", "seq", "heads", "head_dim"))
     k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["attn"]["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["attn"]["k_norm"], cfg.rms_norm_eps)
     if not cfg.rope:
         return q, k, v
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
@@ -297,11 +340,9 @@ def attn_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
     return x + constrain(o, ("batch", "seq", "embed"))
 
 
-def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
-    """Second half: output projection of the attention's o (B, S, H, D),
-    residual, norm, SwiGLU, residual -> x (B, S, E)."""
+def ffn_block(lp, x, cfg: TransformerConfig, constrain=_unconstrained):
+    """`F`, and the end of `D`: norm, SwiGLU, residual -> x (B, S, E)."""
     dt = cfg.dtype
-    x = attn_out(lp, x, o, cfg, constrain)
     h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
     g = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_gate"].astype(dt))
     u = jnp.einsum("bse,em->bsm", h, lp["mlp"]["w_up"].astype(dt))
@@ -309,6 +350,12 @@ def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
     d = jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
                    lp["mlp"]["w_down"].astype(dt))
     return x + constrain(d, ("batch", "seq", "embed"))
+
+
+def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
+    """Second half: output projection of the attention's o (B, S, H, D),
+    residual, then the feed-forward half -> x (B, S, E)."""
+    return ffn_block(lp, attn_out(lp, x, o, cfg, constrain), cfg, constrain)
 
 
 # A prefill's bucket is padded to a power of two, and the block's two halves
@@ -415,9 +462,38 @@ def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
 
 # The kinds of block a pattern is made of.  Each is defined once, here, and
 # every path calls it: `D` the dense block above (attention and SwiGLU),
-# `*` attention alone, `M` a Mamba-2 mixer, `E` a latent routed-expert
-# layer; each is x + mixer(rms_norm(x)).
-KINDS = "D*ME"
+# `*` attention alone, `F` the SwiGLU feed-forward alone (`ffn_block`,
+# above), `M` a Mamba-2 mixer, `C` a gated short convolution, `E` a routed-
+# expert layer; each but `D` is x + mixer(rms_norm(x)).  The STATEFUL kinds
+# carry recurrent state from row to row: a tree a layer (`zero_state`),
+# which the engine keeps a row a slot and checkpoints in its prefix cache
+# without knowing what is in it.
+KINDS = "D*FMCE"
+STATEFUL = "MC"
+
+
+def zero_state(cfg: TransformerConfig, kind: str, batch: int):
+    """The state of `batch` sequences that have read nothing, in one layer
+    of a STATEFUL `kind`."""
+    if kind == "M":
+        return mamba2.zero_state(cfg.mamba, batch, cfg.dtype)
+    return shortconv.zero_state(cfg.conv, cfg.hidden_size, batch, cfg.dtype)
+
+
+def state_bytes(cfg: TransformerConfig) -> int:
+    """One sequence's recurrent state over all the stateful layers."""
+    act = jnp.dtype(cfg.dtype).itemsize
+    per = {"M": lambda: cfg.mamba.state_bytes(act),
+           "C": lambda: cfg.conv.state_bytes(cfg.hidden_size, act)}
+    return sum(per[k]() for k in cfg.kinds if k in STATEFUL)
+
+
+def state_chunk(cfg: TransformerConfig) -> int:
+    """The rows the stateful layers count their state's boundaries in (a
+    mixer's `every` is a multiple of its kind's): 0 where no layer carries
+    state."""
+    return max([0] + [(cfg.mamba if k == "M" else cfg.conv).chunk
+                      for k in set(cfg.kinds) & set(STATEFUL)])
 
 
 def attention_block(lp, x, cos, sin, attend, cfg: TransformerConfig):
@@ -435,6 +511,16 @@ def mamba_block(lp, x, state, cfg: TransformerConfig, **how):
     return x + y, state, kept
 
 
+def conv_block(lp, x, state, cfg: TransformerConfig, **how):
+    """`C`: as `mamba_block`, the state a convolution's tail."""
+    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
+    y, state, kept = shortconv.mixer(lp, h, state, cfg.conv, **how)
+    return x + y, state, kept
+
+
+_STATEFUL_BLOCK = {"M": mamba_block, "C": conv_block}
+
+
 def routed_block(lp, x, cfg: TransformerConfig, real=None):
     """`E`: -> (x, counts (2,) int32: held experts touched and rows
     computed, the experts each row chose (B, S, K)); rows that are not
@@ -446,32 +532,36 @@ def routed_block(lp, x, cfg: TransformerConfig, real=None):
 
 def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
                 per_layer=(), length=None, live=None, every: int = 0):
-    """A pattern of kinds, layer by layer (`layers`: one tree a layer).
+    """A pattern of kinds, block by block (`layers`: one tree a block).
     `attend(q, k, v, *at)` as `scan_blocks` takes it, `at` the i-th slice of
     `per_layer` for the i-th attention layer; `rec` the recurrent state, one
-    {"ssm", "tail"} for each `M` layer in order.  Which rows are real:
-    the first `length` (a prefill's padded bucket), the slots that are
+    tree (`zero_state`) for each STATEFUL block in order.  Which rows are
+    real: the first `length` (a prefill's padded bucket), the slots that are
     `live` (B,) (a decode step); the others move no state and meet no
-    routed expert.  `every`: `mamba2.mixer`'s checkpoints.  Returns (x, the
-    attention layers' `kept` stacked, rec', the `M` layers' checkpoints, the
-    `E` layers' counts (n, 2) and chosen experts (n, B, S, K))."""
+    routed expert.  `every`: the stateful mixers' checkpoints.  Returns (x,
+    the attention layers' `kept` stacked, rec', the stateful blocks'
+    checkpoints, the `E` blocks' counts (n, 2) and chosen experts (n, B, S,
+    K))."""
     kept, new, ckpts, counts, chosen = [], [], [], [], []
     real = None
     if length is not None:
         real = jnp.broadcast_to(jnp.arange(x.shape[1]) < length, x.shape[:2])
     if live is not None:
         real = jnp.broadcast_to(live[:, None], x.shape[:2])
-    for kind, lp in zip(cfg.pattern, layers):
+    for kind, lp in zip(cfg.kinds, layers):
         if kind == "*":
             at = tuple(a[len(kept)] for a in per_layer)
             x, k = attention_block(
                 lp, x, cos, sin, lambda q, k, v: attend(q, k, v, *at), cfg)
             kept.append(k)
-        elif kind == "M":
-            x, state, ck = mamba_block(lp, x, rec[len(new)], cfg,
-                                       length=length, live=live, every=every)
+        elif kind in STATEFUL:
+            x, state, ck = _STATEFUL_BLOCK[kind](
+                lp, x, rec[len(new)], cfg, length=length, live=live,
+                every=every)
             new.append(state)
             ckpts.append(ck)
+        elif kind == "F":
+            x = ffn_block(lp, x, cfg)
         else:
             x, c, ch = routed_block(lp, x, cfg, real)
             counts.append(c)
@@ -501,7 +591,7 @@ def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
     x = embed_tokens(params, tokens, cfg)
     cos, sin = rope_angles(jnp.arange(tokens.shape[1]), cfg)
     layers = []
-    for kind, lp in zip(cfg.pattern, params["layers"]):
+    for kind, lp in zip(cfg.kinds, params["layers"]):
         if kind == "E":
             h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
             s = routed.scores(lp, h)
@@ -509,9 +599,11 @@ def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
                                1.0 - r.top_k / r.experts, axis=0)
             lp = dict(lp, router_bias=jnp.mean(bar) - bar)
             x = routed_block(lp, x, cfg)[0]
-        elif kind == "M":
-            x = mamba_block(lp, x, mamba2.zero_state(
-                cfg.mamba, batch, cfg.dtype), cfg)[0]
+        elif kind in STATEFUL:
+            x = _STATEFUL_BLOCK[kind](
+                lp, x, zero_state(cfg, kind, batch), cfg)[0]
+        elif kind == "F":
+            x = ffn_block(lp, x, cfg)
         else:
             x = attention_block(
                 lp, x, cos, sin, lambda q, k, v: (_xla_attention(q, k, v),

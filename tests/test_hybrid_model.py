@@ -179,19 +179,47 @@ def test_one_held_expert_is_the_plain_feed_forward():
     np.testing.assert_allclose(y.reshape(10, 32), ffn + shared, **TOL)
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer():
-    """The deployment the configuration stands for: 4 chips hold 4 of 16
+def _plain_gated(lp, x, dims):
+    """The gated layer of `benchmark/families/lfm2_moe.py` in plain numpy:
+    every expert of the model in turn, no latent space, no shared expert."""
+    lp = jax.tree.map(lambda a: np.asarray(a, np.float64), lp)
+    x = np.asarray(x, np.float64)
+    s = 1 / (1 + np.exp(-(x @ lp["router"])))
+    take = np.argsort(-(s + lp["router_bias"]), -1, kind="stable")[:, :dims.top_k]
+    w = np.take_along_axis(s, take, -1)
+    w = w / w.sum(-1, keepdims=True) * dims.scale
+    out = np.zeros_like(x)
+    for e in range(dims.experts):
+        mine = np.where(take == e, w, 0).sum(-1)
+        gate, up = np.split(x @ lp["w1"][e], 2, -1)
+        out += mine[:, None] * ((gate / (1 + np.exp(-gate)) * up) @ lp["w2"][e])
+    return out, 0.0
+
+
+@pytest.mark.parametrize("form", ["latent_relu2", "gated"])
+def test_the_four_shares_add_up_to_the_uncut_layer(form):
+    """The deployment a held share stands for: 4 chips hold a quarter of the
     experts each.  Their routed parts, with the shared expert (which every
-    chip computes alike) counted once, are the whole layer."""
-    whole = dataclasses.replace(ROUTED, held=16, held_from=0)
+    chip computes alike) counted once, are the whole layer: 4 of 16 latent
+    relu^2 experts with a shared one (the hybrid), 16 of 64 gated experts at
+    top 4 with none (LFM2, whose cell holds all 64)."""
+    if form == "gated":
+        share = routed.RoutedDims(experts=64, held=16, held_from=0, top_k=4,
+                                  latent=0, width=24, shared_width=0,
+                                  scale=1.0, gated=True)
+    else:
+        share = ROUTED
+    n = share.held
+    whole = dataclasses.replace(share, held=4 * n, held_from=0)
     lp = _routed_layer(whole)
     x = jax.random.normal(jax.random.key(4), (1, 12, 32))
-    mix, shared = _plain_routed(lp, x[0], whole, 0, 16)
+    mix, shared = _plain_gated(lp, x[0], whole) if form == "gated" \
+        else _plain_routed(lp, x[0], whole, 0, 16)
     parts = []
     for chip in range(4):
-        dims = dataclasses.replace(ROUTED, held_from=4 * chip)
-        mine = dict(lp, w1=lp["w1"][4 * chip:4 * chip + 4],
-                    w2=lp["w2"][4 * chip:4 * chip + 4])
+        dims = dataclasses.replace(share, held_from=n * chip)
+        mine = dict(lp, w1=lp["w1"][n * chip:n * chip + n],
+                    w2=lp["w2"][n * chip:n * chip + n])
         y, _, _ = routed.mixer(mine, x, dims)
         parts.append(np.asarray(y[0], np.float64) - shared)
     np.testing.assert_allclose(sum(parts) + shared, mix + shared, **TOL)
