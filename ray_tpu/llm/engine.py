@@ -61,6 +61,9 @@ from ..models.transformer import (ROW_BLOCK, STATEFUL, TransformerConfig,
                                   lm_logits, param_logical_axes, rope_angles,
                                   row_blocks, run_pattern, scan_blocks,
                                   state_bytes, state_chunk, zero_state)
+from ..ops.paged_attention import (decode_path, head_rows,
+                                   paged_decode_attention, pool_row, pool_rows,
+                                   pool_shape)
 from .tick_phases import TickPhases
 
 
@@ -223,10 +226,11 @@ def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
         mask = (tpos[None, :] < prefix_len) | (
             (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
         per_layer = (pool_k, pool_v)
+        heads = (cfg.num_kv_heads, cfg.head_dim_)
 
-        def scores(q, k, v, pk, pv):        # pk, pv: (N, page, KV, D)
-            ck = pk[pages].reshape(T, -1, cfg.head_dim_)
-            cv = pv[pages].reshape(T, -1, cfg.head_dim_)
+        def scores(q, k, v, pk, pv):        # pk, pv: (N, page, *row)
+            ck = head_rows(pk[pages], *heads).reshape(T, *heads)
+            cv = head_rows(pv[pages], *heads).reshape(T, *heads)
             return _xla_prefill_attention(
                 q, jnp.concatenate([ck[None], k], axis=1),
                 jnp.concatenate([cv[None], v], axis=1), mask, cfg)
@@ -293,7 +297,8 @@ def _install_state_fn(rec, ckpt, slot, end, kept, rows):
 
 
 def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
-    """Write a prefill's (L, Sb, KV, D) kv into the slot's reserved pages.
+    """Write a prefill's (L, Sb, KV, D) kv into the slot's reserved pages,
+    whole pages of rows as the pool holds them (`pool_rows`).
 
     pages: (P,) int32 physical page ids.  Entries past the slot's reserved
     count are 0 — the shared scratch page, whose contents are garbage by
@@ -305,8 +310,8 @@ def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
     if pad > 0:
         ks = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vs = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    ks = ks.reshape(L, P, page, KV, D)
-    vs = vs.reshape(L, P, page, KV, D)
+    ks = pool_rows(ks.reshape(L, P, page, KV, D), KV, D)
+    vs = pool_rows(vs.reshape(L, P, page, KV, D), KV, D)
     pool_k = pool_k.at[:, pages].set(ks)
     pool_v = pool_v.at[:, pages].set(vs)
     if kv_sharding is not None:
@@ -328,7 +333,6 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
     new token lands; attention (ops/paged_attention.py) reads the pages a
     slot holds.  Nothing in the step is sized by the pool or by
     max_batch x max_len but the donated pool itself."""
-    from ..ops.paged_attention import paged_decode_attention
     # An inactive slot is one token on the scratch page: it costs one page
     # and what it computes is dropped.
     tables = jnp.where(active[:, None], tables, 0)
@@ -342,13 +346,18 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
         tables, (lengths // page)[:, None], axis=1)[:, 0]         # (B,)
     write_off = lengths % page
     paged = _per_shard(paged_decode_attention, kv_sharding, "hpp...")
+    heads = (cfg.num_kv_heads, cfg.head_dim_)
+
+    def written(pool, li, new):     # new (B, 1, KV, D): one row a slot
+        return pool.at[li, write_page, write_off].set(
+            pool_rows(new[:, 0], *heads))
 
     if cfg.pattern:
         pools = [pool_k, pool_v]        # written layer by layer, in place
 
         def attend(q, k, v, li):
-            pools[0] = pools[0].at[li, write_page, write_off].set(k[:, 0])
-            pools[1] = pools[1].at[li, write_page, write_off].set(v[:, 0])
+            pools[0] = written(pools[0], li, k)
+            pools[1] = written(pools[1], li, v)
             return paged(q[:, 0], *pools, tables, lengths, li)[:, None], None
         x, _, rec, _, counts, chosen = run_pattern(
             params["layers"], x, cos, sin, attend, cfg, rec,
@@ -360,8 +369,7 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
         lp, li = layer
 
         def attend(q, k, v):
-            wk = pk.at[li, write_page, write_off].set(k[:, 0])
-            wv = pv.at[li, write_page, write_off].set(v[:, 0])
+            wk, wv = written(pk, li, k), written(pv, li, v)
             o = paged(q[:, 0], wk, wv, tables, lengths, li)       # (B,H,D)
             return o[:, None], (wk, wv)
         x, (pk, pv) = decoder_block(lp, x, cos, sin, attend, cfg)
@@ -423,9 +431,10 @@ def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
     """One decode step for ALL slots against the paged pool, on state that
     stays on the device.
 
-    pool_k/pool_v (L, N, page, KV, D).  `state` = {"slots": (B, P + 4)
-    int32, "rng": the sampling key} is RESIDENT: the step takes it, advances
-    it and returns it, donated like the two pools, so between two steps the
+    pool_k/pool_v (L, N, page, *row: `pool_shape`).  `state` = {"slots":
+    (B, P + 4) int32, "rng": the sampling key} is RESIDENT: the step takes
+    it, advances it and returns it, donated like the two pools, so between
+    two steps the
     host uploads nothing and runs no program.  A slot's row holds its page
     table (page 0 = scratch for inactive slots), its last token, the tokens
     it has in cache (the new token is written at that index), whether it is
@@ -1029,6 +1038,8 @@ class LLMEngine:
             param_shd = tree_shardings(param_logical_axes(cfg), mesh, rules)
             # No tp axis (e.g. a dp-only serving mesh): weights + KV
             # replicate rather than erroring on the undefined axis name.
+            # (Dimension 3 is the KV heads, or the row of KV * D lanes with
+            # the KV heads major: either way a shard holds whole heads.)
             self._kv_shd = NamedSharding(
                 mesh, P(None, None, None, "tp") if has_tp else P())
         self.params = params if params is not None else \
@@ -1036,9 +1047,9 @@ class LLMEngine:
         if param_shd is not None:
             self.params = jax.device_put(self.params, param_shd)
 
-        pool_shape = (L, self.n_pages, self.page, kvh, d)
-        self._pk = jnp.zeros(pool_shape, cfg.dtype, device=self._kv_shd)
-        self._pv = jnp.zeros(pool_shape, cfg.dtype, device=self._kv_shd)
+        shape = pool_shape(L, self.n_pages, self.page, kvh, d)
+        self._pk = jnp.zeros(shape, cfg.dtype, device=self._kv_shd)
+        self._pv = jnp.zeros(shape, cfg.dtype, device=self._kv_shd)
         self._free_slots = list(range(max_batch))
         self._free_pages = list(range(1, self.n_pages))
         # page -> holder count (requests + cache entries); a page leaves
@@ -1184,14 +1195,14 @@ class LLMEngine:
         self._part_seq = 0
 
         def _tail_gather(pk, pv, li, pages):
-            tk = pk[li][pages].reshape(-1, kvh, d)
-            tv = pv[li][pages].reshape(-1, kvh, d)
+            tk = head_rows(pk[li][pages], kvh, d).reshape(-1, kvh, d)
+            tv = head_rows(pv[li][pages], kvh, d).reshape(-1, kvh, d)
             return tk, tv
         self._tail_gather_jit = jax.jit(_tail_gather)
 
         def _append_tail(pk, pv, ks, vs, page_id, off):
-            pk = pk.at[:, page_id, off].set(ks)
-            pv = pv.at[:, page_id, off].set(vs)
+            pk = pk.at[:, page_id, off].set(pool_rows(ks, kvh, d))
+            pv = pv.at[:, page_id, off].set(pool_rows(vs, kvh, d))
             if kv_shd is not None:
                 pk = jax.lax.with_sharding_constraint(pk, kv_shd)
                 pv = jax.lax.with_sharding_constraint(pv, kv_shd)
@@ -1390,16 +1401,18 @@ class LLMEngine:
     def decode_stats(self) -> Dict[str, Any]:
         """What the batch decode step read: pages the active slots held
         (`lengths // page + 1` each) beside the pages their tables address,
-        over all steps and in the last one, and the attention path.  And
+        over all steps and in the last one, the attention path, and how
+        the pool holds a token's row (`pool_row`: "heads" or "lanes").  And
         what the host wrote into the step's resident state: `state_syncs`
         counts the steps before which it wrote slot rows (one packed
         upload), `state_rows` the rows (`step_state_rows`: the last
         step's); `steps - state_syncs` steps uploaded nothing."""
-        from ..ops.paged_attention import decode_path
         per_step = self.max_batch * self.pages_per_slot
         return {"path": decode_path(
                     (self.cfg.num_heads, self.cfg.head_dim_), self._pk.shape,
                     self._tables.shape),
+                "pool_row": pool_row(self.cfg.num_kv_heads,
+                                     self.cfg.head_dim_),
                 "steps": self._decode_steps,
                 "pages_read": self._pages_read,
                 "pages_addressable": self._decode_steps * per_step,
@@ -1555,8 +1568,9 @@ class LLMEngine:
         decref the pages rejoin the free list and any admission may
         overwrite them)."""
         idx = jnp.asarray(np.asarray(pages, np.int32))
-        kk = np.asarray(self._pk[:, idx])
-        vv = np.asarray(self._pv[:, idx])
+        heads = (self.cfg.num_kv_heads, self.cfg.head_dim_)
+        kk = np.asarray(head_rows(self._pk[:, idx], *heads))
+        vv = np.asarray(head_rows(self._pv[:, idx], *heads))
         self._demote.put(key, kk, vv, len(pages))
 
     def _try_promote(self, req: _Request, c: int, shared: List[int],
@@ -2510,10 +2524,12 @@ class LLMEngine:
             row = np.zeros(self.pages_per_slot, np.int32)
             row[:len(shared)] = shared
             logits, ks, vs = self._run_suffix(prompt, c, row)
-            ck = self._pk[:, jnp.asarray(np.asarray(shared))].reshape(
-                self.cfg.num_layers, c, self.cfg.num_kv_heads, -1)
-            cv = self._pv[:, jnp.asarray(np.asarray(shared))].reshape(
-                self.cfg.num_layers, c, self.cfg.num_kv_heads, -1)
+            heads = (self.cfg.num_kv_heads, self.cfg.head_dim_)
+            idx = jnp.asarray(np.asarray(shared))
+            ck = head_rows(self._pk[:, idx], *heads).reshape(
+                self.cfg.num_layers, c, *heads)
+            cv = head_rows(self._pv[:, idx], *heads).reshape(
+                self.cfg.num_layers, c, *heads)
             k_full = jnp.concatenate([ck, ks[:, :S - c]], 1)
             v_full = jnp.concatenate([cv, vs[:, :S - c]], 1)
         else:

@@ -45,6 +45,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as model
+from ..ops.paged_attention import head_rows
 from ..ops.ring_attention import ring_attention, ulysses_attention
 
 __all__ = ["sp_mesh", "sp_prefill_fn", "sp_suffix_prefill_fn",
@@ -230,9 +231,11 @@ def sp_suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
                   P(None, None, None), P()),
         out_specs=spec, check_vma=False)
 
-    def attend(q, k, v, pk, pv):            # pk/pv: (N, page, KV, D)
-        ck = pk[pages].reshape(T, -1, cfg.head_dim_)
-        cv = pv[pages].reshape(T, -1, cfg.head_dim_)
+    heads = (cfg.num_kv_heads, cfg.head_dim_)
+
+    def attend(q, k, v, pk, pv):            # pk/pv: (N, page, *row)
+        ck = head_rows(pk[pages], *heads).reshape(T, *heads)
+        cv = head_rows(pv[pages], *heads).reshape(T, *heads)
         return shard(q, k, v, ck, cv, prefix_len), (k[0], v[0])
 
     x, (ks, vs) = model.scan_blocks(params["layers"], x, cos, sin, attend,

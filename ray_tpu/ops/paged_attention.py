@@ -15,6 +15,17 @@ rows of the other KV heads, so no key is ever regrouped in memory.
 `paged_decode_attention` runs the kernel on a TPU for shapes it tiles and
 `reference_paged_attention` everywhere else (the CPU test mesh, head widths
 under 128); the reference is also the kernel's parity oracle.
+
+THE POOL'S ROW follows the head width (`pool_row`).  A token's keys of one
+layer are `(KV, D)`, and where `D` is a whole number of 128-lane rows the
+pool holds them so: `(L, N, page, KV, D)`.  A head narrower than a lane row
+leaves that array without a 128-wide minor dimension; the chip then lays the
+pages innermost and every scatter and gather of the serving step converts
+the whole pool.  So where `KV * D` is a multiple of 128 the pool holds a
+token's keys of all KV heads as ONE row of `KV * D` lanes, `(L, N, page,
+KV * D)`, for which there is one layout to want.  `pool_shape`, `pool_rows`
+and `head_rows` are the only places that know; the engine's allocation,
+install, decode write and every reader go through them.
 """
 
 from __future__ import annotations
@@ -29,6 +40,63 @@ import jax.numpy as jnp
 # Rows (tokens x KV heads) of keys one chunk holds in VMEM: K and V chunks,
 # double-buffered, take 4 x _CHUNK_ROWS x D x 2 bytes (2 MiB at D = 128).
 _CHUNK_ROWS = 2048
+_LANES = 128
+
+
+def pool_row(num_kv_heads: int, head_dim: int) -> str:
+    """How the pool holds one token's keys (or values) of one layer:
+    "heads", `(KV, D)`, or "lanes", one row of `KV * D` lanes, for heads
+    narrower than a lane row whose KV heads together fill whole ones."""
+    narrow = head_dim < _LANES and (num_kv_heads * head_dim) % _LANES == 0
+    return "lanes" if narrow else "heads"
+
+
+def _row_shape(num_kv_heads: int, head_dim: int) -> tuple:
+    if pool_row(num_kv_heads, head_dim) == "lanes":
+        return (num_kv_heads * head_dim,)
+    return (num_kv_heads, head_dim)
+
+
+def pool_shape(layers: int, n_pages: int, page: int, num_kv_heads: int,
+               head_dim: int) -> tuple:
+    """The shape of one pool (keys or values) of `layers` attention layers."""
+    return (layers, n_pages, page) + _row_shape(num_kv_heads, head_dim)
+
+
+def pool_rows(x, num_kv_heads: int, head_dim: int):
+    """Rows `(..., KV, D)` as the pool holds them (the two minor dimensions
+    merge where the row is "lanes": no element moves)."""
+    return x.reshape(x.shape[:-2] + _row_shape(num_kv_heads, head_dim))
+
+
+def head_rows(x, num_kv_heads: int, head_dim: int):
+    """Rows read out of a pool, `(..., *row)`, as `(..., KV, D)`."""
+    lead = x.ndim - len(_row_shape(num_kv_heads, head_dim))
+    return x.reshape(x.shape[:lead] + (num_kv_heads, head_dim))
+
+
+def _lanes_attention(q, ck, cv, lengths, scale):
+    """The reference over rows of `C = KV * D` lanes, ck / cv (B, T, C), which
+    are never split into heads (that would move 64-lane halves of every
+    gathered row about): each query head is widened to a whole row with
+    zeros outside its KV head's lanes, so the other heads' products add exact
+    zeros to its scores, and of its output row it keeps its own lanes."""
+    B, H, D = q.shape
+    T, KV = ck.shape[1], ck.shape[2] // D
+    own = (jnp.arange(H) // (H // KV))[:, None] == jnp.arange(KV)  # (H, KV)
+    own = own[None, :, :, None]
+    qw = jnp.where(own, q[:, :, None], 0).reshape(B, H, KV * D)
+    s = jnp.einsum("bhc,btc->bht", qw, ck,
+                   preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(T)[None] <= lengths[:, None]               # (B, T)
+    s = jnp.where(valid[:, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    # Dead rows may hold anything (NaN included): select, never multiply.
+    cv = jnp.where(valid[:, :, None], cv, 0)
+    o = jnp.einsum("bht,btc->bhc", p, cv,
+                   preferred_element_type=jnp.float32)
+    o = jnp.where(own, o.reshape(B, H, KV, D), 0).sum(2)
+    return o.astype(q.dtype)
 
 
 def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
@@ -36,10 +104,14 @@ def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
     """Plain `jax.numpy` form of `paged_decode_attention` (same signature):
     gathers every slot's whole table and masks what is past its length."""
     B, H, D = q.shape
-    page, KV = pool_k.shape[-3:-1]
-    T = tables.shape[1] * page
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     pages = (tables,) if layer is None else (layer, tables)
+    if pool_k.ndim == 2 + len(pages):           # rows of lanes: (.., KV * D)
+        rows = (B, -1, pool_k.shape[-1])
+        return _lanes_attention(q, pool_k[pages].reshape(rows),
+                                pool_v[pages].reshape(rows), lengths, scale)
+    page, KV = pool_k.shape[-3:-1]
+    T = tables.shape[1] * page
     ck = pool_k[pages].reshape(B, T, KV, D)
     cv = pool_v[pages].reshape(B, T, KV, D)
     qg = q.reshape(B, KV, H // KV, D)
@@ -199,8 +271,10 @@ def kernel_tiles(q_shape, pool_shape, tables_shape) -> bool:
     128-lane rows, pages of whole bf16 sublane tiles, heads that group,
     page tables that fit scalar memory."""
     H, D = q_shape[-2:]
+    if D % _LANES:
+        return False        # (and the pool may hold rows of lanes: `pool_row`)
     page, KV = pool_shape[-3:-1]
-    return D % 128 == 0 and page % 16 == 0 and H % KV == 0 \
+    return page % 16 == 0 and H % KV == 0 \
         and 4 * math.prod(tables_shape) <= _TABLE_BYTES
 
 
@@ -218,7 +292,8 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths, layer=None, *,
 
     q (B, H, D) after RoPE; pool_k / pool_v (N, page, KV, D), or the stacked
     (L, N, page, KV, D) with `layer` the (traced) index to read, so that a
-    layer loop never slices the pool; tables (B, P) physical page ids;
+    layer loop never slices the pool, either with its two minor dimensions
+    as one where `pool_row` says "lanes"; tables (B, P) physical page ids;
     lengths (B,) tokens already cached: positions 0..lengths[b] are attended
     (the new token's key is at index lengths[b], written by the caller).
     Slot b reads pages tables[b, 0 .. lengths[b] // page] and no other.

@@ -249,6 +249,35 @@ def _collect_previous_test_garbage():
 
 
 @pytest.fixture
+def captured_recorder():
+    """A context manager that swaps in a flight recorder whose rows the
+    driver's telemetry flush cannot steal (a live shared cluster drains the
+    process singleton every second: mid-test, during a multi-second first
+    compile, in a loaded run): `drain()`, the telemetry entry point, yields
+    nothing; the test reads `rows()`."""
+    from contextlib import contextmanager
+
+    from ray_tpu._private import flight_recorder
+
+    class _Cap(flight_recorder.FlightRecorder):
+        def drain(self, node_id=b"", worker_id=b""):
+            return []
+
+        def rows(self):
+            return flight_recorder.FlightRecorder.drain(self)
+
+    @contextmanager
+    def swap():
+        old = flight_recorder._recorder
+        cap = flight_recorder._recorder = _Cap()
+        try:
+            yield cap
+        finally:
+            flight_recorder._recorder = old
+    return swap
+
+
+@pytest.fixture
 def ray_start_regular():
     """Shared cluster: initialized on first use, reused across tests, torn
     down at interpreter exit (isolated-fixture tests shut it down and the

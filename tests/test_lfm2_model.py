@@ -185,6 +185,7 @@ def test_the_state_is_the_convolutions_tails_and_the_counters_are_fed():
     assert eng._every == 64 and len(eng._dev["rec"]) == 3
     assert [sorted(r) for r in eng._dev["rec"]] == [["tail"]] * 3
     assert eng._pk.shape[0] == 1                # one attention layer's pool
+    assert eng.decode_stats()["pool_row"] == "heads"            # 4 x 16
     doc = prompt_of(cfg, 3, 150)                # checkpoints every 64 tokens
     first, second = doc + prompt_of(cfg, 4, 9), doc + prompt_of(cfg, 5, 12)
     eng.generate([first], SamplingParams(max_tokens=4))
@@ -210,6 +211,60 @@ def test_the_state_is_the_convolutions_tails_and_the_counters_are_fed():
         and rt["experts"] == 8 and rt["top_k"] == 2
     assert len(rt["touched"]) == len(rt["rows"]) == 3           # E layers
     assert rt["rows"] == [12, 12, 12] and max(rt["step_touched"]) <= 2
+
+
+# ---- 64-wide heads: the pool's row is lanes (ops/paged_attention.py) -------
+
+def _narrow(kv):
+    """`tiny` with 8 query heads of 64 over `kv` KV heads: KV x D = 128 or
+    512 lanes, as the published widths have it (8 x 64)."""
+    from benchmark.families import lfm2_moe as family
+    cfg, _ = tiny()
+    cfg = dict(cfg, head_dim=64, num_key_value_heads=kv)
+    return cfg, family.program_config(cfg, max_seq_len=512)
+
+
+@pytest.mark.parametrize("kv", [2, 8])
+def test_narrow_heads_decode_through_the_cache_is_the_full_forward(kv):
+    """Prefill, then 2 x page + 3 decode steps through a pool of rows of
+    lanes and the convolution tails: every step's logits are the float32
+    reference's over the whole sequence."""
+    from benchmark.families import lfm2_moe as family
+    cfg, pc = _narrow(kv)
+    eng = engine(pc, 1)
+    assert eng._pk.shape == (1, 65, 16, kv * 64)
+    assert eng.decode_stats()["pool_row"] == "lanes"
+    assert eng.decode_stats()["path"] == "reference"
+    prompt, steps = prompt_of(cfg, 1), 2 * 16 + 3
+    out = eng.generate([prompt], SamplingParams(max_tokens=steps + 1))[0]
+    got = eng.trace_logits(prompt, out[:-1])
+    toks = jnp.asarray([prompt + out[:-1]], jnp.int32)
+    ref = family.reference_logits(eng.params, toks, cfg)[0, len(prompt) - 1:]
+    assert got["logits"].shape == (steps + 1, cfg["vocab_size"])
+    np.testing.assert_allclose(got["logits"], ref, **TOL)
+    assert np.asarray(ref).argmax(-1).tolist() == out       # greedy, served
+
+
+@pytest.mark.parametrize("kv", [2, 8])
+def test_narrow_heads_a_hit_reads_the_rows_a_prefill_installed(kv):
+    """A prefix-cache hit on a pool of rows of lanes: the suffix prefill's
+    XLA arm gathers the cached pages as they lie, and the greedy tokens are
+    the whole prompt's; `trace_logits(cached=True)` traces that path."""
+    from benchmark.families import lfm2_moe as family
+    cfg, pc = _narrow(kv)
+    eng = engine(pc, 3)
+    doc = prompt_of(cfg, 3, 150)
+    first, second = doc + prompt_of(cfg, 4, 9), doc + prompt_of(cfg, 5, 12)
+    eng.generate([first], SamplingParams(max_tokens=4))
+    warm = eng.generate([second], SamplingParams(max_tokens=20))[0]
+    assert eng.prefix_cache_stats()["hit_pages"] == 8           # 128 tokens
+    cold = engine(pc, 3, prefix_cache=False)
+    assert cold.generate([second], SamplingParams(max_tokens=20))[0] == warm
+    got = eng.trace_logits(second, warm[:-1], cached=True)
+    assert got["from"] == 128
+    toks = jnp.asarray([second + warm[:-1]], jnp.int32)
+    ref = family.reference_logits(eng.params, toks, cfg)[0, len(second) - 1:]
+    np.testing.assert_allclose(got["logits"], ref, **TOL)
 
 
 def test_eviction_frees_pages_and_conv_checkpoint_rows_together():

@@ -277,12 +277,25 @@ def _wide_head_cfg():
         dtype=jnp.bfloat16)
 
 
-@pytest.mark.parametrize("path", ["reference", "kernel"])
+def _narrow_head_cfg(kv=2):
+    """A dense decoder with 64-wide heads whose KV heads fill whole lane
+    rows: the pool holds a token's row as lanes (`pool_row`).  float32, so
+    that greedy tokens can be compared."""
+    import jax.numpy as jnp
+    from ray_tpu.models import TransformerConfig
+    return TransformerConfig(
+        vocab_size=256, hidden_size=128, intermediate_size=256, num_layers=2,
+        num_heads=2 * kv, num_kv_heads=kv, head_dim=64, max_seq_len=256,
+        dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("path", ["reference", "kernel", "lanes2", "lanes8"])
 def test_decode_logits_through_cache_match_float32_forward(path, monkeypatch):
     """Prefill, then 2 x page + 3 decode steps through the paged cache: each
     step's logits against a float32 forward pass over the whole sequence.
     `tiny` takes the plain function; 128-wide heads take the Pallas kernel,
-    here interpreted (steered in the test: there is no TPU)."""
+    here interpreted (steered in the test: there is no TPU); 64-wide heads
+    (KV x D = 128 and 512) the plain function over rows of lanes."""
     import dataclasses
     import functools
 
@@ -298,11 +311,16 @@ def test_decode_logits_through_cache_match_float32_forward(path, monkeypatch):
         monkeypatch.setattr(pa, "_paged_decode_pallas", functools.partial(
             pa._paged_decode_pallas, interpret=True))
         monkeypatch.setattr(pa, "_CHUNK_ROWS", 2 * page * cfg.num_kv_heads)
+    elif path.startswith("lanes"):
+        cfg, tol = _narrow_head_cfg(int(path[5:])), 2e-4
     else:
         cfg, tol = CFG, 2e-4
     steps = 2 * page + 3
     eng = LLMEngine(cfg, max_batch=2, max_len=4 * page, page_size=page,
                     seed=0)
+    assert eng.decode_stats()["pool_row"] == (
+        "lanes" if path.startswith("lanes") else "heads")
+    assert eng._pk.ndim == (4 if path.startswith("lanes") else 5)
     prompt = [3, 17, 42, 7, 99, 5, 23, 11, 2]
     eng.add_request(prompt, SamplingParams(max_tokens=steps + 2))
     assert eng._admit() == 1                     # prefill; slot 0 holds it
@@ -331,6 +349,83 @@ def test_decode_logits_through_cache_match_float32_forward(path, monkeypatch):
         worst = max(worst, np.abs(got - want).max())
         assert worst < tol, (i, worst)
         toks.append(int(np.argmax(want)))
+
+
+def _narrow_engine(**kw):
+    kw = {"max_batch": 2, "max_len": 128, "page_size": 16, "seed": 0, **kw}
+    return LLMEngine(_narrow_head_cfg(), **kw)
+
+
+@pytest.mark.parametrize("what", ["greedy", "prefix_hit", "chunked",
+                                  "demotion", "shipped", "streamed", "tp2"])
+def test_narrow_heads_dense_decoder_through_every_pool_path(what):
+    """A dense decoder with 64-wide heads (KV 2 x 64: the pool's row is 128
+    lanes) through every path that writes or reads the pool: the tokens are
+    those of the plain engine, and the plain engine's those of a forward
+    pass over the whole sequence."""
+    import jax
+    import jax.numpy as jnp
+    cfg = _narrow_head_cfg()
+    rng = np.random.default_rng(1)
+    doc = rng.integers(1, cfg.vocab_size, 50).tolist()
+    first, second = doc + [7, 8, 9], doc + [11, 12, 13, 14]
+    sp = SamplingParams(max_tokens=20)       # past a page's end
+    plain = _narrow_engine()
+    assert plain.decode_stats()["pool_row"] == "lanes"
+    assert plain._pk.shape == (2, 17, 16, 128)
+    want = plain.generate([second], sp)[0]
+    if what == "greedy":
+        toks = list(second)
+        for tok in want:
+            logits = forward(plain.params, jnp.asarray([toks], jnp.int32), cfg)
+            assert int(jnp.argmax(logits[0, -1])) == tok
+            toks.append(tok)
+        return
+    if what == "prefix_hit":                 # the XLA suffix arm
+        eng = _narrow_engine(prefix_cache=True)
+        eng.generate([first], sp)
+        assert eng.generate([second], sp)[0] == want
+        assert eng.prefix_cache_stats()["hit_pages"] == 3
+    elif what == "chunked":                  # suffix prefills, chunk by chunk
+        eng = _narrow_engine(prefill_chunk=16)
+        assert eng.generate([second], sp)[0] == want
+    elif what == "demotion":                 # pool -> host -> pool
+        eng = _narrow_engine(prefix_cache=True, kv_pages=12)
+        assert eng._demote is not None
+        eng.generate([second], sp)
+        while eng._cache._entries:
+            eng._cache.evict_lru(eng._decref, eng._demote_entry)
+        part = next(iter(eng._demote._host.values()))
+        assert part["k"].shape[-2:] == (2, 64)      # stored by heads
+        assert eng.generate([second], sp)[0] == want
+        assert eng.prefix_cache_stats()["promoted_pages"] > 0
+    elif what == "shipped":                  # prefill here, decode there
+        pre = _narrow_engine(prefix_cache=True)
+        pre.prefill_only(first, sp)          # leaves the document's pages
+        blob, tok = pre.prefill_only(second, sp)    # gathers them back
+        assert blob["k"].shape == (2, len(second), 2, 64)
+        assert _narrow_engine().decode_from(blob, tok, sp) == want
+    elif what == "streamed":                 # the tail in the pool
+        pre = _narrow_engine(kv_pages=4)
+        handoff = pre.prefill_paged(second, sp, span=32)
+        dec = _narrow_engine(kv_pages=6)
+        rid = dec.add_paged_request(handoff["parts"], handoff["len"],
+                                    handoff["first"], sp)
+        out = None
+        while dec.has_unfinished():
+            for done in dec.step():
+                if done.req_id == rid:
+                    out = done.out
+        assert out == want
+    elif what == "tp2":                      # a shard's row: one head, 64
+        from ray_tpu.parallel import MeshSpec, build_mesh
+        if len(jax.devices()) < 2:
+            pytest.skip("needs 2 virtual devices")
+        mesh = build_mesh(MeshSpec(tp=2), devices=jax.devices()[:2])
+        eng = _narrow_engine(mesh=mesh, prefix_cache=True)
+        assert "tp" in str(eng._pk.sharding.spec)
+        eng.generate([first], sp)
+        assert eng.generate([second], sp)[0] == want
 
 
 def test_tokens_do_not_depend_on_what_is_addressable():
@@ -605,7 +700,7 @@ def test_debug_stats_count_pages_read():
     st = stats["decode"]
     # Prefill gives the first token; decode step i attends n_prompt + i.
     lengths = [n_prompt + i for i in range(n_out - 1)]
-    assert st["path"] == "reference"
+    assert st["path"] == "reference" and st["pool_row"] == "heads"
     assert st["steps"] == len(lengths)
     assert st["pages_read"] == sum(n // page + 1 for n in lengths)
     assert st["pages_addressable"] == len(lengths) * 2 * (128 // page)

@@ -28,32 +28,6 @@ pytestmark = pytest.mark.serving
 CFG = PRESETS["tiny"]
 
 
-from contextlib import contextmanager
-
-
-@contextmanager
-def _captured_recorder():
-    """Swap in a recorder whose rows the driver's telemetry flush cannot
-    steal (a live shared cluster drains the process singleton every
-    second — mid-test, during multi-second first compiles): drain() (the
-    telemetry entry point) yields nothing; the test reads rows()."""
-
-    class _Cap(flight_recorder.FlightRecorder):
-        def drain(self, node_id=b"", worker_id=b""):
-            return []
-
-        def rows(self):
-            return flight_recorder.FlightRecorder.drain(self)
-
-    old = flight_recorder._recorder
-    cap = _Cap()
-    flight_recorder._recorder = cap
-    try:
-        yield cap
-    finally:
-        flight_recorder._recorder = old
-
-
 @pytest.fixture
 def serve_cluster():
     if ray_tpu.is_initialized():
@@ -67,11 +41,11 @@ def serve_cluster():
 
 # ---------------------------------------------------------------- engine ---
 
-def test_admission_sampling_is_one_transfer_per_tick():
+def test_admission_sampling_is_one_transfer_per_tick(captured_recorder):
     """A 3-request admission wave samples its first tokens in ONE
     device->host pull (one `sample_sync` span per tick, batch=3), not
     one blocking pull per request."""
-    with _captured_recorder() as rec:
+    with captured_recorder() as rec:
         eng = LLMEngine(CFG, max_batch=4, max_len=64, seed=0, page_size=8)
         for i in range(3):
             eng.add_request([i + 1, i + 2, i + 3],
@@ -87,7 +61,7 @@ def test_admission_sampling_is_one_transfer_per_tick():
             eng.step()
 
 
-def test_prefix_cache_hit_parity_eviction_and_accounting():
+def test_prefix_cache_hit_parity_eviction_and_accounting(captured_recorder):
     """Page-granular prefix reuse: a shared-prefix request skips
     prefill for the shared pages (page-pool accounting asserted), tokens
     stay IDENTICAL to an uncached engine, and LRU entries evict under
@@ -103,7 +77,7 @@ def test_prefix_cache_hit_parity_eviction_and_accounting():
     st = eng.prefix_cache_stats()
     assert st["hits"] == 1 and st["hit_pages"] == 2, st
     # Shared pages were NOT re-allocated: B borrowed A's 2 prefix pages.
-    with _captured_recorder() as rec:
+    with captured_recorder() as rec:
         eng.generate([pA], sp)               # full prompt cached now
         rows = [r for r in rec.rows()
                 if r["cat"] == "request" and r["name"] == "prefill"]

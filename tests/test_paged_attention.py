@@ -17,16 +17,18 @@ BF16_TOL = 2e-2     # outputs are O(1) averages of bf16 values rounded to bf16
 
 
 def _case(groups, page, lengths, *, kv=2, n_slots_pages=6, seed=0,
-          shared=(), dtype=jnp.bfloat16):
-    """q, pools, tables, lengths for `len(lengths)` slots of P pages each.
+          shared=(), dtype=jnp.bfloat16, d=D):
+    """q, pools, tables, lengths for `len(lengths)` slots of P pages each,
+    the pools' rows as `pool_row` has them for `kv` heads of `d`.
     `shared`: pairs (a, b, n): slot b's first n pages are slot a's."""
     B, P = len(lengths), n_slots_pages
     H = kv * groups
     N = 1 + B * P
     ks = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(ks[0], (B, H, D), jnp.float32).astype(dtype)
-    pk = jax.random.normal(ks[1], (N, page, kv, D), jnp.float32).astype(dtype)
-    pv = jax.random.normal(ks[2], (N, page, kv, D), jnp.float32).astype(dtype)
+    q = jax.random.normal(ks[0], (B, H, d), jnp.float32).astype(dtype)
+    pk, pv = (pa.pool_rows(jax.random.normal(
+        key, (N, page, kv, d), jnp.float32).astype(dtype), kv, d)
+        for key in ks[1:])
     rng = np.random.default_rng(seed)
     tables = rng.permutation(np.arange(1, N)).reshape(B, P).astype(np.int32)
     for a, b, n in shared:
@@ -39,8 +41,8 @@ def _oracle(q, pk, pv, tables, lengths):
     over positions 0..length of the slot's own pages."""
     q, pk, pv = (np.asarray(a, np.float32) for a in (q, pk, pv))
     tables, lengths = np.asarray(tables), np.asarray(lengths)
-    B, H, _ = q.shape
-    page, kv = pk.shape[1:3]
+    B, H, D = q.shape
+    kv = pk[0, 0].size // D         # a row is (KV, D), or KV * D lanes
     out = np.zeros((B, H, D), np.float32)
     for b in range(B):
         n = lengths[b] + 1
@@ -130,7 +132,7 @@ def test_kernel_cases(what, small_chunks):
     assert np.abs(ref - want).max() < tol
 
 
-@pytest.mark.parametrize("impl", ["kernel", "reference"])
+@pytest.mark.parametrize("impl", ["kernel", "reference", "lanes"])
 def test_reads_live_pages_only(impl, small_chunks):
     """Every page no slot holds is NaN, and the rows of a slot's last page
     past its length are huge: the output is what it was before.  (The old
@@ -138,7 +140,8 @@ def test_reads_live_pages_only(impl, small_chunks):
     page, kv = 16, 2
     small_chunks(page, kv)
     lengths = [0, page - 1, page, 3 * page + 5, 6 * page - 1]
-    q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv)
+    q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv,
+                                    d=64 if impl == "lanes" else D)
     fn = _kernel if impl == "kernel" else pa.reference_paged_attention
     clean = np.asarray(fn(q, pk, pv, tables, lens), np.float32)
     held = np.zeros(pk.shape[0], bool)
@@ -147,9 +150,10 @@ def test_reads_live_pages_only(impl, small_chunks):
         last = n // page
         held[np.asarray(tables)[b, :last + 1]] = True
         tail[int(tables[b, last]), n % page + 1:] = True
+    over = lambda mask, pool: jnp.asarray(mask).reshape(
+        mask.shape + (1,) * (pool.ndim - mask.ndim))
     poison = lambda pool: jnp.where(
-        jnp.asarray(tail)[:, :, None, None], 3e38,
-        jnp.where(jnp.asarray(held)[:, None, None, None], pool, jnp.nan)
+        over(tail, pool), 3e38, jnp.where(over(held, pool), pool, jnp.nan)
     ).astype(pool.dtype)
     dirty = np.asarray(fn(q, poison(pk), poison(pv), tables, lens),
                        np.float32)
@@ -171,6 +175,109 @@ def test_chooser_adapts_to_platform_and_shape():
     np.testing.assert_array_equal(
         np.asarray(pa.paged_decode_attention(*args), np.float32),
         np.asarray(pa.reference_paged_attention(*args), np.float32))
+
+
+# ---- heads narrower than a lane row: the pool's row (`pool_row`) ----------
+
+def test_the_pools_row_follows_the_head_width():
+    from ray_tpu.models.transformer import PRESETS
+    tiny = PRESETS["tiny"]
+    assert pa.pool_row(8, 128) == pa.pool_row(2, 128) == "heads"
+    assert pa.pool_row(8, 256) == "heads"
+    assert pa.pool_row(tiny.num_kv_heads, tiny.head_dim_) == "heads"  # 4 x 16
+    assert pa.pool_row(1, 64) == pa.pool_row(3, 64) == "heads"
+    assert pa.pool_row(8, 64) == pa.pool_row(2, 64) == "lanes"
+    assert pa.pool_shape(2, 3073, 16, 8, 64) == (2, 3073, 16, 512)
+    assert pa.pool_shape(2, 3073, 16, 8, 128) == (2, 3073, 16, 8, 128)
+    assert pa.pool_shape(2, 9, 16, 4, 16) == (2, 9, 16, 4, 16)
+    x = jnp.arange(3 * 5 * 2 * 64).reshape(3, 5, 2, 64)
+    rows = pa.pool_rows(x, 2, 64)
+    assert rows.shape == (3, 5, 128)
+    np.testing.assert_array_equal(rows[1, 2, 64:], x[1, 2, 1])  # KV major
+    np.testing.assert_array_equal(pa.head_rows(rows, 2, 64), x)
+    wide = jnp.zeros((3, 5, 2, 128))
+    assert pa.pool_rows(wide, 2, 128) is wide
+    assert pa.head_rows(wide, 2, 128) is wide
+    # neither kernel takes them; the chooser reads no pool row as heads
+    assert not pa.kernel_tiles((32, 64), (2, 3073, 16, 512), (8, 256))
+    assert pa.decode_path((32, 64), (2, 3073, 16, 512), (8, 256)) \
+        == "reference"
+
+
+@pytest.mark.parametrize("page", [16, 64])
+@pytest.mark.parametrize("groups", [1, 4])
+@pytest.mark.parametrize("kv", [2, 8])
+def test_narrow_heads_match_float32(kv, groups, page):
+    """D 64: the pool's rows are lanes, and the plain function reads them as
+    they lie (lengths 0, page - 1, page, several pages, the last)."""
+    args = _case(groups, page, _lengths(page, 6), kv=kv, d=64)
+    assert args[1].shape == (31, page, kv * 64)
+    got = np.asarray(pa.paged_decode_attention(*args), np.float32)
+    assert np.abs(got - _oracle(*args)).max() < BF16_TOL
+    # and against the same keys held by heads, which take the other form
+    heads = [pa.head_rows(pool, kv, 64) for pool in args[1:3]]
+    assert heads[0].shape == (31, page, kv, 64)
+    split = pa.reference_paged_attention(args[0], *heads, *args[3:])
+    assert np.abs(got - np.asarray(split, np.float32)).max() < BF16_TOL
+
+
+@pytest.mark.parametrize("what", ["inactive", "shared", "stacked", "float32"])
+def test_narrow_head_cases(what):
+    page, kv, kwargs, layer = 16, 8, {}, None
+    lengths = _lengths(page, 6)
+    if what == "inactive":
+        lengths = [0, 40, 0, 17]
+    elif what == "shared":
+        kwargs["shared"] = [(0, 1, 3), (0, 2, 3)]
+        lengths = [70, 3 * page, 3 * page + 20]
+    elif what == "float32":
+        kwargs["dtype"] = jnp.float32
+    q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv, d=64, **kwargs)
+    if what == "inactive":
+        tables = tables.at[0].set(0).at[2].set(0)
+    want = _oracle(q, pk, pv, tables, lens)
+    if what == "stacked":
+        # The engine's form: (L, N, page, KV * D) and a traced layer index.
+        other = jnp.full_like(pk, jnp.nan)
+        pk, pv, layer = (jnp.stack([other, pk, other]),
+                         jnp.stack([other, pv, other]), jnp.int32(1))
+    got = np.asarray(jax.jit(pa.paged_decode_attention)(
+        q, pk, pv, tables, lens, layer), np.float32)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < (1e-4 if what == "float32"
+                                       else BF16_TOL)
+
+
+@pytest.mark.parametrize("kv,d", [(8, 64), (2, 64), (2, 128), (4, 16)])
+def test_install_then_read_gives_back_the_rows(kv, d):
+    """`_install_fn` writes whole pages of rows as the pool holds them; read
+    through the slot's page row they are the rows installed, one by one."""
+    from ray_tpu.llm import engine as E
+    L, page, P_, Sb = 2, 16, 4, 40
+    shape = pa.pool_shape(L, 9, page, kv, d)
+    assert len(shape) == (4 if d == 64 else 5)
+    ks, vs = (jax.random.normal(jax.random.key(i), (L, Sb, kv, d),
+                                jnp.float32).astype(jnp.bfloat16)
+              for i in range(2))
+    pages = jnp.asarray([5, 2, 7, 0], jnp.int32)    # 3 reserved, then scratch
+    pk, pv = jax.jit(lambda *a: E._install_fn(*a, page, None))(
+        jnp.full(shape, jnp.nan, jnp.bfloat16),
+        jnp.full(shape, jnp.nan, jnp.bfloat16), ks, vs, pages)
+    assert pk.shape == shape
+    for pool, rows in ((pk, ks), (pv, vs)):
+        back = pa.head_rows(pool[:, pages[:3]], kv, d).reshape(L, -1, kv, d)
+        np.testing.assert_array_equal(np.asarray(back[:, :Sb], np.float32),
+                                      np.asarray(rows, np.float32))
+        untouched = np.asarray(pool[:, jnp.asarray([1, 3, 4, 6, 8])],
+                               np.float32)
+        assert np.isnan(untouched).all()
+    # one token a slot, as the decode step writes it, read back by attention:
+    # a slot of one token attends to that token alone
+    q = jnp.ones((1, kv, d), jnp.bfloat16)
+    o = pa.paged_decode_attention(q, pk, pv, pages[None, :1],
+                                  jnp.zeros((1,), jnp.int32), jnp.int32(1))
+    np.testing.assert_array_equal(np.asarray(o[0], np.float32),
+                                  np.asarray(vs[1, 0], np.float32))
 
 
 # ---- compiled for the chip, without the chip -----------------------------
@@ -299,6 +406,86 @@ def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
                                                else 1)
     assert mem.alias_size_in_bytes >= 2 * per_device
     assert mem.temp_size_in_bytes < per_device // 8
+
+
+def _pool_sized_copies(compiled, pool) -> list:
+    """The compiled program's `copy` (or `transpose`) instructions whose
+    result is as large as one layer of a pool half or larger."""
+    import re
+    layer, n_pages = math.prod(pool.shape[1:]), pool.shape[1]
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= bf16\[([\d,]+)\]\S* (copy|transpose)\(", line)
+        dims = list(map(int, m.group(1).split(","))) if m else []
+        if n_pages in dims and math.prod(dims) >= layer:
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_lfm2_decode_step_and_install_compile_for_v5e_without_pool_copies(
+        topo, monkeypatch, no_compile_cache):
+    """LFM2's decode step and install at serve_doc_reask_moe's shapes (two
+    attention layers, 3,073 pages of 16, KV 8 x 64, 8 slots x 256 pages):
+    both pools alias their outputs, no copy or transpose gives a pool half
+    (the `(L, N, page, 8, 64)` pool had eight in the step, four in the
+    install, and 0.95 GiB of scratch), and the temporaries stay under a
+    quarter of one."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.families import lfm2_moe
+    from benchmark.run import ROOT
+    from ray_tpu.llm import engine as E
+    from ray_tpu.models import routed
+    from ray_tpu.models.transformer import STATEFUL, init_params, zero_state
+
+    monkeypatch.setattr(routed, "grouped_path", lambda: "megablox")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2-24b-a2b-l9.json")) as f:
+        cfg = lfm2_moe.program_config(json.load(f))
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    on_chip = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+    B, page, P_ = 8, 16, 256
+    L, KV, D_ = cfg.count("*"), cfg.num_kv_heads, cfg.head_dim_
+    assert (L, KV, D_) == (2, 8, 64) and pa.pool_row(KV, D_) == "lanes"
+    pool = S(pa.pool_shape(L, 3073, page, KV, D_), cfg.dtype)
+    half = math.prod(pool.shape) * 2
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = {"slots": S((B, P_ + 4), jnp.int32),
+             "rng": S(key.shape, key.dtype),
+             "rec": on_chip(jax.eval_shape(lambda: [
+                 zero_state(cfg, k, B) for k in cfg.kinds if k in STATEFUL]))}
+
+    def decode_step(p, pk, pv, state, update):
+        return E._decode_fn(p, pk, pv, state, update, cfg, page, None)
+    step = jax.jit(decode_step, donate_argnums=(1, 2, 3)).lower(
+        params, pool, pool, state, S((B, P_ + 5), jnp.int32)).compile()
+    aliases = {int(o): int(i) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", step.as_text().split("\n", 1)[0])}
+    n_params = len(jax.tree.leaves(params))
+    assert aliases[0] == n_params and aliases[1] == n_params + 1
+    assert "gmm" in step.as_text()
+
+    def install_kv(pk, pv, ks, vs, pages):
+        return E._install_fn(pk, pv, ks, vs, pages, page, None)
+    programs = [step]
+    for rows in (4096, 64):
+        kv = S((L, rows, KV, D_), cfg.dtype)
+        programs.append(jax.jit(install_kv, donate_argnums=(0, 1)).lower(
+            pool, pool, kv, kv, S((P_,), jnp.int32)).compile())
+    for compiled in programs:
+        assert _pool_sized_copies(compiled, pool) == []
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 2 * half
+        # (The step reads 15.1 MiB: 8.0 MiB in HBM, 512-byte tuple headers
+        # 16 KiB apart by the compiler's buffer assignment; an install 0.)
+        assert mem.temp_size_in_bytes < half // 4
 
 
 # ---- the prefill kernel (ops/prefill_attention.py) ------------------------

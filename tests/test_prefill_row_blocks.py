@@ -151,10 +151,9 @@ def test_engine_counts_row_blocks_and_serves_the_same_tokens():
     assert (st["row_blocks_run"], st["row_blocks_dense"]) == (3 + 5, 4 + 5)
 
 
-def test_debug_stats_and_the_span_carry_row_blocks():
+def test_debug_stats_and_the_span_carry_row_blocks(captured_recorder):
     import asyncio
 
-    from ray_tpu._private import flight_recorder
     from ray_tpu.llm.serving import EngineReplica
 
     async def run():
@@ -162,14 +161,13 @@ def test_debug_stats_and_the_span_carry_row_blocks():
                            seed=0)
         await er.generate(list(range(1, 1101)), {"max_tokens": 2})
         return await er.debug_stats()
-    old = flight_recorder._recorder
-    flight_recorder._recorder = rec = flight_recorder.FlightRecorder()
-    try:
+    # (Not the process's own recorder, swapped bare: where an earlier test
+    # of the worker left a cluster up, its telemetry flush drains that one
+    # every second, and the compile below takes longer in a loaded run.)
+    with captured_recorder() as rec:
         stats = asyncio.run(run())
-        spans = [r["args"] for r in rec.drain()
+        spans = [r["args"] for r in rec.rows()
                  if r["cat"] == "request" and r["name"] == "prefill"]
-    finally:
-        flight_recorder._recorder = old
     assert stats["prefill"]["row_blocks_run"] == 3
     assert stats["prefill"]["row_blocks_dense"] == 4
     assert [a["row_blocks"] for a in spans] == [3]
