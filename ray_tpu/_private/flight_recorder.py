@@ -29,6 +29,12 @@ Categories ("plane" granularity, gated via config
                a model with recurrent layers: `recomputed` = tokens run
                again behind a state checkpoint, `checkpoints` = rows
                this prefill kept),
+               request:reply (enqueue -> the end of a request that
+               finished put on its stream; `first_us` to its first token
+               and, of the rest, `wait_us` blocked on decode steps and
+               `stop_us` stood still for other callers' admissions, over
+               `ticks` ticks of which `stops` admitted: the span form of
+               the `timing` its terminal item carries),
                request:cancelled, request:kv_broken, sp:gather.
                Per tick, sharing the tick number `n`
                (llm/tick_phases.py; the same boundaries feed

@@ -2009,6 +2009,8 @@ class LLMEngine:
         self._tick_events = []
         t0 = ph.to("admit")
         admitted = self._admit()
+        if admitted or self._prefilling:
+            ph.admitting += 1
         if self._prefilling:
             t1 = ph.to("chunk")
             ph.span("step:admit", t0, t1, admitted=admitted)

@@ -45,14 +45,32 @@ GCS sink.  Per request: ``request:lock_wait`` (entry → the replica's
 lock held and the request enqueued), ``request:admit`` (enqueue →
 admitted, with queue depth and the count of requests already decoding),
 ``prefill`` (with ``cached_tokens`` for prefix-cache hits and the tick
-``n`` that admitted it).  Per tick: the phases of ``tick_phases.py`` —
+``n`` that admitted it), and for a request that finished
+``request:reply`` (enqueue → its end put on its stream, with ``first_us``
+to its first token and, of the rest, ``wait_us`` blocked on decode steps
+and ``stop_us`` stood still for other callers' admissions, over ``ticks``
+ticks of which ``stops`` admitted).  Per tick: the phases of
+``tick_phases.py`` —
 ``tick`` and, tiling it and the stretch to the next one, ``tick:turn``,
 ``tick:expire``, ``tick:hop``, ``step:admit``, ``decode`` (with batch
 size and ``synced``, the slot rows written to the device before it;
 ``decode:prep`` / ``:dispatch`` / ``:wait``), ``sample_sync`` (the
 batched device→host sample pull), ``step:emit``, ``step:ahead`` (the
 next decode step sent off before ``step()`` returns, while no caller waits
-for the lock), ``tick:fan_out`` — whose cumulative nanoseconds ``debug_stats()["tick"]`` also serves.
+for the lock), ``tick:fan_out`` — whose cumulative nanoseconds
+``debug_stats()["tick"]`` also serves.
+
+A request's own account of its time: the replica snapshots those counters
+when a request is enqueued (S0), when its first token is put on its stream
+(S1) and when its end is (S2).  Every instant of a replica lies in one leaf
+phase, so the differences part the two stretches exactly, and the stream's
+terminal dict carries them as ``timing``, recorder on or off:
+``request_id``, ``lock_wait_ns`` (entry → S0), ``first_ns`` (S0 → S1),
+``total_ns`` (S0 → S2), ``first`` and ``rest`` (ns by leaf, which sum to
+``first_ns`` and ``total_ns - first_ns``), ``ticks`` and ``stops`` (ticks
+begun, and those of them that admitted, between S1 and S2),
+``prompt_tokens``, ``cached_tokens``, ``recomputed``.  A request that is
+cancelled, expires, is shed or fails has none.
 
 `run_open_loop` is the arrival-rate-driven (never closed-loop) load
 harness: it offers requests on a fixed schedule regardless of
@@ -70,11 +88,12 @@ from typing import Any, AsyncIterator, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from .._private import deadlines, diagnosis, flight_recorder
+from .._private import clocks, deadlines, diagnosis, flight_recorder
 from .._private.config import get_config
 from ..exceptions import (DeadlineExceededError, OverloadedError,
                           StreamBrokenError)
 from .engine import LLMEngine, SamplingParams
+from .tick_phases import LEAVES, STOP
 
 logger = logging.getLogger("ray_tpu.llm.serving")
 
@@ -82,13 +101,38 @@ __all__ = ["EngineReplica", "run_open_loop"]
 
 
 class _StreamEnd:
-    """Terminal stream item: generation finished."""
+    """Terminal stream item: generation finished.  `timing` is the
+    request's own account of its time (module docstring)."""
 
-    __slots__ = ("finish_reason", "n_tokens")
+    __slots__ = ("finish_reason", "n_tokens", "timing")
 
-    def __init__(self, finish_reason: str, n_tokens: int):
+    def __init__(self, finish_reason: str, n_tokens: int,
+                 timing: Dict[str, Any]):
         self.finish_reason = finish_reason
         self.n_tokens = n_tokens
+        self.timing = timing
+
+    def as_dict(self) -> Dict[str, Any]:
+        """The terminal dict every stream and collected reply ends with."""
+        return {"finish_reason": self.finish_reason,
+                "n_tokens": self.n_tokens, "timing": self.timing}
+
+
+def _timing(req, t_in: int, s0: dict, s1: dict, s2: dict) -> Dict[str, Any]:
+    """A finished request's record, from the `TickPhases` snapshots at its
+    enqueue (S0), first token (S1) and end (S2) and the stamp `t_in` of
+    its entry: two snapshots differ by exactly the time between them."""
+    return {"request_id": req.req_id,
+            "lock_wait_ns": s0["t"] - t_in,
+            "first_ns": s1["t"] - s0["t"],
+            "total_ns": s2["t"] - s0["t"],
+            "first": {p: s1["ns"][p] - s0["ns"][p] for p in LEAVES},
+            "rest": {p: s2["ns"][p] - s1["ns"][p] for p in LEAVES},
+            "ticks": s2["n"] - s1["n"],
+            "stops": s2["admitting"] - s1["admitting"],
+            "prompt_tokens": len(req.prompt),
+            "cached_tokens": req.prefix_len,
+            "recomputed": req.recomputed}
 
 
 class _EngineLock(asyncio.Lock):
@@ -306,6 +350,9 @@ class EngineReplica:
 
     def _ensure_loop(self) -> None:
         if self._loop_task is None or self._loop_task.done():
+            # From here on every instant lies in a leaf: the loop's first
+            # turn starts now, not when the new task first runs.
+            self._phases.to("turn")
             self._loop_task = asyncio.ensure_future(self._decode_loop())
         cfg = get_config()
         if cfg.diagnosis_enabled and (self._silence_task is None
@@ -327,8 +374,7 @@ class EngineReplica:
                 if (not meta.get("admitted") or meta.get("finished")
                         or meta.get("_silent")):
                     continue
-                last = max(meta.get("t_adm", now),
-                           meta.get("t_last_tok", 0.0))
+                last = meta["t_last_tok"]   # set at the first token too
                 if now - last > silence_s:
                     meta["_silent"] = True
                     diagnosis.record_anomaly(
@@ -344,7 +390,6 @@ class EngineReplica:
         so this loop (and the whole worker runtime) stays responsive."""
         loop = asyncio.get_running_loop()
         ph = self._phases
-        ph.to("turn")
         while True:
             try:
                 async with self._lock:
@@ -408,12 +453,12 @@ class EngineReplica:
                 continue
             if not meta.get("admitted"):
                 meta["admitted"] = True
-                meta["t_adm"] = time.monotonic()
-                rec.end("request", "request:admit", meta["t0"],
-                        id=rid.to_bytes(8, "little"),
-                        queued=self.engine.queue_depth,
-                        decoding=max(0, self.engine.active_requests - 1
-                                     + len(done_by_id)))
+                meta["s1"] = s1 = self._phases.snapshot()
+                rec.span_at("request", "request:admit", meta["s0"]["t"],
+                            s1["t"], id=rid.to_bytes(8, "little"),
+                            queued=self.engine.queue_depth,
+                            decoding=max(0, self.engine.active_requests - 1
+                                         + len(done_by_id)))
             meta["t_last_tok"] = time.monotonic()
             q = self._waiters.get(rid)
             if q is not None:
@@ -443,42 +488,60 @@ class EngineReplica:
                     continue
                 self._completed += 1
                 self._tokens_out += len(req.out)
-                # SERVICE time (admission -> finish), not enqueue ->
+                s2 = self._phases.snapshot()
+                timing = _timing(req, meta["t_in"], meta["s0"], meta["s1"],
+                                 s2)
+                rest = timing["rest"]
+                rec.span_at("request", "request:reply", meta["s0"]["t"],
+                            s2["t"], id=rid.to_bytes(8, "little"),
+                            first_us=timing["first_ns"] // 1000,
+                            wait_us=rest["wait"] // 1000,
+                            stop_us=sum(rest[p] for p in STOP) // 1000,
+                            ticks=timing["ticks"], stops=timing["stops"])
+                # SERVICE time (first token -> finish), not enqueue ->
                 # finish: folding queue wait into the EMA would make
                 # the shed estimate grow quadratically with depth.
-                dur = time.monotonic() - meta.get("t_adm",
-                                                  meta["t_mono"])
+                dur = (timing["total_ns"] - timing["first_ns"]) / 1e9
                 self._req_s_ema += 0.2 * (dur - self._req_s_ema)
                 if q is not None:
                     q.put_nowait(_StreamEnd(req.finish_reason,
-                                            len(req.out)))
+                                            len(req.out), timing))
 
     # ------------------------------------------------------------ streams --
-    def _track(self, rid: int, deadline: Optional[float]) -> asyncio.Queue:
-        """Under the lock, right after the engine took request `rid`: its
-        stream's queue and metadata (`t0` opens its `request:admit`
-        span), and the decode loop woken."""
+    def _track(self, rid: int, deadline: Optional[float], t_in: int
+               ) -> asyncio.Queue:
+        """Under the lock, right after the engine took request `rid`, which
+        entered at the stamp `t_in`: its stream's queue and metadata, and
+        the decode loop woken.  The snapshot `s0` is the request's
+        enqueue: its stamp closes `request:lock_wait` and opens
+        `request:admit` and `request:reply`."""
         q: asyncio.Queue = asyncio.Queue()
         self._waiters[rid] = q
-        self._meta[rid] = {"deadline": deadline,
-                           "t0": flight_recorder.recorder().begin(),
-                           "t_mono": time.monotonic(),
-                           "admitted": False, "finished": False}
         self._enqueued += 1
         self._ensure_loop()
+        s0 = self._phases.snapshot()
+        # The wait for the tick that held the lock: the part of a
+        # client's time to first token that neither the engine's
+        # `request:admit` nor the serve library owns.
+        flight_recorder.recorder().span_at(
+            "request", "request:lock_wait", t_in, s0["t"],
+            id=rid.to_bytes(8, "little"), queued=self.engine.queue_depth)
+        self._meta[rid] = {"deadline": deadline, "t_in": t_in, "s0": s0,
+                           "s1": None, "admitted": False, "finished": False}
         self._wake.set()
         return q
 
-    async def _stream(self, prompt_tokens: Optional[Sequence[int]],
-                      opts: Optional[dict], *, external: Optional[tuple]
-                      = None, cache_prompt: Optional[Sequence[int]] = None
-                      ) -> AsyncIterator[Any]:
-        """Shared producer for stream_generate / generate / decode: yields
-        int tokens then one `_StreamEnd`.  Typed failures (shed, deadline,
-        engine rejection) raise out of the first `anext`."""
+    async def _enqueue(self, prompt_tokens: Optional[Sequence[int]],
+                       opts: Optional[dict], *,
+                       external: Optional[tuple] = None,
+                       cache_prompt: Optional[Sequence[int]] = None):
+        """Hand the engine a request (a prompt, or a shipped KV blob and
+        its first token) under the replica's lock: (request id, its
+        stream's queue).  Typed failures (shed, deadline, engine
+        rejection) raise."""
         params = self._params(opts)
         deadline = deadlines.get()
-        t_in = flight_recorder.recorder().begin()
+        t_in = clocks.mono_ns()
         async with self._lock:
             # Shed check INSIDE the lock: concurrent arrivals during a
             # decode tick must each see the true queue depth, not a
@@ -490,24 +553,36 @@ class EngineReplica:
                     blob, first, params, prompt_tokens=cache_prompt)
             else:
                 rid = self.engine.add_request(list(prompt_tokens), params)
-            # The wait for the tick that held the lock: the part of a
-            # client's time to first token that neither the engine's
-            # `request:admit` nor the serve library owns.
-            flight_recorder.recorder().end(
-                "request", "request:lock_wait", t_in,
-                id=rid.to_bytes(8, "little"),
-                queued=self.engine.queue_depth)
-            q = self._track(rid, deadline)
+            return rid, self._track(rid, deadline, t_in)
+
+    async def _items(self, rid: int, q: asyncio.Queue) -> AsyncIterator[Any]:
+        """An enqueued request's stream: int tokens, then the terminal
+        dict (`_StreamEnd.as_dict`); a typed failure put on the queue
+        raises.  Releases the request however it ends."""
         try:
             while True:
                 item = await q.get()
                 if isinstance(item, BaseException):
                     raise item
-                yield item
                 if isinstance(item, _StreamEnd):
+                    yield item.as_dict()
                     return
+                yield item
         finally:
             await self._release(rid)
+
+    @staticmethod
+    async def _whole(items: AsyncIterator[Any]) -> Dict[str, Any]:
+        """A stream drained: {"tokens": [...]} and its terminal dict's
+        keys (`finish_reason`, `n_tokens`, `timing`)."""
+        out: List[int] = []
+        end: Dict[str, Any] = {"finish_reason": ""}
+        async for item in items:
+            if isinstance(item, dict):
+                end = item
+            else:
+                out.append(item)
+        return {"tokens": out, **end}
 
     async def _release(self, rid: int) -> None:
         meta = self._meta.pop(rid, None)
@@ -526,18 +601,16 @@ class EngineReplica:
                               opts: Optional[dict] = None
                               ) -> AsyncIterator[Any]:
         """Async generator: int tokens as they decode, then one terminal
-        dict ``{"finish_reason": ..., "n_tokens": ...}``.  This is the
-        method the serve router dispatches with
-        ``num_returns="streaming"``; each yielded item becomes its own
-        object the client can consume while decode continues."""
-        it = self._stream(prompt_tokens, opts)
+        dict ``{"finish_reason": ..., "n_tokens": ..., "timing": {...}}``
+        (`timing`: where the replica's time went while it held the
+        request, module docstring).  This is the method the serve router
+        dispatches with ``num_returns="streaming"``; each yielded item
+        becomes its own object the client can consume while decode
+        continues."""
+        it = self._items(*await self._enqueue(prompt_tokens, opts))
         try:
             async for item in it:
-                if isinstance(item, _StreamEnd):
-                    yield {"finish_reason": item.finish_reason,
-                           "n_tokens": item.n_tokens}
-                else:
-                    yield item
+                yield item
         finally:
             # async-for does not close the inner generator on early exit;
             # close it NOW so an abandoned stream cancels its request (and
@@ -547,15 +620,10 @@ class EngineReplica:
     async def generate(self, prompt_tokens: Sequence[int],
                        opts: Optional[dict] = None) -> Dict[str, Any]:
         """Non-streaming completion over the same continuous-batching
-        machinery: {"tokens": [...], "finish_reason": ...}."""
-        out: List[int] = []
-        reason = ""
-        async for item in self._stream(prompt_tokens, opts):
-            if isinstance(item, _StreamEnd):
-                reason = item.finish_reason
-            else:
-                out.append(item)
-        return {"tokens": out, "finish_reason": reason}
+        machinery: ``{"tokens": [...], "finish_reason": ..., "n_tokens":
+        ..., "timing": {...}}``."""
+        return await self._whole(self._items(
+            *await self._enqueue(prompt_tokens, opts)))
 
     async def __call__(self, prompt_tokens: Sequence[int],
                        opts: Optional[dict] = None) -> List[int]:
@@ -648,50 +716,33 @@ class EngineReplica:
         while this replica decodes.  Tokens are collected with
         :meth:`collect` / :meth:`collect_stream`."""
         blob = await self._resolve_handoff(handoff)
-        params = self._params(handoff.get("opts"))
-        deadline = deadlines.get()
-        async with self._lock:
-            self._maybe_shed(deadline)
-            rid = self.engine.add_external_request(
-                blob, handoff["first"], params,
-                prompt_tokens=handoff.get("prompt"))
-            self._track(rid, deadline)
+        rid, _ = await self._enqueue(
+            None, handoff.get("opts"), external=(blob, handoff["first"]),
+            cache_prompt=handoff.get("prompt"))
         return rid
 
     async def collect(self, rid: int) -> Dict[str, Any]:
         """Drain an admitted request's stream to completion:
-        ``{"tokens": [...], "finish_reason": ...}``."""
-        out: List[int] = []
-        reason = ""
-        async for item in self.collect_stream(rid):
-            if isinstance(item, dict):
-                reason = item["finish_reason"]
-            else:
-                out.append(item)
-        return {"tokens": out, "finish_reason": reason}
+        ``{"tokens": [...], "finish_reason": ..., "n_tokens": ...,
+        "timing": {...}}``."""
+        return await self._whole(self.collect_stream(rid))
 
     async def collect_stream(self, rid: int):
         """Async generator over an admitted request: int tokens, then one
-        terminal ``{"finish_reason", "n_tokens"}`` dict.  Dispatch with
-        ``num_returns="streaming"`` for live token streaming — the
-        steady-state per-token path is engine tick → waiter queue →
+        terminal ``{"finish_reason", "n_tokens", "timing"}`` dict.
+        Dispatch with ``num_returns="streaming"`` for live token streaming
+        — the steady-state per-token path is engine tick → waiter queue →
         worker→owner stream frames: no GCS work per token."""
         q = self._waiters.get(rid)
         if q is None:
             from ..exceptions import RayError
             raise RayError(f"unknown or already-collected request {rid}")
+        it = self._items(rid, q)
         try:
-            while True:
-                item = await q.get()
-                if isinstance(item, BaseException):
-                    raise item
-                if isinstance(item, _StreamEnd):
-                    yield {"finish_reason": item.finish_reason,
-                           "n_tokens": item.n_tokens}
-                    return
+            async for item in it:
                 yield item
         finally:
-            await self._release(rid)
+            await it.aclose()           # as `stream_generate`
 
     async def decode_handoff(self, handoff: dict) -> Dict[str, Any]:
         """Decode half over a handoff (direct arena pull): admit through
@@ -707,15 +758,9 @@ class EngineReplica:
         """Decode half: admit a shipped KV blob through the SAME
         admission queue as local requests (deadline-aware, shed-bounded)
         and decode to completion."""
-        out: List[int] = []
-        reason = ""
-        async for item in self._stream(None, opts, external=(
-                kv_blob, first_token), cache_prompt=prompt_tokens):
-            if isinstance(item, _StreamEnd):
-                reason = item.finish_reason
-            else:
-                out.append(item)
-        return {"tokens": out, "finish_reason": reason}
+        return await self._whole(self._items(*await self._enqueue(
+            None, opts, external=(kv_blob, first_token),
+            cache_prompt=prompt_tokens)))
 
     # ------------------------------------------ cross-host paged KV (SP) ---
     async def prefill_paged_chunk(self, req: dict) -> dict:
@@ -793,12 +838,13 @@ class EngineReplica:
         node's pool pages."""
         params = self._params(handoff.get("opts"))
         deadline = deadlines.get()
+        t_in = clocks.mono_ns()
         async with self._lock:
             self._maybe_shed(deadline)
             rid = self.engine.add_paged_request(
                 handoff["parts"], handoff["len"], handoff["first"],
                 params, prompt_tokens=handoff.get("prompt"))
-            self._track(rid, deadline)
+            self._track(rid, deadline, t_in)
         return rid
 
     async def decode_paged(self, handoff: dict) -> Dict[str, Any]:

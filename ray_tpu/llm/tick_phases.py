@@ -9,7 +9,10 @@ opens the next at ONE monotonic stamp.  That one call feeds three sinks:
   counters    cumulative ns per leaf (`snapshot()`, served as
               `EngineReplica.debug_stats()["tick"]`): exact window totals
               with no ring to overflow, counted whether or not the flight
-              recorder is on;
+              recorder is on.  Two snapshots differ, leaf by leaf, by
+              exactly the time between their stamps `t`, so the replica
+              takes three a request and every reply carries where its own
+              time went (`serving.py`: the terminal item's `timing`);
   spans       the flight recorder's `request` category (the names in
               `_SPAN` below; `_private/flight_recorder.py` lists them
               all), every span of one tick carrying the tick number `n`;
@@ -41,6 +44,11 @@ One thread at a time drives this object: the loop's thread, or — while the
 loop awaits `step()` — the executor's.  Engine work outside a tick
 (`prefill_only`, `sample_first`: another holder of the replica's lock)
 records its spans as before and counts as the loop's `turn`.
+
+`admitting` counts the ticks that stopped the running streams for somebody
+else's prompt: those in which `_admit` gave a request a slot or a chunked
+prefill advanced.  The window's `STOP` nanoseconds over it are what one
+admission costs a stream that is decoding.
 """
 
 from __future__ import annotations
@@ -54,6 +62,8 @@ from .._private import clocks, flight_recorder
 LEAVES = ("idle", "turn", "expire", "hop", "admit", "prefill",
           "sample_sync", "chunk", "prep", "dispatch", "wait", "emit",
           "ahead", "fan_out")
+# The leaves in which a running stream stands still for an admission.
+STOP = ("admit", "prefill", "sample_sync", "chunk")
 
 # Leaf -> the span a finished piece of it is recorded as.  The others are
 # self time of a parent span, or spans their caller records with the
@@ -68,6 +78,7 @@ class TickPhases:
 
     def __init__(self):
         self.n = 0                      # ticks begun
+        self.admitting = 0              # of them, ticks that admitted
         self.ns: Dict[str, int] = dict.fromkeys(LEAVES, 0)
         self.in_tick = False            # a replica's loop drives this tick
         self.in_step = False            # inside LLMEngine.step()
@@ -138,10 +149,12 @@ class TickPhases:
 
     # ---------------------------------------------------------- counters --
     def snapshot(self) -> Dict[str, Any]:
-        """Ticks begun and cumulative ns per leaf, the open phase counted
-        up to now: two snapshots bracket a window exactly."""
+        """Ticks begun, those of them that admitted, and cumulative ns per
+        leaf with the open phase counted up to the stamp `t`: two snapshots
+        bracket a window exactly, `sum(ns)` apart by their `t`s."""
         ns = dict(self.ns)
         cur, since = self._open
+        t = clocks.mono_ns()
         if cur is not None:
-            ns[cur] += clocks.mono_ns() - since
-        return {"n": self.n, "ns": ns}
+            ns[cur] += t - since
+        return {"n": self.n, "admitting": self.admitting, "t": t, "ns": ns}

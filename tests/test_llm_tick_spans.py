@@ -3,7 +3,10 @@ EngineReplica's decode loop and LLMEngine.step() stamp tile every tick,
 as flight-recorder spans and as cumulative counters taken at the same
 stamps; a request's spans share its id, a tick's spans its number; the
 spans the benchmark's readers already depend on keep their names,
-arguments and extents.  Tiny engine on the CPU.
+arguments and extents; every reply carries its own account of its time
+(`timing`), parted exactly by those counters.  Tiny engine on the CPU.
+The benchmark's readers of that account have their own cases in
+`benchmark/tests/test_request_readers.py`, which run here too.
 """
 
 import asyncio
@@ -16,9 +19,10 @@ from collections import defaultdict
 
 import pytest
 
+from benchmark.tests.test_request_readers import *         # noqa: F401,F403
 from ray_tpu._private import flight_recorder
 from ray_tpu.llm import EngineReplica
-from ray_tpu.llm.tick_phases import LEAVES, _SPAN
+from ray_tpu.llm.tick_phases import LEAVES, STOP, _SPAN
 
 pytestmark = pytest.mark.serving
 
@@ -53,10 +57,16 @@ class _Raw(flight_recorder.FlightRecorder):
         return flight_recorder.FlightRecorder.drain(self)
 
 
+LONG = [11, 12, 13]         # wave 3's request that decodes through two
+INSIDE = [[12, 13, 14], list(range(50, 70))]    # ... later admissions
+
+
 def _serve(rec, *, prefill_chunk=None):
-    """Two waves of requests through one replica, idle in between; returns
-    the replica's stats before, between and after, and what each request
-    streamed with the wall times of its send and first token."""
+    """Three waves of requests through one replica, idle in between;
+    returns the replica's stats before, after the first wave and at the
+    end, and what each request streamed with the wall times of its send
+    and first token.  The third wave is one long reply and two requests
+    sent while it decodes: each when the one before has its first token."""
     old = flight_recorder._recorder
     flight_recorder._recorder = rec
     base = list(range(1, 13))
@@ -67,15 +77,22 @@ def _serve(rec, *, prefill_chunk=None):
         stats = [await er.debug_stats()]
         records = []
 
-        async def one(prompt):
+        async def one(prompt, opts=None, then=()):
             r = {"due": time.time(), "sent": time.time(), "token_times": [],
-                 "finish": None, "error": None, "cut": False}
+                 "finish": None, "error": None, "cut": False,
+                 "prompt": prompt}
             records.append(r)
-            async for item in er.stream_generate(prompt):
+            after = None
+            async for item in er.stream_generate(prompt, opts):
                 if isinstance(item, dict):
                     r["finish"] = item
                 else:
                     r["token_times"].append(time.time())
+                    if then and after is None:
+                        after = asyncio.ensure_future(
+                            one(then[0], then=then[1:]))
+            if after is not None:
+                await after
 
         # buckets: 8 (three prompts), 16, 32; then a prefix-cache hit whose
         # suffix is the first run of its bucket
@@ -86,6 +103,9 @@ def _serve(rec, *, prefill_chunk=None):
             await asyncio.gather(*[one(p) for p in wave])
             await asyncio.sleep(0.05)           # the loop goes idle
             stats.append(await er.debug_stats())
+        await one(LONG, {"max_tokens": 40}, then=INSIDE)
+        await asyncio.sleep(0.05)
+        stats[-1] = await er.debug_stats()
         return stats, records
 
     try:
@@ -213,7 +233,7 @@ def test_a_requests_spans_share_its_id_and_prefill_names_its_tick(run):
             run, "request:lock_wait", "request:admit", "prefill"):
         assert len(rid) == 8, name
         by_id[rid].setdefault(name, []).append((t0, t1, args))
-    assert len(by_id) == len(run["records"]) == 8
+    assert len(by_id) == len(run["records"]) == 11
     fan_outs = {a["n"]: (t0, t1)
                 for t0, t1, _, _, a in _spans(run, "tick:fan_out")}
     holders = _spans(run, "step:admit", "step:chunk")
@@ -246,6 +266,201 @@ def test_new_program_marks_each_buckets_first_prefill(run):
     # chunks of 8 only ever meet the bucket of 8, whole or as a suffix
     assert sum(flagged) == len(seen) >= (2 if run["kind"] == "chunked"
                                          else 4) and 0 in flagged
+
+
+# ------------------------------------------ a request's own account ----
+
+def _wave(run, wave):
+    return [run["records"][:6], run["records"][6:8],
+            run["records"][8:]][wave]
+
+
+def _admitting_ticks(run):
+    """Tick number -> the start of its `step:admit`, for the ticks that
+    gave a request a slot or advanced a chunked prefill."""
+    chunked = {a["n"] for _, _, _, _, a in _spans(run, "step:chunk")}
+    return {a["n"]: t0 for t0, _, _, _, a in _spans(run, "step:admit")
+            if a["admitted"] > 0 or a["n"] in chunked}
+
+
+def _check_timing(rec, n_tokens, shipped=False):
+    """The terminal dict as it was, and in it a `timing` whose leaves part
+    its two stretches to the nanosecond."""
+    end = rec["finish"]
+    assert end["finish_reason"] == "length" and end["n_tokens"] == n_tokens
+    assert set(end) == {"finish_reason", "n_tokens", "timing"}
+    t = end["timing"]
+    assert set(t) == {
+        "request_id", "lock_wait_ns", "first_ns", "total_ns", "first",
+        "rest", "ticks", "stops", "prompt_tokens", "cached_tokens",
+        "recomputed"}
+    assert tuple(t["first"]) == tuple(t["rest"]) == LEAVES
+    ints = [t["lock_wait_ns"], t["first_ns"], t["total_ns"], t["ticks"],
+            t["stops"], *t["first"].values(), *t["rest"].values()]
+    assert all(type(x) is int and x >= 0 for x in ints), t
+    assert sum(t["first"].values()) == t["first_ns"] > 0
+    assert sum(t["rest"].values()) == t["total_ns"] - t["first_ns"]
+    assert t["prompt_tokens"] == len(rec["prompt"])
+    # a tick a token, but for the first two: the tick that admits a request
+    # also runs its first decode step, before the first token is fanned out
+    # (unless that step was sent ahead, for the others, before it came)
+    assert t["ticks"] in (n_tokens - 2, n_tokens - 1)
+    assert t["rest"]["wait"] > 0
+    assert t["first"]["wait"] > 0 and t["first"]["prefill"] > 0
+    # a shipped prefill is installed (still the leaf `prefill`) and brings
+    # its first token with it
+    assert (t["first"]["sample_sync"] == 0) == shipped
+    return t
+
+
+@pytest.mark.parametrize("wave", [0, 1, 2])
+def test_timing_parts_each_reply_exactly(run, wave):
+    for rec in _wave(run, wave):
+        t = _check_timing(rec, 40 if rec["prompt"] == LONG else 5)
+        # the server's account lies inside what the client's clock saw
+        seen = rec["token_times"][-1] - rec["sent"]
+        assert t["lock_wait_ns"] + t["total_ns"] <= (seen + 0.05) * 1e9
+    hit = _wave(run, 1)[0]["finish"]["timing"]
+    assert hit["cached_tokens"] == 8 and hit["recomputed"] == 0
+    assert len({r["finish"]["timing"]["request_id"]
+                for r in run["records"]}) == 11
+
+
+def test_request_reply_shares_the_id_and_the_stamps(run):
+    spans = {name: {rid: (t0, t1, args) for t0, t1, _, rid, args
+                    in _spans(run, name)}
+             for name in ("request:lock_wait", "request:admit",
+                          "request:reply")}
+    assert all(len(by_id) == 11 for by_id in spans.values())
+    for rec in run["records"]:
+        t = rec["finish"]["timing"]
+        rid = t["request_id"].to_bytes(8, "little")
+        w0, w1, _ = spans["request:lock_wait"][rid]
+        a0, a1, _ = spans["request:admit"][rid]
+        r0, r1, args = spans["request:reply"][rid]
+        # S0 closes the lock's wait and opens both other spans; S1 closes
+        # `request:admit`; S2 closes the reply
+        assert w1 == a0 == r0 and w1 - w0 == t["lock_wait_ns"]
+        assert a1 - a0 == t["first_ns"] and r1 - r0 == t["total_ns"]
+        assert args == {
+            "first_us": t["first_ns"] // 1000,
+            "wait_us": t["rest"]["wait"] // 1000,
+            "stop_us": sum(t["rest"][p] for p in STOP) // 1000,
+            "ticks": t["ticks"], "stops": t["stops"]}
+
+
+def test_stops_are_the_admitting_ticks_inside_the_reply(run):
+    admitting = _admitting_ticks(run)
+    s1 = {rid: t1 for _, t1, _, rid, _ in _spans(run, "request:admit")}
+    s2 = {rid: t1 for _, t1, _, rid, _ in _spans(run, "request:reply")}
+    for rec in run["records"]:
+        t = rec["finish"]["timing"]
+        rid = t["request_id"].to_bytes(8, "little")
+        inside = [n for n, at in admitting.items() if s1[rid] < at <= s2[rid]]
+        assert t["stops"] == len(inside), (rec["prompt"], inside)
+        if not inside:
+            assert sum(t["rest"][p] for p in STOP) < t["rest"]["wait"]
+    long = next(r for r in run["records"] if r["prompt"] == LONG)
+    # two admissions inside it; chunked, the 20-token prompt takes three
+    # ticks, the first of them the one that gave it its slot
+    assert long["finish"]["timing"]["stops"] == (
+        4 if run["kind"] == "chunked" else 2)
+    assert long["finish"]["timing"]["rest"]["prefill"] > 0
+
+
+def test_admitting_counts_the_ticks_that_admitted(run):
+    first, mid, last = (s["tick"] for s in run["stats"])
+    assert first["admitting"] == 0 < mid["admitting"] < last["admitting"]
+    assert last["admitting"] == len(_admitting_ticks(run)) < last["n"]
+    chunks = {a["n"] for _, _, _, _, a in _spans(run, "step:chunk")}
+    plain = {a["n"] for _, _, _, _, a in _spans(run, "step:admit")
+             if a["admitted"] > 0}
+    assert bool(chunks - plain) == (run["kind"] == "chunked")
+    # a snapshot says up to when it counted: its leaves sum to the time
+    # since the replica's loop began
+    assert last["t"] - mid["t"] == sum(last["ns"].values()) \
+        - sum(mid["ns"].values())
+
+
+METHODS = ("stream_generate", "generate", "collect_stream", "collect")
+
+
+@pytest.fixture(scope="module", params=["recorder_on", "recorder_off"])
+def ends(request):
+    """One reply through each public way to a whole reply, then a stream
+    its consumer abandons and a request whose deadline passes mid-decode;
+    the replica serves on after both."""
+    from ray_tpu._private import deadlines
+    from ray_tpu.exceptions import DeadlineExceededError
+    rec = _Raw(enabled=request.param == "recorder_on")
+    old = flight_recorder._recorder
+    flight_recorder._recorder = rec
+    prompt = [4, 5, 6, 7]
+
+    async def main():
+        er = EngineReplica("tiny", max_batch=4, max_len=512, page_size=8,
+                           max_tokens=5)
+        out = {}
+
+        async def streamed(gen):
+            items = [x async for x in gen]
+            return dict(items[-1], tokens=items[:-1])
+
+        async def handed_off():
+            blob, first = await er.prefill(prompt)
+            return await er.admit_external(
+                {"blob": blob, "first": first, "prompt": prompt})
+
+        out["stream_generate"] = await streamed(er.stream_generate(prompt))
+        out["generate"] = await er.generate(prompt)
+        out["collect_stream"] = await streamed(
+            er.collect_stream(await handed_off()))
+        out["collect"] = await er.collect(await handed_off())
+
+        gen = er.stream_generate([9, 8, 7], {"max_tokens": 400})
+        out["abandoned"] = [await gen.__anext__() for _ in range(2)]
+        await gen.aclose()
+        token = deadlines.set_current(time.time() + 0.15)
+        try:
+            with pytest.raises(DeadlineExceededError, match="mid-decode"):
+                await er.generate([6, 6, 6], {"max_tokens": 480})
+        finally:
+            deadlines.reset(token)
+        out["after"] = await er.generate(prompt)
+        out["stats"] = await er.debug_stats()
+        return out
+
+    try:
+        out = asyncio.run(main())
+    finally:
+        flight_recorder._recorder = old
+    out.update(prompt=prompt, raw=rec.raw, on=rec.enabled)
+    return out
+
+
+@pytest.mark.parametrize("method", METHODS + ("after",))
+def test_every_whole_reply_carries_its_timing(ends, method):
+    """... in the terminal dict of a stream and beside the tokens of a
+    collected reply, whether or not the recorder is on."""
+    reply = dict(ends[method])
+    assert len(reply.pop("tokens")) == 5
+    t = _check_timing({"finish": reply, "prompt": ends["prompt"]}, 5,
+                      shipped=method.startswith("collect"))
+    assert t["stops"] == 0 and t["recomputed"] == 0
+
+
+def test_no_timing_and_no_span_for_a_reply_that_did_not_finish(ends):
+    st = ends["stats"]
+    assert len(ends["abandoned"]) == 2
+    assert (st["cancelled"], st["expired"], st["completed"]) == (1, 1, 5)
+    # both gave their pages back (cached pages count as free)
+    assert st["active"] == 0 and st["kv_pages_free"] == st["kv_pages_total"]
+    replies = [r for r in ends["raw"] if r[3] == "request:reply"]
+    assert len(replies) == (5 if ends["on"] else 0)
+    assert bool(ends["raw"]) == ends["on"]
+    if ends["on"]:
+        admits = {r[4] for r in ends["raw"] if r[3] == "request:admit"}
+        assert len(admits) == 7 and {r[4] for r in replies} < admits
 
 
 # ------------------------------------------- what the readers depend on ----
