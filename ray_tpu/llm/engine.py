@@ -57,9 +57,10 @@ from jax.sharding import NamedSharding, PartitionSpec
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
 from ..models.transformer import (ROW_BLOCK, STATEFUL, TransformerConfig,
-                                  decoder_block, embed_tokens, init_params,
-                                  lm_logits, param_logical_axes, rope_angles,
-                                  row_blocks, run_pattern, scan_blocks,
+                                  blocks_to_run, decoder_block, embed_tokens,
+                                  init_params, lm_logits, over_rows,
+                                  param_logical_axes, rope_angles, row_blocks,
+                                  run_pattern, scan_blocks,
                                   state_bytes, state_chunk, zero_state)
 from ..ops.paged_attention import (decode_path, head_rows,
                                    paged_decode_attention, pool_row, pool_rows,
@@ -187,13 +188,16 @@ def _xla_prefill_attention(q, k, v, mask, cfg: TransformerConfig):
 
 
 def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
-                    cached=None):
+                    cached=None, blocks=None, row_block: int = ROW_BLOCK):
     """The one place a prefill's attention form is chosen (`_prefill_path`).
     For `rows` padded rows of which `length` are real and, in the suffix
     form, `cached` = (pool_k, pool_v, pages, prefix_len, page) — the slot's
     page row, whose first `prefix_len` tokens precede row 0 — returns
     (attend, per_layer) for `scan_blocks`: `attend(q, k, v, *at)` gives
-    (o, the layer's new cache rows (k[0], v[0]))."""
+    (o, the layer's new cache rows (k[0], v[0])).  `blocks`, `row_block`
+    (`over_rows`'s): the suffix form's built scores are row-wise in their
+    QUERIES, so they are built for the query blocks that hold a real row, a
+    block at a time against all keys, and o is zeros in the others."""
     pool = per_layer = ()
     if cached is None:
         path = _prefill_path(cfg, rows, kv_sharding)
@@ -231,9 +235,12 @@ def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
         def scores(q, k, v, pk, pv):        # pk, pv: (N, page, *row)
             ck = head_rows(pk[pages], *heads).reshape(T, *heads)
             cv = head_rows(pv[pages], *heads).reshape(T, *heads)
-            return _xla_prefill_attention(
-                q, jnp.concatenate([ck[None], k], axis=1),
-                jnp.concatenate([cv[None], v], axis=1), mask, cfg)
+            keys = jnp.concatenate([ck[None], k], axis=1)
+            values = jnp.concatenate([cv[None], v], axis=1)
+            return over_rows(
+                lambda q, mask: (_xla_prefill_attention(
+                    q, keys, values, mask, cfg),),
+                [(q, 1), (mask, 0)], (q,), blocks, row_block)[0]
 
     def attend(q, k, v, *at):
         return scores(q, k, v, *at), (k[0], v[0])   # drop the B=1 dim
@@ -260,7 +267,7 @@ def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
 
 def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
                       length, ckpt, row, cfg: TransformerConfig, page: int,
-                      every: int):
+                      every: int, row_block: int = ROW_BLOCK):
     """A prefill of a pattern with recurrent layers: ONE form for a whole
     prompt and for a suffix, since both run the recurrence from a given
     state.  The rows `tokens` (1, Sb), of which `length` are real, follow
@@ -270,16 +277,21 @@ def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     with prefix_len 0).  Returns (last-token logits, the attention layers'
     ks, vs (nA, Sb, KV, D), the state after `length` rows, the state after
     every `every` rows (the stateful mixers' `every`), the experts every row
-    chose (nE, Sb, K))."""
+    chose (nE, Sb, K)).  Where the bucket is run by row blocks (`run_pattern`
+    says when) what lies past the last block that holds a real row is
+    zeros, as `_prefill_fn` has it: ks, vs, the checkpoints at boundaries
+    past the prompt (`_install_state` gives those to the scratch row), the
+    experts chosen.  `row_block`: the tests'."""
     Sb = tokens.shape[1]
     x = embed_tokens(params, tokens, cfg)
     cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
     attend, per_layer = _prefill_attend(
-        cfg, Sb, length, None, (pool_k, pool_v, pages, prefix_len, page))
+        cfg, Sb, length, None, (pool_k, pool_v, pages, prefix_len, page),
+        blocks_to_run(length, Sb, row_block, every), row_block)
     rec = [{k: c[k][row][None] for k in c} for c in ckpt]
     x, (ks, vs), rec, kept, _, chosen = run_pattern(
         params["layers"], x, cos, sin, attend, cfg, rec, per_layer,
-        length=length, every=every)
+        length=length, every=every, row_block=row_block)
     return (lm_logits(params, x[0, length - 1], cfg), ks, vs, rec, kept,
             chosen)
 
@@ -1461,25 +1473,26 @@ class LLMEngine:
         """The attention form of the last prefill (`path`: "kernel" or
         "xla"), how many prefills took each, the key blocks they ran
         beside the blocks of the dense S x S form (`kv_blocks_dense`), and
-        the row blocks a layer's two halves ran beside those of the padded
-        bucket (`row_blocks_dense`; the same below 2,048 padded rows)."""
+        the row blocks a layer's row-wise halves ran beside those of the
+        padded bucket (`row_blocks_dense`; the same below 2,048 padded
+        rows), a dense decoder's and a pattern's alike."""
         return dict(self._prefill_stats)
 
     def _count_prefill(self, rows: int, padded: int,
                        prefix_len: Optional[int] = None) -> None:
         """Host-side count of one prefill of `rows` real rows in a
         `padded` bucket (`prefix_len` given: the suffix form), from the
-        shapes alone: nothing is read back."""
+        shapes alone, by the rule the programs themselves go by
+        (`models/transformer.py:row_blocks`; a sequence-parallel prefill
+        gives its halves no length): nothing is read back."""
         from ..ops.prefill_attention import kv_blocks
         row = () if prefix_len is None else (self.page, self.pages_per_slot)
         path = _prefill_path(self.cfg, padded, self._kv_shd, *row) \
             if self.sp_degree == 1 else "xla"
         run, dense = kv_blocks(rows, padded, prefix_len or 0,
                                math.prod(row) if row else 0)
-        # The halves go by row blocks where `scan_blocks` is given the
-        # length: not a pattern's prefill, not a sequence-parallel one.
-        by_rows = self.sp_degree == 1 and not self.cfg.pattern
-        rows_run, rows_dense = row_blocks(rows if by_rows else None, padded)
+        rows_run, rows_dense = row_blocks(
+            rows if self.sp_degree == 1 else None, padded, every=self._every)
         self._prefill_ran = {"path": path,
                              "kv_blocks": run if path == "kernel" else dense,
                              "row_blocks": rows_run}

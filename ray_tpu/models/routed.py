@@ -65,8 +65,8 @@ def scores(lp, x):
 
 
 def route(lp, x, dims: RoutedDims):
-    """x (N, E) -> the chosen experts idx (N, K) int32 and their weights
-    (N, K) float32."""
+    """x (..., E) -> the chosen experts idx (..., K) int32 and their weights
+    (..., K) float32."""
     s = scores(lp, x)
     _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
                            dims.top_k)
@@ -107,11 +107,16 @@ def _grouped(rows, weights, sizes):
 
 
 def held_experts(lp, u, idx, w, dims: RoutedDims, real=None):
-    """The held experts' part of the weighted sum: u (N, latent or hidden),
-    idx and w (N, K) from `route` -> (out like u, counts (2,) int32: distinct
-    held experts that got a row, and (token, expert) rows computed).  A row
-    of u that is not `real` (N,) (padding, a slot that is not live) goes to
-    no expert: it costs no product and touches no weights."""
+    """The held experts' part of the weighted sum: u (..., latent or
+    hidden), idx and w (..., K) from `route` -> (out like u, counts (2,)
+    int32: distinct held experts that got a row, and (token, expert) rows
+    computed).  A row of u that is not `real` (...) (padding, a slot that is
+    not live) goes to no expert: it costs no product and touches no
+    weights.  ONE sort and two grouped products over all the rows."""
+    shape = u.shape
+    u, idx, w = (a.reshape(-1, a.shape[-1]) for a in (u, idx, w))
+    if real is not None:
+        real = real.reshape(-1)
     N, K = idx.shape
     local = idx.reshape(-1) - dims.held_from
     here = (local >= 0) & (local < dims.held)
@@ -136,31 +141,43 @@ def held_experts(lp, u, idx, w, dims: RoutedDims, real=None):
     weight = jnp.where(here.reshape(N, K), w, 0.0)
     out = jnp.einsum("nkl,nk->nl", out.astype(jnp.float32), weight)
     counts = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes)]).astype(jnp.int32)
-    return out.astype(u.dtype), counts
+    return out.astype(u.dtype).reshape(shape), counts
 
 
 def _relu2_mlp(x, w1, w2):
-    h = jnp.einsum("ne,em->nm", x, w1.astype(x.dtype))
-    return jnp.einsum("nm,me->ne", jnp.square(jax.nn.relu(h)),
+    h = jnp.einsum("...e,em->...m", x, w1.astype(x.dtype))
+    return jnp.einsum("...m,me->...e", jnp.square(jax.nn.relu(h)),
                       w2.astype(x.dtype))
+
+
+def choose(lp, x, dims: RoutedDims):
+    """What comes before the experts, row by row: normalised rows x (..., E)
+    -> the chosen experts (..., K), their weights (..., K) float32 and the
+    rows the experts act on (..., latent or E)."""
+    idx, w = route(lp, x, dims)
+    u = jnp.einsum("...e,el->...l", x, lp["w_down"].astype(x.dtype)) \
+        if dims.latent else x
+    return idx, w, u
+
+
+def combine(lp, x, y, dims: RoutedDims):
+    """What comes after them, row by row: the experts' weighted sum y
+    (`held_experts`) back from the latent width, and the shared expert on
+    the rows x themselves -> (..., E)."""
+    if dims.latent:
+        y = jnp.einsum("...l,le->...e", y, lp["w_up"].astype(x.dtype))
+    if dims.shared_width:
+        y = y + _relu2_mlp(x, lp["ws1"], lp["ws2"])
+    return y
 
 
 def mixer(lp, x, dims: RoutedDims, real=None):
     """The layer on normalised rows x (B, S, E) -> (y (B, S, E), counts (2,)
     int32 as `held_experts` gives them, the chosen experts (B, S, K));
     `real` (B, S) bool: the rows that are not get no routed expert."""
-    B, S, E = x.shape
-    x = x.reshape(B * S, E)
-    idx, w = route(lp, x, dims)
-    u = jnp.einsum("ne,el->nl", x, lp["w_down"].astype(x.dtype)) \
-        if dims.latent else x
-    y, counts = held_experts(lp, u, idx, w, dims,
-                             None if real is None else real.reshape(-1))
-    if dims.latent:
-        y = jnp.einsum("nl,le->ne", y, lp["w_up"].astype(x.dtype))
-    if dims.shared_width:
-        y = y + _relu2_mlp(x, lp["ws1"], lp["ws2"])
-    return y.reshape(B, S, E), counts, idx.reshape(B, S, -1)
+    idx, w, u = choose(lp, x, dims)
+    y, counts = held_experts(lp, u, idx, w, dims, real)
+    return combine(lp, x, y, dims), counts, idx
 
 
 def init_layer(key, hidden: int, dims: RoutedDims, dtype):

@@ -358,56 +358,84 @@ def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
     return ffn_block(lp, attn_out(lp, x, o, cfg, constrain), cfg, constrain)
 
 
-# A prefill's bucket is padded to a power of two, and the block's two halves
-# are row-wise: what they compute for a row past the prompt's real length is
-# thrown away.  Told that length, `decoder_block` runs each half over blocks
-# of ROW_BLOCK rows (the prefill kernel's own block) and over those alone
-# that hold a real row.  Decided from the shapes, on any platform: a bucket
-# of at least MIN_ROW_BLOCKS whole blocks (2,048 rows).  A bucket is more
-# than half full, so under four blocks none can be left out and the loop
-# only costs (a 1,024-row bucket always ran both of its two: +2.0 ms of
-# 45.5 on a v5e, PERF.md PR 39).  Below that, and for every caller that
-# gives no length, the halves take all rows at once.
+# A prefill's bucket is padded to a power of two, and every half of every
+# kind of block but the attention itself is row-wise: what it computes for a
+# row past the prompt's real length is thrown away.  Told that length,
+# `decoder_block` and `run_pattern` run each half over blocks of ROW_BLOCK
+# rows (the prefill kernel's own block) and over those alone that hold a
+# real row (`over_rows`, the one loop).  Decided from the shapes, on any
+# platform: a bucket of at least MIN_ROW_BLOCKS whole blocks (2,048 rows).
+# A bucket is more than half full, so under four blocks none can be left out
+# and the loop only costs (a 1,024-row bucket always ran both of its two:
+# +2.0 ms of 45.5 on a v5e, PERF.md PR 39).  Below that, and for every
+# caller that gives no length, the halves take all rows at once.
 ROW_BLOCK = 512
 MIN_ROW_BLOCKS = 4
 
 
-def by_row_blocks(length, rows: int, row_block: int = ROW_BLOCK) -> bool:
+def by_row_blocks(length, rows: int, row_block: int = ROW_BLOCK,
+                  every: int = 0) -> bool:
     """Whether the halves of a `rows`-row bucket with `length` real rows go
-    by row blocks: a length, and MIN_ROW_BLOCKS whole blocks or more."""
+    by row blocks: a length, and MIN_ROW_BLOCKS whole blocks or more; where
+    layers carry state that is kept every `every` rows, a block holds a
+    whole number of those steps."""
     return length is not None and rows % row_block == 0 \
-        and rows // row_block >= MIN_ROW_BLOCKS
+        and rows // row_block >= MIN_ROW_BLOCKS \
+        and not (every and row_block % every)
 
 
-def row_blocks(length, rows: int, row_block: int = ROW_BLOCK):
+def blocks_to_run(length, rows: int, row_block: int = ROW_BLOCK,
+                  every: int = 0):
+    """`over_rows`'s `blocks` for such a bucket: the blocks that hold a real
+    row (traced where `length` is), None = all rows at once."""
+    if not by_row_blocks(length, rows, row_block, every):
+        return None
+    return -(-length // row_block)
+
+
+def row_blocks(length, rows: int, row_block: int = ROW_BLOCK, every: int = 0):
     """(row blocks the halves of one layer run, row blocks of the bucket) for
     `length` real rows (an int, or traced) of a `rows`-row bucket; the same
     where the halves take all rows at once."""
     dense = max(1, rows // row_block)
-    if not by_row_blocks(length, rows, row_block):
-        return dense, dense
-    return -(-length // row_block), dense
+    run = blocks_to_run(length, rows, row_block, every)
+    return (dense if run is None else run), dense
 
 
-def _over_rows(half, ins, outs, blocks, row_block: int):
-    """`half(*arrays of ins)` -> a tuple of arrays shaped as `outs` [(shape,
-    dtype)], rows on axis 1.  `ins` pairs each array with its row axis.
-    `blocks` None: all rows at once.  Else (traced) a loop runs the half on
-    the first `blocks` blocks of rows, a block a trip, and leaves ZEROS in
-    the rows of the others, so that whatever reads them (a softmax over
-    masked scores, a decode step's read of a page's tail) meets nothing
-    that is not finite.  A loop traces its body once."""
+def over_rows(half, ins, outs, blocks, row_block: int, carry=None):
+    """`half(*arrays of ins)` -> a tree of arrays shaped as `outs` (a tree of
+    anything with a shape and a dtype), rows on axis 1.  `ins` pairs each
+    array with its row axis.  `blocks` None: all rows at once.  Else
+    (traced) a loop runs the half on the first `blocks` blocks of
+    `row_block` rows, a block a trip, and leaves ZEROS in the rows of the
+    others, so that whatever reads them (a softmax over masked scores, a
+    decode step's read of a page's tail) meets nothing that is not finite.
+    A result may have fewer rows than went in (a state kept every so many):
+    each trip's lands behind the trip's before.  With `carry` (a layer's
+    recurrent state) the half is `half(carry, *arrays) -> (carry', tree)`,
+    each trip starts from what the one before left, and (carry', tree)
+    comes back.  A loop traces its body once."""
     if blocks is None:
-        return half(*(a for a, _ in ins))
+        rows = (a for a, _ in ins)
+        return half(*rows) if carry is None else half(carry, *rows)
+    if carry is None:
+        stateless = half
+        half = lambda _, *rows: (None, stateless(*rows))    # noqa: E731
 
-    def body(i, bufs):
+    def body(i, at_trip):
+        bufs, carry = at_trip
         at = i * row_block
-        got = half(*(jax.lax.dynamic_slice_in_dim(a, at, row_block, ax)
-                     for a, ax in ins))
-        return tuple(jax.lax.dynamic_update_slice_in_dim(b, g, at, 1)
-                     for b, g in zip(bufs, got))
-    return jax.lax.fori_loop(
-        0, blocks, body, tuple(jnp.zeros(s, dt) for s, dt in outs))
+        carry, got = half(carry, *(jax.lax.dynamic_slice_in_dim(
+            a, at, row_block, ax) for a, ax in ins))
+        # (`at` again where a result has as many rows as went in, so that
+        # the dense decoder's programs lower as they did.)
+        return jax.tree.map(
+            lambda b, g: jax.lax.dynamic_update_slice_in_dim(
+                b, g, at if g.shape[1] == row_block else i * g.shape[1], 1),
+            bufs, got), carry
+    bufs, end = jax.lax.fori_loop(0, blocks, body, (jax.tree.map(
+        lambda o: jnp.zeros(o.shape, o.dtype), outs), carry))
+    return bufs if carry is None else (end, bufs)
 
 
 def _layer_of(stack, layer, pin=None):
@@ -429,6 +457,24 @@ def _layer_of(stack, layer, pin=None):
         w, layer, keepdims=False), stack)
 
 
+def _around_attention(qkv, out, x, cos, sin, attend, cfg: TransformerConfig,
+                      blocks, row_block: int):
+    """The two row-wise halves around an attention, each over the rows as
+    `over_rows` has it: `qkv(x, cos, sin)` -> q, k, v as `block_qkv` gives
+    them, the caller's `attend(q, k, v)` -> (o, kept) between the loops,
+    `out(x, o)` -> the new x.  Returns (x, kept)."""
+    heads = tuple(jax.ShapeDtypeStruct((*x.shape[:2], n, cfg.head_dim_),
+                                       x.dtype)
+                  for n in (cfg.num_heads, cfg.num_kv_heads, cfg.num_kv_heads))
+    q, k, v = over_rows(
+        qkv, [(x, 1), (cos, cos.ndim - 2), (sin, sin.ndim - 2)], heads,
+        blocks, row_block)
+    o, kept = attend(q, k, v)
+    x, = over_rows(lambda x, o: (out(x, o),), [(x, 1), (o, 1)], (x,), blocks,
+                   row_block)
+    return x, kept
+
+
 def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
                   constrain=_unconstrained, length=None,
                   row_block: int = ROW_BLOCK, layer=None):
@@ -443,21 +489,12 @@ def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
     `lp` is the whole stack and `layer` this layer's index (`_layer_of`).
     `row_block` is an argument for the tests' small buckets alone.
     Returns (x, kept)."""
-    B, S, _ = x.shape
-    blocks = row_blocks(length, S, row_block)[0] \
-        if by_row_blocks(length, S, row_block) else None
     weights = functools.partial(_layer_of, lp, layer)
-    heads = [((B, S, n, cfg.head_dim_), x.dtype)
-             for n in (cfg.num_heads, cfg.num_kv_heads, cfg.num_kv_heads)]
-    q, k, v = _over_rows(
+    return _around_attention(
         lambda x, cos, sin: block_qkv(weights(), x, cos, sin, cfg, constrain),
-        [(x, 1), (cos, cos.ndim - 2), (sin, sin.ndim - 2)], heads, blocks,
-        row_block)
-    o, kept = attend(q, k, v)
-    x, = _over_rows(
-        lambda x, o: (block_out(weights(x), x, o, cfg, constrain),),
-        [(x, 1), (o, 1)], [(x.shape, x.dtype)], blocks, row_block)
-    return x, kept
+        lambda x, o: block_out(weights(x), x, o, cfg, constrain),
+        x, cos, sin, attend, cfg,
+        blocks_to_run(length, x.shape[1], row_block), row_block)
 
 
 # The kinds of block a pattern is made of.  Each is defined once, here, and
@@ -496,74 +533,125 @@ def state_chunk(cfg: TransformerConfig) -> int:
                       for k in set(cfg.kinds) & set(STATEFUL)])
 
 
-def attention_block(lp, x, cos, sin, attend, cfg: TransformerConfig):
-    """`*`: the dense block's attention half and nothing after it."""
-    q, k, v = block_qkv(lp, x, cos, sin, cfg)
-    o, kept = attend(q, k, v)
-    return attn_out(lp, x, o, cfg), kept
+def attention_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
+                    blocks=None, row_block: int = ROW_BLOCK):
+    """`*`: the dense block's attention half and nothing after it; `blocks`
+    and `row_block` here and below as `over_rows` takes them."""
+    return _around_attention(
+        lambda x, cos, sin: block_qkv(lp, x, cos, sin, cfg),
+        lambda x, o: attn_out(lp, x, o, cfg), x, cos, sin, attend, cfg,
+        blocks, row_block)
 
 
-def mamba_block(lp, x, state, cfg: TransformerConfig, **how):
-    """`M`: x (B, S, E) from the layer's recurrent `state` -> (x, state',
-    checkpoints); `how` is `mamba2.mixer`'s (length, live, every)."""
-    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
-    y, state, kept = mamba2.mixer(lp, h, state, cfg.mamba, **how)
-    return x + y, state, kept
+def _stateful_block(mixer, dims):
+    def block(lp, x, state, cfg: TransformerConfig, blocks=None,
+              row_block: int = ROW_BLOCK, length=None, live=None,
+              every: int = 0):
+        """x (B, S, E) from the layer's recurrent `state` -> (x, state',
+        checkpoints); `length`, `live` and `every` are the mixer's.  By row
+        blocks the loop carries the state: a trip is the mixer on its
+        block's rows from the state the trip before left, told how many of
+        its rows are real, and the checkpoints it passes are its own share
+        of the prefill's."""
+        def rows(state, x, length):
+            h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
+            y, state, kept = mixer(lp, h, state, dims(cfg), length=length,
+                                   live=live, every=every)
+            return state, (x + y, kept)
+        if blocks is None:
+            state, (x, kept) = rows(state, x, length)
+            return x, state, kept
+
+        def trip(carry, x):
+            state, left = carry
+            state, out = rows(state, x, jnp.minimum(left, row_block))
+            return (state, left - row_block), out
+        kept = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            (s.shape[0], x.shape[1] // every, *s.shape[1:]), s.dtype),
+            state) if every else None
+        (state, _), (x, kept) = over_rows(
+            trip, [(x, 1)], (x, kept), blocks, row_block,
+            (state, jnp.asarray(length, jnp.int32)))
+        return x, state, kept
+    return block
 
 
-def conv_block(lp, x, state, cfg: TransformerConfig, **how):
-    """`C`: as `mamba_block`, the state a convolution's tail."""
-    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
-    y, state, kept = shortconv.mixer(lp, h, state, cfg.conv, **how)
-    return x + y, state, kept
-
-
+# `M` (`models/mamba2.py`) and `C` (`models/shortconv.py`, the state a
+# convolution's tail): one block for both.
+mamba_block = _stateful_block(mamba2.mixer, lambda cfg: cfg.mamba)
+conv_block = _stateful_block(shortconv.mixer, lambda cfg: cfg.conv)
 _STATEFUL_BLOCK = {"M": mamba_block, "C": conv_block}
 
 
-def routed_block(lp, x, cfg: TransformerConfig, real=None):
+def routed_block(lp, x, cfg: TransformerConfig, real=None, blocks=None,
+                 row_block: int = ROW_BLOCK):
     """`E`: -> (x, counts (2,) int32: held experts touched and rows
     computed, the experts each row chose (B, S, K)); rows that are not
-    `real` (B, S) go to no routed expert."""
-    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
-    y, counts, chosen = routed.mixer(lp, h, cfg.routed, real)
-    return x + y, counts, chosen
+    `real` (B, S) go to no routed expert.  The layer's row-wise parts
+    (`models/routed.py`: `choose` before the experts, `combine` after them)
+    go as every other half; the grouped products between them are ONE call
+    over the bucket, which runs no row past the real ones as it is: a call a
+    block would read every touched expert's weights once a block."""
+    r = cfg.routed
+
+    def before(x):
+        h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
+        return (h, *routed.choose(lp, h, r))
+    picks = [jax.ShapeDtypeStruct((*x.shape[:2], r.top_k), t)
+             for t in (jnp.int32, jnp.float32)]
+    acted = jax.ShapeDtypeStruct(
+        (*x.shape[:2], r.latent or x.shape[2]), x.dtype)
+    h, chosen, w, u = over_rows(before, [(x, 1)], (x, *picks, acted), blocks,
+                                row_block)
+    y, counts = routed.held_experts(lp, u, chosen, w, r, real)
+    x, = over_rows(lambda x, h, y: (x + routed.combine(lp, h, y, r),),
+                   [(x, 1), (h, 1), (y, 1)], (x,), blocks, row_block)
+    return x, counts, chosen
 
 
 def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
-                per_layer=(), length=None, live=None, every: int = 0):
+                per_layer=(), length=None, live=None, every: int = 0,
+                row_block: int = ROW_BLOCK):
     """A pattern of kinds, block by block (`layers`: one tree a block).
     `attend(q, k, v, *at)` as `scan_blocks` takes it, `at` the i-th slice of
     `per_layer` for the i-th attention layer; `rec` the recurrent state, one
     tree (`zero_state`) for each STATEFUL block in order.  Which rows are
     real: the first `length` (a prefill's padded bucket), the slots that are
     `live` (B,) (a decode step); the others move no state and meet no
-    routed expert.  `every`: the stateful mixers' checkpoints.  Returns (x,
-    the attention layers' `kept` stacked, rec', the stateful blocks'
-    checkpoints, the `E` blocks' counts (n, 2) and chosen experts (n, B, S,
-    K))."""
+    routed expert.  `every`: the stateful mixers' checkpoints.  Given
+    `length` in a bucket that `by_row_blocks` (blocks of `row_block` rows,
+    an argument for the tests' small buckets alone; each a whole number of
+    checkpoints), every kind's row-wise halves run over the blocks that
+    hold a real row, a stateful kind's state carried from block to block,
+    and x, the kept keys and values, the checkpoints and the chosen experts
+    are ZEROS past the last block that ran.  Returns (x, the attention
+    layers' `kept` stacked, rec', the stateful blocks' checkpoints, the `E`
+    blocks' counts (n, 2) and chosen experts (n, B, S, K))."""
     kept, new, ckpts, counts, chosen = [], [], [], [], []
     real = None
     if length is not None:
         real = jnp.broadcast_to(jnp.arange(x.shape[1]) < length, x.shape[:2])
     if live is not None:
         real = jnp.broadcast_to(live[:, None], x.shape[:2])
+    by = (blocks_to_run(length, x.shape[1], row_block, every), row_block)
     for kind, lp in zip(cfg.kinds, layers):
         if kind == "*":
             at = tuple(a[len(kept)] for a in per_layer)
             x, k = attention_block(
-                lp, x, cos, sin, lambda q, k, v: attend(q, k, v, *at), cfg)
+                lp, x, cos, sin, lambda q, k, v: attend(q, k, v, *at), cfg,
+                *by)
             kept.append(k)
         elif kind in STATEFUL:
             x, state, ck = _STATEFUL_BLOCK[kind](
-                lp, x, rec[len(new)], cfg, length=length, live=live,
+                lp, x, rec[len(new)], cfg, *by, length=length, live=live,
                 every=every)
             new.append(state)
             ckpts.append(ck)
         elif kind == "F":
-            x = ffn_block(lp, x, cfg)
+            x, = over_rows(lambda x: (ffn_block(lp, x, cfg),), [(x, 1)],
+                           (x,), *by)
         else:
-            x, c, ch = routed_block(lp, x, cfg, real)
+            x, c, ch = routed_block(lp, x, cfg, real, *by)
             counts.append(c)
             chosen.append(ch)
     kept = jax.tree.map(lambda *a: jnp.stack(a), *kept) \
