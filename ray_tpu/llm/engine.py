@@ -42,6 +42,7 @@ vllm's engine surface so reference users can map concepts 1:1.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import math
 import os
@@ -56,14 +57,17 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
-from ..models.transformer import (ROW_BLOCK, STATEFUL, TransformerConfig,
-                                  blocks_to_run, decoder_block, embed_tokens,
-                                  init_params, lm_logits, over_rows,
-                                  param_logical_axes, rope_angles, row_blocks,
-                                  run_pattern, scan_blocks,
-                                  state_bytes, state_chunk, zero_state)
+from ..models.transformer import (LATENT_FORMS, ROW_BLOCK, STATEFUL,
+                                  TransformerConfig, blocks_to_run,
+                                  decoder_block, embed_tokens, init_params,
+                                  latent_absorb, latent_form, latent_unabsorb,
+                                  lm_logits, over_rows, param_logical_axes,
+                                  rope_angles, row_blocks, run_pattern,
+                                  scan_blocks, state_bytes, state_chunk,
+                                  zero_state)
 from ..ops.paged_attention import (decode_path, head_rows,
-                                   paged_decode_attention, pool_row, pool_rows,
+                                   paged_decode_attention,
+                                   paged_latent_attention, pool_row, pool_rows,
                                    pool_shape)
 from .tick_phases import TickPhases
 
@@ -133,6 +137,7 @@ class _Flight:
     t0: int
     pages: int
     synced: int
+    rows: int = 0       # tokens the batch holds in cache, this step's included
 
 
 # --------------------------------------------------------------------------
@@ -197,7 +202,11 @@ def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
     (o, the layer's new cache rows (k[0], v[0])).  `blocks`, `row_block`
     (`over_rows`'s): the suffix form's built scores are row-wise in their
     QUERIES, so they are built for the query blocks that hold a real row, a
-    block at a time against all keys, and o is zeros in the others."""
+    block at a time against all keys, and o is zeros in the others.
+    A latent pattern's pool_v is None and its attend is `run_pattern`'s for
+    an `L` layer (`_latent_prefill_attend`)."""
+    if cfg.latent:
+        return _latent_prefill_attend(cfg, rows, cached, blocks, row_block)
     pool = per_layer = ()
     if cached is None:
         path = _prefill_path(cfg, rows, kv_sharding)
@@ -223,14 +232,9 @@ def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
             mask = jnp.tril(jnp.ones((rows, rows), bool))
             return _xla_prefill_attention(q, k, v, mask, cfg)
     else:
-        # Key t (over [cached T | suffix Sb]) is valid for suffix query s
-        # iff it is a REAL cached prefix position or a suffix position <= s.
-        tpos = jnp.arange(T + rows)
-        qpos = jnp.arange(rows)
-        mask = (tpos[None, :] < prefix_len) | (
-            (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
+        mask = _suffix_mask(rows, T, prefix_len)
         per_layer = (pool_k, pool_v)
-        heads = (cfg.num_kv_heads, cfg.head_dim_)
+        heads = cfg.cache_row
 
         def scores(q, k, v, pk, pv):        # pk, pv: (N, page, *row)
             ck = head_rows(pk[pages], *heads).reshape(T, *heads)
@@ -244,6 +248,61 @@ def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
 
     def attend(q, k, v, *at):
         return scores(q, k, v, *at), (k[0], v[0])   # drop the B=1 dim
+    return attend, per_layer
+
+
+def _suffix_mask(rows: int, T: int, prefix_len):
+    """Key t (over [cached T | suffix rows]) is open to suffix query s iff
+    it is a REAL cached prefix position or a suffix position <= s."""
+    tpos = jnp.arange(T + rows)
+    qpos = jnp.arange(rows)
+    return (tpos[None, :] < prefix_len) | (
+        (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
+
+
+def _latent_prefill_attend(cfg: TransformerConfig, rows: int, cached, blocks,
+                           row_block: int):
+    """`_prefill_attend` for a pattern of latent layers: the key rows are
+    the slot's cached rows as they lie in its pages (none: a whole prompt)
+    and then the prefill's own, and `latent_form` says from the cached rows
+    which of `LATENT_FORMS` attends them (over gathered rows the absorbed
+    one alone); either is built a block of query rows at a time.  attend(q, row, w, *at) -> (o, (the layer's new
+    cache rows (Sb, 1, C), None: no second pool))."""
+    if cached is None:
+        T, per_layer = 0, ()
+        mask = jnp.tril(jnp.ones((rows, rows), bool))
+    else:
+        pool, _, pages, prefix_len, page = cached
+        T = pages.shape[0] * page
+        per_layer = (jnp.arange(pool.shape[0], dtype=jnp.int32),)
+        mask = _suffix_mask(rows, T, prefix_len)
+    build = LATENT_FORMS[latent_form(T)]
+    heads = cfg.cache_row
+
+    def attend(q, row, w, *li):
+        keys = row[:, :, 0]
+        if li:
+            # ONE gather of the slot's pages out of the whole pool (a layer
+            # sliced out first is a copy of it: 0.25 GB a layer).
+            cached_rows = pool[jnp.full_like(pages, li[0]), pages]
+            keys = jnp.concatenate(
+                [head_rows(cached_rows, *heads).reshape(1, T, heads[1]),
+                 keys], axis=1)
+        form = build(w, keys, cfg)
+        o = jax.ShapeDtypeStruct((*q.shape[:3], cfg.latent.value), q.dtype)
+        ins, block = [(q, 1), (mask, 0)], lambda q, mask: (form(q, mask),)
+        if not li and blocks is not None:
+            # A whole prompt's block of query rows sees no key past its own
+            # last row: one branch for every two blocks of keys, each built
+            # over the keys up to there (half the scores of a full bucket).
+            step = 2 * row_block
+            upto = [functools.partial(form, upto=min(n, rows))
+                    for n in range(step, rows + step, step)]
+            ins.append((jnp.arange(rows), 0))
+            block = lambda q, mask, at: (jax.lax.switch(
+                at[-1] // step, upto, q, mask),)
+        return over_rows(block, ins, (o,), blocks, row_block)[0], \
+            (row[0], None)
     return attend, per_layer
 
 
@@ -281,12 +340,16 @@ def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     says when) what lies past the last block that holds a real row is
     zeros, as `_prefill_fn` has it: ks, vs, the checkpoints at boundaries
     past the prompt (`_install_state` gives those to the scratch row), the
-    experts chosen.  `row_block`: the tests'."""
+    experts chosen.  `row_block`: the tests'.  `pages` None: a whole
+    prompt that attends nothing cached (a latent pattern's, whose attention
+    form follows from that: `_latent_prefill_attend`)."""
     Sb = tokens.shape[1]
     x = embed_tokens(params, tokens, cfg)
     cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
+    cached = None if pages is None else (pool_k, pool_v, pages, prefix_len,
+                                         page)
     attend, per_layer = _prefill_attend(
-        cfg, Sb, length, None, (pool_k, pool_v, pages, prefix_len, page),
+        cfg, Sb, length, None, cached,
         blocks_to_run(length, Sb, row_block, every), row_block)
     rec = [{k: c[k][row][None] for k in c} for c in ckpt]
     x, (ks, vs), rec, kept, _, chosen = run_pattern(
@@ -319,17 +382,18 @@ def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
     L, Sb, KV, D = ks.shape
     P = pages.shape[0]
     pad = P * page - Sb
+    # (Each step over the pair of pools, of which a latent pattern's second
+    # is None: an empty tree.)
+    pools, new = (pool_k, pool_v), (ks, vs)
     if pad > 0:
-        ks = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vs = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    ks = pool_rows(ks.reshape(L, P, page, KV, D), KV, D)
-    vs = pool_rows(vs.reshape(L, P, page, KV, D), KV, D)
-    pool_k = pool_k.at[:, pages].set(ks)
-    pool_v = pool_v.at[:, pages].set(vs)
+        new = jax.tree.map(
+            lambda r: jnp.pad(r, ((0, 0), (0, pad), (0, 0), (0, 0))), new)
+    new = jax.tree.map(
+        lambda r: pool_rows(r.reshape(L, P, page, KV, D), KV, D), new)
+    pools = jax.tree.map(lambda pool, r: pool.at[:, pages].set(r), pools, new)
     if kv_sharding is not None:
-        pool_k = jax.lax.with_sharding_constraint(pool_k, kv_sharding)
-        pool_v = jax.lax.with_sharding_constraint(pool_v, kv_sharding)
-    return pool_k, pool_v
+        pools = jax.lax.with_sharding_constraint(pools, kv_sharding)
+    return pools
 
 
 def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
@@ -358,7 +422,7 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
         tables, (lengths // page)[:, None], axis=1)[:, 0]         # (B,)
     write_off = lengths % page
     paged = _per_shard(paged_decode_attention, kv_sharding, "hpp...")
-    heads = (cfg.num_kv_heads, cfg.head_dim_)
+    heads = cfg.cache_row
 
     def written(pool, li, new):     # new (B, 1, KV, D): one row a slot
         return pool.at[li, write_page, write_off].set(
@@ -371,6 +435,18 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
             pools[0] = written(pools[0], li, k)
             pools[1] = written(pools[1], li, v)
             return paged(q[:, 0], *pools, tables, lengths, li)[:, None], None
+
+        def latent_attend(q, row, w, li):
+            # One query row a slot over rows that lie in the pool: the
+            # absorbed form (`latent_form(cached)`), the rows read where
+            # they lie (ops/paged_attention.py: `paged_latent_attention`).
+            pools[0] = written(pools[0], li, row)
+            o = paged_latent_attention(
+                latent_absorb(w, q[:, 0], cfg), pools[0], tables, lengths,
+                li, scale=cfg.latent.scale, value_lanes=cfg.latent.rank)
+            return latent_unabsorb(w, o[:, None], cfg), None
+        if cfg.latent:
+            attend = latent_attend
         x, _, rec, _, counts, chosen = run_pattern(
             params["layers"], x, cos, sin, attend, cfg, rec,
             (jnp.arange(pool_k.shape[0], dtype=jnp.int32),), live=active)
@@ -973,9 +1049,9 @@ class LLMEngine:
         self.n_pages = 1 + (kv_pages if kv_pages is not None
                             else max_batch * self.pages_per_slot)
         # The pool has rows for the layers that attend: all of the dense
-        # decoder's, the `*` layers of a pattern.
-        L, kvh, d = cfg.count("*") or cfg.num_layers, cfg.num_kv_heads, \
-            cfg.head_dim_
+        # decoder's, the `*` or `L` layers of a pattern.
+        L = cfg.count("*") + cfg.count("L") or cfg.num_layers
+        kvh, d = cfg.cache_row
         # State checkpoints, every `_every` tokens (0: no recurrent layer).
         self._every = _CKPT_CHUNKS * state_chunk(cfg)
         if cfg.pattern:
@@ -988,6 +1064,11 @@ class LLMEngine:
                 raise ValueError(
                     f"page_size {self.page} does not divide the state "
                     f"checkpoints' spacing of {self._every} tokens")
+            if cfg.latent and pool_row(*cfg.cache_row) != "latent":
+                raise ValueError(
+                    f"a cache row of {cfg.cache_row[1]} values is not a "
+                    "latent row: more than one 128-lane row and no whole "
+                    "number of them (ops/paged_attention.py: pool_row)")
 
         from . import sequence_parallel as _sp
         deg = sp_degree if sp_degree is not None \
@@ -1059,9 +1140,12 @@ class LLMEngine:
         if param_shd is not None:
             self.params = jax.device_put(self.params, param_shd)
 
+        # Two pools, keys and values; a latent pattern has ONE, whose row is
+        # both, and None (an empty tree) where the others have the second.
         shape = pool_shape(L, self.n_pages, self.page, kvh, d)
         self._pk = jnp.zeros(shape, cfg.dtype, device=self._kv_shd)
-        self._pv = jnp.zeros(shape, cfg.dtype, device=self._kv_shd)
+        self._pv = None if cfg.latent else jnp.zeros(
+            shape, cfg.dtype, device=self._kv_shd)
         self._free_slots = list(range(max_batch))
         self._free_pages = list(range(1, self.n_pages))
         # page -> holder count (requests + cache entries); a page leaves
@@ -1099,8 +1183,9 @@ class LLMEngine:
                 _demo_dir = os.path.join(
                     tempfile.gettempdir(),
                     "ray_tpu_kv_demote_%d" % os.getpid())
-            if _demo_on and not self._every:
-                # (Demoted pages would leave their state checkpoints behind.)
+            if _demo_on and not self._every and not cfg.latent:
+                # (Demoted pages would leave their state checkpoints behind;
+                # the store keeps K/V pairs, and a latent page is one array.)
                 self._demote = _KVDemoteStore(_demo_lim, _demo_dir)
         self._tables = np.zeros((max_batch, self.pages_per_slot), np.int32)
         self._slots: Dict[int, _Request] = {}
@@ -1166,6 +1251,15 @@ class LLMEngine:
                                "kv_blocks_run": 0, "kv_blocks_dense": 0,
                                "row_blocks_run": 0, "row_blocks_dense": 0}
         self._prefill_ran: Dict[str, Any] = {}
+        # A latent pattern: the cache rows its decode steps read (live
+        # tokens, all slots) and the key rows its prefills attended, with
+        # how many of them were up-projected to per-head keys and values
+        # (`latent_form`: all of an expanded prefill's, none of an absorbed
+        # one's); real rows, counted on the host.
+        self._latent = {"rows_read": 0, "step_rows_read": 0,
+                        "rows_attended": 0, "rows_expanded": 0,
+                        "prefills": {"expanded": 0, "absorbed": 0},
+                        "form": ""}
         page, kv_shd = self.page, self._kv_shd
         # The decode step stays a lambda ON PURPOSE: the benchmark's
         # `decode_tick` and `decode_roofline` readers pick it out of a
@@ -1207,23 +1301,26 @@ class LLMEngine:
         self._part_seq = 0
 
         def _tail_gather(pk, pv, li, pages):
-            tk = head_rows(pk[li][pages], kvh, d).reshape(-1, kvh, d)
-            tv = head_rows(pv[li][pages], kvh, d).reshape(-1, kvh, d)
-            return tk, tv
+            return jax.tree.map(lambda pool: head_rows(
+                pool[li][pages], kvh, d).reshape(-1, kvh, d), (pk, pv))
         self._tail_gather_jit = jax.jit(_tail_gather)
 
         def _append_tail(pk, pv, ks, vs, page_id, off):
-            pk = pk.at[:, page_id, off].set(pool_rows(ks, kvh, d))
-            pv = pv.at[:, page_id, off].set(pool_rows(vs, kvh, d))
+            pools = jax.tree.map(
+                lambda pool, r: pool.at[:, page_id, off].set(
+                    pool_rows(r, kvh, d)), (pk, pv), (ks, vs))
             if kv_shd is not None:
-                pk = jax.lax.with_sharding_constraint(pk, kv_shd)
-                pv = jax.lax.with_sharding_constraint(pv, kv_shd)
-            return pk, pv
+                pools = jax.lax.with_sharding_constraint(pools, kv_shd)
+            return pools
         self._append_tail_jit = jax.jit(_append_tail,
                                         donate_argnums=(0, 1))
 
     # ------------------------------------------------------------ requests --
     def _dense_only(self, what: str) -> None:
+        if self.cfg.latent:
+            raise ValueError(
+                f"{what}: a shipped or streamed cache is a K/V pair, and a "
+                "latent pattern caches one row a token in one pool")
         if self.cfg.pattern:
             raise ValueError(
                 f"{what}: keys and values shipped or streamed from elsewhere "
@@ -1420,11 +1517,11 @@ class LLMEngine:
         upload), `state_rows` the rows (`step_state_rows`: the last
         step's); `steps - state_syncs` steps uploaded nothing."""
         per_step = self.max_batch * self.pages_per_slot
+        z = self.cfg.latent
         return {"path": decode_path(
                     (self.cfg.num_heads, self.cfg.head_dim_), self._pk.shape,
-                    self._tables.shape),
-                "pool_row": pool_row(self.cfg.num_kv_heads,
-                                     self.cfg.head_dim_),
+                    self._tables.shape, z.rank if z else 0),
+                "pool_row": pool_row(*self.cfg.cache_row),
                 "steps": self._decode_steps,
                 "pages_read": self._pages_read,
                 "pages_addressable": self._decode_steps * per_step,
@@ -1453,6 +1550,25 @@ class LLMEngine:
                        tokens_recomputed=c.recomputed,
                        hit_prompt_tokens=c.hit_tokens)
         return out
+
+    def latent_stats(self) -> Dict[str, Any]:
+        """A pattern of latent layers: the bytes of one token's cache row in
+        one layer (its real values; `pool_row_bytes` as the pool pads it)
+        and the pool's row form, the cache rows the decode steps read (a
+        slot's live tokens, summed over the slots; in every latent layer
+        alike), and the key rows the prefills attended, cached and new,
+        with how many of them were up-projected to per-head keys and values
+        and which form the last prefill took."""
+        z = self.cfg.latent
+        if not z:
+            return {"enabled": False}
+        act = jnp.dtype(self.cfg.dtype).itemsize
+        return {"enabled": True, "layers": self.cfg.count("L"),
+                "row_bytes": z.row * act,
+                "pool_row_bytes": self._pk.shape[-1] * act,
+                "pool_row": pool_row(*self.cfg.cache_row),
+                "steps": self._decode_steps, **self._latent,
+                "prefills": dict(self._latent["prefills"])}
 
     def routed_stats(self) -> Dict[str, Any]:
         """What the routed layers' DECODE steps touched, a number a layer:
@@ -1489,13 +1605,24 @@ class LLMEngine:
         row = () if prefix_len is None else (self.page, self.pages_per_slot)
         path = _prefill_path(self.cfg, padded, self._kv_shd, *row) \
             if self.sp_degree == 1 else "xla"
-        run, dense = kv_blocks(rows, padded, prefix_len or 0,
-                               math.prod(row) if row else 0)
+        table = math.prod(row) if row else 0    # cached rows a suffix sees
+        run, dense = kv_blocks(rows, padded, prefix_len or 0, table)
         rows_run, rows_dense = row_blocks(
             rows if self.sp_degree == 1 else None, padded, every=self._every)
         self._prefill_ran = {"path": path,
                              "kv_blocks": run if path == "kernel" else dense,
                              "row_blocks": rows_run}
+        if self.cfg.latent:
+            # The form, by the rule the program went by; real rows.
+            form = latent_form(table)
+            attended = (prefix_len or 0) + rows
+            expanded = attended if form == "expanded" else 0
+            self._prefill_ran.update(form=form, expanded=expanded)
+            lat = self._latent
+            lat["form"] = form
+            lat["prefills"][form] += 1
+            lat["rows_attended"] += attended
+            lat["rows_expanded"] += expanded
         st = self._prefill_stats
         st["path"] = path
         st[path + "_calls"] += 1
@@ -1768,6 +1895,11 @@ class LLMEngine:
         Sb = self._bucket(S)
         sp = self.sp_degree > 1
         key = ("sp-suffix", Sb) if sp else ("suffix", Sb)
+        if self.cfg.latent and not prefix_len:
+            # A whole prompt attends nothing cached, and is given no pages
+            # to gather: another program (and, `latent_form`, the expanded
+            # attention).
+            key, pages_row = ("whole", Sb), None
         if key not in self._prefill_jit:
             cfg, page = self.cfg, self.page
             if cfg.pattern:
@@ -1793,10 +1925,11 @@ class LLMEngine:
                 self._prefill_jit[key] = jax.jit(suffix_prefill)
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = suf
-        self._count_prefill(S, Sb, prefix_len)
+        self._count_prefill(S, Sb, None if pages_row is None else prefix_len)
         state = (self._ckpt, from_row) if self.cfg.pattern else ()
         return self._prefill_jit[key](
-            self.params, self._pk, self._pv, jnp.asarray(pages_row),
+            self.params, self._pk, self._pv,
+            None if pages_row is None else jnp.asarray(pages_row),
             jnp.asarray(toks), prefix_len, S, *state)
 
     def _prefill_slot(self, req: _Request):
@@ -1817,13 +1950,26 @@ class LLMEngine:
         return logits, state[2] if state else None
 
     def _admit(self) -> int:
-        """Admit what fits; returns how many requests took a slot."""
+        """Admit what fits, in order of arrival; returns how many requests
+        took a slot.  A tick admits the first that waits and then others
+        while their prompts, cached or not, come to no more than `max_len`
+        tokens in all, what one slot holds: the running sequences stand
+        still for an admission's host work (a prompt's page keys, looked up
+        and inserted: 9 ms for 6k tokens) and its prefill, so a tick's stop
+        is bounded by one longest prompt's, and two long prompts that came
+        in one tick leave a tick apart and do not come back together (a
+        closed loop's callers, welded for a whole run by a millisecond of
+        the ramp: PERF.md §6, PR 44)."""
         ph = self.phases
         admitted = []
         taken = 0
-        while self._waiting and self._reserve(self._waiting[0]):
+        room = self.max_len
+        while self._waiting \
+                and (not taken or len(self._waiting[0].prompt) <= room) \
+                and self._reserve(self._waiting[0]):
             req = self._waiting.pop(0)
             taken += 1
+            room -= len(req.prompt)
             if req.kv_paged:
                 # External paged context: nothing to prefill — the
                 # parts stay wherever they live (possibly remote); the
@@ -2078,6 +2224,9 @@ class LLMEngine:
             self._step_routed = nxt[self.max_batch:].reshape(-1, 2)
             self._routed += self._step_routed
             extra["experts"] = int(self._step_routed[:, 0].sum())
+        if self.cfg.latent:
+            self._latent["rows_read"] += flight.rows
+            self._latent["step_rows_read"] = extra["latent_rows"] = flight.rows
         ph.span("decode", flight.t0, ph.to("emit"), batch=len(flight.batch),
                 pages=flight.pages, synced=flight.synced, **extra)
         # The host advances its mirrors as the step advanced the device's.
@@ -2119,7 +2268,9 @@ class LLMEngine:
             ph.to("dispatch")
         self._pk, self._pv, self._dev, nxt = self._decode_jit(
             self.params, self._pk, self._pv, self._dev, update)
-        return _Flight(nxt, batch, t0, pages, synced)
+        rows = int((self._lengths[active] + 1).sum()) if self.cfg.latent \
+            else 0
+        return _Flight(nxt, batch, t0, pages, synced, rows)
 
     def _next_batch_if_ahead(self) -> Dict[int, _Request]:
         """Whom the NEXT decode step is for, if it may leave now, at the

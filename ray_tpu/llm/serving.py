@@ -862,7 +862,9 @@ class EngineReplica:
         device-resident state (`state_syncs` of `steps`, `state_rows`);
         `state` and `routed` are `LLMEngine.state_stats()` (recurrent-state
         checkpoints) and `routed_stats()` (experts the decode steps
-        touched), `{"enabled": False}` for a model without such layers."""
+        touched) and `latent` `latent_stats()` (cache rows the decode steps
+        read, key rows the prefills attended and up-projected),
+        `{"enabled": False}` for a model without such layers."""
         e = self.engine
         return {"ticks": self._ticks, "max_active": self._max_active,
                 "shed": self._shed, "cancelled": self._cancelled,
@@ -880,6 +882,7 @@ class EngineReplica:
                 "prefill": e.prefill_stats(),
                 "state": e.state_stats(),
                 "routed": e.routed_stats(),
+                "latent": e.latent_stats(),
                 "tick": self._phases.snapshot()}
 
     async def pid(self) -> int:

@@ -4,7 +4,7 @@ transformer.py: `routed_block`), dropless, told which experts it holds.
 The router scores ALL experts of the model: `s = sigmoid(x W_r)` in float32;
 the `top_k` experts with the largest `s + bias` are chosen, their weights
 are their `s` normalised to sum 1 over the chosen and scaled.  ONE layer in
-two published forms, told apart by its sizes (`RoutedDims`):
+three published forms, told apart by its sizes (`RoutedDims`):
 
 - latent, ungated, with a shared expert (Nemotron-H): the experts work in a
   latent space, `u = x W_down`, expert e gives `relu(u W1_e)^2 W2_e`, the
@@ -14,6 +14,11 @@ two published forms, told apart by its sizes (`RoutedDims`):
   `shared_width` 0, `gated`): expert e gives `(silu(x W1_e) * (x W3_e))
   W2_e`; W1 and W3 lie side by side in `w1` (k, 2 x width), so a layer is
   two grouped products in either form.
+- on the hidden width, gated, WITH a shared expert that is gated too
+  (DeepSeek-V3's layer, as Moonlight has it: `latent` 0, `gated`,
+  `shared_width` > 0): the routed experts as LFM2's; the shared expert one
+  SwiGLU of `shared_width` on every row, `(silu(x Ws1) * (x Ws3)) Ws2`, its
+  Ws1 and Ws3 side by side in `ws1` as the routed experts' are.
 
 This process holds experts [held_from, held_from + held) of every layer (a
 chip of an expert-parallel group holds its share) and computes THEIR part of
@@ -54,7 +59,7 @@ class RoutedDims:
     def shared_params(self, hidden: int) -> int:
         """A layer's parameters outside its routed experts."""
         return (hidden * self.experts + self.experts + 2 * hidden * self.latent
-                + 2 * hidden * self.shared_width)
+                + (3 if self.gated else 2) * hidden * self.shared_width)
 
 
 def scores(lp, x):
@@ -76,12 +81,16 @@ def route(lp, x, dims: RoutedDims):
 
 
 def _tile(width: int) -> int:
-    """A tile of the grouped product along a width: all of it up to 1,024,
-    beyond that its largest divisor that is a multiple of 128 and no more
-    than 1,024 (896 of 2,688, 768 of 1,536, 1,024 of 2,048 and 3,072)."""
-    if width <= 1024:
-        return width
-    return max(t for t in range(128, 1025, 128) if width % t == 0)
+    """A tile of the grouped product along a width: of the width's divisors
+    in whole 128-lane rows the one nearest 1,024 (the smaller of two as
+    near), so that a tile of an expert's matrix is a piece of about two
+    megabytes: 1,024 of 2,048 and 3,072, 896 of 2,688, 768 of 1,536, a
+    width up to 1,024 whole, and 1,408 of 1,408 and of 2,816, whose only
+    smaller divisors, 128 and 256, read the matrix in pieces of half a
+    megabyte at two thirds of the bandwidth (PERF.md §6, PR 44).  A width
+    that is no whole number of lane rows is taken whole."""
+    fits = [t for t in range(128, width + 1, 128) if width % t == 0]
+    return min(fits, key=lambda t: (abs(t - 1024), t)) if fits else width
 
 
 def grouped_path() -> str:
@@ -150,6 +159,13 @@ def _relu2_mlp(x, w1, w2):
                       w2.astype(x.dtype))
 
 
+def _gated_mlp(x, w13, w2):
+    gate, up = jnp.split(
+        jnp.einsum("...e,em->...m", x, w13.astype(x.dtype)), 2, axis=-1)
+    return jnp.einsum("...m,me->...e", jax.nn.silu(gate) * up,
+                      w2.astype(x.dtype))
+
+
 def choose(lp, x, dims: RoutedDims):
     """What comes before the experts, row by row: normalised rows x (..., E)
     -> the chosen experts (..., K), their weights (..., K) float32 and the
@@ -167,7 +183,8 @@ def combine(lp, x, y, dims: RoutedDims):
     if dims.latent:
         y = jnp.einsum("...l,le->...e", y, lp["w_up"].astype(x.dtype))
     if dims.shared_width:
-        y = y + _relu2_mlp(x, lp["ws1"], lp["ws2"])
+        shared = _gated_mlp if dims.gated else _relu2_mlp
+        y = y + shared(x, lp["ws1"], lp["ws2"])
     return y
 
 
@@ -201,7 +218,8 @@ def init_layer(key, hidden: int, dims: RoutedDims, dtype):
         lp.update(w_down=dense(ks[2], (hidden, dims.latent), hidden),
                   w_up=dense(ks[5], (dims.latent, hidden), dims.latent))
     if dims.shared_width:
-        lp.update(ws1=dense(ks[6], (hidden, dims.shared_width), hidden),
+        lp.update(ws1=dense(ks[6], (hidden, (2 if dims.gated else 1)
+                                    * dims.shared_width), hidden),
                   ws2=dense(ks[7], (dims.shared_width, hidden),
                             dims.shared_width))
     return lp

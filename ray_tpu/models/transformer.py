@@ -37,6 +37,33 @@ from .shortconv import ShortConvDims
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentDims:
+    """A latent attention layer's widths (DeepSeek-V2/V3's multi-head latent
+    attention without a compressed query, as Moonlight-16B-A3B publishes
+    it): a token's keys and values of ALL heads are one compressed row of
+    `rank` values, beside `rope` rotated values that every head shares."""
+    rank: int = 512             # kv_lora_rank: the compressed row c
+    nope: int = 128             # qk_nope_head_dim: a head's unrotated part
+    rope: int = 64              # qk_rope_head_dim: the shared rotated part
+    value: int = 128            # v_head_dim
+
+    @property
+    def row(self) -> int:
+        """THE CACHE ROW's values: [c | k_r], key and value at once."""
+        return self.rank + self.rope
+
+    @property
+    def scale(self) -> float:
+        return 1.0 / math.sqrt(self.nope + self.rope)
+
+    def param_count(self, hidden: int, heads: int) -> int:
+        return (hidden * heads * (self.nope + self.rope)        # W_q
+                + hidden * self.row + self.rank                 # W_kva, norm
+                + self.rank * heads * (self.nope + self.value)  # W_kvb
+                + heads * self.value * hidden)                  # W_o
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -66,23 +93,37 @@ class TransformerConfig:
     # layer is an operator AND a feed-forward, each a residual half behind
     # its own norm, spells a layer as two letters and parts the layers with
     # spaces ("CF *E CE": `num_layers` 3, six blocks, one attention layer
-    # in the page pool).  A pattern with `M`, `E` or `C` blocks brings their
-    # sizes in `mamba`, `routed` and `conv`; `rope` off = the attention
-    # layers rotate nothing (position is carried by the recurrent layers);
-    # `qk_norm` = an RMS norm with a learned scale over each q and k head
-    # before the rotation; `tie_embeddings` = the head is the embedding
-    # table, held once.
+    # in the page pool).  A pattern with `M`, `E`, `C` or `L` blocks brings
+    # their sizes in `mamba`, `routed`, `conv` and `latent` (`L`: latent
+    # attention, whose page pool holds ONE row a token, `cache_row`; a
+    # pattern's attention layers are all `*` or all `L`); `rope` off = the
+    # attention layers rotate nothing (position is carried by the recurrent
+    # layers); `qk_norm` = an RMS norm with a learned scale over each q and
+    # k head before the rotation; `tie_embeddings` = the head is the
+    # embedding table, held once.
     pattern: str = ""
     mamba: Optional[Mamba2Dims] = None
     routed: Optional[RoutedDims] = None
     conv: Optional[ShortConvDims] = None
+    latent: Optional[LatentDims] = None
     rope: bool = True
     qk_norm: bool = False
     tie_embeddings: bool = False
 
     @property
     def head_dim_(self) -> int:
+        if self.latent:
+            return self.latent.nope + self.latent.rope
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def cache_row(self) -> Tuple[int, int]:
+        """(KV heads, width) of what one token leaves in one attention
+        layer's page pool: its keys' (and values') heads, or a latent
+        layer's one row (ops/paged_attention.py: `pool_row`)."""
+        if self.latent:
+            return 1, self.latent.row
+        return self.num_kv_heads, self.head_dim_
 
     @property
     def kinds(self) -> str:
@@ -115,6 +156,8 @@ class TransformerConfig:
                         + self.routed.held * self.routed.expert_params(h))
         if self.conv:
             per["C"] = self.conv.param_count(h) + h
+        if self.latent:
+            per["L"] = self.latent.param_count(h, self.num_heads) + h
         head = 0 if self.tie_embeddings else v * h
         return v * h + sum(per[k] for k in self.kinds) + h + head
 
@@ -193,6 +236,15 @@ def _init_pattern_layer(kind: str, key, cfg: TransformerConfig):
     if kind == "C":
         return {"ln": ln, **shortconv.init_layer(key, h, cfg.conv, dt)}
     ks = jax.random.split(key, 4)
+    if kind == "L":
+        z, nh = cfg.latent, cfg.num_heads
+        return {"ln": ln, "attn": {
+            "wq": _dense(ks[0], (h, nh, z.nope + z.rope), h, dt),
+            "w_kva": _dense(ks[1], (h, z.row), h, dt),
+            "kv_norm": jnp.ones((z.rank,), jnp.float32),
+            "w_kvb": _dense(ks[2], (z.rank, nh, z.nope + z.value), z.rank,
+                            dt),
+            "wo": _dense(ks[3], (nh, z.value, h), nh * z.value, dt)}}
     if kind == "F":
         m = cfg.intermediate_size
         return {"ln_mlp": ln, "mlp": {
@@ -272,7 +324,7 @@ def rope_angles(positions, cfg: TransformerConfig
     """cos, sin (N, D/2) float32 of the rotary angles at `positions` (N,),
     which may be traced (a suffix after cached tokens, each slot's own
     length in a decode step)."""
-    d = cfg.head_dim_
+    d = cfg.latent.rope if cfg.latent else cfg.head_dim_
     freqs = 1.0 / (cfg.rope_theta
                    ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]
@@ -458,18 +510,20 @@ def _layer_of(stack, layer, pin=None):
 
 
 def _around_attention(qkv, out, x, cos, sin, attend, cfg: TransformerConfig,
-                      blocks, row_block: int):
+                      blocks, row_block: int, heads=None):
     """The two row-wise halves around an attention, each over the rows as
     `over_rows` has it: `qkv(x, cos, sin)` -> q, k, v as `block_qkv` gives
-    them, the caller's `attend(q, k, v)` -> (o, kept) between the loops,
-    `out(x, o)` -> the new x.  Returns (x, kept)."""
-    heads = tuple(jax.ShapeDtypeStruct((*x.shape[:2], n, cfg.head_dim_),
-                                       x.dtype)
-                  for n in (cfg.num_heads, cfg.num_kv_heads, cfg.num_kv_heads))
-    q, k, v = over_rows(
+    them (or what `heads`, the (heads, width) of each result, says: a latent
+    layer's q and cache row), the caller's `attend(q, k, v)` -> (o, kept)
+    between the loops, `out(x, o)` -> the new x.  Returns (x, kept)."""
+    heads = heads or [(n, cfg.head_dim_) for n in (
+        cfg.num_heads, cfg.num_kv_heads, cfg.num_kv_heads)]
+    heads = tuple(jax.ShapeDtypeStruct((*x.shape[:2], *h), x.dtype)
+                  for h in heads)
+    first = over_rows(
         qkv, [(x, 1), (cos, cos.ndim - 2), (sin, sin.ndim - 2)], heads,
         blocks, row_block)
-    o, kept = attend(q, k, v)
+    o, kept = attend(*first)
     x, = over_rows(lambda x, o: (out(x, o),), [(x, 1), (o, 1)], (x,), blocks,
                    row_block)
     return x, kept
@@ -499,13 +553,14 @@ def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
 
 # The kinds of block a pattern is made of.  Each is defined once, here, and
 # every path calls it: `D` the dense block above (attention and SwiGLU),
-# `*` attention alone, `F` the SwiGLU feed-forward alone (`ffn_block`,
-# above), `M` a Mamba-2 mixer, `C` a gated short convolution, `E` a routed-
-# expert layer; each but `D` is x + mixer(rms_norm(x)).  The STATEFUL kinds
-# carry recurrent state from row to row: a tree a layer (`zero_state`),
-# which the engine keeps a row a slot and checkpoints in its prefix cache
-# without knowing what is in it.
-KINDS = "D*FMCE"
+# `*` attention alone, `L` latent attention alone (`latent_block`, below),
+# `F` the SwiGLU feed-forward alone (`ffn_block`, above), `M` a Mamba-2
+# mixer, `C` a gated short convolution, `E` a routed-expert layer; each but
+# `D` is x + mixer(rms_norm(x)).  The STATEFUL kinds carry recurrent state
+# from row to row: a tree a layer (`zero_state`), which the engine keeps a
+# row a slot and checkpoints in its prefix cache without knowing what is in
+# it.
+KINDS = "D*FMCEL"
 STATEFUL = "MC"
 
 
@@ -541,6 +596,129 @@ def attention_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
         lambda x, cos, sin: block_qkv(lp, x, cos, sin, cfg),
         lambda x, o: attn_out(lp, x, o, cfg), x, cos, sin, attend, cfg,
         blocks, row_block)
+
+
+# Latent attention (`L`; Moonlight-16B-A3B, `model_type` deepseek_v3, no
+# compressed query).  With h = rms_norm(x):
+#
+#     q = h W_q                 -> H x [q_n (nope) | q_r (rope)],  q_r rotated
+#     [c | k_r] = h W_kva       -> ONE row a token, shared by all heads
+#     c = rms_norm(c);  k_r rotated          THE CACHE ROW: [c | k_r]
+#     expanded:  [k_n,h | v_h] = c W_kvb,h
+#                s_h(t,u) = (q_n,h(t).k_n,h(u) + q_r,h(t).k_r(u)) * scale
+#                o_h = sum_u softmax_u(s_h)(t,u) v_h(u)
+#     absorbed:  q~_h = q_n,h W_kvb,h[k]^T;  s_h = (q~_h.c(u) + q_r,h.k_r(u))
+#                * scale;  o~_h = sum_u p_h c(u);  o_h = o~_h W_kvb,h[v]
+#     x + concat_h(o_h) W_o
+#
+# The two forms give the same numbers from the same weights.  The expanded
+# one pays W_kvb once for every key row and then 320 values a head and pair;
+# the absorbed one pays nothing a key row and 1,088 a head and pair: ONE
+# first half (`latent_qrow`), two builders of the attention over given key
+# rows (`LATENT_FORMS`), and one rule that picks from the shapes
+# (`latent_form`).  Departures from the published modelling code: a rotated
+# pair is split in halves, not interleaved (with seeded weights a
+# permutation of W_q's and W_kva's columns); no rope scaling.
+
+def latent_form(cached: int) -> str:
+    """Which form a call's attention takes, from how many of its key rows
+    lie in the pool: over `cached` rows (a decode step, a suffix after a
+    cached prefix) it attends them as they lie, "absorbed"; a whole prompt
+    (nothing cached) up-projects every key row once, "expanded".  A key row
+    costs the expanded form W_kvb once (rank x H x (nope + value) products)
+    and the absorbed form 768 products more a head and query row, equal at
+    171 query rows at the published widths: a threshold for long suffixes
+    over cached rows waits for traffic that has them and a chip run that
+    sets it."""
+    return "absorbed" if cached else "expanded"
+
+
+def latent_qrow(lp, x, cos, sin, cfg: TransformerConfig):
+    """The first half of `L`: x (B, S, E) -> the per-head queries (B, S, H,
+    nope + rope) and the token's cache row (B, S, 1, rank + rope)."""
+    z, dt, w = cfg.latent, cfg.dtype, lp["attn"]
+    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
+    q = jnp.einsum("bse,ehd->bshd", h, w["wq"].astype(dt))
+    row = jnp.einsum("bse,ec->bsc", h, w["w_kva"].astype(dt))[:, :, None]
+    q = jnp.concatenate(
+        [q[..., :z.nope], apply_rope(q[..., z.nope:], cos, sin)], axis=-1)
+    row = jnp.concatenate(
+        [rms_norm(row[..., :z.rank], w["kv_norm"], cfg.rms_norm_eps),
+         apply_rope(row[..., z.rank:], cos, sin)], axis=-1)
+    return q, row
+
+
+def _latent_softmax(q, k, v, mask, scale: float):
+    """q (B, S, H, C) over keys k and values v, (B, T, H, .) a head's own or
+    (B, T, .) shared by all heads; key t open to query s where mask (S, T)."""
+    keys = "bthc" if k.ndim == 4 else "btc"
+    s = jnp.einsum(f"bshc,{keys}->bhst", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask[None, None], s, -1e30)
+    # The row maximum behind a barrier: fused with the subtraction that
+    # broadcasts it back, the chip's compiler turns the two into ONE
+    # reduce-window as wide as the keys, 23.5 ms a block of 512 query rows
+    # against 8,192 keys where the scores' product takes 0.4 (PERF.md §6,
+    # PR 44).
+    top = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - top)
+    p = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(q.dtype)
+    return jnp.einsum(f"bhst,{keys}->bshc", p, v)
+
+
+def latent_absorb(w, q, cfg: TransformerConfig):
+    """Queries as wide as the cache row: [q_n W_kvb[k]^T | q_r] (..., H,
+    rank + rope)."""
+    z = cfg.latent
+    wide = jnp.einsum("...hd,chd->...hc", q[..., :z.nope],
+                      w["w_kvb"][..., :z.nope].astype(q.dtype))
+    return jnp.concatenate([wide, q[..., z.nope:]], axis=-1)
+
+
+def latent_unabsorb(w, o, cfg: TransformerConfig):
+    """Sums of compressed rows (..., H, rank) -> a head's values."""
+    return jnp.einsum("...hc,chd->...hd", o,
+                      w["w_kvb"][..., cfg.latent.nope:].astype(o.dtype))
+
+
+def expanded_attend(w, rows, cfg: TransformerConfig):
+    """Key rows (B, T, rank + rope) up-projected ONCE to per-head keys and
+    values -> attend(q (B, S, H, nope + rope), mask (S, T), upto=None) ->
+    (B, S, H, value); `upto` (static): the first so many keys alone, for a
+    caller that knows its queries see no later one."""
+    z = cfg.latent
+    kv = jnp.einsum("btc,chd->bthd", rows[..., :z.rank],
+                    w["w_kvb"].astype(rows.dtype))
+    shared = jnp.broadcast_to(rows[:, :, None, z.rank:],
+                              (*kv.shape[:3], z.rope))
+    k = jnp.concatenate([kv[..., :z.nope], shared], axis=-1)
+    v = kv[..., z.nope:]
+    return lambda q, mask, upto=None: _latent_softmax(
+        q, k[:, :upto], v[:, :upto], mask[:, :upto], z.scale)
+
+
+def absorbed_attend(w, rows, cfg: TransformerConfig):
+    """The same attention over the key rows as they lie: the queries take
+    W_kvb's key half, the sums of compressed rows its value half."""
+    z = cfg.latent
+    return lambda q, mask, upto=None: latent_unabsorb(w, _latent_softmax(
+        latent_absorb(w, q, cfg), rows[:, :upto], rows[:, :upto, :z.rank],
+        mask[:, :upto], z.scale), cfg)
+
+
+LATENT_FORMS = {"expanded": expanded_attend, "absorbed": absorbed_attend}
+
+
+def latent_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
+                 blocks=None, row_block: int = ROW_BLOCK):
+    """`L`: `attend(q, row, w)` -> (o (B, S, H, value), kept), given the
+    layer's attention weights `w` for whichever form it takes."""
+    z = cfg.latent
+    return _around_attention(
+        lambda x, cos, sin: latent_qrow(lp, x, cos, sin, cfg),
+        lambda x, o: attn_out(lp, x, o, cfg), x, cos, sin,
+        lambda q, row: attend(q, row, lp["attn"]), cfg, blocks, row_block,
+        [(cfg.num_heads, z.nope + z.rope), (1, z.row)])
 
 
 def _stateful_block(mixer, dims):
@@ -614,7 +792,8 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
                 row_block: int = ROW_BLOCK):
     """A pattern of kinds, block by block (`layers`: one tree a block).
     `attend(q, k, v, *at)` as `scan_blocks` takes it, `at` the i-th slice of
-    `per_layer` for the i-th attention layer; `rec` the recurrent state, one
+    `per_layer` for the i-th attention layer (an `L` layer: `attend(q, row,
+    w, *at)`, `latent_block`); `rec` the recurrent state, one
     tree (`zero_state`) for each STATEFUL block in order.  Which rows are
     real: the first `length` (a prefill's padded bucket), the slots that are
     `live` (B,) (a decode step); the others move no state and meet no
@@ -640,6 +819,12 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
             x, k = attention_block(
                 lp, x, cos, sin, lambda q, k, v: attend(q, k, v, *at), cfg,
                 *by)
+            kept.append(k)
+        elif kind == "L":
+            at = tuple(a[len(kept)] for a in per_layer)
+            x, k = latent_block(
+                lp, x, cos, sin, lambda q, row, w: attend(q, row, w, *at),
+                cfg, *by)
             kept.append(k)
         elif kind in STATEFUL:
             x, state, ck = _STATEFUL_BLOCK[kind](
@@ -692,6 +877,11 @@ def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
                 lp, x, zero_state(cfg, kind, batch), cfg)[0]
         elif kind == "F":
             x = ffn_block(lp, x, cfg)
+        elif kind == "L":
+            causal = jnp.tril(jnp.ones((tokens.shape[1],) * 2, bool))
+            x = latent_block(
+                lp, x, cos, sin, lambda q, row, w: (expanded_attend(
+                    w, row[:, :, 0], cfg)(q, causal), None), cfg)[0]
         else:
             x = attention_block(
                 lp, x, cos, sin, lambda q, k, v: (_xla_attention(q, k, v),

@@ -23,9 +23,19 @@ leaves that array without a 128-wide minor dimension; the chip then lays the
 pages innermost and every scatter and gather of the serving step converts
 the whole pool.  So where `KV * D` is a multiple of 128 the pool holds a
 token's keys of all KV heads as ONE row of `KV * D` lanes, `(L, N, page,
-KV * D)`, for which there is one layout to want.  `pool_shape`, `pool_rows`
-and `head_rows` are the only places that know; the engine's allocation,
-install, decode write and every reader go through them.
+KV * D)`, for which there is one layout to want.  A LATENT layer caches one
+row a token that is key and value at once (`[c | k_r]`, 512 + 64 values for
+the published widths): one KV "head" that is no whole number of lane rows.
+The pool holds it as one row padded with zeros to whole lane rows, `(L, N,
+page, 640)` (the chip pads a 576-wide minor dimension to 640 either way),
+and a latent model has ONE pool where the others have two: the second is
+None, an empty tree, so the engine's allocation, install, decode write and
+gathers stay one code path (`jax.tree.map` over the pair).  `pool_shape`,
+`pool_rows` and `head_rows` are the only places that know; the engine's
+allocation, install, decode write and every reader go through them.
+`paged_latent_attention` is the decode step's read of such a pool: the
+same kernel, one copy of a page serving as keys and, in its first lanes,
+as values.
 """
 
 from __future__ import annotations
@@ -45,14 +55,22 @@ _LANES = 128
 
 def pool_row(num_kv_heads: int, head_dim: int) -> str:
     """How the pool holds one token's keys (or values) of one layer:
-    "heads", `(KV, D)`, or "lanes", one row of `KV * D` lanes, for heads
-    narrower than a lane row whose KV heads together fill whole ones."""
+    "heads", `(KV, D)`; "lanes", one row of `KV * D` lanes, for heads
+    narrower than a lane row whose KV heads together fill whole ones; or
+    "latent", the one row a token that a latent layer caches (one KV head,
+    wider than a lane row and no whole number of them: 576), padded to whole
+    lane rows."""
+    if num_kv_heads == 1 and head_dim > _LANES and head_dim % _LANES:
+        return "latent"
     narrow = head_dim < _LANES and (num_kv_heads * head_dim) % _LANES == 0
     return "lanes" if narrow else "heads"
 
 
 def _row_shape(num_kv_heads: int, head_dim: int) -> tuple:
-    if pool_row(num_kv_heads, head_dim) == "lanes":
+    row = pool_row(num_kv_heads, head_dim)
+    if row == "latent":
+        return (-(-head_dim // _LANES) * _LANES,)
+    if row == "lanes":
         return (num_kv_heads * head_dim,)
     return (num_kv_heads, head_dim)
 
@@ -65,13 +83,19 @@ def pool_shape(layers: int, n_pages: int, page: int, num_kv_heads: int,
 
 def pool_rows(x, num_kv_heads: int, head_dim: int):
     """Rows `(..., KV, D)` as the pool holds them (the two minor dimensions
-    merge where the row is "lanes": no element moves)."""
-    return x.reshape(x.shape[:-2] + _row_shape(num_kv_heads, head_dim))
+    merge where the row is "lanes": no element moves; a "latent" row gains
+    its zeros)."""
+    row = _row_shape(num_kv_heads, head_dim)
+    if pool_row(num_kv_heads, head_dim) == "latent":
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, row[0] - head_dim)])
+    return x.reshape(x.shape[:-2] + row)
 
 
 def head_rows(x, num_kv_heads: int, head_dim: int):
     """Rows read out of a pool, `(..., *row)`, as `(..., KV, D)`."""
     lead = x.ndim - len(_row_shape(num_kv_heads, head_dim))
+    if pool_row(num_kv_heads, head_dim) == "latent":
+        x = x[..., :head_dim]
     return x.reshape(x.shape[:lead] + (num_kv_heads, head_dim))
 
 
@@ -127,17 +151,42 @@ def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
     return o.reshape(B, H, D).astype(q.dtype)
 
 
+def _wide_queries(q, pool):
+    """Queries (B, H, C) as wide as the latent pool's padded row."""
+    return jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
+
+
+def reference_latent_attention(q, pool, tables, lengths, layer=None, *,
+                               scale: float, value_lanes: int):
+    """Plain `jax.numpy` form of `paged_latent_attention` (same signature):
+    gathers every slot's whole table and masks what is past its length.  A
+    latent row is a row of lanes with ONE KV head that is key and value:
+    `_lanes_attention` over it, of whose sums the first lanes are kept."""
+    pages = (tables,) if layer is None else (layer, tables)
+    rows = pool[pages].reshape(q.shape[0], -1, pool.shape[-1])    # (B, T, W)
+    return _lanes_attention(_wide_queries(q, pool), rows, rows, lengths,
+                            scale)[..., :value_lanes]
+
+
 def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
-                  q_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, bias_scr, sem, *,
-                  scale: float, page: int, kv_heads: int, chunk_pages: int):
+                  q_ref, *refs,
+                  scale: float, page: int, kv_heads: int, chunk_pages: int,
+                  value_lanes: int = 0):
     """All slots of one layer.  Work is the list of (slot, chunk) pairs in
     order; while one chunk is computed the next one's pages are in flight,
-    across slot boundaries too."""
+    across slot boundaries too.  `refs`: k_hbm, v_hbm, o_ref, kbuf, vbuf,
+    bias_scr, sem; with `value_lanes` (a latent pool) there is one pool and
+    one buffer, k_hbm, o_ref, kbuf, bias_scr, sem: a copied row is the key
+    and, in its first `value_lanes` lanes, the value."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, D = q_ref.shape
+    if value_lanes:
+        k_hbm, o_ref, kbuf, bias_scr, sem = refs
+    else:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, bias_scr, sem = refs
+    B, H, _ = q_ref.shape
+    D = o_ref.shape[-1]
     P = tables_ref.shape[0] // B
     rows_page = page * kv_heads
     R = chunk_pages * rows_page
@@ -152,8 +201,11 @@ def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
 
     def page_copies(pid, buf, j):
         dst = pl.ds(j * rows_page, rows_page)
-        return (pltpu.make_async_copy(k_hbm.at[li, pid], kbuf.at[buf, dst],
-                                      sem.at[0, buf]),
+        keys = pltpu.make_async_copy(k_hbm.at[li, pid], kbuf.at[buf, dst],
+                                     sem.at[0, buf])
+        if value_lanes:
+            return (keys,)
+        return (keys,
                 pltpu.make_async_copy(v_hbm.at[li, pid], vbuf.at[buf, dst],
                                       sem.at[1, buf]))
 
@@ -200,7 +252,7 @@ def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
 
             wait(buf)
             k = kbuf[buf]                                     # (R, D)
-            v = vbuf[buf]
+            v = k[:, :value_lanes] if value_lanes else vbuf[buf]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)           # (H, R)
@@ -262,6 +314,43 @@ def _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
       q, pool_k.reshape(L, N, rows_page, D), pool_v.reshape(L, N, rows_page, D))
 
 
+def _paged_latent_pallas(q, pool, tables, lengths, layer, scale,
+                         value_lanes, interpret=False):
+    """`_paged_kernel` over a latent pool (L, N, page, W): one KV head, W
+    lanes a row, half the rows a chunk (a row is 5 lane rows wide)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, _ = q.shape
+    W = pool.shape[-1]
+    page = pool.shape[2]
+    chunk_pages = max(1, _CHUNK_ROWS // 2 // page)
+    R = chunk_pages * page
+    kernel = functools.partial(_paged_kernel, scale=scale, page=page,
+                               kv_heads=1, chunk_pages=chunk_pages,
+                               value_lanes=value_lanes)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(),
+            in_specs=[vmem, hbm],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, R, W), pool.dtype),
+                pltpu.VMEM((H, R), jnp.float32),
+                pltpu.SemaphoreType.DMA((1, 2)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, value_lanes), q.dtype),
+        name="paged_latent_attention",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      _wide_queries(q, pool), pool)
+
+
 # Page tables ride in scalar memory (1 MiB on a v5e) beside the lengths.
 _TABLE_BYTES = 512 << 10
 
@@ -278,12 +367,23 @@ def kernel_tiles(q_shape, pool_shape, tables_shape) -> bool:
         and 4 * math.prod(tables_shape) <= _TABLE_BYTES
 
 
-def decode_path(q_shape, pool_shape, tables_shape) -> str:
-    """Which implementation `paged_decode_attention` runs for these shapes
-    in this process: "pallas" or "reference"."""
+def latent_kernel_tiles(pool_shape, tables_shape, value_lanes: int) -> bool:
+    """`kernel_tiles` for a latent pool: rows and values of whole lane
+    rows, pages of whole bf16 sublane tiles, tables that fit."""
+    return pool_shape[-1] % _LANES == 0 and value_lanes % _LANES == 0 \
+        and pool_shape[-2] % 16 == 0 \
+        and 4 * math.prod(tables_shape) <= _TABLE_BYTES
+
+
+def decode_path(q_shape, pool_shape, tables_shape,
+                value_lanes: int = 0) -> str:
+    """Which implementation `paged_decode_attention` (with `value_lanes`,
+    over a latent pool: `paged_latent_attention`) runs for these shapes in
+    this process: "pallas" or "reference"."""
     on_tpu = jax.devices()[0].platform == "tpu"
-    return "pallas" if on_tpu and kernel_tiles(
-        q_shape, pool_shape, tables_shape) else "reference"
+    tiles = latent_kernel_tiles(pool_shape, tables_shape, value_lanes) \
+        if value_lanes else kernel_tiles(q_shape, pool_shape, tables_shape)
+    return "pallas" if on_tpu and tiles else "reference"
 
 
 def paged_decode_attention(q, pool_k, pool_v, tables, lengths, layer=None, *,
@@ -309,3 +409,23 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths, layer=None, *,
         pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
     return _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer,
                                 scale)
+
+
+def paged_latent_attention(q, pool, tables, lengths, layer=None, *,
+                           scale: float, value_lanes: int):
+    """`paged_decode_attention` over a LATENT pool, where a token's one row
+    is its key for every query head and, in its first `value_lanes` lanes,
+    its value: q (B, H, C) the queries as wide as the row's real values (the
+    absorbed form, models/transformer.py: `latent_absorb`), pool (N, page,
+    W) or the stacked (L, N, page, W) with `layer`, W the row padded to
+    whole lane rows.  Returns (B, H, value_lanes) in q's dtype: every page a
+    slot holds is copied once and serves all H heads twice."""
+    if decode_path(q.shape, pool.shape, tables.shape,
+                   value_lanes) != "pallas":
+        return reference_latent_attention(
+            q, pool, tables, lengths, layer, scale=scale,
+            value_lanes=value_lanes)
+    if layer is None:
+        pool, layer = pool[None], 0
+    return _paged_latent_pallas(q, pool, tables, lengths, layer, scale,
+                                value_lanes)

@@ -181,7 +181,9 @@ def test_one_held_expert_is_the_plain_feed_forward():
 
 def _plain_gated(lp, x, dims):
     """The gated layer of `benchmark/families/lfm2_moe.py` in plain numpy:
-    every expert of the model in turn, no latent space, no shared expert."""
+    every expert of the model in turn, no latent space; with a shared width
+    `benchmark/families/deepseek_v3.py`'s, whose shared expert is one more
+    SwiGLU on every row.  -> (the routed sum, the shared expert's part)."""
     lp = jax.tree.map(lambda a: np.asarray(a, np.float64), lp)
     x = np.asarray(x, np.float64)
     s = 1 / (1 + np.exp(-(x @ lp["router"])))
@@ -193,30 +195,40 @@ def _plain_gated(lp, x, dims):
         mine = np.where(take == e, w, 0).sum(-1)
         gate, up = np.split(x @ lp["w1"][e], 2, -1)
         out += mine[:, None] * ((gate / (1 + np.exp(-gate)) * up) @ lp["w2"][e])
-    return out, 0.0
+    if not dims.shared_width:
+        return out, 0.0
+    gate, up = np.split(x @ lp["ws1"], 2, -1)
+    return out, (gate / (1 + np.exp(-gate)) * up) @ lp["ws2"]
 
 
-@pytest.mark.parametrize("form", ["latent_relu2", "gated"])
+@pytest.mark.parametrize("form", ["latent_relu2", "gated", "gated_shared"])
 def test_the_four_shares_add_up_to_the_uncut_layer(form):
     """The deployment a held share stands for: 4 chips hold a quarter of the
     experts each.  Their routed parts, with the shared expert (which every
     chip computes alike) counted once, are the whole layer: 4 of 16 latent
     relu^2 experts with a shared one (the hybrid), 16 of 64 gated experts at
-    top 4 with none (LFM2, whose cell holds all 64)."""
+    top 4 with none (LFM2, whose cell holds all 64), and two halves of 32
+    gated experts at top 6 with a gated shared one (Moonlight's layer)."""
+    chips = 4
     if form == "gated":
         share = routed.RoutedDims(experts=64, held=16, held_from=0, top_k=4,
                                   latent=0, width=24, shared_width=0,
                                   scale=1.0, gated=True)
+    elif form == "gated_shared":
+        chips = 2
+        share = routed.RoutedDims(experts=64, held=32, held_from=0, top_k=6,
+                                  latent=0, width=24, shared_width=48,
+                                  scale=2.446, gated=True)
     else:
         share = ROUTED
     n = share.held
-    whole = dataclasses.replace(share, held=4 * n, held_from=0)
+    whole = dataclasses.replace(share, held=chips * n, held_from=0)
     lp = _routed_layer(whole)
     x = jax.random.normal(jax.random.key(4), (1, 12, 32))
-    mix, shared = _plain_gated(lp, x[0], whole) if form == "gated" \
+    mix, shared = _plain_gated(lp, x[0], whole) if form != "latent_relu2" \
         else _plain_routed(lp, x[0], whole, 0, 16)
     parts = []
-    for chip in range(4):
+    for chip in range(chips):
         dims = dataclasses.replace(share, held_from=n * chip)
         mine = dict(lp, w1=lp["w1"][n * chip:n * chip + n],
                     w2=lp["w2"][n * chip:n * chip + n])

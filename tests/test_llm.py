@@ -673,6 +673,39 @@ def test_a_step_sent_ahead_changes_no_token():
     assert held.decode_stats() == plain.decode_stats()
 
 
+def test_a_tick_admits_one_slots_worth_of_prompt():
+    """Two long prompts that arrive in one tick are admitted a tick apart,
+    in their order, and end a tick apart (callers of a closed loop do not
+    come back together); short ones still take their slots in one tick; and
+    nobody's tokens change."""
+    make = lambda: _engine(None, max_batch=4, max_len=64, page_size=16,
+                           seed=0)
+    long_a, long_b, short = list(range(1, 41)), list(range(41, 81)), [7, 8, 9]
+    sp = SamplingParams(max_tokens=6)
+    eng = make()
+    for p in (long_a, long_b, short):
+        eng.add_request(p, sp)
+    ends, active = {}, []
+    for tick in range(12):
+        for r in eng.step():
+            ends[r.req_id] = tick
+        active.append(eng.active_requests + len(ends))
+    # 40 + 40 > 64: the second waits a tick, and the short one behind it
+    # goes with it (40 + 3 <= 64)
+    assert active[:2] == [1, 3] and not eng.has_unfinished()
+    assert ends[1] == ends[0] + 1 == ends[2]
+    assert eng.phases.admitting == 2
+    alone = [make().generate([p], sp)[0] for p in (long_a, long_b, short)]
+    again = make()
+    assert again.generate([long_a, long_b, short], sp) == alone
+    # three short prompts: one tick
+    eng = make()
+    for p in ([1, 2], [3, 4, 5], short):
+        eng.add_request(p, sp)
+    eng.step()
+    assert eng.active_requests == 3 and eng.phases.admitting == 1
+
+
 def test_debug_stats_count_pages_read():
     """`debug_stats()["decode"]`: pages the decode steps read (live ones:
     lengths // page + 1 of each active slot) beside what the tables

@@ -202,6 +202,20 @@ def test_the_pools_row_follows_the_head_width():
     assert not pa.kernel_tiles((32, 64), (2, 3073, 16, 512), (8, 256))
     assert pa.decode_path((32, 64), (2, 3073, 16, 512), (8, 256)) \
         == "reference"
+    # the third form: a latent layer's ONE row a token (512 + 64 values),
+    # one KV head that is no whole number of lane rows, padded to them
+    assert pa.pool_row(1, 576) == pa.pool_row(1, 160) == "latent"
+    assert pa.pool_row(1, 512) == pa.pool_row(2, 576) == "heads"
+    assert pa.pool_shape(9, 12289, 16, 1, 576) == (9, 12289, 16, 640)
+    assert pa.pool_shape(3, 9, 16, 1, 160) == (3, 9, 16, 256)
+    y = jnp.arange(1, 1 + 3 * 5 * 160).reshape(3, 5, 1, 160)
+    rows = pa.pool_rows(y, 1, 160)
+    assert rows.shape == (3, 5, 256) and not rows[..., 160:].any()
+    np.testing.assert_array_equal(pa.head_rows(rows, 1, 160), y)
+    assert pa.latent_kernel_tiles((9, 12289, 16, 640), (16, 512), 512)
+    assert not pa.latent_kernel_tiles((3, 9, 16, 256), (4, 8), 144)
+    assert pa.decode_path((16, 192), (9, 12289, 16, 640), (16, 512), 512) \
+        == "reference"                                          # no TPU
 
 
 @pytest.mark.parametrize("page", [16, 64])
@@ -248,23 +262,28 @@ def test_narrow_head_cases(what):
                                        else BF16_TOL)
 
 
-@pytest.mark.parametrize("kv,d", [(8, 64), (2, 64), (2, 128), (4, 16)])
+@pytest.mark.parametrize("kv,d", [(8, 64), (2, 64), (2, 128), (4, 16),
+                                  (1, 576), (1, 160)])
 def test_install_then_read_gives_back_the_rows(kv, d):
     """`_install_fn` writes whole pages of rows as the pool holds them; read
-    through the slot's page row they are the rows installed, one by one."""
+    through the slot's page row they are the rows installed, one by one.  A
+    latent row (one head of 576 or 160) has ONE pool and None beside it."""
     from ray_tpu.llm import engine as E
     L, page, P_, Sb = 2, 16, 4, 40
+    latent = pa.pool_row(kv, d) == "latent"
     shape = pa.pool_shape(L, 9, page, kv, d)
-    assert len(shape) == (4 if d == 64 else 5)
+    assert len(shape) == (4 if d == 64 or latent else 5)
     ks, vs = (jax.random.normal(jax.random.key(i), (L, Sb, kv, d),
                                 jnp.float32).astype(jnp.bfloat16)
               for i in range(2))
     pages = jnp.asarray([5, 2, 7, 0], jnp.int32)    # 3 reserved, then scratch
+    empty = jnp.full(shape, jnp.nan, jnp.bfloat16)
+    if latent:
+        vs = None
     pk, pv = jax.jit(lambda *a: E._install_fn(*a, page, None))(
-        jnp.full(shape, jnp.nan, jnp.bfloat16),
-        jnp.full(shape, jnp.nan, jnp.bfloat16), ks, vs, pages)
-    assert pk.shape == shape
-    for pool, rows in ((pk, ks), (pv, vs)):
+        empty, None if latent else empty, ks, vs, pages)
+    assert pk.shape == shape and (pv is None) == latent
+    for pool, rows in ((pk, ks), (pv, vs))[:1 if latent else 2]:
         back = pa.head_rows(pool[:, pages[:3]], kv, d).reshape(L, -1, kv, d)
         np.testing.assert_array_equal(np.asarray(back[:, :Sb], np.float32),
                                       np.asarray(rows, np.float32))
@@ -274,10 +293,87 @@ def test_install_then_read_gives_back_the_rows(kv, d):
     # one token a slot, as the decode step writes it, read back by attention:
     # a slot of one token attends to that token alone
     q = jnp.ones((1, kv, d), jnp.bfloat16)
-    o = pa.paged_decode_attention(q, pk, pv, pages[None, :1],
-                                  jnp.zeros((1,), jnp.int32), jnp.int32(1))
+    if latent:
+        o = pa.paged_latent_attention(
+            jnp.ones((1, 4, d), jnp.bfloat16), pk, pages[None, :1],
+            jnp.zeros((1,), jnp.int32), jnp.int32(1), scale=0.1,
+            value_lanes=d - 16)
+        want = jnp.broadcast_to(ks[1, 0, 0, :d - 16], (4, d - 16))
+    else:
+        o = pa.paged_decode_attention(q, pk, pv, pages[None, :1],
+                                      jnp.zeros((1,), jnp.int32), jnp.int32(1))
+        want = vs[1, 0]
     np.testing.assert_array_equal(np.asarray(o[0], np.float32),
-                                  np.asarray(vs[1, 0], np.float32))
+                                  np.asarray(want, np.float32))
+
+
+# ---- a latent pool: one row a token, key and value at once ----------------
+
+def _latent_case(lengths, *, page=16, width=576, n_slots_pages=6, seed=0,
+                 heads=16, shared=(), dtype=jnp.bfloat16):
+    """Wide queries, ONE pool of padded rows, tables, lengths."""
+    q, pool, _, tables, lens = _case(heads, page, lengths, kv=1,
+                                     n_slots_pages=n_slots_pages, seed=seed,
+                                     shared=shared, dtype=dtype, d=width)
+    return q, pool, tables, lens
+
+
+def _latent_oracle(q, pool, tables, lengths, scale, lanes):
+    q, pool = np.asarray(q, np.float32), np.asarray(pool, np.float32)
+    tables, lengths = np.asarray(tables), np.asarray(lengths)
+    out = np.zeros((*q.shape[:2], lanes), np.float32)
+    for b in range(q.shape[0]):
+        rows = pool[tables[b]].reshape(-1, pool.shape[-1])[:lengths[b] + 1]
+        for h in range(q.shape[1]):
+            s = rows[:, :q.shape[2]] @ q[b, h] * scale
+            p = np.exp(s - s.max())
+            out[b, h] = (p / p.sum()) @ rows[:, :lanes]
+    return out
+
+
+@pytest.mark.parametrize("what", ["page16", "page64", "inactive", "shared",
+                                  "stacked", "narrow", "float32"])
+def test_latent_kernel_cases(what, monkeypatch):
+    """The kernel over a latent pool (interpreted), the plain function and a
+    float32 softmax written here: every head of a slot against each row
+    once, the row's first lanes its value."""
+    page, width, lanes, kwargs, layer = 16, 576, 512, {}, None
+    lengths, scale = _lengths(16, 6), 1 / math.sqrt(192)
+    if what == "page64":
+        page, lengths = 64, _lengths(64, 6)
+    elif what == "inactive":
+        lengths = [0, 40, 0, 17]
+    elif what == "shared":
+        kwargs["shared"] = [(0, 1, 3), (0, 2, 3)]
+        lengths = [70, 3 * page, 3 * page + 20]
+    elif what == "narrow":
+        width, lanes = 160, 128     # a rehearsal's row with whole-lane values
+        kwargs["heads"] = 8
+    elif what == "float32":
+        kwargs["dtype"] = jnp.float32
+    monkeypatch.setattr(pa, "_CHUNK_ROWS", 4 * page)    # two pages a chunk
+    q, pool, tables, lens = _latent_case(lengths, page=page, width=width,
+                                         **kwargs)
+    assert pool.shape[-1] % 128 == 0 and pool.ndim == 3
+    if what == "inactive":
+        tables = tables.at[0].set(0).at[2].set(0)
+    want = _latent_oracle(q, pool, tables, lens, scale, lanes)
+    if what == "stacked":
+        other = jnp.full_like(pool, jnp.nan)
+        pool, layer = jnp.stack([other, pool, other]), jnp.int32(1)
+    ref = np.asarray(jax.jit(
+        lambda *a: pa.paged_latent_attention(*a, scale=scale,
+                                             value_lanes=lanes))(
+        q, pool, tables, lens, layer), np.float32)
+    got = np.asarray(jax.jit(
+        lambda q, pool, tb, ln, li: pa._paged_latent_pallas(
+            q, pool if li is not None else pool[None], tb, ln,
+            0 if li is None else li, scale, lanes, interpret=True))(
+        q, pool, tables, lens, layer), np.float32)
+    tol = 1e-4 if what == "float32" else BF16_TOL
+    assert np.isfinite(got).all() and got.shape == (*q.shape[:2], lanes)
+    assert np.abs(got - want).max() < tol
+    assert np.abs(ref - want).max() < tol
 
 
 # ---- compiled for the chip, without the chip -----------------------------
@@ -486,6 +582,84 @@ def test_lfm2_decode_step_and_install_compile_for_v5e_without_pool_copies(
         # (The step reads 15.1 MiB: 8.0 MiB in HBM, 512-byte tuple headers
         # 16 KiB apart by the compiler's buffer assignment; an install 0.)
         assert mem.temp_size_in_bytes < half // 4
+
+
+def test_moonlight_programs_compile_for_v5e_around_one_pool(
+        topo, monkeypatch, no_compile_cache):
+    """Moonlight's decode step, install and a re-ask's suffix prefill at
+    serve_doc_reask_mla's shapes (9 latent layers, 8,193 pages of 16 rows of
+    640 lanes, 16 slots x 512 pages): the ONE pool aliases its output, the
+    step holds nine latent kernels and no copy of the pool or of a layer of
+    it (a layer sliced out before the suffix's gather was a whole pool of
+    scratch), and the kernel alone compiles at those shapes."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.families import deepseek_v3
+    from benchmark.run import ROOT
+    from ray_tpu.llm import engine as E
+    from ray_tpu.models import routed
+    from ray_tpu.models.transformer import init_params
+
+    monkeypatch.setattr(routed, "grouped_path", lambda: "megablox")
+    monkeypatch.setattr(pa, "decode_path", lambda *a: "pallas")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "moonlight-16b-a3b-l9.json")) as f:
+        cfg = deepseek_v3.program_config(json.load(f))
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    on_chip = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+    B, page, P_ = 16, 16, 512
+    L = cfg.count("L")
+    assert (L, cfg.cache_row) == (9, (1, 576))
+    pool = S(pa.pool_shape(L, 8193, page, *cfg.cache_row), cfg.dtype)
+    assert pool.shape == (9, 8193, 16, 640)
+    whole = math.prod(pool.shape) * 2
+
+    kernel = jax.jit(lambda q, pool, tb, ln, li: pa._paged_latent_pallas(
+        q, pool, tb, ln, li, cfg.latent.scale, 512)).lower(
+        S((B, 16, 576), cfg.dtype), pool, S((B, P_), jnp.int32),
+        S((B,), jnp.int32), S((), jnp.int32)).compile()
+    assert "paged_latent_attention" in kernel.as_text()
+    assert kernel.memory_analysis().temp_size_in_bytes < 1 << 20
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = {"slots": S((B, P_ + 4), jnp.int32),
+             "rng": S(key.shape, key.dtype)}
+
+    def decode_step(p, pk, pv, state, update):
+        return E._decode_fn(p, pk, pv, state, update, cfg, page, None)
+    step = jax.jit(decode_step, donate_argnums=(1, 2, 3)).lower(
+        params, pool, None, state, S((B, P_ + 5), jnp.int32)).compile()
+    aliases = {int(o): int(i) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", step.as_text().split("\n", 1)[0])}
+    assert aliases[0] == len(jax.tree.leaves(params))
+    text = step.as_text()
+    assert len(set(re.findall(r"%(paged_latent_attention[.\d]*) =", text))) \
+        == 9 and "gmm" in text
+
+    def install_kv(pk, pv, ks, vs, pages):
+        return E._install_fn(pk, pv, ks, vs, pages, page, None)
+    install = jax.jit(install_kv, donate_argnums=(0, 1)).lower(
+        pool, None, S((L, 8192, 1, 576), cfg.dtype), None,
+        S((P_,), jnp.int32)).compile()
+
+    def suffix(p, pk, pv, pg, t, pl, n, ckpt, row):
+        return E._state_prefill_fn(p, pk, pv, pg, t, pl, n, ckpt, row, cfg,
+                                   page, 0)
+    reask = jax.jit(suffix).lower(
+        params, pool, None, S((P_,), jnp.int32), S((1, 64), jnp.int32),
+        S((), jnp.int32), S((), jnp.int32), [], S((), jnp.int32)).compile()
+    for compiled in (step, install, reask):
+        assert _pool_sized_copies(compiled, pool) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < whole // 16
+    for compiled in (step, install):
+        assert compiled.memory_analysis().alias_size_in_bytes >= whole
 
 
 # ---- the prefill kernel (ops/prefill_attention.py) ------------------------
