@@ -80,17 +80,52 @@ def route(lp, x, dims: RoutedDims):
     return idx.astype(jnp.int32), w
 
 
+ROW_TILE = 128      # rows of a tile of the grouped product
+_VMEM = 15 << 20    # of the 16 MiB a kernel may use unasked, with a margin
+
+
 def _tile(width: int) -> int:
-    """A tile of the grouped product along a width: of the width's divisors
-    in whole 128-lane rows the one nearest 1,024 (the smaller of two as
-    near), so that a tile of an expert's matrix is a piece of about two
-    megabytes: 1,024 of 2,048 and 3,072, 896 of 2,688, 768 of 1,536, a
-    width up to 1,024 whole, and 1,408 of 1,408 and of 2,816, whose only
-    smaller divisors, 128 and 256, read the matrix in pieces of half a
-    megabyte at two thirds of the bandwidth (PERF.md §6, PR 44).  A width
-    that is no whole number of lane rows is taken whole."""
+    """A tile of the grouped product along n: of the width's divisors in
+    whole 128-lane rows the one nearest 1,024 (the smaller of two as near),
+    so that a tile of an expert's matrix is a piece of about two megabytes
+    or more: 1,024 of 2,048 and 3,072, 896 of 2,688, 768 of 1,536, a width
+    up to 1,024 whole, and 1,408 of 1,408 and of 2,816, whose only smaller
+    divisors, 128 and 256, read the matrix in pieces of half a megabyte at
+    two thirds of the bandwidth (PERF.md §6, PR 44).  A width that is no
+    whole number of lane rows is taken whole.  Along k it is what is left
+    where k whole does not fit (`_tiling`)."""
     fits = [t for t in range(128, width + 1, 128) if width % t == 0]
     return min(fits, key=lambda t: (abs(t - 1024), t)) if fits else width
+
+
+def _tiling(k: int, n: int, itemsize: int = 2):
+    """The (row, k, n) tiles of a grouped product with matrices (k, n):
+    k in ONE tile.  The kernel's grid runs the n tiles outermost, then the
+    row tiles, the k tiles innermost, and copies a block of a group's matrix
+    again whenever the block it asks for changes.  With k cut in two tiles
+    or more that is at every grid step: each row tile of a group reads the
+    group's whole matrix again, and a prompt's products are bound by those
+    reads and not by the MXU (Moonlight's 560 rows an expert read its w1
+    4-5 times).  With k whole, consecutive row tiles of a group ask for the
+    same block, and a matrix is read once a group whatever its rows.  That
+    holds for every call: a decode step, whose groups have one row tile
+    each, reads what it read and takes as long, and a re-ask's 64-row
+    suffix is a tenth faster.  The row tile stays `ROW_TILE`: a taller one
+    wastes products on every group's edge tiles and won nowhere (all timed
+    alone on the chip at the three families' shapes: PERF.md §6, PR 45).
+    Only where the kernel's blocks at k whole (its three blocks twice, the
+    pipeline's double buffer, and a float32 accumulator) would not fit its
+    VMEM, as a float32 layer's would not, is k cut by `_tile`.  The widest
+    blocks the cells take, bf16 (128, 2,048, 1,408), are 13.4 MiB by this
+    reckoning and compile and run on the chip.  `_VMEM` was set against the
+    compiler (PR 45: for a v5e it took every tiling up to 15.1 MiB by this
+    reckoning, bf16 and float32, and refused every one from 16.4), and
+    tests/test_paged_attention.py compiles what the guard lets through at
+    that edge: probe again there before changing a tile."""
+    tn = _tile(n)
+    vmem = 2 * itemsize * (ROW_TILE * k + k * tn + ROW_TILE * tn) \
+        + 4 * ROW_TILE * tn
+    return ROW_TILE, k if vmem <= _VMEM else _tile(k), tn
 
 
 def grouped_path() -> str:
@@ -105,8 +140,7 @@ def _grouped(rows, weights, sizes):
     are not computed and hold anything."""
     if grouped_path() == "megablox":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
-        k, n = weights.shape[1:]
-        tile = (128, _tile(k), _tile(n))
+        tile = _tiling(*weights.shape[1:], weights.dtype.itemsize)
         pad = -rows.shape[0] % tile[0]
         out = gmm(jnp.pad(rows, ((0, pad), (0, 0))), weights, sizes,
                   preferred_element_type=rows.dtype, tiling=tile)

@@ -57,6 +57,98 @@ def test_tile_of_a_width_of_eleven_lane_rows(width, tile):
     assert routed._tile(width) == tile and width % tile == 0
 
 
+# ---- the grouped products take a matrix's k in one tile --------------------
+
+PUBLISHED = {"nemotron_h": "nemotron-3-super-120b-l11-ep4.json",
+             "lfm2_moe": "lfm2-24b-a2b-l9.json",
+             "deepseek_v3": "moonlight-16b-a3b-l9.json"}
+# What every call's products take, w1's and w2's (PERF.md §6, PR 45): k in
+# ONE tile; `_tile(k)` gave Moonlight's w2 and the hybrid's w1 that already.
+K_WHOLE = {"nemotron_h": ((128, 1024, 896), (128, 2688, 1024)),
+           "lfm2_moe": ((128, 2048, 1024), (128, 1536, 1024)),
+           "deepseek_v3": ((128, 2048, 1408), (128, 1408, 1024))}
+
+
+def _published(name):
+    """(RoutedDims, the (k, n) of w1 and of w2) of a family's cell."""
+    import importlib
+    import json
+    import os
+
+    from benchmark.run import ROOT
+    family = importlib.import_module("benchmark.families." + name)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           PUBLISHED[name])) as f:
+        cfg = family.program_config(json.load(f))
+    lp = jax.eval_shape(lambda: routed.init_layer(
+        jax.random.key(0), cfg.hidden_size, cfg.routed, jnp.bfloat16))
+    return cfg.routed, (lp["w1"].shape[1:], lp["w2"].shape[1:])
+
+
+@pytest.mark.parametrize("product", [0, 1], ids=["w1", "w2"])
+@pytest.mark.parametrize("name", sorted(PUBLISHED))
+def test_a_grouped_product_takes_k_in_one_tile(name, product):
+    """The one rule, at the three families' published widths: k whole, so
+    that the next row tile of a group asks for the block that is there; n
+    by `_tile`; 128 rows.  A decode step, a suffix and a whole prompt take
+    the same tiles: the rows are not asked."""
+    k, n = _published(name)[1][product]
+    tm, tk, tn = got = routed._tiling(k, n)
+    assert got == K_WHOLE[name][product] == (routed.ROW_TILE, k,
+                                             routed._tile(n))
+    # the rows are padded to the row tile; the widths must divide
+    assert n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+    # two of each block and the accumulator, in the kernel's 16 MiB
+    assert 4 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn < 16 << 20
+
+
+@pytest.mark.parametrize("k,n,itemsize,tile", [
+    (2048, 2816, 4, (128, 1024, 1408)),     # Moonlight's w1 in float32
+    (8192, 2048, 2, (128, 1024, 1024)),     # a matrix four times as tall
+    (2688, 1024, 4, (128, 896, 1024)),      # the hybrid's w2 in float32
+    (1024, 2688, 4, (128, 1024, 896)),      # its w1 fits in float32 too
+])
+def test_k_is_cut_only_where_its_blocks_would_not_fit(k, n, itemsize, tile):
+    assert routed._tiling(k, n, itemsize) == tile and k % tile[1] == 0
+
+
+def test_k_whole_is_the_same_product_as_k_in_tiles():
+    """The kernel itself, interpreted: k whole and k cut in two give what
+    `ragged_dot` gives."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    k, n, sizes = 1536, 128, jnp.asarray([200, 0, 312], jnp.int32)
+    rows = jax.random.normal(jax.random.key(0), (512, k))
+    w = jax.random.normal(jax.random.key(1), (3, k, n)) / np.sqrt(k)
+    assert routed._tiling(k, n) == (128, 1536, 128)
+    plain = jax.lax.ragged_dot(rows, w, sizes)
+    for tile in ((128, 1536, 128), (128, 768, 128)):
+        got = gmm(rows, w, sizes, tiling=tile, interpret=True)
+        np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["latent_relu2", "gated", "gated_shared"])
+def test_many_rows_a_group_give_the_plain_per_expert_loop(form):
+    """512 tokens over a handful of experts, each given a row tile or more
+    (a whole prompt's shape), and the sums are the plain loop's."""
+    from tests.test_hybrid_model import _plain_routed
+    if form == "latent_relu2":
+        dims = routed.RoutedDims(experts=8, held=2, held_from=2, top_k=2,
+                                 latent=16, width=24, shared_width=40)
+    else:
+        dims = dataclasses.replace(
+            SHARED, experts=4, held=4, top_k=2,
+            shared_width=40 if form == "gated_shared" else 0)
+    lp = routed.init_layer(jax.random.key(0), 32, dims, jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (1, 512, 32))
+    y, counts, chosen = jax.jit(lambda p, x: routed.mixer(p, x, dims))(lp, x)
+    mix, shared = _plain_routed(lp, x[0], dims, 2, 2) if dims.latent \
+        else _plain_gated(lp, x[0], dims)
+    np.testing.assert_allclose(y[0], mix + shared, **TOL)
+    chosen = np.asarray(chosen)
+    held = (chosen >= dims.held_from) & (chosen < dims.held_from + dims.held)
+    assert counts.tolist() == [dims.held, held.sum()]
+
+
 # ---- the two forms of one attention ----------------------------------------
 
 def _layer(seed=0):

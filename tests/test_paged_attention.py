@@ -662,6 +662,60 @@ def test_moonlight_programs_compile_for_v5e_around_one_pool(
         assert compiled.memory_analysis().alias_size_in_bytes >= whole
 
 
+@pytest.mark.parametrize("tokens", [16, 64, 4096])
+@pytest.mark.parametrize("name", ["nemotron_h", "lfm2_moe", "deepseek_v3"])
+def test_grouped_products_compile_for_v5e_at_k_whole(
+        name, tokens, topo, monkeypatch, no_compile_cache):
+    """A decode step's rows, a suffix's and a whole prompt's bucket of the
+    three routed cells: both products of a layer take a matrix's k in ONE
+    tile (`models/routed.py:_tiling`) and the kernel's blocks fit its VMEM
+    at the published widths."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import routed
+    from tests.test_moonlight_model import K_WHOLE, _published
+
+    monkeypatch.setattr(routed, "grouped_path", lambda: "megablox")
+    dims, shapes = _published(name)
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    rows = tokens * dims.top_k
+    for (k, n), tile in zip(shapes, K_WHOLE[name]):
+        assert routed._tiling(k, n) == tile
+        text = jax.jit(routed._grouped).lower(
+            S((rows, k), jnp.bfloat16), S((dims.held, k, n), jnp.bfloat16),
+            S((dims.held,), jnp.int32)).compile().as_text()
+        assert "tpu_custom_call" in text and "gmm" in text
+
+
+@pytest.mark.parametrize("k,n,dtype,whole", [
+    # the widest blocks the guard lets through whole: 14.9 and 15.0 MiB by
+    # its reckoning; the compiler took up to 15.1 and refused from 16.4
+    (2304, 1408, jnp.bfloat16, True),
+    (1536, 1024, jnp.float32, True),
+    (2560, 1408, jnp.bfloat16, False),      # 16.4 MiB whole: refused, so cut
+    (2048, 2816, jnp.float32, False),       # Moonlight's w1 in float32
+])
+def test_what_the_vmem_guard_lets_through_compiles_for_v5e(
+        k, n, dtype, whole, topo, monkeypatch, no_compile_cache):
+    """`routed._tiling`'s reckoning of the kernel's blocks against `_VMEM`,
+    held to the compiler at its edge: the tiles it gives compile, k whole
+    or cut."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import routed
+
+    monkeypatch.setattr(routed, "grouped_path", lambda: "megablox")
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    tile = routed._tiling(k, n, jnp.dtype(dtype).itemsize)
+    assert (tile[1] == k) == whole
+    text = jax.jit(routed._grouped).lower(
+        S((1024, k), dtype), S((8, k, n), dtype),
+        S((8,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "gmm" in text
+
+
 # ---- the prefill kernel (ops/prefill_attention.py) ------------------------
 # Its interpreted cases are in tests/test_prefill_attention.py; what needs
 # the described chip is here, in the one file that describes it.
