@@ -57,7 +57,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
-from ..models.transformer import (LATENT_FORMS, ROW_BLOCK, STATEFUL,
+from ..models import retention
+from ..models.transformer import (ATTEND, LATENT_FORMS, ROW_BLOCK, STATEFUL,
                                   TransformerConfig, blocks_to_run,
                                   decoder_block, embed_tokens, init_params,
                                   latent_absorb, latent_form, latent_unabsorb,
@@ -326,7 +327,7 @@ def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
 
 def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
                       length, ckpt, row, cfg: TransformerConfig, page: int,
-                      every: int, row_block: int = ROW_BLOCK):
+                      every: int, row_block: int = ROW_BLOCK, keep: int = 0):
     """A prefill of a pattern with recurrent layers: ONE form for a whole
     prompt and for a suffix, since both run the recurrence from a given
     state.  The rows `tokens` (1, Sb), of which `length` are real, follow
@@ -342,19 +343,24 @@ def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
     past the prompt (`_install_state` gives those to the scratch row), the
     experts chosen.  `row_block`: the tests'.  `pages` None: a whole
     prompt that attends nothing cached (a latent pattern's, whose attention
-    form follows from that: `_latent_prefill_attend`)."""
+    form follows from that: `_latent_prefill_attend`), or a pattern no
+    layer of which attends (no pool: ks and vs are None, and what precedes
+    the rows is in the state alone).  `keep`: `run_pattern`'s."""
     Sb = tokens.shape[1]
     x = embed_tokens(params, tokens, cfg)
     cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
     cached = None if pages is None else (pool_k, pool_v, pages, prefix_len,
                                          page)
-    attend, per_layer = _prefill_attend(
-        cfg, Sb, length, None, cached,
-        blocks_to_run(length, Sb, row_block, every), row_block)
+    attend, per_layer = None, ()
+    if set(cfg.kinds) & set(ATTEND):
+        attend, per_layer = _prefill_attend(
+            cfg, Sb, length, None, cached,
+            blocks_to_run(length, Sb, row_block, every), row_block)
     rec = [{k: c[k][row][None] for k in c} for c in ckpt]
-    x, (ks, vs), rec, kept, _, chosen = run_pattern(
+    x, kv, rec, kept, _, chosen = run_pattern(
         params["layers"], x, cos, sin, attend, cfg, rec, per_layer,
-        length=length, every=every, row_block=row_block)
+        length=length, every=every, row_block=row_block, keep=keep)
+    ks, vs = kv or (None, None)
     return (lm_logits(params, x[0, length - 1], cfg), ks, vs, rec, kept,
             chosen)
 
@@ -447,9 +453,12 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
             return latent_unabsorb(w, o[:, None], cfg), None
         if cfg.latent:
             attend = latent_attend
+        # (No pool: no layer attends, and `attend` is never called.)
+        layer = () if pool_k is None else (
+            jnp.arange(pool_k.shape[0], dtype=jnp.int32),)
         x, _, rec, _, counts, chosen = run_pattern(
-            params["layers"], x, cos, sin, attend, cfg, rec,
-            (jnp.arange(pool_k.shape[0], dtype=jnp.int32),), live=active)
+            params["layers"], x, cos, sin, attend, cfg, rec, layer,
+            live=active)
         return (*pools, lm_logits(params, x[:, 0], cfg), rec, counts, chosen)
 
     def body(carry, layer):
@@ -556,7 +565,8 @@ def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
     state = {"slots": slots, "rng": rng}
     if pattern:
         state["rec"], counts, _ = pattern
-        nxt = jnp.concatenate([nxt, counts.reshape(-1)])
+        if counts is not None:
+            nxt = jnp.concatenate([nxt, counts.reshape(-1)])
     if kv_sharding is not None:
         state = jax.lax.with_sharding_constraint(
             state, NamedSharding(kv_sharding.mesh, PartitionSpec()))
@@ -612,7 +622,14 @@ class _PrefixCache:
     entry holds a reference to every checkpoint row at or before its own
     boundary, as it does to its pages, so evicting it frees pages and rows
     together and a row outlives every entry that could use it.  The rows
-    are this cache's to hand out (`free_rows`): nothing else holds one."""
+    are this cache's to hand out (`free_rows`): nothing else holds one.
+    A prefill keeps the LAST `keep` boundaries it passes (`boundaries`), a
+    number that follows from the rows there are and names no model: a
+    re-ask needs the last boundary inside the text it shares, and a row may
+    cost as much as thousands of tokens of keys and values.  Where no layer
+    attends there are no pages (`insert` without a page row): an entry then
+    holds rows only, and the keys, the boundaries and the eviction are as
+    they are."""
 
     def __init__(self, page: int, tag: bytes = b"", every: int = 0,
                  rows: Sequence[int] = ()):
@@ -695,13 +712,15 @@ class _PrefixCache:
         self.misses += 1
         return 0, [], 0
 
-    def boundaries(self, prompt: Sequence[int], after: int) -> List[int]:
+    def boundaries(self, prompt: Sequence[int], after: int,
+                   keep: int = 0) -> List[int]:
         """The checkpoint boundaries (token counts) of `prompt` past
-        `after` that its full pages cover and no row is kept for yet."""
+        `after` that its full pages cover, the last `keep` of them (0:
+        all), and of those the ones no row is kept for yet."""
         if not self.every:
             return []
         full = len(prompt) // self.page * self.page
-        marks = range(after + self.every, full + 1, self.every)
+        marks = range(after + self.every, full + 1, self.every)[-keep:]
         if not marks:
             return []
         keys = self._keys(prompt, full // self.page)
@@ -723,7 +742,8 @@ class _PrefixCache:
     def insert(self, prompt: Sequence[int], table_row, incref,
                rows: Optional[Dict[int, int]] = None) -> None:
         """Register every full prompt page of a freshly admitted request
-        (decode writes land strictly after them, so they are immutable).
+        (decode writes land strictly after them, so they are immutable);
+        `table_row` None: there are no pages, and an entry holds rows only.
         `rows`: boundary (tokens) -> the checkpoint row (taken from
         `free_rows`) this prefill wrote for it; each new entry takes a
         reference to every row at or before its boundary, and a row no
@@ -745,7 +765,8 @@ class _PrefixCache:
             if key in self._entries:
                 self._entries.move_to_end(key)
                 continue
-            pages = [int(p) for p in table_row[:k]]
+            pages = [] if table_row is None \
+                else [int(p) for p in table_row[:k]]
             self._entries[key] = pages
             for p in pages:
                 incref(p)
@@ -1014,6 +1035,7 @@ class LLMEngine:
                  max_batch: int = 4, max_len: int = 256, seed: int = 0,
                  mesh=None, rules=None, page_size: int = 64,
                  kv_pages: Optional[int] = None,
+                 ckpt_rows: Optional[int] = None,
                  prefix_cache: bool = False,
                  sp_degree: Optional[int] = None,
                  sp_strategy: str = "ring",
@@ -1022,7 +1044,17 @@ class LLMEngine:
                  kv_fetch=None, kv_prefetch=None):
         """kv_pages sizes the shared pool (default: enough for every slot
         at max_len — set it lower to oversubscribe: admission then queues
-        until pages free up).  mesh: shard weights + KV over its tp axis.
+        until pages free up).  ckpt_rows sizes the state-checkpoint pool of
+        a model with recurrent layers, in rows an operator can keep (two
+        more are the engine's own); default: one for every `_every` tokens
+        of `kv_pages`, so that the page pool is the one thing sized, which
+        serves where a row is small beside the pages of those tokens.  A
+        row that is a whole cache (power retention: 38 MB a layer) is sized
+        by what it costs, and where no layer attends nothing else sizes
+        it; a prefill then keeps only the last boundaries it passes, the
+        rows' share of two prompts a slot and at least two (`_keep`), where
+        the default pool keeps every one.  mesh: shard weights + KV over
+        its tp axis.
         prefix_cache=True enables page-granular KV prefix reuse (shared
         full prompt pages skip prefill; LRU-evicted under pool
         pressure) — off by default: retired pages then linger in the
@@ -1045,12 +1077,16 @@ class LLMEngine:
         self.max_len = max_len
         self.page = max(8, min(page_size, max_len))
         self.pages_per_slot = math.ceil(max_len / self.page)
-        # page 0 is scratch (inactive-slot writes land there); never handed out
-        self.n_pages = 1 + (kv_pages if kv_pages is not None
-                            else max_batch * self.pages_per_slot)
         # The pool has rows for the layers that attend: all of the dense
-        # decoder's, the `*` or `L` layers of a pattern.
-        L = cfg.count("*") + cfg.count("L") or cfg.num_layers
+        # decoder's, the `*` or `L` layers of a pattern.  Where none does
+        # (a pattern of recurrent layers alone) there is NO pool: no array,
+        # no page to reserve or to wait for, and the whole cache is the
+        # state rows below.
+        L = sum(cfg.count(k) for k in ATTEND)
+        pages = kv_pages if kv_pages is not None \
+            else max_batch * self.pages_per_slot
+        # page 0 is scratch (inactive-slot writes land there); never handed out
+        self.n_pages = 1 + (pages if L else 0)
         kvh, d = cfg.cache_row
         # State checkpoints, every `_every` tokens (0: no recurrent layer).
         self._every = _CKPT_CHUNKS * state_chunk(cfg)
@@ -1143,8 +1179,9 @@ class LLMEngine:
         # Two pools, keys and values; a latent pattern has ONE, whose row is
         # both, and None (an empty tree) where the others have the second.
         shape = pool_shape(L, self.n_pages, self.page, kvh, d)
-        self._pk = jnp.zeros(shape, cfg.dtype, device=self._kv_shd)
-        self._pv = None if cfg.latent else jnp.zeros(
+        self._pk = jnp.zeros(shape, cfg.dtype, device=self._kv_shd) \
+            if L else None
+        self._pv = None if cfg.latent or not L else jnp.zeros(
             shape, cfg.dtype, device=self._kv_shd)
         self._free_slots = list(range(max_batch))
         self._free_pages = list(range(1, self.n_pages))
@@ -1152,12 +1189,22 @@ class LLMEngine:
         # _free_pages with count 1 and returns when the count hits 0.
         self._page_refs: Dict[int, int] = {}
         cache_tag = (b"sp%d" % self.sp_degree) if self.sp_degree > 1 else b""
-        # One checkpoint row for every `_every` tokens the page pool holds,
-        # so that the page pool is the one thing an operator sizes.  Row 0
-        # is the state of having read nothing and row 1 takes the
-        # checkpoints nobody keeps; neither is handed out.
-        n_rows = (self.n_pages - 1) * self.page // self._every \
-            if self._every and prefix_cache else 0
+        # `ckpt_rows` checkpoint rows, or one for every `_every` tokens of
+        # `kv_pages`.  Row 0 is the state of having read nothing and row 1
+        # takes the checkpoints nobody keeps; neither is handed out.
+        n_rows = 0
+        if self._every and prefix_cache:
+            n_rows = ckpt_rows if ckpt_rows is not None \
+                else pages * self.page // self._every
+        # A prefill keeps the last `_keep` boundaries it passes.  A pool
+        # sized by the pages (rows that are small beside them) keeps every
+        # one, as it always did: an early boundary serves a prompt that
+        # shares only its beginning.  A pool sized by its bytes keeps the
+        # rows' share of two prompts a slot, at least two (the last
+        # boundary may fall inside a prompt's own question).
+        self._keep = max_len // self._every if self._every else 0
+        if ckpt_rows is not None:
+            self._keep = min(max(2, n_rows // (2 * max_batch)), self._keep)
         self._cache = _PrefixCache(self.page, cache_tag, self._every,
                                    range(2, 2 + n_rows)) \
             if prefix_cache else None
@@ -1260,6 +1307,15 @@ class LLMEngine:
                         "rows_attended": 0, "rows_expanded": 0,
                         "prefills": {"expanded": 0, "absorbed": 0},
                         "form": ""}
+        # A pattern of power retention layers: the sequences its decode
+        # steps moved the state of, its prefills by form ("attention": a
+        # whole prompt, every output from the rows' own keys; "chunked":
+        # from a checkpoint, the state's part beside them), and the
+        # checkpoint boundaries the admitted prompts passed and kept.
+        self._retention = {"rows_stepped": 0, "step_rows_stepped": 0,
+                           "prefills": {"attention": 0, "chunked": 0},
+                           "form": "", "boundaries_passed": 0,
+                           "boundaries_kept": 0}
         page, kv_shd = self.page, self._kv_shd
         # The decode step stays a lambda ON PURPOSE: the benchmark's
         # `decode_tick` and `decode_roofline` readers pick it out of a
@@ -1327,6 +1383,8 @@ class LLMEngine:
                 "are not the whole cache of a pattern with other layer kinds")
 
     def _pages_needed(self, req: _Request) -> int:
+        if self._pk is None:
+            return 0                    # no layer attends: nothing to hold
         if req.kv_paged:
             # External context: only the decode tail lives in the pool.
             return math.ceil((req.params.max_tokens + 1) / self.page)
@@ -1490,7 +1548,9 @@ class LLMEngine:
         return self.n_pages - 1
 
     def kv_page_occupancy(self) -> float:
-        return 1.0 - len(self._free_pages) / max(1, self.n_pages - 1)
+        if self.n_pages == 1:
+            return 0.0                  # no pool
+        return 1.0 - len(self._free_pages) / (self.n_pages - 1)
 
     @property
     def queue_depth(self) -> int:
@@ -1516,12 +1576,15 @@ class LLMEngine:
         counts the steps before which it wrote slot rows (one packed
         upload), `state_rows` the rows (`step_state_rows`: the last
         step's); `steps - state_syncs` steps uploaded nothing."""
-        per_step = self.max_batch * self.pages_per_slot
         z = self.cfg.latent
+        pooled = self._pk is not None
+        per_step = self.max_batch * self.pages_per_slot if pooled else 0
         return {"path": decode_path(
                     (self.cfg.num_heads, self.cfg.head_dim_), self._pk.shape,
-                    self._tables.shape, z.rank if z else 0),
-                "pool_row": pool_row(*self.cfg.cache_row),
+                    self._tables.shape, z.rank if z else 0)
+                if pooled else "none",
+                "pool_row": pool_row(*self.cfg.cache_row)
+                if pooled else "none",
                 "steps": self._decode_steps,
                 "pages_read": self._pages_read,
                 "pages_addressable": self._decode_steps * per_step,
@@ -1570,6 +1633,24 @@ class LLMEngine:
                 "steps": self._decode_steps, **self._latent,
                 "prefills": dict(self._latent["prefills"])}
 
+    def retention_stats(self) -> Dict[str, Any]:
+        """A pattern of power retention layers: the bytes of one sequence's
+        state over all of them (`row_bytes`: a state row, and a checkpoint
+        row) and phi's `block` and width `D`; the sequences the decode
+        steps read and wrote the state of (`rows_stepped` over `steps`, and
+        the last step's) and by which `path`; the prefills by form and the
+        last one's; the checkpoint boundaries the admitted prompts passed
+        and how many of them were kept (`_PrefixCache.boundaries`)."""
+        z = self.cfg.retention
+        if not z:
+            return {"enabled": False}
+        return {"enabled": True, "layers": self.cfg.count("P"),
+                "row_bytes": state_bytes(self.cfg), "block": z.block,
+                "D": z.expanded, "path": retention.step_path(z),
+                "keep": self._keep, "steps": self._decode_steps,
+                **self._retention,
+                "prefills": dict(self._retention["prefills"])}
+
     def routed_stats(self) -> Dict[str, Any]:
         """What the routed layers' DECODE steps touched, a number a layer:
         distinct held experts that got a row and (token, expert) rows
@@ -1602,7 +1683,8 @@ class LLMEngine:
         (`models/transformer.py:row_blocks`; a sequence-parallel prefill
         gives its halves no length): nothing is read back."""
         from ..ops.prefill_attention import kv_blocks
-        row = () if prefix_len is None else (self.page, self.pages_per_slot)
+        row = () if prefix_len is None or self._pk is None \
+            else (self.page, self.pages_per_slot)
         path = _prefill_path(self.cfg, padded, self._kv_shd, *row) \
             if self.sp_degree == 1 else "xla"
         table = math.prod(row) if row else 0    # cached rows a suffix sees
@@ -1623,9 +1705,23 @@ class LLMEngine:
             lat["prefills"][form] += 1
             lat["rows_attended"] += attended
             lat["rows_expanded"] += expanded
+        if self.cfg.retention:
+            # The form, by the host's mirror of the rule `retention.mixer`
+            # goes by (the state's part is added where the state has read
+            # anything, which on the device is `any(z != 0)` and is not
+            # read back): a prefill from a checkpoint starts from such a
+            # state, a whole prompt from the zero row.
+            form = "chunked" if prefix_len else "attention"
+            self._prefill_ran.update(form=form)
+            self._retention["form"] = form
+            self._retention["prefills"][form] += 1
         st = self._prefill_stats
+        if self._pk is None:            # no layer attends: no attention form
+            path, dense = "none", 0
+            self._prefill_ran.update(path=path, kv_blocks=0)
+        else:
+            st[path + "_calls"] += 1
         st["path"] = path
-        st[path + "_calls"] += 1
         st["kv_blocks_run"] += self._prefill_ran["kv_blocks"]
         st["kv_blocks_dense"] += dense
         st["row_blocks_run"] += rows_run
@@ -1785,7 +1881,7 @@ class LLMEngine:
             from .._private.memory_monitor import pressure_signal
             sig = pressure_signal()
             total = max(1, self.n_pages - 1)
-            if self._waiting and not self._free_pages:
+            if self._waiting and not self._free_pages and self.n_pages > 1:
                 sig.report("kv_pool", 1.0 - len(self._free_pages) / total)
             else:
                 sig.clear("kv_pool")
@@ -1805,7 +1901,7 @@ class LLMEngine:
             before = self._cache.recomputed
             c, shared, req.from_row = self._cache.lookup(req.prompt)
             req.recomputed = self._cache.recomputed - before
-            marks = self._cache.boundaries(req.prompt, c)
+            marks = self._cache.boundaries(req.prompt, c, self._keep)
         total = self._pages_needed(req)
         need = total - len(shared)
         # Hold the shared pages, and the checkpoint row the prefill starts
@@ -1867,7 +1963,9 @@ class LLMEngine:
         prompt, which go to the scratch row."""
         rows = np.ones(jax.tree.leaves(kept[0])[0].shape[1], np.int32)
         for b, row in req.new_rows.items():
-            rows[(b - req.prefix_len) // self._every - 1] = row
+            # (Boundary j of the prefill lies in slot (j - 1) % slots: a kind
+            # that builds only the last `_keep` has as many slots.)
+            rows[((b - req.prefix_len) // self._every - 1) % len(rows)] = row
         self._dev["rec"], self._ckpt = self._install_state_jit(
             self._dev["rec"], self._ckpt, req.slot, end, kept,
             jnp.asarray(rows))
@@ -1895,19 +1993,23 @@ class LLMEngine:
         Sb = self._bucket(S)
         sp = self.sp_degree > 1
         key = ("sp-suffix", Sb) if sp else ("suffix", Sb)
+        after = prefix_len              # what `_count_prefill` is told
         if self.cfg.latent and not prefix_len:
             # A whole prompt attends nothing cached, and is given no pages
             # to gather: another program (and, `latent_form`, the expanded
             # attention).
-            key, pages_row = ("whole", Sb), None
+            key, pages_row, after = ("whole", Sb), None, None
+        if self._pk is None:
+            pages_row = None            # no pool: one program a bucket
         if key not in self._prefill_jit:
             cfg, page = self.cfg, self.page
             if cfg.pattern:
-                every = self._every
+                every, keep = self._every, self._keep
 
                 def state_prefill(p, pk, pv, pg, t, pl, n, ckpt, row):
                     return _state_prefill_fn(p, pk, pv, pg, t, pl, n, ckpt,
-                                             row, cfg, page, every)
+                                             row, cfg, page, every,
+                                             keep=keep)
                 self._prefill_jit[key] = jax.jit(state_prefill)
             elif sp:
                 mesh = self.mesh
@@ -1925,7 +2027,7 @@ class LLMEngine:
                 self._prefill_jit[key] = jax.jit(suffix_prefill)
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = suf
-        self._count_prefill(S, Sb, None if pages_row is None else prefix_len)
+        self._count_prefill(S, Sb, after)
         state = (self._ckpt, from_row) if self.cfg.pattern else ()
         return self._prefill_jit[key](
             self.params, self._pk, self._pv,
@@ -1944,7 +2046,8 @@ class LLMEngine:
         logits, ks, vs, *state = self._run_suffix(
             req.prompt, req.prefix_len, self._tables[req.slot],
             from_row=req.from_row)
-        self._install_new_pages(req, ks, vs)
+        if self._pk is not None:
+            self._install_new_pages(req, ks, vs)
         if self._every:
             self._install_state(req, *state[:2])
         return logits, state[2] if state else None
@@ -1997,14 +2100,21 @@ class LLMEngine:
             if self._every:
                 ran = dict(ran, checkpoints=len(req.new_rows),
                            recomputed=req.recomputed)
+            if self.cfg.retention:
+                passed = (S - req.prefix_len) // self._every
+                ran = dict(ran, kept=len(req.new_rows), passed=passed)
+                self._retention["boundaries_passed"] += passed
+                self._retention["boundaries_kept"] += len(req.new_rows)
             ph.leave(t0, "prefill", req.req_id.to_bytes(8, "little"),
                      tokens=S, cached_tokens=req.prefix_len,
                      active=active_before, n=ph.n,
                      new_program=len(self._prefill_jit) - programs, **ran)
             if self._cache is not None and not req.no_cache:
                 self._cache.hold_row(req.from_row, -1)
-                self._cache.insert(req.prompt, self._tables[req.slot],
-                                   self._incref, req.new_rows)
+                self._cache.insert(
+                    req.prompt,
+                    self._tables[req.slot] if self._pk is not None else None,
+                    self._incref, req.new_rows)
             if self.sp_degree > 1:
                 # Which pages each sequence-parallel shard installed —
                 # the stripe accounting the cross-host handoff consumes.
@@ -2227,6 +2337,10 @@ class LLMEngine:
         if self.cfg.latent:
             self._latent["rows_read"] += flight.rows
             self._latent["step_rows_read"] = extra["latent_rows"] = flight.rows
+        if self.cfg.retention:
+            ret, n = self._retention, len(flight.batch)
+            ret["rows_stepped"] += n
+            ret["step_rows_stepped"] = extra["state_rows"] = n
         ph.span("decode", flight.t0, ph.to("emit"), batch=len(flight.batch),
                 pages=flight.pages, synced=flight.synced, **extra)
         # The host advances its mirrors as the step advanced the device's.
@@ -2255,7 +2369,8 @@ class LLMEngine:
         active = np.zeros(self.max_batch, bool)
         active[list(batch)] = True
         t0 = ph.to("ahead" if ahead else "prep", **closing)
-        pages = int((self._lengths[active] // self.page + 1).sum())
+        pages = int((self._lengths[active] // self.page + 1).sum()) \
+            if self._pk is not None else 0
         update, synced = self._no_rows, int(self._touched.sum())
         if synced:
             update = jax.device_put(_pack_rows(

@@ -163,7 +163,8 @@ class EngineReplica:
 
     def __init__(self, preset: str = "tiny", *, max_batch: int = 4,
                  max_len: int = 128, page_size: int = 16,
-                 kv_pages: Optional[int] = None, prefix_cache: bool = True,
+                 kv_pages: Optional[int] = None,
+                 ckpt_rows: Optional[int] = None, prefix_cache: bool = True,
                  max_queue: int = 64, max_tokens: int = 16,
                  temperature: float = 0.0, eos_id: Optional[int] = None,
                  seed: int = 0, mesh=None, sp_degree: Optional[int] = None,
@@ -193,7 +194,7 @@ class EngineReplica:
             max_workers=2, thread_name_prefix="kv-gather")
         self.engine = LLMEngine(cfg, max_batch=max_batch, max_len=max_len,
                                 seed=seed, mesh=mesh, page_size=page_size,
-                                kv_pages=kv_pages,
+                                kv_pages=kv_pages, ckpt_rows=ckpt_rows,
                                 prefix_cache=prefix_cache,
                                 sp_degree=sp_degree,
                                 sp_strategy=sp_strategy,
@@ -863,8 +864,10 @@ class EngineReplica:
         `state` and `routed` are `LLMEngine.state_stats()` (recurrent-state
         checkpoints) and `routed_stats()` (experts the decode steps
         touched) and `latent` `latent_stats()` (cache rows the decode steps
-        read, key rows the prefills attended and up-projected),
-        `{"enabled": False}` for a model without such layers."""
+        read, key rows the prefills attended and up-projected) and
+        `retention` `retention_stats()` (sequences whose state the decode
+        steps moved, prefills by form, checkpoint boundaries passed and
+        kept), `{"enabled": False}` for a model without such layers."""
         e = self.engine
         return {"ticks": self._ticks, "max_active": self._max_active,
                 "shed": self._shed, "cancelled": self._cancelled,
@@ -883,6 +886,7 @@ class EngineReplica:
                 "state": e.state_stats(),
                 "routed": e.routed_stats(),
                 "latent": e.latent_stats(),
+                "retention": e.retention_stats(),
                 "tick": self._phases.snapshot()}
 
     async def pid(self) -> int:

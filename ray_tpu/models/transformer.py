@@ -30,8 +30,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..parallel.sharding import LogicalAxisRules, with_logical_constraint
-from . import mamba2, routed, shortconv
+from . import mamba2, retention, routed, shortconv
 from .mamba2 import Mamba2Dims
+from .retention import RetentionDims
 from .routed import RoutedDims
 from .shortconv import ShortConvDims
 
@@ -93,10 +94,13 @@ class TransformerConfig:
     # layer is an operator AND a feed-forward, each a residual half behind
     # its own norm, spells a layer as two letters and parts the layers with
     # spaces ("CF *E CE": `num_layers` 3, six blocks, one attention layer
-    # in the page pool).  A pattern with `M`, `E`, `C` or `L` blocks brings
-    # their sizes in `mamba`, `routed`, `conv` and `latent` (`L`: latent
-    # attention, whose page pool holds ONE row a token, `cache_row`; a
-    # pattern's attention layers are all `*` or all `L`); `rope` off = the
+    # in the page pool).  A pattern with `M`, `E`, `C`, `L` or `P` blocks
+    # brings their sizes in `mamba`, `routed`, `conv`, `latent` and
+    # `retention` (`L`: latent attention, whose page pool holds ONE row a
+    # token, `cache_row`; a pattern's attention layers are all `*` or all
+    # `L`; `P`: power retention, whose projections, head norm and rotation
+    # are the attention block's and whose cache is a recurrent state alone:
+    # a pattern of `P` and `F` has no page pool); `rope` off = the
     # attention layers rotate nothing (position is carried by the recurrent
     # layers); `qk_norm` = an RMS norm with a learned scale over each q and
     # k head before the rotation; `tie_embeddings` = the head is the
@@ -106,6 +110,7 @@ class TransformerConfig:
     routed: Optional[RoutedDims] = None
     conv: Optional[ShortConvDims] = None
     latent: Optional[LatentDims] = None
+    retention: Optional[RetentionDims] = None
     rope: bool = True
     qk_norm: bool = False
     tie_embeddings: bool = False
@@ -158,6 +163,8 @@ class TransformerConfig:
             per["C"] = self.conv.param_count(h) + h
         if self.latent:
             per["L"] = self.latent.param_count(h, self.num_heads) + h
+        if self.retention:
+            per["P"] = per["*"] + self.retention.param_count(h)
         head = 0 if self.tie_embeddings else v * h
         return v * h + sum(per[k] for k in self.kinds) + h + head
 
@@ -251,7 +258,7 @@ def _init_pattern_layer(kind: str, key, cfg: TransformerConfig):
             "w_gate": _dense(ks[0], (h, m), h, dt),
             "w_up": _dense(ks[1], (h, m), h, dt),
             "w_down": _dense(ks[2], (m, h), m, dt)}}
-    if kind != "*":
+    if kind not in "*P":
         raise ValueError(f"layer kind {kind!r} is none of {KINDS}")
     nh, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     attn = {"wq": _dense(ks[0], (h, nh, d), h, dt),
@@ -261,6 +268,9 @@ def _init_pattern_layer(kind: str, key, cfg: TransformerConfig):
     if cfg.qk_norm:
         attn.update(q_norm=jnp.ones((d,), jnp.float32),
                     k_norm=jnp.ones((d,), jnp.float32))
+    if kind == "P":
+        attn.update(retention.init_layer(
+            jax.random.fold_in(key, 4), h, cfg.retention, dt))
     return {"ln_attn": ln, "attn": attn}
 
 
@@ -366,10 +376,12 @@ def _unconstrained(x, axes):
 
 
 def block_qkv(lp, x, cos, sin, cfg: TransformerConfig,
-              constrain=_unconstrained):
+              constrain=_unconstrained, beside=None):
     """First half of the block: norm, the three projections, with `qk_norm`
     each q and k head's own norm, RoPE.
-    x (B, S, E) -> q (B, S, H, D), k, v (B, S, KV, D)."""
+    x (B, S, E) -> q (B, S, H, D), k, v (B, S, KV, D), and after them
+    `beside(h)` of the normed rows h where a kind reads more of them (a
+    retention layer's gate)."""
     dt = cfg.dtype
     h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
     q = jnp.einsum("bse,ehd->bshd", h, lp["attn"]["wq"].astype(dt))
@@ -380,9 +392,9 @@ def block_qkv(lp, x, cos, sin, cfg: TransformerConfig,
     if cfg.qk_norm:
         q = rms_norm(q, lp["attn"]["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["attn"]["k_norm"], cfg.rms_norm_eps)
-    if not cfg.rope:
-        return q, k, v
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+    if cfg.rope:
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    return (q, k, v) if beside is None else (q, k, v, beside(h))
 
 
 def attn_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
@@ -555,13 +567,16 @@ def decoder_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
 # every path calls it: `D` the dense block above (attention and SwiGLU),
 # `*` attention alone, `L` latent attention alone (`latent_block`, below),
 # `F` the SwiGLU feed-forward alone (`ffn_block`, above), `M` a Mamba-2
-# mixer, `C` a gated short convolution, `E` a routed-expert layer; each but
-# `D` is x + mixer(rms_norm(x)).  The STATEFUL kinds carry recurrent state
-# from row to row: a tree a layer (`zero_state`), which the engine keeps a
-# row a slot and checkpoints in its prefix cache without knowing what is in
-# it.
-KINDS = "D*FMCEL"
-STATEFUL = "MC"
+# mixer, `C` a gated short convolution, `E` a routed-expert layer, `P` power
+# retention (`retention_block`, below); each but `D` is x +
+# mixer(rms_norm(x)).  The STATEFUL kinds carry recurrent state from row to
+# row: a tree a layer (`zero_state`), which the engine keeps a row a slot
+# and checkpoints in its prefix cache without knowing what is in it.  The
+# kinds that ATTEND leave keys and values (or a latent row) in the engine's
+# page pool; a pattern with none of them has no pool.
+KINDS = "D*FMCELP"
+STATEFUL = "MCP"
+ATTEND = "D*L"
 
 
 def zero_state(cfg: TransformerConfig, kind: str, batch: int):
@@ -569,6 +584,8 @@ def zero_state(cfg: TransformerConfig, kind: str, batch: int):
     of a STATEFUL `kind`."""
     if kind == "M":
         return mamba2.zero_state(cfg.mamba, batch, cfg.dtype)
+    if kind == "P":
+        return retention.zero_state(cfg.retention, batch)
     return shortconv.zero_state(cfg.conv, cfg.hidden_size, batch, cfg.dtype)
 
 
@@ -576,7 +593,8 @@ def state_bytes(cfg: TransformerConfig) -> int:
     """One sequence's recurrent state over all the stateful layers."""
     act = jnp.dtype(cfg.dtype).itemsize
     per = {"M": lambda: cfg.mamba.state_bytes(act),
-           "C": lambda: cfg.conv.state_bytes(cfg.hidden_size, act)}
+           "C": lambda: cfg.conv.state_bytes(cfg.hidden_size, act),
+           "P": lambda: cfg.retention.state_bytes()}
     return sum(per[k]() for k in cfg.kinds if k in STATEFUL)
 
 
@@ -584,8 +602,8 @@ def state_chunk(cfg: TransformerConfig) -> int:
     """The rows the stateful layers count their state's boundaries in (a
     mixer's `every` is a multiple of its kind's): 0 where no layer carries
     state."""
-    return max([0] + [(cfg.mamba if k == "M" else cfg.conv).chunk
-                      for k in set(cfg.kinds) & set(STATEFUL)])
+    dims = {"M": cfg.mamba, "C": cfg.conv, "P": cfg.retention}
+    return max([0] + [dims[k].chunk for k in set(cfg.kinds) & set(STATEFUL)])
 
 
 def attention_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
@@ -754,11 +772,50 @@ def _stateful_block(mixer, dims):
     return block
 
 
+def retention_block(lp, x, state, cfg: TransformerConfig, blocks=None,
+                    row_block: int = ROW_BLOCK, length=None, live=None,
+                    every: int = 0, cos=None, sin=None, keep: int = 0):
+    """`P` (`models/retention.py`): the attention block's first half
+    (`block_qkv`: the projections, each q and k head's norm, the rotation)
+    with the layer's log gate beside it, power retention in the attention's
+    place, the attention block's output projection.  The two row-wise
+    halves go as every other (`over_rows`); the mixer between them takes the
+    whole call, as an attention does: its outputs come from the rows' own
+    keys and the state it was given, and its state is built once, at the
+    boundaries that are kept (`keep`: the last so many) and at the end.
+    -> (x, state', checkpoints)."""
+    z = cfg.retention
+
+    def first(x, cos, sin):
+        return block_qkv(lp, x, cos, sin, cfg,
+                         beside=lambda h: retention.gate(lp["attn"], h))
+    rows = x.shape[:2]
+    shapes = tuple(jax.ShapeDtypeStruct((*rows, n, cfg.head_dim_), x.dtype)
+                   for n in (cfg.num_heads, cfg.num_kv_heads,
+                             cfg.num_kv_heads)) \
+        + (jax.ShapeDtypeStruct((*rows, z.num_kv_heads), jnp.float32),)
+    q, k, v, a = over_rows(
+        first, [(x, 1), (cos, cos.ndim - 2), (sin, sin.ndim - 2)], shapes,
+        blocks, row_block)
+    o, state, kept = retention.mixer(q, k, v, a, state, z, length=length,
+                                     live=live, every=every, keep=keep)
+    x, = over_rows(lambda x, o: (attn_out(lp, x, o, cfg),),
+                   [(x, 1), (o, 1)], (x,), blocks, row_block)
+    return x, state, kept
+
+
 # `M` (`models/mamba2.py`) and `C` (`models/shortconv.py`, the state a
 # convolution's tail): one block for both.
 mamba_block = _stateful_block(mamba2.mixer, lambda cfg: cfg.mamba)
 conv_block = _stateful_block(shortconv.mixer, lambda cfg: cfg.conv)
-_STATEFUL_BLOCK = {"M": mamba_block, "C": conv_block}
+_STATEFUL_BLOCK = {"M": mamba_block, "C": conv_block, "P": retention_block}
+
+
+def _beside(kind: str, **more):
+    """What `retention_block` takes besides what every stateful block does
+    (the others rotate nothing, and their state is small enough to keep at
+    every boundary)."""
+    return more if kind == "P" else {}
 
 
 def routed_block(lp, x, cfg: TransformerConfig, real=None, blocks=None,
@@ -789,7 +846,7 @@ def routed_block(lp, x, cfg: TransformerConfig, real=None, blocks=None,
 
 def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
                 per_layer=(), length=None, live=None, every: int = 0,
-                row_block: int = ROW_BLOCK):
+                row_block: int = ROW_BLOCK, keep: int = 0):
     """A pattern of kinds, block by block (`layers`: one tree a block).
     `attend(q, k, v, *at)` as `scan_blocks` takes it, `at` the i-th slice of
     `per_layer` for the i-th attention layer (an `L` layer: `attend(q, row,
@@ -797,7 +854,9 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
     tree (`zero_state`) for each STATEFUL block in order.  Which rows are
     real: the first `length` (a prefill's padded bucket), the slots that are
     `live` (B,) (a decode step); the others move no state and meet no
-    routed expert.  `every`: the stateful mixers' checkpoints.  Given
+    routed expert.  `every`: the stateful mixers' checkpoints; `keep`: how
+    many of those a prompt passes the caller keeps, the last so many (0:
+    all), which a kind whose state is large builds no others for.  Given
     `length` in a bucket that `by_row_blocks` (blocks of `row_block` rows,
     an argument for the tests' small buckets alone; each a whole number of
     checkpoints), every kind's row-wise halves run over the blocks that
@@ -829,7 +888,7 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
         elif kind in STATEFUL:
             x, state, ck = _STATEFUL_BLOCK[kind](
                 lp, x, rec[len(new)], cfg, *by, length=length, live=live,
-                every=every)
+                every=every, **_beside(kind, cos=cos, sin=sin, keep=keep))
             new.append(state)
             ckpts.append(ck)
         elif kind == "F":
@@ -874,7 +933,8 @@ def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
             x = routed_block(lp, x, cfg)[0]
         elif kind in STATEFUL:
             x = _STATEFUL_BLOCK[kind](
-                lp, x, zero_state(cfg, kind, batch), cfg)[0]
+                lp, x, zero_state(cfg, kind, batch), cfg,
+                **_beside(kind, cos=cos, sin=sin))[0]
         elif kind == "F":
             x = ffn_block(lp, x, cfg)
         elif kind == "L":

@@ -662,6 +662,80 @@ def test_moonlight_programs_compile_for_v5e_around_one_pool(
         assert compiled.memory_analysis().alias_size_in_bytes >= whole
 
 
+def test_brumby_programs_compile_for_v5e_around_the_state(
+        topo, monkeypatch, no_compile_cache):
+    """Brumby's decode step, a whole prompt's prefill and the install of
+    its state at serve_doc_reask_retention's shapes (six power retention
+    layers, 8 slots, 14 checkpoint rows, NO pool): the step holds six
+    `retention_step` kernels, the state rows alias their results (1.83 GB
+    in place, no copy of a layer's 304 MB), the prefill returns the last
+    TWO boundaries' checkpoints and the end, and its scratch leaves the
+    chip room beside 12.1 GB at rest."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.families import brumby
+    from benchmark.run import ROOT
+    from ray_tpu.llm import engine as E
+    from ray_tpu.models import retention
+    from ray_tpu.models.transformer import init_params, zero_state
+
+    monkeypatch.setattr(retention, "step_path", lambda dims: "pallas")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "brumby-14b-base-l6.json")) as f:
+        cfg = brumby.program_config(json.load(f), max_seq_len=4096)
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    on_chip = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+    B, page, P_ = 8, 16, 256
+    rows = lambda n: [on_chip(jax.eval_shape(
+        lambda: zero_state(cfg, "P", n))) for _ in range(6)]
+    layer = 38_043_648
+    assert cfg.retention.state_bytes() == layer
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = {"slots": S((B, P_ + 4), jnp.int32),
+             "rng": S(key.shape, key.dtype), "rec": rows(B)}
+
+    def decode_step(p, pk, pv, state, update):
+        return E._decode_fn(p, pk, pv, state, update, cfg, page, None)
+    step = jax.jit(decode_step, donate_argnums=(1, 2, 3)).lower(
+        params, None, None, state, S((B, P_ + 5), jnp.int32)).compile()
+    text = step.as_text()
+    assert len(set(re.findall(r"%(retention_step[.\d]*) =", text))) == 6
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * B * layer
+    assert mem.temp_size_in_bytes < layer               # 9 MB
+
+    def prefill(p, pk, pv, pg, t, pl, n, ckpt, row):
+        return E._state_prefill_fn(p, pk, pv, pg, t, pl, n, ckpt, row, cfg,
+                                   page, 512, keep=2)
+    whole = jax.jit(prefill).lower(
+        params, None, None, None, S((1, 4096), jnp.int32), S((), jnp.int32),
+        S((), jnp.int32), rows(14), S((), jnp.int32))
+    kept = jax.tree.leaves(jax.eval_shape(
+        prefill, params, None, None, None, S((1, 4096), jnp.int32),
+        S((), jnp.int32), S((), jnp.int32), rows(14), S((), jnp.int32))[4][0])
+    assert kept[0].shape[:2] == (1, 2)                  # a ring of `keep`
+    mem = whole.compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9               # 1.10 GB
+    assert mem.output_size_in_bytes < 3.1 * 6 * layer   # 2 kept + the end
+
+    ring = [jax.tree.map(lambda a: S((1, 2, *a.shape[1:]), a.dtype), r)
+            for r in rows(1)]
+    install = jax.jit(E._install_state_fn, donate_argnums=(0, 1)).lower(
+        rows(B), rows(14), S((), jnp.int32), rows(1), ring,
+        S((2,), jnp.int32)).compile()
+    mem = install.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * (B + 14) * layer
+    assert mem.temp_size_in_bytes < layer
+
+
 @pytest.mark.parametrize("tokens", [16, 64, 4096])
 @pytest.mark.parametrize("name", ["nemotron_h", "lfm2_moe", "deepseek_v3"])
 def test_grouped_products_compile_for_v5e_at_k_whole(
