@@ -61,11 +61,11 @@ from ..models import retention
 from ..models.transformer import (ATTEND, LATENT_FORMS, ROW_BLOCK, STATEFUL,
                                   TransformerConfig, blocks_to_run,
                                   decoder_block, embed_tokens, init_params,
-                                  latent_absorb, latent_form, latent_unabsorb,
-                                  lm_logits, over_rows, param_logical_axes,
-                                  rope_angles, row_blocks, run_pattern,
-                                  scan_blocks, state_bytes, state_chunk,
-                                  zero_state)
+                                  latent_absorb, latent_expand, latent_form,
+                                  latent_unabsorb, lm_logits, over_rows,
+                                  param_logical_axes, rope_angles, row_blocks,
+                                  run_pattern, scan_blocks, state_bytes,
+                                  state_chunk, zero_state)
 from ..ops.paged_attention import (decode_path, head_rows,
                                    paged_decode_attention,
                                    paged_latent_attention, pool_row, pool_rows,
@@ -150,15 +150,22 @@ def _prefill_path(cfg: TransformerConfig, rows: int, kv_sharding,
     """The attention form a prefill of `rows` padded rows takes: "kernel"
     (ops/prefill_attention.py) or "xla" (`_xla_prefill_attention`).
     Decided from the platform and the shapes alone; under a `tp` mesh the
-    kernel runs per shard, so a shard's heads decide."""
+    kernel runs per shard, so a shard's heads decide.  A latent layer's
+    whole prompt is attended expanded: every head its own keys, nope + rope
+    wide, over values of `value`; its pool holds compressed rows and no
+    head's keys, so over cached pages it has the XLA form alone."""
     from ..ops.prefill_attention import prefill_path
     tp = 1
     if kv_sharding is not None and "tp" in kv_sharding.spec:
         tp = kv_sharding.mesh.shape["tp"]
-    if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+    kv_heads, value = cfg.num_kv_heads, cfg.head_dim_
+    if cfg.latent:
+        kv_heads, value = cfg.num_heads, cfg.latent.value
+    if cfg.num_heads % tp or kv_heads % tp \
+            or (cfg.latent and page is not None):
         return "xla"
     return prefill_path((rows, cfg.num_heads // tp, cfg.head_dim_),
-                        cfg.num_kv_heads // tp, cfg.dtype, page=page,
+                        kv_heads // tp, cfg.dtype, value=value, page=page,
                         table_len=table_len)
 
 
@@ -207,7 +214,8 @@ def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
     A latent pattern's pool_v is None and its attend is `run_pattern`'s for
     an `L` layer (`_latent_prefill_attend`)."""
     if cfg.latent:
-        return _latent_prefill_attend(cfg, rows, cached, blocks, row_block)
+        return _latent_prefill_attend(cfg, rows, length, cached, blocks,
+                                      row_block)
     pool = per_layer = ()
     if cached is None:
         path = _prefill_path(cfg, rows, kv_sharding)
@@ -261,15 +269,28 @@ def _suffix_mask(rows: int, T: int, prefix_len):
         (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
 
 
-def _latent_prefill_attend(cfg: TransformerConfig, rows: int, cached, blocks,
-                           row_block: int):
+def _latent_prefill_attend(cfg: TransformerConfig, rows: int, length, cached,
+                           blocks, row_block: int):
     """`_prefill_attend` for a pattern of latent layers: the key rows are
     the slot's cached rows as they lie in its pages (none: a whole prompt)
     and then the prefill's own, and `latent_form` says from the cached rows
     which of `LATENT_FORMS` attends them (over gathered rows the absorbed
-    one alone); either is built a block of query rows at a time.  attend(q, row, w, *at) -> (o, (the layer's new
-    cache rows (Sb, 1, C), None: no second pool))."""
+    one alone).  A whole prompt on path "kernel" up-projects its rows once
+    and goes through the blocked kernel, which runs no block past `length`
+    or above the diagonal and builds no scores array; everything else
+    builds its scores a block of query rows at a time.  attend(q, row, w,
+    *at) -> (o, (the layer's new cache rows (Sb, 1, C), None: no second
+    pool))."""
     if cached is None:
+        if _prefill_path(cfg, rows, None) == "kernel":
+            from ..ops.prefill_attention import prefill_attention
+
+            def attend(q, row, w):
+                k, v = latent_expand(w, row[:, :, 0], cfg)
+                return prefill_attention(q[0], k[0], v[0], length,
+                                         scale=cfg.latent.scale)[None], \
+                    (row[0], None)
+            return attend, ()
         T, per_layer = 0, ()
         mask = jnp.tril(jnp.ones((rows, rows), bool))
     else:

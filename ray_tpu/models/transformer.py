@@ -647,7 +647,11 @@ def latent_form(cached: int) -> str:
     and the absorbed form 768 products more a head and query row, equal at
     171 query rows at the published widths: a threshold for long suffixes
     over cached rows waits for traffic that has them and a chip run that
-    sets it."""
+    sets it.  The expanded rows of a whole prompt go through the blocked
+    prefill kernel where the engine's chooser takes it
+    (`llm/engine.py:_prefill_path`: a TPU, 1,024 padded rows or more) and
+    no scores are built; `LATENT_FORMS`' built scores are what a suffix, a
+    bucket under that and the CPU still run."""
     return "absorbed" if cached else "expanded"
 
 
@@ -668,7 +672,12 @@ def latent_qrow(lp, x, cos, sin, cfg: TransformerConfig):
 
 def _latent_softmax(q, k, v, mask, scale: float):
     """q (B, S, H, C) over keys k and values v, (B, T, H, .) a head's own or
-    (B, T, .) shared by all heads; key t open to query s where mask (S, T)."""
+    (B, T, .) shared by all heads; key t open to query s where mask (S, T).
+    THE BUILT SCORES of both forms: reached by a suffix over cached rows
+    (absorbed, a bucket of 64-1,024 rows against the slot's whole table
+    row), by a whole prompt under the prefill kernel's least bucket, and by
+    every prefill off a TPU; a whole prompt of 1,024 padded rows or more on
+    a TPU never comes here (`latent_form`)."""
     keys = "bthc" if k.ndim == 4 else "btc"
     s = jnp.einsum(f"bshc,{keys}->bhst", q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -699,20 +708,27 @@ def latent_unabsorb(w, o, cfg: TransformerConfig):
                       w["w_kvb"][..., cfg.latent.nope:].astype(o.dtype))
 
 
-def expanded_attend(w, rows, cfg: TransformerConfig):
-    """Key rows (B, T, rank + rope) up-projected ONCE to per-head keys and
-    values -> attend(q (B, S, H, nope + rope), mask (S, T), upto=None) ->
-    (B, S, H, value); `upto` (static): the first so many keys alone, for a
-    caller that knows its queries see no later one."""
+def latent_expand(w, rows, cfg: TransformerConfig):
+    """Key rows (B, T, rank + rope) up-projected ONCE -> every head's own
+    keys (B, T, H, nope + rope), the rotated part shared by all heads, and
+    values (B, T, H, value)."""
     z = cfg.latent
     kv = jnp.einsum("btc,chd->bthd", rows[..., :z.rank],
                     w["w_kvb"].astype(rows.dtype))
     shared = jnp.broadcast_to(rows[:, :, None, z.rank:],
                               (*kv.shape[:3], z.rope))
-    k = jnp.concatenate([kv[..., :z.nope], shared], axis=-1)
-    v = kv[..., z.nope:]
+    return (jnp.concatenate([kv[..., :z.nope], shared], axis=-1),
+            kv[..., z.nope:])
+
+
+def expanded_attend(w, rows, cfg: TransformerConfig):
+    """Key rows (B, T, rank + rope) up-projected (`latent_expand`) ->
+    attend(q (B, S, H, nope + rope), mask (S, T), upto=None) -> (B, S, H,
+    value); `upto` (static): the first so many keys alone, for a caller
+    that knows its queries see no later one."""
+    k, v = latent_expand(w, rows, cfg)
     return lambda q, mask, upto=None: _latent_softmax(
-        q, k[:, :upto], v[:, :upto], mask[:, :upto], z.scale)
+        q, k[:, :upto], v[:, :upto], mask[:, :upto], cfg.latent.scale)
 
 
 def absorbed_attend(w, rows, cfg: TransformerConfig):

@@ -3,9 +3,18 @@ and (on a prefix-cache hit) against the prefix its slot already holds in the
 paged pool — blocked, with an online softmax, so that no S x S score matrix
 is ever made.
 
-The serving engine's two prefill bodies call it (llm/engine.py:_prefill_fn,
-_suffix_prefill_fn).  It is forward only and emits no residuals; training's
-differentiable kernel is ops/flash_attention.py and shares nothing with it.
+The serving engine's prefill bodies call it (llm/engine.py:_prefill_fn,
+_suffix_prefill_fn, and a latent pattern's whole prompt in
+_latent_prefill_attend).  It is forward only and emits no residuals;
+training's differentiable kernel is ops/flash_attention.py and shares
+nothing with it.
+
+Keys (and queries) are `Dk` wide and values `Dv`, both read from the shapes:
+one width in a dense decoder, 128 + 64 = 192 over 128 in a latent layer's
+expanded form.  A `Dk` that is not whole 128-lane rows is filled with zero
+columns up to the next one before the call (exact; the MXU contracts 128
+deep, so 192 costs two passes either way, and Mosaic takes no 64-lane slice
+of a separate rotated part: PERF.md, PR 47).
 
 Keys are `[prefix_len tokens in pages | Sb new tokens]`.  The grid is one
 cell per (KV head, query block); a cell loads its `H // KV` query heads once
@@ -62,27 +71,30 @@ def _block(rows: int) -> int:
     return min(rows, _BLOCK)
 
 
-def kernel_tiles(q_shape, kv_heads: int, dtype, *, page: Optional[int] = None,
-                 table_len: int = 0) -> bool:
+def kernel_tiles(q_shape, kv_heads: int, dtype, *, value: Optional[int] = None,
+                 page: Optional[int] = None, table_len: int = 0) -> bool:
     """Whether the kernel can tile a prefill of q `(Sb, H, D)` over
-    `kv_heads`: heads of whole 128-lane rows that group, whole blocks; with
-    a prefix in pages (`page` given) also pages of whole sublane tiles that
-    divide a block, a KV-head stride the row read can take, and a page row
-    that fits scalar memory."""
+    `kv_heads` whose values are `value` wide (None: D, as the keys): values
+    of whole 128-lane rows, heads that group, whole blocks; the keys' width
+    is the wrapper's to fill up to whole lane rows.  With a prefix in pages
+    (`page` given) keys and values lie in pools of ONE row width, and also:
+    pages of whole sublane tiles that divide a block, a KV-head stride the
+    row read can take, and a page row that fits scalar memory."""
     Sb, H, D = q_shape
+    value = D if value is None else value
     bits = jnp.dtype(dtype).itemsize * 8
-    ok = D % 128 == 0 and H % kv_heads == 0 and Sb % _block(Sb) == 0 \
+    ok = value % 128 == 0 and H % kv_heads == 0 and Sb % _block(Sb) == 0 \
         and _block(Sb) % 128 == 0 and bits in (16, 32)
     if ok and page is not None:
-        ok = (page * kv_heads) % (256 // bits) == 0 \
+        ok = D == value and (page * kv_heads) % (256 // bits) == 0 \
             and _block(Sb) % page == 0 \
             and (bits == 32 or kv_heads == 1 or kv_heads % 2 == 0) \
             and 4 * table_len <= _TABLE_BYTES
     return ok
 
 
-def prefill_path(q_shape, kv_heads: int, dtype, *, page: Optional[int] = None,
-                 table_len: int = 0) -> str:
+def prefill_path(q_shape, kv_heads: int, dtype, *, value: Optional[int] = None,
+                 page: Optional[int] = None, table_len: int = 0) -> str:
     """Which form a prefill of these shapes takes in this process: "kernel"
     (on a TPU, `MIN_ROWS` padded rows or more, `MIN_ROWS_PAGED` with a
     prefix in pages, shapes that tile) or "xla" (the caller's own
@@ -90,7 +102,8 @@ def prefill_path(q_shape, kv_heads: int, dtype, *, page: Optional[int] = None,
     on_tpu = jax.devices()[0].platform == "tpu"
     least = MIN_ROWS if page is None else MIN_ROWS_PAGED
     return "kernel" if on_tpu and q_shape[0] >= least and kernel_tiles(
-        q_shape, kv_heads, dtype, page=page, table_len=table_len) else "xla"
+        q_shape, kv_heads, dtype, value=value, page=page,
+        table_len=table_len) else "xla"
 
 
 def kv_blocks(length: int, padded: int, prefix_len: int = 0,
@@ -119,7 +132,7 @@ def _kernel(*refs, scale: float, block: int, groups: int, paged: bool,
          acc_scr, sem) = refs
     h, qi = pl.program_id(0), pl.program_id(1)
     length = meta[0]
-    D = q_scr.shape[-1]
+    Dk, Dv = q_scr.shape[-1], acc_scr.shape[-1]
 
     @pl.when(qi * block >= length)
     def _dead():
@@ -149,7 +162,7 @@ def _kernel(*refs, scale: float, block: int, groups: int, paged: bool,
             p = jnp.exp(s - pltpu.repeat(m_new, s.shape[1] // 128, axis=1))
             alpha = jnp.exp(m_prev - m_new)
             l_scr[g] = alpha * l_scr[g] + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[g] = pltpu.repeat(alpha, D // 128, axis=1) * acc_scr[g] \
+            acc_scr[g] = pltpu.repeat(alpha, Dv // 128, axis=1) * acc_scr[g] \
                 + jax.lax.dot_general(
                     p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -162,7 +175,7 @@ def _kernel(*refs, scale: float, block: int, groups: int, paged: bool,
             cp.start()
         for g in range(groups):
             # The softmax scale goes into q once, not into every score.
-            q_scr[g] = (q_ref[:, g * D:(g + 1) * D].astype(jnp.float32)
+            q_scr[g] = (q_ref[:, g * Dk:(g + 1) * Dk].astype(jnp.float32)
                         * scale).astype(q_scr.dtype)
         m_scr[...] = jnp.full_like(m_scr, -1e30)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -265,8 +278,8 @@ def _kernel(*refs, scale: float, block: int, groups: int, paged: bool,
 
         jax.lax.fori_loop(0, qi + 1, new_body, None)
         for g in range(groups):
-            o_ref[:, g * D:(g + 1) * D] = (
-                acc_scr[g] / pltpu.repeat(l_scr[g], D // 128, axis=1)
+            o_ref[:, g * Dv:(g + 1) * Dv] = (
+                acc_scr[g] / pltpu.repeat(l_scr[g], Dv // 128, axis=1)
             ).astype(o_ref.dtype)
 
 
@@ -276,8 +289,14 @@ def _prefill_attention_pallas(q, k, v, length, pool_k=None, pool_v=None,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    Sb, H, D = q.shape
-    KV = k.shape[1]
+    Sb, H, Dk = q.shape
+    KV, Dv = v.shape[1:]
+    # Keys (and queries) of a width that is not whole lane rows take zero
+    # columns up to the next one: a zero column adds nothing to a score.
+    fill = -Dk % 128
+    if fill:
+        q, k = (jnp.pad(a, ((0, 0), (0, 0), (0, fill))) for a in (q, k))
+        Dk += fill
     groups = H // KV
     block = _block(Sb)
     paged = pool_k is not None
@@ -290,28 +309,28 @@ def _prefill_attention_pallas(q, k, v, length, pool_k=None, pool_v=None,
         return jnp.minimum(qi, jnp.maximum(meta[0] - 1, 0) // block), h
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((block, groups * D), q_map), hbm, hbm]
+    in_specs = [pl.BlockSpec((block, groups * Dk), q_map), hbm, hbm]
     scratch = [
-        pltpu.VMEM((groups, block, D), q.dtype),
-        pltpu.VMEM((2, block, D), k.dtype),
-        pltpu.VMEM((2, block, D), v.dtype),
+        pltpu.VMEM((groups, block, Dk), q.dtype),
+        pltpu.VMEM((2, block, Dk), k.dtype),
+        pltpu.VMEM((2, block, Dv), v.dtype),
         pltpu.VMEM((groups, block, 128), jnp.float32),     # running max
         pltpu.VMEM((groups, block, 128), jnp.float32),     # running denom
-        pltpu.VMEM((groups, block, D), jnp.float32),       # accumulator
+        pltpu.VMEM((groups, block, Dv), jnp.float32),      # accumulator
         pltpu.SemaphoreType.DMA((2, 2)),
     ]
-    args = [meta, q.reshape(Sb, H * D), k.transpose(1, 0, 2),
+    args = [meta, q.reshape(Sb, H * Dk), k.transpose(1, 0, 2),
             v.transpose(1, 0, 2)]
     page = 0
     if paged:
         L, N, page = pool_k.shape[:3]
         in_specs += [hbm, hbm]
-        scratch += [pltpu.VMEM((2, block * KV, D), pool_k.dtype),
-                    pltpu.VMEM((2, block * KV, D), pool_v.dtype),
+        scratch += [pltpu.VMEM((2, block * KV, Dk), pool_k.dtype),
+                    pltpu.VMEM((2, block * KV, Dv), pool_v.dtype),
                     pltpu.SemaphoreType.DMA((2, 2))]
         args.insert(1, pages.astype(jnp.int32))
-        args += [pool_k.reshape(L, N, page * KV, D),
-                 pool_v.reshape(L, N, page * KV, D)]
+        args += [pool_k.reshape(L, N, page * KV, Dk),
+                 pool_v.reshape(L, N, page * KV, Dv)]
     kernel = functools.partial(_kernel, scale=scale, block=block,
                                groups=groups, paged=paged, page=page,
                                kv_heads=KV)
@@ -321,29 +340,31 @@ def _prefill_attention_pallas(q, k, v, length, pool_k=None, pool_v=None,
             num_scalar_prefetch=2 if paged else 1,
             grid=(KV, Sb // block),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((block, groups * D),
+            out_specs=pl.BlockSpec((block, groups * Dv),
                                    lambda h, qi, *_: (qi, h)),
             scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((Sb, H * D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Sb, H * Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT),
         name="prefill_attention",
         interpret=interpret,
     )(*args)
-    return out.reshape(Sb, H, D)
+    return out.reshape(Sb, H, Dv)
 
 
 def prefill_attention(q, k, v, length, pool_k=None, pool_v=None, pages=None,
                       prefix_len=0, layer=0, *, scale: Optional[float] = None):
     """Causal attention of one prompt's new rows, through the kernel.
 
-    q (Sb, H, D) and k, v (Sb, KV, D) after RoPE, padded: rows at or past
-    `length` (traced scalar) come back as anything finite.  With `pool_k`,
-    `pool_v` (L, N, page, KV, D), `pages` (P,) the slot's page row,
-    `prefix_len` and `layer` (traced scalars), row i also attends to the
-    `prefix_len` tokens the pages hold, which precede row 0; only pages
-    below `prefix_len` are read.  Returns (Sb, H, D) in q's dtype.
+    q (Sb, H, Dk), k (Sb, KV, Dk) and v (Sb, KV, Dv) after RoPE, padded:
+    rows at or past `length` (traced scalar) come back as anything finite.
+    The two widths are read from the shapes (a latent layer's expanded form
+    has keys of 192 over values of 128; a dense decoder's are one width).
+    With `pool_k`, `pool_v` (L, N, page, KV, D), `pages` (P,) the slot's
+    page row, `prefix_len` and `layer` (traced scalars), row i also attends
+    to the `prefix_len` tokens the pages hold, which precede row 0; only
+    pages below `prefix_len` are read.  Returns (Sb, H, Dv) in q's dtype.
 
     The caller decides with `prefill_path` whether to call this at all."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])

@@ -17,12 +17,14 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.tests.test_deepseek_v3 import *              # noqa: F401,F403
+from benchmark.tests.test_prefill_kernel import *           # noqa: F401,F403
 from benchmark.tests.test_deepseek_v3 import (TOL, engine, family, prompt_of,
                                               tiny)
 from ray_tpu.llm.engine import SamplingParams
 from ray_tpu.models import routed
 from ray_tpu.models import transformer as T
 from tests.test_hybrid_model import _plain_gated
+from tests.test_llm import prefill_kernel                   # noqa: F401
 
 SHARED = routed.RoutedDims(experts=16, held=16, held_from=0, top_k=3,
                            latent=0, width=24, shared_width=40, scale=2.446,
@@ -244,6 +246,40 @@ def test_a_prompt_across_pages_and_row_blocks_against_the_reference():
     ref = family.reference_logits(eng.params, toks, cfg)[0][len(prompt) - 1:]
     np.testing.assert_allclose(got["logits"], ref, rtol=5e-4, atol=5e-4)
     assert np.asarray(ref).argmax(-1).tolist() == out
+
+
+def test_a_whole_prompt_through_the_kernel_against_the_reference(
+        prefill_kernel):                                    # noqa: F811
+    """Heads as the published ones lie (keys 128 + 64 over values of 128),
+    the prefill bodies steered onto the interpreted kernel: 1,100 tokens in
+    a 2,048-row bucket attend through it, 9 of 16 query blocks of 128, and
+    build no scores; the re-ask's suffix still absorbs over its pages, in
+    XLA."""
+    cfg, _ = tiny()
+    cfg = dict(cfg, kv_lora_rank=96, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128)
+    pc = family.program_config(cfg, max_seq_len=512)
+    prefill_kernel()
+    eng = engine(pc, 8, max_len=2304, kv_pages=160, max_batch=1)
+    prompt = prompt_of(cfg, 8, 1100)
+    out = eng.generate([prompt], SamplingParams(max_tokens=6))[0]
+    st = eng.prefill_stats()
+    assert (st["path"], st["kernel_calls"], st["xla_calls"]) == ("kernel", 1,
+                                                                 0)
+    assert (st["kv_blocks_run"], st["kv_blocks_dense"]) == (45, 256)
+    assert (st["row_blocks_run"], st["row_blocks_dense"]) == (3, 4)
+    assert eng.latent_stats()["prefills"] == {"expanded": 1, "absorbed": 0}
+    toks = jnp.asarray([prompt + out[:-1]], jnp.int32)
+    ref = family.reference_logits(eng.params, toks, cfg)[0][len(prompt) - 1:]
+    assert np.asarray(ref).argmax(-1).tolist() == out
+    cold = eng.trace_logits(prompt, out[:-1])
+    assert eng.prefill_stats()["kernel_calls"] == 2
+    np.testing.assert_allclose(cold["logits"], ref, rtol=5e-4, atol=5e-4)
+    hit = eng.trace_logits(prompt, out[:-1], cached=True)
+    st = eng.prefill_stats()
+    assert hit["from"] == 1088 and eng.latent_stats()["form"] == "absorbed"
+    assert (st["path"], st["kernel_calls"], st["xla_calls"]) == ("xla", 2, 1)
+    np.testing.assert_allclose(hit["logits"], ref, rtol=5e-4, atol=5e-4)
 
 
 def test_one_pool_and_what_the_counters_say():

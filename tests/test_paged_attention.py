@@ -591,7 +591,9 @@ def test_moonlight_programs_compile_for_v5e_around_one_pool(
     640 lanes, 16 slots x 512 pages): the ONE pool aliases its output, the
     step holds nine latent kernels and no copy of the pool or of a layer of
     it (a layer sliced out before the suffix's gather was a whole pool of
-    scratch), and the kernel alone compiles at those shapes."""
+    scratch), and the kernel alone compiles at those shapes.  And a whole
+    prompt's prefill in the check's 4,096-row bucket: nine prefill kernels
+    and no scores array, where the suffix keeps its built scores."""
     import json
     import os
     import re
@@ -606,9 +608,14 @@ def test_moonlight_programs_compile_for_v5e_around_one_pool(
 
     monkeypatch.setattr(routed, "grouped_path", lambda: "megablox")
     monkeypatch.setattr(pa, "decode_path", lambda *a: "pallas")
+    # `prefill_path` asks the platform: let it see the described chips.
+    monkeypatch.setattr(jax, "devices", lambda *a: topo.devices)
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "moonlight-16b-a3b-l9.json")) as f:
         cfg = deepseek_v3.program_config(json.load(f))
+    assert E._prefill_path(cfg, 8192, None) == "kernel"
+    assert E._prefill_path(cfg, 512, None) == "xla"         # under MIN_ROWS
+    assert E._prefill_path(cfg, 1024, None, 16, 512) == "xla"   # over pages
     one = SingleDeviceSharding(topo.devices[0])
     S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
     on_chip = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
@@ -655,6 +662,16 @@ def test_moonlight_programs_compile_for_v5e_around_one_pool(
     reask = jax.jit(suffix).lower(
         params, pool, None, S((P_,), jnp.int32), S((1, 64), jnp.int32),
         S((), jnp.int32), S((), jnp.int32), [], S((), jnp.int32)).compile()
+    assert "prefill_attention" not in reask.as_text()
+
+    def whole_prompt(p, t, n, row):
+        return E._state_prefill_fn(p, None, None, None, t, 0, n, [], row,
+                                   cfg, page, 0)
+    text = jax.jit(whole_prompt).lower(
+        params, S((1, 4096), jnp.int32), S((), jnp.int32),
+        S((), jnp.int32)).compile().as_text()
+    assert len(set(re.findall(r"%(prefill_attention[.\d]*) =", text))) == 9
+    assert not re.search(r"f32\[(1,)?16,512,\d+\]", text)     # no scores
     for compiled in (step, install, reask):
         assert _pool_sized_copies(compiled, pool) == []
         assert compiled.memory_analysis().temp_size_in_bytes < whole // 16
@@ -804,27 +821,33 @@ def test_what_the_vmem_guard_lets_through_compiles_for_v5e(
     (128, 8, 4, jnp.bfloat16, True),
     (512, 4, 2, jnp.float32, True),
     (256, 1, 8, jnp.bfloat16, True),
+    # serve_doc_reask_mla's bucket and its check's: a latent layer expanded,
+    # every head its own keys of 192 over values of 128
+    (8192, 16, 1, jnp.bfloat16, False, 192),
+    (4096, 16, 1, jnp.bfloat16, False, 192),
 ])
 def test_prefill_kernel_compiles_for_v5e(shape, topo, no_compile_cache):
     from jax.sharding import SingleDeviceSharding
     from ray_tpu.ops import prefill_attention as pfa
-    rows, KV, groups, dtype, paged = shape
+    rows, KV, groups, dtype, paged, Dk = (*shape, D)[:6]
     one = SingleDeviceSharding(topo.devices[0])
     S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
-    assert pfa.kernel_tiles((rows, KV * groups, D), KV, dtype,
+    assert pfa.kernel_tiles((rows, KV * groups, Dk), KV, dtype, value=D,
                             **(dict(page=16, table_len=256) if paged else {}))
-    args = [S((rows, KV * groups, D), dtype), S((rows, KV, D), dtype),
+    args = [S((rows, KV * groups, Dk), dtype), S((rows, KV, Dk), dtype),
             S((rows, KV, D), dtype), S((), jnp.int32)]
     if paged:
         pool = S((3, 3073, 16, KV, D), dtype)
         args += [pool, pool, S((256,), jnp.int32), S((), jnp.int32),
                  S((), jnp.int32)]
     compiled = jax.jit(lambda *a: pfa._prefill_attention_pallas(
-        *a, scale=1 / math.sqrt(D))).lower(*args).compile()
+        *a, scale=1 / math.sqrt(Dk))).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "prefill_attention" in text
-    # The pool reaches the kernel as it lies in HBM: no copy of it, no slice.
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    # The pool reaches the kernel as it lies in HBM: no copy of it, no slice
+    # (keys of 192: q and k filled to 256 lanes, k and v by heads, 128 MiB).
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1 << 20 if Dk == D else 129 << 20)
 
 
 @pytest.mark.parametrize("mesh_axes", [None, {"tp": 2}])

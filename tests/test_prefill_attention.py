@@ -26,11 +26,11 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(pa, "_BLOCK", BLOCK)
 
 
-def _qkv(rows, kv, groups, seed=0, dtype=jnp.bfloat16):
+def _qkv(rows, kv, groups, seed=0, dtype=jnp.bfloat16, dk=D):
+    """q, k `dk` wide, v `D` wide."""
     ks = jax.random.split(jax.random.key(seed), 3)
-    shape = lambda h: (rows, h, D)
-    return tuple(jax.random.normal(k, shape(h), jnp.float32).astype(dtype)
-                 for k, h in zip(ks, (kv * groups, kv, kv)))
+    return tuple(jax.random.normal(k, (rows, h, d), jnp.float32).astype(dtype)
+                 for k, h, d in zip(ks, (kv * groups, kv, kv), (dk, dk, D)))
 
 
 def _pool(kv, page, n_pages, layers=1, seed=1, dtype=jnp.bfloat16):
@@ -42,10 +42,10 @@ def _pool(kv, page, n_pages, layers=1, seed=1, dtype=jnp.bfloat16):
 
 def _oracle(q, k, v, length, pool_k=None, pool_v=None, pages=None,
             prefix_len=0):
-    """softmax(q K^T / sqrt(D)) V in float32, row by row, head by head:
+    """softmax(q K^T / sqrt(Dk)) V in float32, row by row, head by head:
     row i sees the `prefix_len` tokens of its pages, then new keys 0..i."""
     q, k, v = (np.asarray(a, np.float32) for a in (q, k, v))
-    rows, H, _ = q.shape
+    rows, H, dk = q.shape
     kv = k.shape[1]
     if prefix_len:
         pages = np.asarray(pages)
@@ -57,7 +57,7 @@ def _oracle(q, k, v, length, pool_k=None, pool_v=None, pages=None,
     for i in range(length):
         for h in range(H):
             kh = k[:prefix_len + i + 1, h // (H // kv)]
-            s = kh @ q[i, h] / math.sqrt(D)
+            s = kh @ q[i, h] / math.sqrt(dk)
             p = np.exp(s - s.max())
             out[i, h] = (p / p.sum()) @ v[:prefix_len + i + 1, h // (H // kv)]
     return out
@@ -67,16 +67,22 @@ def _kernel(q, k, v, length, *paged):
     """The Pallas kernel itself, interpreted (on a TPU the engine's prefill
     bodies choose it by `prefill_path`)."""
     return np.asarray(pa._prefill_attention_pallas(
-        q, k, v, length, *paged, scale=1 / math.sqrt(D), interpret=True),
-        np.float32)
+        q, k, v, length, *paged, scale=1 / math.sqrt(q.shape[-1]),
+        interpret=True), np.float32)
 
 
-@pytest.mark.parametrize("groups", [1, 4])
+# (query heads a KV head, the keys' width): a dense decoder's, alone and
+# grouped, and a latent layer's expanded form, 192 over values of 128.
+WIDTHS = [(1, D), (4, D), (1, 192)]
+
+
+@pytest.mark.parametrize("groups,dk", WIDTHS)
 @pytest.mark.parametrize("length", [256, 200, 1])
-def test_whole_prompt_matches_float32(length, groups):
+def test_whole_prompt_matches_float32(length, groups, dk):
     """`length` = the bucket, inside the second block, one token."""
-    q, k, v = _qkv(256, 2, groups)
+    q, k, v = _qkv(256, 2, groups, dk=dk)
     got = _kernel(q, k, v, length)
+    assert got.shape == (256, 2 * groups, D)
     assert np.isfinite(got).all()           # rows past `length` too
     assert np.abs(got[:length] - _oracle(q, k, v, length)).max() < BF16_TOL
 
@@ -153,14 +159,14 @@ def test_reads_live_pages_only():
     np.testing.assert_array_equal(dirty, clean)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_rows_past_length_do_not_reach_the_rows_below(paged):
+@pytest.mark.parametrize("what", ["whole", "paged", "keys_192"])
+def test_rows_past_length_do_not_reach_the_rows_below(what):
     """What q, k and v hold at and past `length` (the padding's rows) moves
     no row below it, and whatever comes back there is finite."""
     page, kv, groups, length = 16, 2, 4, 140
-    q, k, v = _qkv(256, kv, groups)
+    q, k, v = _qkv(256, kv, groups, dk=192 if what == "keys_192" else D)
     extra = ()
-    if paged:
+    if what == "paged":
         pk, pv = _pool(kv, page, 33)
         extra = (pk, pv, jnp.asarray(_table(33, 8)), 5 * page, 0)
     clean = _kernel(q, k, v, length, *extra)
@@ -213,3 +219,14 @@ def test_chooser_adapts_to_platform_and_shape(monkeypatch):
     assert pa.prefill_path((128, 32, D), 8, bf16, **cell) == "kernel"
     assert pa.prefill_path((64, 32, D), 8, bf16, **cell) == "xla"
     assert pa.prefill_path((4096, 8, 16), 4, jnp.float32) == "xla"
+    # keys of 192 over values of 128 (a latent layer expanded): unpaged
+    # alone, since a pool holds keys and values of one width
+    wide = (8192, 16, 192)
+    assert pa.kernel_tiles(wide, 16, bf16, value=D)
+    assert pa.prefill_path(wide, 16, bf16, value=D) == "kernel"
+    assert not pa.kernel_tiles(wide, 16, bf16, value=D, **cell)
+    assert pa.prefill_path(wide, 16, bf16, value=D, **cell) == "xla"
+    assert not pa.kernel_tiles(wide, 16, bf16)          # values of 192
+    assert not pa.kernel_tiles((8192, 16, D), 16, bf16, value=64)
+    assert pa.kernel_tiles((4096, 32, D), 8, bf16, value=D)
+    assert pa.prefill_path((128, 32, D), 8, bf16, value=D, **cell) == "kernel"
