@@ -132,13 +132,13 @@ class _Request:
 class _Flight:
     """A decode step that has been dispatched and not read yet: what it
     returns (the next tokens, a pattern's routed counts after them), whom
-    it ran for (slot -> request), and what its `decode` span carries."""
+    it ran for (slot -> request), the slot rows the host wrote before it,
+    and whether it left while the step before it was still unread."""
     nxt: Any
     batch: Dict[int, _Request]
     t0: int
-    pages: int
     synced: int
-    rows: int = 0       # tokens the batch holds in cache, this step's included
+    queued: bool = False
 
 
 # --------------------------------------------------------------------------
@@ -1271,14 +1271,19 @@ class LLMEngine:
         self._lengths = np.zeros(max_batch, np.int32)
         self._temps = np.zeros(max_batch, np.float32)
         self._touched = np.zeros(max_batch, bool)
-        # The next decode step, where the last `step()` already sent it off
-        # (`_next_batch_if_ahead`); and the owner's word that someone is waiting
-        # to hand the engine work (a replica: its lock has waiters), which
-        # keeps the next step back.  None: no owner who could tell, and no
-        # step leaves ahead (a request added between two calls joins the
-        # very next step, as ever).
+        # The decode step that is out, where the last `step()` left one
+        # (`_next_batch_if_ahead`, `_next_batch_if_queued`); the owner's word
+        # that someone is waiting to hand the engine work (a replica: its
+        # lock has waiters), which keeps the next step back; and the
+        # owner's hook for a tick's events so far, its first tokens, called
+        # on the engine's thread before the tick's decode step is read.
+        # `hold_ahead` None: no owner who could tell, and no step leaves
+        # ahead (a request added between two calls joins the very next
+        # step, as ever).
         self._ahead: Optional[_Flight] = None
         self.hold_ahead: Optional[Callable[[], bool]] = None
+        self.hand_first: Optional[Callable[[List[Tuple[int, int, bool]]],
+                                           None]] = None
         self._state_shd = None if mesh is None else NamedSharding(
             mesh, PartitionSpec())
         idle = np.zeros(max_batch, bool)
@@ -1303,6 +1308,7 @@ class LLMEngine:
         # How much of what the tables address the batch decode step reads
         # (ops/paged_attention.py reads live pages only), and by which path.
         self._decode_steps = 0
+        self._steps_queued = 0      # of them, left with the one before unread
         self._pages_read = 0
         self._step_pages_read = 0
         # How often the host wrote slot state to the device, and how many
@@ -1596,7 +1602,10 @@ class LLMEngine:
         what the host wrote into the step's resident state: `state_syncs`
         counts the steps before which it wrote slot rows (one packed
         upload), `state_rows` the rows (`step_state_rows`: the last
-        step's); `steps - state_syncs` steps uploaded nothing."""
+        step's); `steps - state_syncs` steps uploaded nothing.
+        `steps_queued` of the `steps` left for the device while the step
+        before them was still unread (`_next_batch_if_queued`).  A step
+        counts when it is read, for the rows that were still live then."""
         z = self.cfg.latent
         pooled = self._pk is not None
         per_step = self.max_batch * self.pages_per_slot if pooled else 0
@@ -1607,6 +1616,7 @@ class LLMEngine:
                 "pool_row": pool_row(*self.cfg.cache_row)
                 if pooled else "none",
                 "steps": self._decode_steps,
+                "steps_queued": self._steps_queued,
                 "pages_read": self._pages_read,
                 "pages_addressable": self._decode_steps * per_step,
                 "step_pages_read": self._step_pages_read,
@@ -2231,23 +2241,27 @@ class LLMEngine:
         prompt's real last-token logits (serve_patterns.LongContextApp)."""
         return self._sample_host(logits, params or SamplingParams())
 
+    def _length_left(self, req: _Request) -> int:
+        """The tokens `req` may still emit before it ends by length (none
+        or fewer: it has ended): what the host knows of a reply's end
+        without reading a token."""
+        p = req.params
+        if req.kv_paged:
+            # Paged context: length is bounded by max_tokens and the
+            # reserved decode-tail pages, never by max_len (the context
+            # itself lives in external parts).
+            return min(p.max_tokens - len(req.out),
+                       len(req.pages) * self.page - req.ext_written - 1)
+        return min(p.max_tokens,
+                   self.max_len - 1 - len(req.prompt)) - len(req.out)
+
     def _emit(self, req: _Request, token: int):
         req.out.append(token)
         p = req.params
         if p.eos_id is not None and token == p.eos_id:
             req.finished = True
             req.finish_reason = req.finish_reason or "stop"
-        elif req.kv_paged:
-            # Paged context: length is bounded by max_tokens and the
-            # reserved decode-tail pages, never by max_len (the context
-            # itself lives in external parts).
-            tail_cap = len(req.pages) * self.page
-            if len(req.out) >= p.max_tokens \
-                    or req.ext_written + 1 >= tail_cap:
-                req.finished = True
-                req.finish_reason = req.finish_reason or "length"
-        elif len(req.out) >= p.max_tokens \
-                or len(req.prompt) + len(req.out) >= self.max_len - 1:
+        elif self._length_left(req) <= 0:
             req.finished = True
             req.finish_reason = req.finish_reason or "length"
         self._tick_events.append((req.req_id, token, req.finished))
@@ -2268,20 +2282,54 @@ class LLMEngine:
         ride to the device as ONE packed upload in the next step's `prep`.
         A step before which nothing was touched uploads nothing and runs
         no program but the decode step (`decode_stats()`).  Such a step
-        needs nothing of the host: where the call can see that the next
-        one is of that kind and no request stands between
-        (`_next_batch_if_ahead`) it sends it off before it returns, and
-        the device runs it while the caller hands this step's tokens on
-        and comes back; the next call reads it (`_ahead`) and dispatches
-        nothing.  What a call returns is what it returned before: one
-        token for every active slot.
+        needs nothing of the host, not even the tokens of the step before
+        it, so host and device need not meet at every step.  Where the
+        engine has an owner who can say that nobody is about to hand it
+        work (`hold_ahead`):
+
+          - a call that read its step and sees that the next one is of
+            that kind sends it off before it returns
+            (`_next_batch_if_ahead`), and the device runs it while the
+            caller hands this step's tokens on and comes back;
+          - a call that finds a step out (`_ahead`) sends the one AFTER it
+            off first, if it may (`_next_batch_if_queued`), and only then
+            blocks on the read-back: the device holds one step running and
+            one queued, and the read-back's late return, `emit`, the
+            loop's leaves and the next `prep` and `dispatch` all run under
+            a busy chip.  A step that may not be queued (somebody waits,
+            a slot was touched, this call will retire a reply that ends by
+            length) is read first and the chain starts again at the end
+            of a later call.
+
+        A reply that ends by EOS is seen one step late: its row is still
+        active in the step queued behind the one that sampled the EOS.
+        That is a DEAD step for the row: it writes the row's own reserved
+        page and its slot's state row and nothing else, its token is
+        dropped, and the row counts in none of the step's numbers
+        (`batch=`, `pages=`, `latent_rows=`, `state_rows=`; the routed
+        layers' counts are the device's own and hold what it touched).  The
+        host frees the slot and its pages when it reads the EOS; whatever
+        is dispatched into them afterwards runs behind the dead step on
+        the device, which runs in order.  A step none of whose rows is
+        live any more is dropped unread and counts as no step.  A
+        cancelled request's row is skipped the same way, in every step
+        that was out when it went.
+
+        What a call returns is what it returned before: one token for
+        every slot of the step it read, and with greedy sampling every
+        request's tokens are those of the lockstep order.  A tick's first
+        tokens (`_admit`, a chunked prefill's last chunk) do not wait for
+        the tick's decode step: once that step is dispatched the engine
+        hands the events so far to its owner's hook (`hand_first`), on
+        this thread, and `take_tick_events()` later returns the rest.
 
         Every instant of the call belongs to one phase of
         `tick_phases.TickPhases` (self.phases): `admit`, `chunk`, `emit`,
-        then the decode step's `prep`, `dispatch` and `wait`, then `emit`
-        again (and `ahead`, where the next step leaves now: that call's
-        `prep` and `dispatch` are then empty); it hands back to the
-        replica's loop in `hop`."""
+        then `ahead` (a step queued behind the one that is out: its `prep`
+        and `dispatch`), the decode step's `prep`, `dispatch` and `wait`,
+        then `emit` again (and `ahead`, where the next step leaves at the
+        end of the call: the next call's `prep` and `dispatch` are then
+        empty); it hands back to the replica's loop in `hop`."""
         ph = self.phases
         ph.in_step = True
         done: List[_Request] = []
@@ -2334,20 +2382,40 @@ class LLMEngine:
                 done.append(self._retire(slot))
         before = len(done)
         flight, self._ahead = self._ahead, None
-        if flight is None:
-            batch = {s: r for s, r in self._slots.items() if not r.kv_paged}
-            if not batch:
+        # Whom a step that is out still counts for: a row that ended by
+        # eos in the step before it (a dead step) or was cancelled while
+        # it was out is in none of its numbers, and a step with no such
+        # row left is never read.
+        live = flight and {s: r for s, r in flight.batch.items()
+                           if self._slots.get(s) is r}
+        if not live:
+            live = {s: r for s, r in self._slots.items() if not r.kv_paged}
+            if not live:
                 return 0
-            flight = self._dispatch_decode(ph, batch, retired=before)
+            flight = self._dispatch_decode(ph, live, retired=before)
         else:
-            # Sent off by the last call (`ahead`): nothing to prepare.
-            flight.t0 = ph.to("prep", retired=before)
+            # Sent off by an earlier call: nothing to prepare.  The step
+            # after it leaves first, if it may.
+            batch = self._next_batch_if_queued()
+            if batch:
+                self._ahead = self._dispatch_decode(
+                    ph, batch, ahead=True, queued=True, retired=before)
+                flight.t0 = ph.to("prep")
+            else:
+                flight.t0 = ph.to("prep", retired=before)
             ph.to("dispatch")
+        if self.hand_first is not None and self._tick_events:
+            # First tokens leave now, under a busy chip.
+            self.hand_first(self.take_tick_events())
         ph.to("wait")
         nxt = np.asarray(flight.nxt)
+        lengths = self._lengths[list(live)]
+        pages = int((lengths // self.page + 1).sum()) \
+            if self._pk is not None else 0
         self._decode_steps += 1
-        self._pages_read += flight.pages
-        self._step_pages_read = flight.pages
+        self._steps_queued += flight.queued
+        self._pages_read += pages
+        self._step_pages_read = pages
         self._step_state_rows = flight.synced
         extra = {}
         if len(self._routed):
@@ -2356,42 +2424,43 @@ class LLMEngine:
             self._routed += self._step_routed
             extra["experts"] = int(self._step_routed[:, 0].sum())
         if self.cfg.latent:
-            self._latent["rows_read"] += flight.rows
-            self._latent["step_rows_read"] = extra["latent_rows"] = flight.rows
+            rows = int((lengths + 1).sum())     # this step's token included
+            self._latent["rows_read"] += rows
+            self._latent["step_rows_read"] = extra["latent_rows"] = rows
         if self.cfg.retention:
-            ret, n = self._retention, len(flight.batch)
-            ret["rows_stepped"] += n
-            ret["step_rows_stepped"] = extra["state_rows"] = n
-        ph.span("decode", flight.t0, ph.to("emit"), batch=len(flight.batch),
-                pages=flight.pages, synced=flight.synced, **extra)
+            ret = self._retention
+            ret["rows_stepped"] += len(live)
+            ret["step_rows_stepped"] = extra["state_rows"] = len(live)
+        ph.span("decode", flight.t0, ph.to("emit"), batch=len(live),
+                pages=pages, synced=flight.synced,
+                queued=int(flight.queued), **extra)
         # The host advances its mirrors as the step advanced the device's.
-        for slot, req in flight.batch.items():
-            if self._slots.get(slot) is not req:
-                continue                # cancelled while the step was out
+        for slot, req in live.items():
             self._lengths[slot] += 1          # the token we just attended
             tok = int(nxt[slot])
             self._last[slot] = tok
             self._emit(req, tok)
             if req.finished:
                 done.append(self._retire(slot))
-        batch = self._next_batch_if_ahead()
-        if batch:
-            self._ahead = self._dispatch_decode(ph, batch, ahead=True)
-            ph.to("emit")
+        if self._ahead is None:
+            batch = self._next_batch_if_ahead()
+            if batch:
+                self._ahead = self._dispatch_decode(ph, batch, ahead=True)
+                ph.to("emit")
         return before
 
     def _dispatch_decode(self, ph: TickPhases, batch: Dict[int, _Request],
-                         ahead: bool = False, **closing) -> _Flight:
+                         ahead: bool = False, queued: bool = False,
+                         **closing) -> _Flight:
         """One decode step for `batch` (slot -> request) leaves for the
         device: `prep` (the one packed upload of the rows the host
         touched, if any) and `dispatch`; both in the one leaf `ahead` for
-        a step sent off at the end of a call, before the caller asks for
-        it.  What comes back is read in `_step`."""
+        a step sent off before the call that will ask for it, at the end
+        of a call or (`queued`) behind a step that is still unread.  What
+        comes back is read in `_step`."""
         active = np.zeros(self.max_batch, bool)
         active[list(batch)] = True
         t0 = ph.to("ahead" if ahead else "prep", **closing)
-        pages = int((self._lengths[active] // self.page + 1).sum()) \
-            if self._pk is not None else 0
         update, synced = self._no_rows, int(self._touched.sum())
         if synced:
             update = jax.device_put(_pack_rows(
@@ -2404,32 +2473,49 @@ class LLMEngine:
             ph.to("dispatch")
         self._pk, self._pv, self._dev, nxt = self._decode_jit(
             self.params, self._pk, self._pv, self._dev, update)
-        rows = int((self._lengths[active] + 1).sum()) if self.cfg.latent \
-            else 0
-        return _Flight(nxt, batch, t0, pages, synced, rows)
+        return _Flight(nxt, batch, t0, synced, queued)
 
     def _next_batch_if_ahead(self) -> Dict[int, _Request]:
-        """Whom the NEXT decode step is for, if it may leave now, at the
-        end of this call, so that the device runs it while the caller fans
-        this step's tokens out and comes back (`_step` then finds it in
-        `_ahead` and only reads it); nobody if it may not.  It may when
+        """Whom the NEXT decode step is for, if it may leave now, before
+        the call that will ask for it, so that the device runs it while
+        the host does everything else; nobody if it may not.  It may when
         the next call would dispatch exactly this step: every slot is the
         batch step's, nothing waits for admission, the engine's owner says
         that nobody is about to hand it work (`hold_ahead`), whose prefill
         would otherwise queue behind the step, and no row is touched:
-        nobody retired in this call.  A caller whose answer has just ended
-        comes back with its next request within the tick that follows, and
-        that tick is left as long as it ever was: cut short by a step sent
-        ahead, it ends a millisecond before a closed loop's request
-        arrives about once in ten, the request joins a tick late, and
-        callers laid ticks apart walk into each other's prefills
-        (PERF.md §6, PR 38)."""
+        nobody retired in this call, nobody was admitted or cancelled
+        since the last dispatch.  (So the host's mirrors and the device's
+        rows agree but for the steps that are out, and the step uploads
+        nothing.)  A caller whose answer has just ended comes back with
+        its next request within the tick that follows, and that tick is
+        left as long as it ever was: cut short by a step sent ahead, it
+        ends a millisecond before a closed loop's request arrives about
+        once in ten, the request joins a tick late, and callers laid ticks
+        apart walk into each other's prefills (PERF.md §6, PR 38)."""
         if (self.hold_ahead is None or self._waiting or self._prefilling
                 or self._touched.any()
                 or any(r.kv_paged for r in self._slots.values())
                 or self.hold_ahead()):
             return {}
         return dict(self._slots)
+
+    def _next_batch_if_queued(self) -> Dict[int, _Request]:
+        """Whom the step AFTER the one that is out is for, if it may leave
+        while that one is still unread; nobody if it may not.  The rules
+        are `_next_batch_if_ahead`'s, read at the start of the call (no row
+        is touched, so the step that is out ran for these very slots), and
+        one more, since "nobody retired in this call" is not known yet: no
+        row will end by LENGTH when the step that is out is read, which
+        the host knows by counting (`_length_left`).  The call that retires a reply
+        so keeps PR 38's order: it reads its step with nothing behind it
+        and sends none ahead, the tick after it is as long as it ever was,
+        and the caller who comes back in it finds at most the one running
+        step in front of its prefill.  An EOS cannot be foreseen: its row
+        takes one dead step (`step`)."""
+        batch = self._next_batch_if_ahead()
+        if any(self._length_left(r) <= 1 for r in batch.values()):
+            return {}
+        return batch
 
     def _advance_prefilling(self) -> None:
         """Advance chunked prefills by AT MOST one chunk per tick: the

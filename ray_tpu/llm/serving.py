@@ -55,10 +55,12 @@ ticks of which ``stops`` admitted).  Per tick: the phases of
 ``tick:expire``, ``tick:hop``, ``step:admit``, ``decode`` (with batch
 size and ``synced``, the slot rows written to the device before it;
 ``decode:prep`` / ``:dispatch`` / ``:wait``), ``sample_sync`` (the
-batched device→host sample pull), ``step:emit``, ``step:ahead`` (the
-next decode step sent off before ``step()`` returns, while no caller waits
-for the lock), ``tick:fan_out`` — whose cumulative nanoseconds
-``debug_stats()["tick"]`` also serves.
+batched device→host sample pull), ``step:emit``, ``step:ahead`` (a
+later decode step sent off before the call that will read it, at the end of
+``step()`` or behind a step still unread, while no caller waits for the
+lock), ``tick:fan_out`` — whose cumulative nanoseconds
+``debug_stats()["tick"]`` also serves.  A tick's first tokens are fanned
+out from inside it, before its decode step is read (``_hand_first``).
 
 A request's own account of its time: the replica snapshots those counters
 when a request is enqueued (S0), when its first token is put on its stream
@@ -211,6 +213,10 @@ class EngineReplica:
         # A caller waiting for the lock is about to hand the engine work:
         # the tick does not send its next decode step off ahead of it.
         self.engine.hold_ahead = lambda: self._lock.waiting > 0
+        # A tick's first tokens leave for their streams before the tick's
+        # decode step is read (`_hand_first`).
+        self.engine.hand_first = self._hand_first
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._wake = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
         # req_id -> consumer queue / metadata for in-flight streams.
@@ -389,7 +395,7 @@ class EngineReplica:
         decode step for every active slot, retire per tick, fan tokens
         out to their streams.  Engine compute runs on an executor thread
         so this loop (and the whole worker runtime) stays responsive."""
-        loop = asyncio.get_running_loop()
+        loop = self._loop = asyncio.get_running_loop()
         ph = self._phases
         while True:
             try:
@@ -445,7 +451,21 @@ class EngineReplica:
                     else "deadline exceeded mid-decode"))
             meta["finished"] = True
 
+    def _hand_first(self, events) -> None:
+        """The engine's hook (`LLMEngine.hand_first`), called on the
+        ENGINE's thread inside `step()` with the tick's events so far, its
+        first tokens, once the tick's decode step has been dispatched and
+        before it is read: they go to their streams now, on the loop's
+        thread, which is idle while it awaits `step()`, and not a decode
+        step later.  The engine has taken them out of the tick's events,
+        so the `_fan_out` at the end of the call hands each token once."""
+        self._loop.call_soon_threadsafe(self._fan_out, events, ())
+
     def _fan_out(self, events, done_reqs) -> None:
+        """Tokens onto their streams, and the ends of the requests that
+        retired.  A request's first token takes its snapshot S1 here, on
+        the loop's thread: at the end of a tick, or inside it while the
+        engine's thread is in `dispatch` or `wait` (`_hand_first`)."""
         rec = flight_recorder.recorder()
         done_by_id = {r.req_id: r for r in done_reqs}
         for rid, tok, fin in events:

@@ -34,14 +34,33 @@ page tables), `prefill` and `sample_sync` (inside either), `prep`
 step, the one packed upload of the touched rows), `dispatch`, `wait` (the blocking read-back),
 `emit` (the emit/retire loops: requests finished at admission and
 paged-context slots before the decode step, every slot after it),
-`ahead` (the NEXT step's `prep` and `dispatch`, where `step()` sends it
-off before it returns: the device then runs it through `hop`, `fan_out`,
-`turn` and the next tick's `expire`, `hop` and `admit`, and that tick's
-own `prep` and `dispatch` are empty), `fan_out`.  Parent spans (`tick`, `step:admit`, `step:chunk`, `decode`)
-are stamped by their callers from the stamps `to()` returns.
+`ahead` (a LATER step's `prep` and `dispatch`, wherever `step()` sends a
+decode step off before the call that will read it: at the end of a call,
+once its own step is read, so that the device runs the next one through
+`hop`, `fan_out`, `turn` and the next tick's `expire`, `hop` and `admit`
+(PR 38); and at the start of a call that finds a step still out, BEHIND
+that step and before it is read, so that the device holds one step running
+and one queued and never waits for the read-back, `emit` or the loop (PR
+48: `decode_stats()["steps_queued"]`, the `decode` span's `queued=`).  The
+tick that reads a step sent off earlier has an empty `prep` and
+`dispatch`), `fan_out`.  Parent spans (`tick`, `step:admit`, `step:chunk`,
+`decode`) are stamped by their callers from the stamps `to()` returns.
+
+A step queued behind the one that sampled a reply's EOS still holds that
+reply's row: a DEAD step for the row, whose token is dropped and which
+counts in none of the `decode` span's numbers (`LLMEngine.step`).
+
+A tick's first tokens do not wait for its decode step: once that step is
+dispatched, still in `dispatch`, the engine's thread calls the replica's
+hook (`EngineReplica._hand_first`), which posts `_fan_out` onto the loop;
+the LOOP's thread, idle while it awaits `step()`, puts them on their
+streams and takes the request's snapshot S1 there, while the open leaf is
+the engine's (`dispatch` or `wait`).
 
 One thread at a time drives this object: the loop's thread, or — while the
-loop awaits `step()` — the executor's.  Engine work outside a tick
+loop awaits `step()` — the executor's; `snapshot()` may be called from the
+other one (a sequence number brackets every change of the counters, so it
+reads them whole).  Engine work outside a tick
 (`prefill_only`, `sample_first`: another holder of the replica's lock)
 records its spans as before and counts as the loop's `turn`.
 
@@ -53,6 +72,7 @@ admission costs a stream that is decoding.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
@@ -82,9 +102,10 @@ class TickPhases:
         self.ns: Dict[str, int] = dict.fromkeys(LEAVES, 0)
         self.in_tick = False            # a replica's loop drives this tick
         self.in_step = False            # inside LLMEngine.step()
-        # (leaf, since): replaced whole, so that `snapshot()` on another
-        # thread reads a consistent pair.
-        self._open: Tuple[Optional[str], int] = (None, 0)
+        self._open: Tuple[Optional[str], int] = (None, 0)    # leaf, since
+        # Odd while `to()` moves time from the open phase into `ns`:
+        # `snapshot()` on the other thread reads between two even values.
+        self._seq = 0
         self._note: Optional[TraceAnnotation] = None
         self._tick: Tuple[int, int, int] = (0, 0, 0)
 
@@ -95,8 +116,12 @@ class TickPhases:
         the phase that closes."""
         now = clocks.mono_ns()
         cur, since = self._open
+        self._seq += 1
         if cur is not None:
             self.ns[cur] += now - since
+        self._open = (leaf, now)
+        self._seq += 1
+        if cur is not None:
             self._note.__exit__(None, None, None)
             name = _SPAN.get(cur)
             if name is not None:
@@ -104,7 +129,6 @@ class TickPhases:
                     closing["n"] = self.n
                 flight_recorder.recorder().span_at(
                     "request", name, since, now, **closing)
-        self._open = (leaf, now)
         if leaf is not None:
             self._note = TraceAnnotation("ray_tpu/tick:" + leaf)
             self._note.__enter__()
@@ -152,8 +176,13 @@ class TickPhases:
         """Ticks begun, those of them that admitted, and cumulative ns per
         leaf with the open phase counted up to the stamp `t`: two snapshots
         bracket a window exactly, `sum(ns)` apart by their `t`s."""
-        ns = dict(self.ns)
-        cur, since = self._open
+        while True:
+            seq = self._seq
+            ns = dict(self.ns)
+            cur, since = self._open
+            if not seq % 2 and seq == self._seq:
+                break
+            time.sleep(0)               # let the thread inside `to()` finish
         t = clocks.mono_ns()
         if cur is not None:
             ns[cur] += t - since

@@ -331,12 +331,21 @@ def test_prefill_then_decode_through_the_slot_state_is_the_full_forward(seed):
     assert st["steps"] == 7 and max(st["step_rows"]) <= 4   # one live slot
 
 
-def test_a_step_sent_ahead_advances_the_slot_state_all_the_same():
+@pytest.mark.parametrize("cell", ["serve_doc_reask_hybrid",
+                                  "serve_doc_reask_moe",
+                                  "serve_doc_reask_retention"])
+def test_a_step_sent_ahead_advances_the_slot_state_all_the_same(cell):
     """An engine whose owner can say that nobody waits (`hold_ahead`) sends
-    the next decode step off before `step()` returns: the recurrent state
-    and the routed counts come out as from steps read at once, with a
-    second request admitted while a step is out."""
-    cfg, pc = _tiny()
+    the next decode step off before `step()` returns, and the one after it
+    before that one is read: with two steps out the recurrent state (the
+    hybrid's Mamba-2, LFM2's convolution tails, Brumby's retention) and the
+    routed counts come out as from steps read at once, with a second
+    request admitted while steps are out, whose prefill installs its
+    state into a slot row BEHIND them on the device."""
+    loaded = load_cell(cell)
+    selftest.shrink(loaded)
+    cfg = loaded["config"]
+    pc = loaded["family"].program_config(cfg, max_seq_len=512)
     outs, stats = [], []
     for hold in (None, lambda: False):
         eng = _engine(pc, 3)
@@ -350,12 +359,16 @@ def test_a_step_sent_ahead_advances_the_slot_state_all_the_same():
             done.update((r.req_id, list(r.out)) for r in eng.step())
         assert not eng.has_unfinished() and len(done) == 2
         outs.append(done)
-        stats.append(eng.routed_stats())
+        stats.append((eng.routed_stats(), eng.decode_stats(),
+                      eng.retention_stats()))
     assert outs[0] == outs[1]
     assert eng.phases.snapshot()["ns"]["ahead"] > 0
     # the second request joined a step later, inside the first one's nine
-    assert stats[0]["rows"] == stats[1]["rows"]
-    assert stats[0]["steps"] == stats[1]["steps"] == 9
+    (routed0, decode0, ret0), (routed1, decode1, ret1) = stats
+    assert routed0.get("rows") == routed1.get("rows")
+    assert decode0["steps"] == decode1["steps"] == 9
+    assert decode0["steps_queued"] == 0 < decode1["steps_queued"]
+    assert ret0.get("rows_stepped") == ret1.get("rows_stepped")
 
 
 def test_a_hit_is_cut_back_to_a_checkpoint_and_answers_like_a_cold_prompt():

@@ -638,13 +638,16 @@ def _script(eng, events, steps):
     return outs
 
 
-def test_a_step_sent_ahead_changes_no_token():
+@pytest.mark.parametrize("owner", ["never_holds", "always_holds"])
+def test_a_step_sent_ahead_changes_no_token(owner):
     """With an owner who can say that nobody waits (`hold_ahead`), `step()`
     sends the next decode step off before it returns where that step needs
-    nothing of the host.  Requests that end by length and by eos, one that
-    arrives while a step is out, one cancelled while a step is out and its
-    slot taken by the next: every token is the one the engine without an
-    owner gives, and the stats count the steps that were read."""
+    nothing of the host, and the one after it before that one is read.
+    Requests that end by length and by eos, one that arrives while steps
+    are out, one cancelled while steps are out and its slot taken by the
+    next: every token is the one the engine without an owner gives, and
+    the stats count the steps that were read.  An owner for whom somebody
+    always waits gets the lockstep order, step for step."""
     make = lambda: _engine(None, max_batch=2, max_len=64, page_size=16,
                            seed=0)
     first = make().generate([[5, 6, 7]], SamplingParams(max_tokens=9))[0]
@@ -657,20 +660,84 @@ def test_a_step_sent_ahead_changes_no_token():
         9: lambda e: e.add_request([2, 4, 6, 8], SamplingParams(max_tokens=5)),
     }
     plain = make()
-    want = _script(plain, events, 24)
-    ahead = make()
-    ahead.hold_ahead = lambda: False
-    got = _script(ahead, events, 24)
-    assert got == want and len(want) == 3 and len(want[0]) == 5
-    ns = ahead.phases.snapshot()["ns"]
+    want = _script(plain, events, 26)
+    assert len(want) == 3 and len(want[0]) == 5
+    eng = make()
+    eng.hold_ahead = {"never_holds": lambda: False,
+                      "always_holds": lambda: True}[owner]
+    assert _script(eng, events, 26) == want
+    ns = eng.phases.snapshot()["ns"]
+    stats = eng.decode_stats()
+    if owner == "always_holds":
+        assert ns["ahead"] == 0 and stats == plain.decode_stats()
+        assert stats["steps_queued"] == 0
+        return
     assert ns["ahead"] > 0
-    steps = plain.decode_stats()["steps"]
-    assert ahead.decode_stats()["steps"] >= steps
-    held = make()
-    held.hold_ahead = lambda: True          # someone always waits
-    assert _script(held, events, 24) == want
-    assert held.phases.snapshot()["ns"]["ahead"] == 0
-    assert held.decode_stats() == plain.decode_stats()
+    # a step that was out when its last row went is dropped unread
+    assert stats["steps"] == plain.decode_stats()["steps"]
+    assert 0 < stats["steps_queued"] < stats["steps"]
+    assert stats["pages_read"] == plain.decode_stats()["pages_read"]
+
+
+def test_a_reply_that_ends_by_length_has_no_step_queued_behind_it():
+    """The host counts a reply's tokens, so the call that will retire it
+    queues no step behind the one it reads (and sends none ahead after
+    it): the caller who comes back finds at most one running step in front
+    of its prefill."""
+    eng = _engine(None, max_batch=2, max_len=64, page_size=16, seed=0)
+    eng.hold_ahead = lambda: False
+    eng.add_request([1, 2, 3, 4], SamplingParams(max_tokens=8))
+    out = []                    # per call: (steps queued so far, a step out)
+    while eng.has_unfinished():
+        done = eng.step()
+        out.append((eng.decode_stats()["steps_queued"],
+                    eng._ahead is not None, bool(done)))
+    # first token + 7 decode steps: the first call dispatches and sends one
+    # ahead, five calls queue, the last reads with nothing behind it
+    assert [o[1] for o in out] == [True] * 6 + [False]
+    assert [o[2] for o in out] == [False] * 6 + [True]
+    assert out[-1][0] == 5 and eng.decode_stats()["steps"] == 7
+
+
+def test_an_eos_rows_dead_step_writes_its_own_page_alone(captured_recorder):
+    """A reply that ends by eos is seen one step late: the step queued
+    behind the one that sampled the eos still holds its row.  That dead
+    step writes the row's own page and no other, its token is dropped, and
+    the `decode` spans count the rows the lockstep order counts."""
+    make = lambda: _engine(None, max_batch=2, max_len=64, page_size=16,
+                           seed=0)
+    first = make().generate([[5, 6, 7]], SamplingParams(max_tokens=9))[0]
+    params = [SamplingParams(max_tokens=9, eos_id=first[4]),
+              SamplingParams(max_tokens=12)]
+    pools, batches, outs, own = [], [], [], []
+    for hold in (None, lambda: False):
+        with captured_recorder() as rec:
+            eng = make()
+            eng.hold_ahead = hold
+            for prompt, p in zip(([5, 6, 7], [1, 2, 3, 4]), params):
+                eng.add_request(prompt, p)
+            done, dead = {}, 0
+            while eng.has_unfinished():
+                done.update((r.req_id, list(r.out)) for r in eng.step())
+                if not own:
+                    own = list(eng._requests[0].pages)
+                flight = eng._ahead
+                dead += flight is not None and any(
+                    eng._slots.get(s) is not r
+                    for s, r in flight.batch.items())
+            batches.append([r["args"]["batch"] for r in rec.rows()
+                            if r["name"] == "decode"])
+        assert (dead > 0) == (hold is not None)
+        outs.append(done)
+        pools.append((np.asarray(eng._pk), np.asarray(eng._pv)))
+    assert outs[0] == outs[1] and outs[0][0] == first[:5]
+    assert batches[0] == batches[1] and min(batches[0]) == 1
+    others = [p for p in range(1, pools[0][0].shape[1]) if p not in own]
+    for plain, queued in zip(*pools):
+        np.testing.assert_array_equal(plain[:, others], queued[:, others])
+    # ... and it did write there: the eos token's keys, one row past the
+    # last the lockstep order wrote
+    assert any((a[:, own] != b[:, own]).any() for a, b in zip(*pools))
 
 
 def test_a_tick_admits_one_slots_worth_of_prompt():

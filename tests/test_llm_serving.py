@@ -198,6 +198,36 @@ def test_a_caller_waiting_for_the_lock_holds_the_next_step_back():
     asyncio.run(main())
 
 
+def test_while_somebody_waits_for_the_lock_no_second_step_is_queued():
+    """A reply decoded with nobody at the lock runs with one step running
+    and one queued; the same reply decoded while a caller stands at the
+    lock all the time has no step queued behind another and none sent
+    ahead, and the same tokens."""
+
+    async def main():
+        er = EngineReplica("tiny", max_batch=2, max_len=64, page_size=8,
+                           max_tokens=12)
+        alone = await er.generate([1, 2, 3])
+        free = await er.debug_stats()
+        assert free["decode"]["steps_queued"] >= 6
+
+        # What `hold_ahead` reads (the test above: a caller at the lock is
+        # counted): one who stands there for as long as the reply takes.
+        er._lock.waiting += 1
+        try:
+            held_reply = await er.generate([1, 2, 3])
+        finally:
+            er._lock.waiting -= 1
+        held = await er.debug_stats()
+        assert held_reply["tokens"] == alone["tokens"]
+        assert held["decode"]["steps"] == 2 * free["decode"]["steps"]
+        assert held["decode"]["steps_queued"] \
+            == free["decode"]["steps_queued"]
+        assert held["tick"]["ns"]["ahead"] == free["tick"]["ns"]["ahead"]
+
+    asyncio.run(main())
+
+
 def test_queued_deadline_expires_typed():
     """A request whose deadline passes while parked in the admission
     queue fails typed (DeadlineExceededError) without occupying a slot,

@@ -20,6 +20,7 @@ from collections import defaultdict
 import pytest
 
 from benchmark.tests.test_request_readers import *         # noqa: F401,F403
+from benchmark.tests.test_steps_queued import *            # noqa: F401,F403
 from ray_tpu._private import flight_recorder
 from ray_tpu.llm import EngineReplica
 from ray_tpu.llm.tick_phases import LEAVES, STOP, _SPAN
@@ -234,22 +235,24 @@ def test_a_requests_spans_share_its_id_and_prefill_names_its_tick(run):
         assert len(rid) == 8, name
         by_id[rid].setdefault(name, []).append((t0, t1, args))
     assert len(by_id) == len(run["records"]) == 11
-    fan_outs = {a["n"]: (t0, t1)
-                for t0, t1, _, _, a in _spans(run, "tick:fan_out")}
+    fan_outs = {a["n"]: t1
+                for _, t1, _, _, a in _spans(run, "tick:fan_out")}
     holders = _spans(run, "step:admit", "step:chunk")
     for rid, spans in by_id.items():
         (w0, w1, wa), = spans["request:lock_wait"]
         (a0, a1, aa), = spans["request:admit"]
         assert set(wa) == {"queued"} and set(aa) == {"queued", "decoding"}
         # enqueue follows the lock's wait at once; the span ends where the
-        # tick that sampled the first token fans it out
+        # first token is put on its stream: in the tick that sampled it, from
+        # the sampling on (before that tick's decode step is read:
+        # `test_a_first_token_leaves_before_its_ticks_decode_step_is_read`)
         assert w1 <= a0 <= w1 + 1_000_000
         last = max(spans["prefill"])
         n = last[2]["n"]
         assert all(p[2]["n"] <= n for p in spans["prefill"])
-        assert [h for h in holders if h[4]["n"] == n
-                and h[0] <= last[0] and last[1] <= h[1]]
-        assert fan_outs[n][0] <= a1 <= fan_outs[n][1]
+        holder, = [h for h in holders if h[4]["n"] == n
+                   and h[0] <= last[0] and last[1] <= h[1]]
+        assert holder[1] <= a1 <= fan_outs[n]
 
 
 def test_new_program_marks_each_buckets_first_prefill(run):
@@ -302,11 +305,12 @@ def _check_timing(rec, n_tokens, shipped=False):
     assert sum(t["rest"].values()) == t["total_ns"] - t["first_ns"]
     assert t["prompt_tokens"] == len(rec["prompt"])
     # a tick a token, but for the first two: the tick that admits a request
-    # also runs its first decode step, before the first token is fanned out
-    # (unless that step was sent ahead, for the others, before it came)
+    # also runs its first decode step (unless a step was out, for the
+    # others, before it came); the first token leaves before that step is
+    # read, so the step's `wait` lies in `rest`
     assert t["ticks"] in (n_tokens - 2, n_tokens - 1)
     assert t["rest"]["wait"] > 0
-    assert t["first"]["wait"] > 0 and t["first"]["prefill"] > 0
+    assert t["first"]["prefill"] > 0
     # a shipped prefill is installed (still the leaf `prefill`) and brings
     # its first token with it
     assert (t["first"]["sample_sync"] == 0) == shipped
@@ -463,6 +467,98 @@ def test_no_timing_and_no_span_for_a_reply_that_did_not_finish(ends):
         assert len(admits) == 7 and {r[4] for r in replies} < admits
 
 
+# ------------------------------------- a first token leaves when sampled ----
+
+class _Late:
+    """A decode step's result whose read-back takes 30 ms, as a chip's
+    step would: `np.asarray` on it is the tick's `decode:wait`."""
+
+    def __init__(self, x):
+        self.x = x
+
+    def __array__(self, *args, **kwargs):
+        import numpy as np
+        time.sleep(0.03)
+        return np.asarray(self.x)
+
+
+@pytest.fixture(scope="module")
+def early():
+    """One reply, and a second sent when the first has three tokens, through
+    a replica whose decode steps take 30 ms to read back."""
+    rec = _Raw()
+    old = flight_recorder._recorder
+    flight_recorder._recorder = rec
+
+    async def main():
+        er = EngineReplica("tiny", max_batch=4, max_len=64, page_size=8,
+                           max_tokens=6)
+        step = er.engine._decode_jit
+
+        def late(*args):
+            *state, nxt = step(*args)
+            return (*state, _Late(nxt))
+        er.engine._decode_jit = late
+        records = []
+
+        async def one(prompt, then=None):
+            r = {"prompt": prompt, "tokens": [], "finish": None}
+            records.append(r)
+            after = None
+            async for item in er.stream_generate(prompt):
+                if isinstance(item, dict):
+                    r["finish"] = item
+                else:
+                    r["tokens"].append(item)
+                    if then and len(r["tokens"]) == 3:
+                        after = asyncio.ensure_future(one(then))
+            if after is not None:
+                await after
+        await one([4, 5, 6, 7], then=[9, 8, 7])
+        return records, await er.debug_stats()
+
+    try:
+        records, stats = asyncio.run(main())
+    finally:
+        flight_recorder._recorder = old
+    return {"records": records, "stats": stats,
+            "raw": [r for r in rec.raw if r[2] == "request"]}
+
+
+def test_a_first_token_leaves_before_its_ticks_decode_step_is_read(early):
+    """`request:admit` ends, and the first token is on its stream, while the
+    tick that sampled it still waits for its decode step: after that tick's
+    `sample_sync` and a step's read-back before its `decode:wait` ends."""
+    waits = {a["n"]: (t0, t1)
+             for t0, t1, _, _, a in _spans(early, "decode:wait")}
+    ticks = _spans(early, "tick")
+    samples = [t1 for _, t1, _, _, _ in _spans(early, "sample_sync")]
+    admits = _spans(early, "request:admit")
+    assert len(admits) == 2
+    for _, a1, _, _, _ in admits:
+        (t0, _, _, _, args), = [t for t in ticks if t[0] <= a1 <= t[1]]
+        sampled, = [s for s in samples if t0 <= s <= a1]
+        w0, w1 = waits[args["n"]]
+        assert w1 - w0 >= 25_000_000
+        assert sampled <= a1 < w1 - 20_000_000, (a1 - sampled, w1 - a1)
+
+
+def test_an_early_first_token_is_handed_over_once_and_timing_adds_up(early):
+    from ray_tpu.llm import LLMEngine, SamplingParams
+    from ray_tpu.models import PRESETS
+    ref = LLMEngine(PRESETS["tiny"], max_batch=4, max_len=64, page_size=8,
+                    seed=0)
+    for rec in early["records"]:
+        assert rec["tokens"] == ref.generate(
+            [rec["prompt"]], SamplingParams(max_tokens=6))[0]
+        t = _check_timing(rec, 6)
+        # its first token left before the admitting tick's step was read:
+        # nearly all of that step's 30 ms lies in the rest of the reply
+        assert t["first"]["wait"] < 10_000_000
+        assert t["rest"]["wait"] >= (t["ticks"] + 1) * 25_000_000
+    assert early["stats"]["decode"]["steps_queued"] > 0
+
+
 # ------------------------------------------- what the readers depend on ----
 
 def test_old_spans_keep_names_arguments_and_extents(run):
@@ -474,7 +570,7 @@ def test_old_spans_keep_names_arguments_and_extents(run):
         assert {"tokens", "cached_tokens", "active"} <= set(r["args"])
         assert len(bytes(r["task_id"])) == 8
     assert all(set(r["args"]) == {"batch"} for r in rows["sample_sync"])
-    assert all(set(r["args"]) == {"batch", "n", "pages", "synced"}
+    assert all(set(r["args"]) == {"batch", "n", "pages", "synced", "queued"}
                for r in rows["decode"])
     # `synced` = the slot rows the host wrote into the step's resident state
     # before it: some steps carry an admission or a retirement, most none
