@@ -1,9 +1,13 @@
-"""Continuous-batching LLM generation engine, TPU-first.
+"""Continuous-batching LLM generation engine, TPU-first: the SCHEDULER.
 
 Reference surface: python/ray/llm/_internal — the reference wraps vLLM
 (engines/vllm/) for batch inference and serving.  On TPU we own the whole
 stack, so the engine is native JAX on the in-tree flagship transformer
-(models/transformer.py) and is built around XLA's compilation model:
+(models/transformer.py) and is built around XLA's compilation model.  This
+file holds requests, slots, pages, admission and the tick; what it compiles
+is llm/programs.py (every traced function, and the table of what a kind of
+layer caches, how it attends and what it counts: this file names no kind),
+and its host-side stores are llm/kv_cache.py:
 
   - ONE compiled decode step for the whole slot batch: static shapes,
     per-slot lengths/active masks as data, so admission/retirement of
@@ -15,19 +19,13 @@ stack, so the engine is native JAX on the in-tree flagship transformer
     dense (max_batch, max_len) cache could not.  Pages are reserved at
     admission (no mid-flight exhaustion, no preemption machinery).
   - Prefill is compiled per prompt-length *bucket* (pow-2 padding) —
-    a handful of compilations total, amortized across all requests.  The
-    attention form is the bucket's: on a TPU, with 128-wide heads, a
-    whole-prompt bucket of 1,024 rows or more and a suffix bucket (a
-    prefix-cache hit, a chunk) of 128 or more run the blocked kernel
-    (ops/prefill_attention.py: no S x S scores, nothing run past the
-    prompt's real length, the prefix read from its pages); smaller
-    buckets, other head widths and the CPU build the scores in XLA.
-    `prefill_stats()` says which form the prefills took.
+    a handful of compilations total, amortized across all requests.
+    `prefill_stats()` says which attention form the prefills took.
   - KV pool lives on device between steps (no host round-trips in the
     decode loop); only sampled token ids come back per step.  So does the
     step's own state (page tables, last tokens, lengths, active mask,
     temperatures, sampling key): the step advances it, and the host
-    writes to it only the slots it changed (`_decode_fn`).
+    writes to it only the slots it changed (`programs._decode_fn`).
   - Tensor parallelism via GSPMD: pass ``mesh=`` and the engine shards
     weights (heads/kv_heads/mlp over tp, Megatron layout) and the KV pool
     (kv_heads over tp) with NamedShardings; XLA inserts the collectives in
@@ -42,12 +40,9 @@ vllm's engine surface so reference users can map concepts 1:1.
 from __future__ import annotations
 
 import dataclasses
-import functools
-import hashlib
 import math
 import os
 import tempfile
-from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -57,19 +52,14 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
-from ..models import retention
-from ..models.transformer import (ATTEND, LATENT_FORMS, ROW_BLOCK, STATEFUL,
-                                  TransformerConfig, blocks_to_run,
-                                  decoder_block, embed_tokens, init_params,
-                                  latent_absorb, latent_expand, latent_form,
-                                  latent_unabsorb, lm_logits, over_rows,
-                                  param_logical_axes, rope_angles, row_blocks,
-                                  run_pattern, scan_blocks, state_bytes,
+from ..models.transformer import (STATEFUL, TransformerConfig, init_params,
+                                  param_logical_axes, row_blocks, state_bytes,
                                   state_chunk, zero_state)
-from ..ops.paged_attention import (decode_path, head_rows,
-                                   paged_decode_attention,
-                                   paged_latent_attention, pool_row, pool_rows,
-                                   pool_shape)
+from ..ops.paged_attention import (decode_path, head_rows, pool_row,
+                                   pool_rows)
+from . import programs
+from .kv_cache import (_default_kv_fetch, _KVDemoteStore, _KVWindow,
+                       _PrefixCache)
 from .tick_phases import TickPhases
 
 
@@ -142,897 +132,6 @@ class _Flight:
 
 
 # --------------------------------------------------------------------------
-# Pure compiled pieces
-# --------------------------------------------------------------------------
-
-def _prefill_path(cfg: TransformerConfig, rows: int, kv_sharding,
-                  page: Optional[int] = None, table_len: int = 0) -> str:
-    """The attention form a prefill of `rows` padded rows takes: "kernel"
-    (ops/prefill_attention.py) or "xla" (`_xla_prefill_attention`).
-    Decided from the platform and the shapes alone; under a `tp` mesh the
-    kernel runs per shard, so a shard's heads decide.  A latent layer's
-    whole prompt is attended expanded: every head its own keys, nope + rope
-    wide, over values of `value`; its pool holds compressed rows and no
-    head's keys, so over cached pages it has the XLA form alone."""
-    from ..ops.prefill_attention import prefill_path
-    tp = 1
-    if kv_sharding is not None and "tp" in kv_sharding.spec:
-        tp = kv_sharding.mesh.shape["tp"]
-    kv_heads, value = cfg.num_kv_heads, cfg.head_dim_
-    if cfg.latent:
-        kv_heads, value = cfg.num_heads, cfg.latent.value
-    if cfg.num_heads % tp or kv_heads % tp \
-            or (cfg.latent and page is not None):
-        return "xla"
-    return prefill_path((rows, cfg.num_heads // tp, cfg.head_dim_),
-                        kv_heads // tp, cfg.dtype, value=value, page=page,
-                        table_len=table_len)
-
-
-def _per_shard(kernel, kv_sharding, args: str):
-    """A Pallas attention kernel as it runs beside a pool placed as
-    `kv_sharding`.  The kernel is a custom call the GSPMD partitioner cannot
-    split, so on a mesh it runs per shard (training's flash kernel does the
-    same, models/transformer.py:_flash_attention): KV heads and their query
-    groups over `tp`, everything else whole on every device.  `args` names
-    the kernel's positional arguments: "h" one split by heads, "p" a pool as
-    it lies, "." one every device holds whole."""
-    if kv_sharding is None:
-        return kernel
-    from jax.sharding import PartitionSpec as P
-    spec = kv_sharding.spec
-    by = {"h": P(None, "tp") if "tp" in spec else P(), "p": spec, ".": P()}
-    return jax.shard_map(kernel, mesh=kv_sharding.mesh,
-                         in_specs=tuple(by[a] for a in args),
-                         out_specs=by["h"], check_vma=False)
-
-
-def _xla_prefill_attention(q, k, v, mask, cfg: TransformerConfig):
-    """A prefill's attention with the scores built: q (1, Sb, H, D) over
-    k, v (1, T, KV, D), key t open to query s where mask[s, t]."""
-    groups = cfg.num_heads // cfg.num_kv_heads
-    kr = jnp.repeat(k, groups, axis=2)
-    vr = jnp.repeat(v, groups, axis=2)
-    scores = jnp.einsum("bshd,bthd->bhst", q, kr) / jnp.sqrt(
-        jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
-    scores = jnp.where(mask[None, None], scores, -1e30)
-    p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhst,bthd->bshd", p, vr)
-
-
-def _prefill_attend(cfg: TransformerConfig, rows: int, length, kv_sharding,
-                    cached=None, blocks=None, row_block: int = ROW_BLOCK):
-    """The one place a prefill's attention form is chosen (`_prefill_path`).
-    For `rows` padded rows of which `length` are real and, in the suffix
-    form, `cached` = (pool_k, pool_v, pages, prefix_len, page) — the slot's
-    page row, whose first `prefix_len` tokens precede row 0 — returns
-    (attend, per_layer) for `scan_blocks`: `attend(q, k, v, *at)` gives
-    (o, the layer's new cache rows (k[0], v[0])).  `blocks`, `row_block`
-    (`over_rows`'s): the suffix form's built scores are row-wise in their
-    QUERIES, so they are built for the query blocks that hold a real row, a
-    block at a time against all keys, and o is zeros in the others.
-    A latent pattern's pool_v is None and its attend is `run_pattern`'s for
-    an `L` layer (`_latent_prefill_attend`)."""
-    if cfg.latent:
-        return _latent_prefill_attend(cfg, rows, length, cached, blocks,
-                                      row_block)
-    pool = per_layer = ()
-    if cached is None:
-        path = _prefill_path(cfg, rows, kv_sharding)
-    else:
-        pool_k, pool_v, pages, prefix_len, page = cached
-        T = pages.shape[0] * page
-        path = _prefill_path(cfg, rows, kv_sharding, page, pages.shape[0])
-    if path == "kernel":
-        # Blocked, no S x S scores, nothing run past `length`.  The whole
-        # pool goes in as it lies; the kernel copies the pages below
-        # `prefix_len` of layer `li` and no other.
-        from ..ops.prefill_attention import prefill_attention
-        kernel = _per_shard(prefill_attention, kv_sharding,
-                            "hhh.pp..." if cached else "hhh.")
-        if cached:
-            pool = (pool_k, pool_v, pages, prefix_len)
-            per_layer = (jnp.arange(pool_k.shape[0], dtype=jnp.int32),)
-
-        def scores(q, k, v, *li):
-            return kernel(q[0], k[0], v[0], length, *pool, *li)[None]
-    elif cached is None:
-        def scores(q, k, v):
-            mask = jnp.tril(jnp.ones((rows, rows), bool))
-            return _xla_prefill_attention(q, k, v, mask, cfg)
-    else:
-        mask = _suffix_mask(rows, T, prefix_len)
-        per_layer = (pool_k, pool_v)
-        heads = cfg.cache_row
-
-        def scores(q, k, v, pk, pv):        # pk, pv: (N, page, *row)
-            ck = head_rows(pk[pages], *heads).reshape(T, *heads)
-            cv = head_rows(pv[pages], *heads).reshape(T, *heads)
-            keys = jnp.concatenate([ck[None], k], axis=1)
-            values = jnp.concatenate([cv[None], v], axis=1)
-            return over_rows(
-                lambda q, mask: (_xla_prefill_attention(
-                    q, keys, values, mask, cfg),),
-                [(q, 1), (mask, 0)], (q,), blocks, row_block)[0]
-
-    def attend(q, k, v, *at):
-        return scores(q, k, v, *at), (k[0], v[0])   # drop the B=1 dim
-    return attend, per_layer
-
-
-def _suffix_mask(rows: int, T: int, prefix_len):
-    """Key t (over [cached T | suffix rows]) is open to suffix query s iff
-    it is a REAL cached prefix position or a suffix position <= s."""
-    tpos = jnp.arange(T + rows)
-    qpos = jnp.arange(rows)
-    return (tpos[None, :] < prefix_len) | (
-        (tpos[None, :] >= T) & (tpos[None, :] - T <= qpos[:, None]))
-
-
-def _latent_prefill_attend(cfg: TransformerConfig, rows: int, length, cached,
-                           blocks, row_block: int):
-    """`_prefill_attend` for a pattern of latent layers: the key rows are
-    the slot's cached rows as they lie in its pages (none: a whole prompt)
-    and then the prefill's own, and `latent_form` says from the cached rows
-    which of `LATENT_FORMS` attends them (over gathered rows the absorbed
-    one alone).  A whole prompt on path "kernel" up-projects its rows once
-    and goes through the blocked kernel, which runs no block past `length`
-    or above the diagonal and builds no scores array; everything else
-    builds its scores a block of query rows at a time.  attend(q, row, w,
-    *at) -> (o, (the layer's new cache rows (Sb, 1, C), None: no second
-    pool))."""
-    if cached is None:
-        if _prefill_path(cfg, rows, None) == "kernel":
-            from ..ops.prefill_attention import prefill_attention
-
-            def attend(q, row, w):
-                k, v = latent_expand(w, row[:, :, 0], cfg)
-                return prefill_attention(q[0], k[0], v[0], length,
-                                         scale=cfg.latent.scale)[None], \
-                    (row[0], None)
-            return attend, ()
-        T, per_layer = 0, ()
-        mask = jnp.tril(jnp.ones((rows, rows), bool))
-    else:
-        pool, _, pages, prefix_len, page = cached
-        T = pages.shape[0] * page
-        per_layer = (jnp.arange(pool.shape[0], dtype=jnp.int32),)
-        mask = _suffix_mask(rows, T, prefix_len)
-    build = LATENT_FORMS[latent_form(T)]
-    heads = cfg.cache_row
-
-    def attend(q, row, w, *li):
-        keys = row[:, :, 0]
-        if li:
-            # ONE gather of the slot's pages out of the whole pool (a layer
-            # sliced out first is a copy of it: 0.25 GB a layer).
-            cached_rows = pool[jnp.full_like(pages, li[0]), pages]
-            keys = jnp.concatenate(
-                [head_rows(cached_rows, *heads).reshape(1, T, heads[1]),
-                 keys], axis=1)
-        form = build(w, keys, cfg)
-        o = jax.ShapeDtypeStruct((*q.shape[:3], cfg.latent.value), q.dtype)
-        ins, block = [(q, 1), (mask, 0)], lambda q, mask: (form(q, mask),)
-        if not li and blocks is not None:
-            # A whole prompt's block of query rows sees no key past its own
-            # last row: one branch for every two blocks of keys, each built
-            # over the keys up to there (half the scores of a full bucket).
-            step = 2 * row_block
-            upto = [functools.partial(form, upto=min(n, rows))
-                    for n in range(step, rows + step, step)]
-            ins.append((jnp.arange(rows), 0))
-            block = lambda q, mask, at: (jax.lax.switch(
-                at[-1] // step, upto, q, mask),)
-        return over_rows(block, ins, (o,), blocks, row_block)[0], \
-            (row[0], None)
-    return attend, per_layer
-
-
-def _prefill_fn(params, tokens, length, cfg: TransformerConfig,
-                kv_sharding=None, row_block: int = ROW_BLOCK):
-    """tokens (1, Sb) padded prompt → (last_logits (V,), k, v (L, Sb, KV, D)).
-
-    Cache rows at positions ≥ length are padding's, or zeros where the
-    bucket is run by row blocks (`decoder_block`: those past the last block
-    that holds a real row); decode masks them out via per-slot lengths, and
-    the last-real-token logits only attend backwards (causal), so padding
-    never leaks into results.  `row_block`: the tests'."""
-    S = tokens.shape[1]
-    x = embed_tokens(params, tokens, cfg)
-    cos, sin = rope_angles(jnp.arange(0, S, dtype=jnp.float32), cfg)
-    attend, per_layer = _prefill_attend(cfg, S, length, kv_sharding)
-    x, (ks, vs) = scan_blocks(params["layers"], x, cos, sin, attend, cfg,
-                              per_layer, length, row_block)
-    return lm_logits(params, x[0, length - 1], cfg), ks, vs
-
-
-def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
-                      length, ckpt, row, cfg: TransformerConfig, page: int,
-                      every: int, row_block: int = ROW_BLOCK, keep: int = 0):
-    """A prefill of a pattern with recurrent layers: ONE form for a whole
-    prompt and for a suffix, since both run the recurrence from a given
-    state.  The rows `tokens` (1, Sb), of which `length` are real, follow
-    `prefix_len` tokens whose keys and values lie in `pages` (as
-    `_suffix_prefill_fn` has it) and whose recurrent state is row `row` of
-    the checkpoint pool `ckpt` (row 0: the state of having read nothing,
-    with prefix_len 0).  Returns (last-token logits, the attention layers'
-    ks, vs (nA, Sb, KV, D), the state after `length` rows, the state after
-    every `every` rows (the stateful mixers' `every`), the experts every row
-    chose (nE, Sb, K)).  Where the bucket is run by row blocks (`run_pattern`
-    says when) what lies past the last block that holds a real row is
-    zeros, as `_prefill_fn` has it: ks, vs, the checkpoints at boundaries
-    past the prompt (`_install_state` gives those to the scratch row), the
-    experts chosen.  `row_block`: the tests'.  `pages` None: a whole
-    prompt that attends nothing cached (a latent pattern's, whose attention
-    form follows from that: `_latent_prefill_attend`), or a pattern no
-    layer of which attends (no pool: ks and vs are None, and what precedes
-    the rows is in the state alone).  `keep`: `run_pattern`'s."""
-    Sb = tokens.shape[1]
-    x = embed_tokens(params, tokens, cfg)
-    cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
-    cached = None if pages is None else (pool_k, pool_v, pages, prefix_len,
-                                         page)
-    attend, per_layer = None, ()
-    if set(cfg.kinds) & set(ATTEND):
-        attend, per_layer = _prefill_attend(
-            cfg, Sb, length, None, cached,
-            blocks_to_run(length, Sb, row_block, every), row_block)
-    rec = [{k: c[k][row][None] for k in c} for c in ckpt]
-    x, kv, rec, kept, _, chosen = run_pattern(
-        params["layers"], x, cos, sin, attend, cfg, rec, per_layer,
-        length=length, every=every, row_block=row_block, keep=keep)
-    ks, vs = kv or (None, None)
-    return (lm_logits(params, x[0, length - 1], cfg), ks, vs, rec, kept,
-            chosen)
-
-
-def _install_state_fn(rec, ckpt, slot, end, kept, rows):
-    """Write a prefill's recurrent state into slot `slot` of the resident
-    per-slot state `rec`, and the checkpoints it passed into rows `rows`
-    (n,) of the pool `ckpt`; a checkpoint nobody keeps goes to row 1, the
-    scratch row."""
-    rec = [{k: r[k].at[slot].set(e[k][0]) for k in r}
-           for r, e in zip(rec, end)]
-    ckpt = [{k: c[k].at[rows].set(kp[k][0]) for k in c}
-            for c, kp in zip(ckpt, kept)]
-    return rec, ckpt
-
-
-def _install_fn(pool_k, pool_v, ks, vs, pages, page: int, kv_sharding):
-    """Write a prefill's (L, Sb, KV, D) kv into the slot's reserved pages,
-    whole pages of rows as the pool holds them (`pool_rows`).
-
-    pages: (P,) int32 physical page ids.  Entries past the slot's reserved
-    count are 0 — the shared scratch page, whose contents are garbage by
-    contract: every read of it is masked (valid = t <= length always stays
-    within the reserved pages) and the allocator never hands page 0 out."""
-    L, Sb, KV, D = ks.shape
-    P = pages.shape[0]
-    pad = P * page - Sb
-    # (Each step over the pair of pools, of which a latent pattern's second
-    # is None: an empty tree.)
-    pools, new = (pool_k, pool_v), (ks, vs)
-    if pad > 0:
-        new = jax.tree.map(
-            lambda r: jnp.pad(r, ((0, 0), (0, pad), (0, 0), (0, 0))), new)
-    new = jax.tree.map(
-        lambda r: pool_rows(r.reshape(L, P, page, KV, D), KV, D), new)
-    pools = jax.tree.map(lambda pool, r: pool.at[:, pages].set(r), pools, new)
-    if kv_sharding is not None:
-        pools = jax.lax.with_sharding_constraint(pools, kv_sharding)
-    return pools
-
-
-def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
-                      active, cfg: TransformerConfig, page: int, kv_sharding,
-                      rec=()):
-    """The model half of a decode step: every slot's last token through the
-    layers against the paged pool -> (pool_k', pool_v', logits (B, V) f32),
-    and for a pattern three more: the recurrent layers' per-slot state `rec`
-    advanced for the active slots, the routed layers' counts (n, 2) and
-    their chosen experts (n, B, 1, K).
-
-    The pool is carried through the layer loop whole and written where the
-    new token lands; attention (ops/paged_attention.py) reads the pages a
-    slot holds.  Nothing in the step is sized by the pool or by
-    max_batch x max_len but the donated pool itself."""
-    # An inactive slot is one token on the scratch page: it costs one page
-    # and what it computes is dropped.
-    tables = jnp.where(active[:, None], tables, 0)
-    lengths = jnp.where(active, lengths, 0)
-    x = embed_tokens(params, last_tokens, cfg)[:, None]           # (B,1,E)
-    # Per-slot RoPE at each slot's own position.
-    cos, sin = rope_angles(lengths, cfg)                          # (B, D/2)
-    cos, sin = cos[:, None], sin[:, None]                         # (B,1,D/2)
-    # Physical write position of the incoming token for every slot.
-    write_page = jnp.take_along_axis(
-        tables, (lengths // page)[:, None], axis=1)[:, 0]         # (B,)
-    write_off = lengths % page
-    paged = _per_shard(paged_decode_attention, kv_sharding, "hpp...")
-    heads = cfg.cache_row
-
-    def written(pool, li, new):     # new (B, 1, KV, D): one row a slot
-        return pool.at[li, write_page, write_off].set(
-            pool_rows(new[:, 0], *heads))
-
-    if cfg.pattern:
-        pools = [pool_k, pool_v]        # written layer by layer, in place
-
-        def attend(q, k, v, li):
-            pools[0] = written(pools[0], li, k)
-            pools[1] = written(pools[1], li, v)
-            return paged(q[:, 0], *pools, tables, lengths, li)[:, None], None
-
-        def latent_attend(q, row, w, li):
-            # One query row a slot over rows that lie in the pool: the
-            # absorbed form (`latent_form(cached)`), the rows read where
-            # they lie (ops/paged_attention.py: `paged_latent_attention`).
-            pools[0] = written(pools[0], li, row)
-            o = paged_latent_attention(
-                latent_absorb(w, q[:, 0], cfg), pools[0], tables, lengths,
-                li, scale=cfg.latent.scale, value_lanes=cfg.latent.rank)
-            return latent_unabsorb(w, o[:, None], cfg), None
-        if cfg.latent:
-            attend = latent_attend
-        # (No pool: no layer attends, and `attend` is never called.)
-        layer = () if pool_k is None else (
-            jnp.arange(pool_k.shape[0], dtype=jnp.int32),)
-        x, _, rec, _, counts, chosen = run_pattern(
-            params["layers"], x, cos, sin, attend, cfg, rec, layer,
-            live=active)
-        return (*pools, lm_logits(params, x[:, 0], cfg), rec, counts, chosen)
-
-    def body(carry, layer):
-        x, pk, pv = carry               # pk/pv: the whole pool, in place
-        lp, li = layer
-
-        def attend(q, k, v):
-            wk, wv = written(pk, li, k), written(pv, li, v)
-            o = paged(q[:, 0], wk, wv, tables, lengths, li)       # (B,H,D)
-            return o[:, None], (wk, wv)
-        x, (pk, pv) = decoder_block(lp, x, cos, sin, attend, cfg)
-        return (x, pk, pv), None
-
-    (x, pool_k, pool_v), _ = jax.lax.scan(
-        body, (x, pool_k, pool_v),
-        (params["layers"], jnp.arange(pool_k.shape[0], dtype=jnp.int32)))
-    if kv_sharding is not None:
-        pool_k = jax.lax.with_sharding_constraint(pool_k, kv_sharding)
-        pool_v = jax.lax.with_sharding_constraint(pool_v, kv_sharding)
-    return pool_k, pool_v, lm_logits(params, x[:, 0], cfg)
-
-
-def _sample_fn(logits, active, temps, key):
-    """Every slot's next token from its logits (B, V): greedy where its
-    temperature is 0, else drawn with its own split of `key`; 0 for an
-    inactive slot."""
-    greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-    keys = jax.random.split(key, logits.shape[0])
-    sampled = jax.vmap(
-        lambda key, lg, t: jax.random.categorical(
-            key, lg / jnp.maximum(t, 1e-6)))(keys, logits, temps)
-    nxt = jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
-    return jnp.where(active, nxt, 0)
-
-
-# The decode step's resident state is `slots`, one int32 row a slot, and the
-# sampling key.  A row is the slot's page-table row (P physical page ids)
-# and then these columns (the temperature as its float32 bits); the packed
-# update the host sends has one column more, `take`: the device is to
-# accept the row.
-_COL_LAST, _COL_LENGTH, _COL_ACTIVE, _COL_TEMP = range(4)
-_COLS = 4
-
-
-def _pack_rows(tables, last, lengths, active, temps, take) -> np.ndarray:
-    """Host side: every slot's row as the host's mirrors have it, (B, P + 5)
-    int32, with `take` marking the slots the device is to accept."""
-    P = tables.shape[1]
-    rows = np.empty((tables.shape[0], P + _COLS + 1), np.int32)
-    rows[:, :P] = tables
-    rows[:, P + _COL_LAST] = last
-    rows[:, P + _COL_LENGTH] = lengths
-    rows[:, P + _COL_ACTIVE] = active
-    rows[:, P + _COL_TEMP] = np.asarray(temps, np.float32).view(np.int32)
-    rows[:, -1] = take
-    return rows
-
-
-def _accept_rows(slots, update):
-    """Device side: the rows a packed update marks replace the state's; an
-    update that marks none leaves it as it is."""
-    return jnp.where(update[:, -1:] != 0, update[:, :-1], slots)
-
-
-def _decode_fn(params, pool_k, pool_v, state, update, cfg: TransformerConfig,
-               page: int, kv_sharding):
-    """One decode step for ALL slots against the paged pool, on state that
-    stays on the device.
-
-    pool_k/pool_v (L, N, page, *row: `pool_shape`).  `state` = {"slots":
-    (B, P + 4) int32, "rng": the sampling key} is RESIDENT: the step takes
-    it, advances it and returns it, donated like the two pools, so between
-    two steps the
-    host uploads nothing and runs no program.  A slot's row holds its page
-    table (page 0 = scratch for inactive slots), its last token, the tokens
-    it has in cache (the new token is written at that index), whether it is
-    active, and its temperature (0 = greedy).  The step first accepts
-    `update` (`_pack_rows`), the one packed upload through which the host
-    writes the slots IT changed (a reservation, an admission, a
-    retirement); then it splits the key as the host would (`rng, key =
-    split(rng)`: the same two keys), samples, and advances what it owns:
-    last token <- next token and length + 1 for the active slots.  On a
-    mesh the state is replicated.
-    A pattern with recurrent layers keeps their state there too, under
-    "rec": one tree for each stateful layer, a row a slot, advanced
-    by the step for the active slots; the host writes a slot's row when it
-    installs a prefill (`_install_state_fn`) and at no other time.
-    Returns (pool_k', pool_v', state', out): `out` the next tokens (B,),
-    and after them a pattern's routed counts, flattened (held experts
-    touched and rows computed, for each `E` layer): one read-back."""
-    slots = _accept_rows(state["slots"], update)
-    P = slots.shape[1] - _COLS
-    tables, last, lengths = (slots[:, :P], slots[:, P + _COL_LAST],
-                             slots[:, P + _COL_LENGTH])
-    active = slots[:, P + _COL_ACTIVE] != 0
-    temps = jax.lax.bitcast_convert_type(slots[:, P + _COL_TEMP], jnp.float32)
-    rng, key = jax.random.split(state["rng"])
-    pool_k, pool_v, logits, *pattern = _decode_logits_fn(
-        params, pool_k, pool_v, tables, last, lengths, active, cfg, page,
-        kv_sharding, state.get("rec", ()))
-    nxt = _sample_fn(logits, active, temps, key)
-    slots = slots.at[:, P + _COL_LAST].set(jnp.where(active, nxt, last))
-    slots = slots.at[:, P + _COL_LENGTH].add(active)
-    state = {"slots": slots, "rng": rng}
-    if pattern:
-        state["rec"], counts, _ = pattern
-        if counts is not None:
-            nxt = jnp.concatenate([nxt, counts.reshape(-1)])
-    if kv_sharding is not None:
-        state = jax.lax.with_sharding_constraint(
-            state, NamedSharding(kv_sharding.mesh, PartitionSpec()))
-    return pool_k, pool_v, state, nxt
-
-
-def _suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
-                       length, cfg: TransformerConfig, page: int,
-                       kv_sharding=None, row_block: int = ROW_BLOCK):
-    """Suffix half of a prefix-cache hit: run the transformer over ONLY
-    tokens[prefix_len:] while attending to the cached KV of
-    tokens[:prefix_len] already resident in the pool's shared pages.
-
-    pages: (P,) a full page-table row — shared prefix pages first, then
-    the freshly reserved pages whose contents are garbage (masked, like
-    decode's scratch reads; prefix_len is page-aligned by construction).
-    tokens: (1, Sb) the PADDED suffix; length = real suffix length.
-    Returns (last-token logits, suffix ks, vs (L, Sb, KV, D)) — the same
-    contract as _prefill_fn, so the install path is shared."""
-    Sb = tokens.shape[1]
-    x = embed_tokens(params, tokens, cfg)
-    # RoPE at absolute positions prefix_len + i.
-    cos, sin = rope_angles(prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
-    attend, per_layer = _prefill_attend(
-        cfg, Sb, length, kv_sharding, (pool_k, pool_v, pages, prefix_len, page))
-    x, (ks, vs) = scan_blocks(params["layers"], x, cos, sin, attend, cfg,
-                              per_layer, length, row_block)
-    return lm_logits(params, x[0, length - 1], cfg), ks, vs
-
-
-class _PrefixCache:
-    """Page-granular KV prefix reuse (vLLM's PagedAttention block
-    sharing, Kwon et al. SOSP'23, mapped onto the paged pool): every
-    FULL prompt page is keyed by the rolling hash of all tokens up to
-    its end, so requests sharing a prompt prefix share the physical
-    pages — skipping both the page allocation and the prefill compute
-    for the shared span.
-
-    Entries are LRU-ordered; eviction is driven by pool pressure (the
-    reserve path evicts until the new request fits or the cache is dry).
-    Pages are ref-counted by the engine: cache membership holds one ref
-    per entry, each active request one — a page returns to the free
-    list only when the last holder lets go, so evicting an entry out
-    from under an in-flight request is safe.
-
-    STATE CHECKPOINTS (`every` > 0: a model with recurrent layers).  Cached
-    keys and values are then half of what a prefix left behind: the other
-    half is the recurrent state after it, which is kept only at every
-    `every`-th token (a row of the engine's checkpoint pool, keyed like the
-    page that ends there).  An entry can be used from the last such
-    boundary at or before it: `lookup` cuts the hit back to there and the
-    prefill recomputes the tokens between (`recomputed` counts them).  An
-    entry holds a reference to every checkpoint row at or before its own
-    boundary, as it does to its pages, so evicting it frees pages and rows
-    together and a row outlives every entry that could use it.  The rows
-    are this cache's to hand out (`free_rows`): nothing else holds one.
-    A prefill keeps the LAST `keep` boundaries it passes (`boundaries`), a
-    number that follows from the rows there are and names no model: a
-    re-ask needs the last boundary inside the text it shares, and a row may
-    cost as much as thousands of tokens of keys and values.  Where no layer
-    attends there are no pages (`insert` without a page row): an entry then
-    holds rows only, and the keys, the boundaries and the eviction are as
-    they are."""
-
-    def __init__(self, page: int, tag: bytes = b"", every: int = 0,
-                 rows: Sequence[int] = ()):
-        self.page = page
-        self.every = every
-        self.free_rows: List[int] = list(rows)
-        self.n_rows = len(self.free_rows)
-        # boundary key -> checkpoint row, and back; row -> entries holding
-        # it; entry key -> the rows it holds
-        self._rows: Dict[bytes, int] = {}
-        self._row_key: Dict[int, bytes] = {}
-        self._row_refs: Dict[int, int] = {}
-        self._held: Dict[bytes, List[int]] = {}
-        self.recomputed = 0         # tokens recomputed behind a checkpoint
-        self.hit_tokens = 0         # prompt tokens of the requests that hit
-        self.rows_kept = 0
-        self.rows_evicted = 0
-        # Key namespace tag: sequence-parallel engines key their pages
-        # per SP layout (tag = b"sp<degree>") so pages cached under one
-        # shard→stripe mapping can never alias pages cached under
-        # another — the per-shard half of "prefix-cache keys become
-        # per-shard" (the other half is _Request.sp_stripes).
-        self.tag = tag
-        self._memo: Tuple[Any, List[bytes]] = (None, [])
-        # rolling-hash key -> page ids covering the whole prefix
-        self._entries: "OrderedDict[bytes, List[int]]" = OrderedDict()
-        self.hits = 0
-        self.misses = 0
-        self.hit_pages = 0          # pages whose prefill was skipped
-        self.evictions = 0
-
-    def _keys(self, prompt: Sequence[int], upto: int) -> List[bytes]:
-        """Rolling hash at every page boundary 1..upto.  One admission asks
-        three times (`lookup`, `boundaries`, `insert`) about one prompt:
-        the last prompt's keys are kept, by the list's identity."""
-        memo, keys = self._memo
-        if memo is prompt and len(keys) >= upto:
-            return keys[:upto]
-        full = max(upto, len(prompt) // self.page)
-        data = np.asarray(prompt[:full * self.page], np.int32).tobytes()
-        h = hashlib.blake2b(digest_size=16)
-        h.update(self.tag)
-        keys, step = [], 4 * self.page
-        for k in range(full):
-            h.update(data[k * step:(k + 1) * step])
-            keys.append(h.copy().digest())
-        self._memo = (prompt, keys)
-        return keys[:upto]
-
-    def lookup(self, prompt: Sequence[int]) -> Tuple[int, List[int], int]:
-        """Longest cached prefix usable by this prompt: (token count,
-        page ids, checkpoint row).  Capped at S-1 tokens — the last prompt
-        token's logits must be computed, so at least a one-token suffix
-        always runs through prefill.  With state checkpoints the hit is cut
-        back to the last boundary that kept one (row 0 with no tokens: a
-        miss); without, the row is 0 and means nothing."""
-        usable = (len(prompt) - 1) // self.page
-        if usable <= 0:
-            return 0, [], 0
-        keys = self._keys(prompt, usable)
-        for k in range(usable, 0, -1):
-            pages = self._entries.get(keys[k - 1])
-            if pages is None:
-                continue
-            row, found = 0, k
-            if self.every:
-                per = self.every // self.page
-                k -= k % per
-                while k and keys[k - 1] not in self._rows:
-                    k -= per
-                if not k:
-                    break               # cached pages, but no state to go on
-                row = self._rows[keys[k - 1]]
-                self.recomputed += (found - k) * self.page
-            self._entries.move_to_end(keys[found - 1])
-            self.hits += 1
-            self.hit_pages += k
-            self.hit_tokens += len(prompt)
-            return k * self.page, list(pages[:k]), row
-        self.misses += 1
-        return 0, [], 0
-
-    def boundaries(self, prompt: Sequence[int], after: int,
-                   keep: int = 0) -> List[int]:
-        """The checkpoint boundaries (token counts) of `prompt` past
-        `after` that its full pages cover, the last `keep` of them (0:
-        all), and of those the ones no row is kept for yet."""
-        if not self.every:
-            return []
-        full = len(prompt) // self.page * self.page
-        marks = range(after + self.every, full + 1, self.every)[-keep:]
-        if not marks:
-            return []
-        keys = self._keys(prompt, full // self.page)
-        return [b for b in marks if keys[b // self.page - 1] not in self._rows]
-
-    def hold_row(self, row: int, by: int = 1) -> None:
-        """A prefill that starts from `row` holds it (`by` 1) until it has
-        run (`by` -1); row 0, the state of nothing read, is nobody's."""
-        if row:
-            self._row_refs[row] += by
-            if not self._row_refs[row]:
-                self._drop_row(row)
-                self.rows_evicted += 1
-
-    def _drop_row(self, row: int) -> None:
-        del self._rows[self._row_key.pop(row)], self._row_refs[row]
-        self.free_rows.append(row)
-
-    def insert(self, prompt: Sequence[int], table_row, incref,
-               rows: Optional[Dict[int, int]] = None) -> None:
-        """Register every full prompt page of a freshly admitted request
-        (decode writes land strictly after them, so they are immutable);
-        `table_row` None: there are no pages, and an entry holds rows only.
-        `rows`: boundary (tokens) -> the checkpoint row (taken from
-        `free_rows`) this prefill wrote for it; each new entry takes a
-        reference to every row at or before its boundary, and a row no
-        entry took goes back."""
-        full = len(prompt) // self.page
-        if full <= 0:
-            for row in (rows or {}).values():
-                self.free_rows.append(row)
-            return
-        keys = self._keys(prompt, full)
-        for b, row in (rows or {}).items():
-            self._rows[keys[b // self.page - 1]] = row
-            self._row_key[row] = keys[b // self.page - 1]
-            self._row_refs[row] = 0
-            self.rows_kept += 1
-        per = self.every // self.page if self.every else 0
-        for k in range(1, full + 1):
-            key = keys[k - 1]
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                continue
-            pages = [] if table_row is None \
-                else [int(p) for p in table_row[:k]]
-            self._entries[key] = pages
-            for p in pages:
-                incref(p)
-            if per:
-                self._held[key] = held = [
-                    self._rows[keys[j - 1]] for j in range(per, k + 1, per)
-                    if keys[j - 1] in self._rows]
-                for r in held:
-                    self._row_refs[r] += 1
-        for row in (rows or {}).values():
-            if not self._row_refs[row]:
-                self._drop_row(row)
-                self.rows_kept -= 1
-
-    def evict_lru(self, decref, demote=None) -> bool:
-        """Drop the least-recently-used entry; True if one was dropped.
-        Pages still held by active requests stay allocated (ref > 0); a
-        checkpoint row whose last holder this entry was is free again.
-        `demote(key, pages)` — when given — runs BEFORE the refs drop,
-        so the hook can copy the page contents out of the pool while
-        they are still guaranteed unrecycled (after decref the pages
-        rejoin the free list and may be overwritten by any admission)."""
-        if not self._entries:
-            return False
-        key, pages = self._entries.popitem(last=False)
-        self.evictions += 1
-        if demote is not None:
-            demote(key, pages)
-        for p in pages:
-            decref(p)
-        for r in self._held.pop(key, ()):
-            self._row_refs[r] -= 1
-            if not self._row_refs[r]:
-                self._drop_row(r)
-                self.rows_evicted += 1
-        return True
-
-
-class _KVDemoteStore:
-    """Demoted prefix-cache pages: bounded host window + NVMe overflow.
-
-    LRU-evicted prefix-cache entries land here instead of being freed
-    outright: the evicted pages' contents move device -> host (a byte-
-    bounded LRU window) and overflow to NVMe part files under the spill
-    dir, in the external-KV part format ({"k", "v", "len"}).  A later
-    request sharing the prefix PROMOTES the entry back into the pool
-    (device_put + page re-alloc) instead of re-running prefill — the
-    same demote-then-restore policy shape as the object store's
-    arena -> NVMe spill tier, driven by the same pool-pressure signal.
-    Entries are caches, never truth: any demoted entry may be dropped
-    (e.g. on a disk write failure) at the cost of a re-prefill."""
-
-    def __init__(self, byte_limit: int, spill_dir: str):
-        self.byte_limit = max(0, int(byte_limit))
-        self.spill_dir = spill_dir
-        self._host: "OrderedDict[bytes, dict]" = OrderedDict()
-        self._disk: Dict[bytes, str] = {}
-        self._host_bytes = 0
-        self._seq = 0
-        self.demoted_pages = 0
-        self.promoted_pages = 0
-        self.disk_spills = 0
-
-    def __len__(self) -> int:
-        return len(self._host) + len(self._disk)
-
-    def contains(self, key: bytes) -> bool:
-        return key in self._host or key in self._disk
-
-    def put(self, key: bytes, k_np, v_np, npages: int) -> None:
-        if self.contains(key):
-            return
-        self._host[key] = {"k": k_np, "v": v_np, "len": int(npages)}
-        self._host_bytes += k_np.nbytes + v_np.nbytes
-        self.demoted_pages += int(npages)
-        while self._host_bytes > self.byte_limit and self._host:
-            okey, part = self._host.popitem(last=False)
-            self._host_bytes -= part["k"].nbytes + part["v"].nbytes
-            self._spill(okey, part)
-
-    def _spill(self, key: bytes, part: dict) -> None:
-        try:
-            os.makedirs(self.spill_dir, exist_ok=True)
-            self._seq += 1
-            path = os.path.join(
-                self.spill_dir,
-                "kvdemote-%d-%d.npz" % (os.getpid(), self._seq))
-            np.savez(path, k=part["k"], v=part["v"],
-                     len=np.int64(part["len"]))
-            self._disk[key] = path
-            self.disk_spills += 1
-        except OSError:
-            pass    # dropped: a demoted entry is a cache, never truth
-
-    def get(self, key: bytes) -> Optional[dict]:
-        """Pop an entry for promotion ({"k","v","len"}), or None."""
-        part = self._host.pop(key, None)
-        if part is not None:
-            self._host_bytes -= part["k"].nbytes + part["v"].nbytes
-            self.promoted_pages += part["len"]
-            return part
-        path = self._disk.pop(key, None)
-        if path is None:
-            return None
-        try:
-            with np.load(path) as z:
-                part = {"k": z["k"], "v": z["v"], "len": int(z["len"])}
-        except OSError:
-            return None
-        finally:
-            try:
-                os.unlink(path)
-            except OSError:
-                pass
-        self.promoted_pages += part["len"]
-        return part
-
-    def stats(self) -> Dict[str, Any]:
-        return {"demoted_pages": self.demoted_pages,
-                "promoted_pages": self.promoted_pages,
-                "demoted_entries": len(self),
-                "demoted_host_bytes": self._host_bytes,
-                "demoted_disk_entries": len(self._disk),
-                "demoted_disk_spills": self.disk_spills}
-
-
-class _KVWindow:
-    """Bounded host-side prefetch window over external KV parts.
-
-    The streamed-attention path never materializes a paged request's
-    context in the device pool; what it does need is the CURRENT part's
-    bytes on host.  This window holds at most `capacity` parts (LRU),
-    fetched through the engine's `kv_fetch` callback (the serving layer
-    wires it to an object-plane get — a swarm-plane bulk pull when the
-    part lives in a remote arena) and optionally warmed ahead of the
-    attention step via `kv_prefetch` (async; gather overlaps compute).
-    A window smaller than the part count degrades to re-fetching —
-    counted, never silent (`refetches`)."""
-
-    def __init__(self, capacity: int, fetch, prefetch=None):
-        self.capacity = max(1, int(capacity))
-        self._fetch = fetch
-        self._prefetch = prefetch
-        self._data: "OrderedDict[str, dict]" = OrderedDict()
-        self._futures: Dict[str, Any] = {}
-        # Recently-seen keys for refetch detection, LRU-BOUNDED: a
-        # prefill shard streams thousands of one-shot context-part keys
-        # that no request ever drop()s — an unbounded set would be a
-        # slow leak in exactly the always-on serving process.
-        self._seen: "OrderedDict[str, None]" = OrderedDict()
-        self._seen_cap = max(64, 16 * self.capacity)
-        self.fetches = 0
-        self.refetches = 0
-        self.bytes_fetched = 0
-        self.wait_s = 0.0
-
-    def _mark_seen(self, key: str) -> None:
-        self._seen[key] = None
-        self._seen.move_to_end(key)
-        while len(self._seen) > self._seen_cap:
-            self._seen.popitem(last=False)
-
-    def _validate(self, key: str, data) -> dict:
-        if not isinstance(data, dict) or "k" not in data or "v" not in data:
-            raise KVGatherError(
-                f"KV part {key!r} resolved to {type(data).__name__}, "
-                f"expected a {{'k','v','len'}} dict")
-        return data
-
-    def _admit(self, key: str, data: dict) -> dict:
-        self._data[key] = data
-        self._data.move_to_end(key)
-        while len(self._data) > self.capacity:
-            self._data.popitem(last=False)
-        return data
-
-    def put(self, key: str, data: dict) -> None:
-        """Seed a locally-produced part (chunked prefill keeps its own
-        freshly published stripes hot for the next chunk)."""
-        self._mark_seen(key)
-        self._admit(key, data)
-
-    def prefetch(self, items) -> None:
-        """Kick async fetches for [(key, handle)] not already resident."""
-        if self._prefetch is None:
-            return
-        for key, handle in items:
-            if key in self._data or key in self._futures:
-                continue
-            try:
-                self._futures[key] = self._prefetch(handle)
-            except Exception:      # prefetch is best-effort; get() retries
-                self._futures.pop(key, None)
-
-    def get(self, key: str, handle) -> dict:
-        import time as _time
-        data = self._data.get(key)
-        if data is not None:
-            self._data.move_to_end(key)
-            return data
-        t0 = _time.perf_counter()
-        fut = self._futures.pop(key, None)
-        try:
-            if fut is not None:
-                data = fut.result()
-            else:
-                data = self._fetch(handle)
-        except KVGatherError:
-            raise
-        except Exception as e:
-            raise KVGatherError(
-                f"gather of KV part {key!r} failed: "
-                f"{type(e).__name__}: {e}") from e
-        self.wait_s += _time.perf_counter() - t0
-        data = self._validate(key, data)
-        self.fetches += 1
-        if key in self._seen:
-            self.refetches += 1
-        self._mark_seen(key)
-        self.bytes_fetched += (getattr(data["k"], "nbytes", 0)
-                               + getattr(data["v"], "nbytes", 0))
-        return self._admit(key, data)
-
-    def drop(self, keys) -> None:
-        for k in keys:
-            self._data.pop(k, None)
-            self._futures.pop(k, None)
-            self._seen.pop(k, None)
-
-    def stats(self) -> Dict[str, Any]:
-        return {"fetches": self.fetches, "refetches": self.refetches,
-                "bytes": self.bytes_fetched, "wait_s": self.wait_s,
-                "resident": len(self._data), "capacity": self.capacity}
-
-
-def _default_kv_fetch(handle):
-    """Engine-standalone fetch: parts passed by value ARE their data."""
-    if isinstance(handle, dict):
-        return handle
-    raise KVGatherError(
-        f"remote KV handle {type(handle).__name__} needs a kv_fetch "
-        f"callback (the serving layer wires ray_tpu.get)")
-
-
-# --------------------------------------------------------------------------
 # Engine
 # --------------------------------------------------------------------------
 
@@ -1098,16 +197,14 @@ class LLMEngine:
         self.max_len = max_len
         self.page = max(8, min(page_size, max_len))
         self.pages_per_slot = math.ceil(max_len / self.page)
-        # The pool has rows for the layers that attend: all of the dense
-        # decoder's, the `*` or `L` layers of a pattern.  Where none does
-        # (a pattern of recurrent layers alone) there is NO pool: no array,
-        # no page to reserve or to wait for, and the whole cache is the
-        # state rows below.
-        L = sum(cfg.count(k) for k in ATTEND)
+        # How this configuration caches (programs.CACHES), looked up once.
+        # Where no layer attends there is NO pool: no array, no page to
+        # reserve or to wait for, and the whole cache is the state rows below.
+        self._cache_form = form = programs.cache_of(cfg)
         pages = kv_pages if kv_pages is not None \
             else max_batch * self.pages_per_slot
         # page 0 is scratch (inactive-slot writes land there); never handed out
-        self.n_pages = 1 + (pages if L else 0)
+        self.n_pages = 1 + (pages if form.pools else 0)
         kvh, d = cfg.cache_row
         # State checkpoints, every `_every` tokens (0: no recurrent layer).
         self._every = _CKPT_CHUNKS * state_chunk(cfg)
@@ -1121,11 +218,6 @@ class LLMEngine:
                 raise ValueError(
                     f"page_size {self.page} does not divide the state "
                     f"checkpoints' spacing of {self._every} tokens")
-            if cfg.latent and pool_row(*cfg.cache_row) != "latent":
-                raise ValueError(
-                    f"a cache row of {cfg.cache_row[1]} values is not a "
-                    "latent row: more than one 128-lane row and no whole "
-                    "number of them (ops/paged_attention.py: pool_row)")
 
         from . import sequence_parallel as _sp
         deg = sp_degree if sp_degree is not None \
@@ -1197,13 +289,10 @@ class LLMEngine:
         if param_shd is not None:
             self.params = jax.device_put(self.params, param_shd)
 
-        # Two pools, keys and values; a latent pattern has ONE, whose row is
-        # both, and None (an empty tree) where the others have the second.
-        shape = pool_shape(L, self.n_pages, self.page, kvh, d)
-        self._pk = jnp.zeros(shape, cfg.dtype, device=self._kv_shd) \
-            if L else None
-        self._pv = None if cfg.latent or not L else jnp.zeros(
-            shape, cfg.dtype, device=self._kv_shd)
+        # The pools, of which a form may have two, one (the other None: an
+        # empty tree) or none.
+        self._pk, self._pv = programs.make_pools(
+            cfg, self.n_pages, self.page, self._kv_shd)
         self._free_slots = list(range(max_batch))
         self._free_pages = list(range(1, self.n_pages))
         # page -> holder count (requests + cache entries); a page leaves
@@ -1251,9 +340,9 @@ class LLMEngine:
                 _demo_dir = os.path.join(
                     tempfile.gettempdir(),
                     "ray_tpu_kv_demote_%d" % os.getpid())
-            if _demo_on and not self._every and not cfg.latent:
+            if _demo_on and not self._every and form.pools == 2:
                 # (Demoted pages would leave their state checkpoints behind;
-                # the store keeps K/V pairs, and a latent page is one array.)
+                # the store keeps K/V pairs.)
                 self._demote = _KVDemoteStore(_demo_lim, _demo_dir)
         self._tables = np.zeros((max_batch, self.pages_per_slot), np.int32)
         self._slots: Dict[int, _Request] = {}
@@ -1287,8 +376,8 @@ class LLMEngine:
         self._state_shd = None if mesh is None else NamedSharding(
             mesh, PartitionSpec())
         idle = np.zeros(max_batch, bool)
-        none = _pack_rows(self._tables, self._last, self._lengths, idle,
-                          self._temps, idle)
+        none = programs._pack_rows(self._tables, self._last, self._lengths,
+                                   idle, self._temps, idle)
         self._dev = jax.device_put(
             {"slots": none[:, :-1], "rng": jax.random.key(seed + 1)},
             self._state_shd)
@@ -1296,11 +385,9 @@ class LLMEngine:
             # Per slot, the recurrent layers' state: resident with the rest.
             self._dev["rec"] = [zero_state(cfg, k, max_batch)
                                 for k in stateful]
-        # What the routed layers' decode steps touched, a row a layer:
-        # held experts that got a row, (token, expert) rows computed;
-        # cumulative, and the last step's.
-        self._routed = np.zeros((cfg.count("E"), 2), np.int64)
-        self._step_routed = np.zeros((cfg.count("E"), 2), np.int64)
+        # What the host counts for the kinds of layer the configuration has
+        # (programs.COUNTED): by name, the `<name>_stats()` below.
+        self._counts = programs.counters(cfg, pool=self._pk, keep=self._keep)
         # The update of a step before which no slot was touched: marks none.
         self._no_rows = jax.device_put(none, self._state_shd)
         self._prefill_jit = {}
@@ -1325,24 +412,6 @@ class LLMEngine:
                                "kv_blocks_run": 0, "kv_blocks_dense": 0,
                                "row_blocks_run": 0, "row_blocks_dense": 0}
         self._prefill_ran: Dict[str, Any] = {}
-        # A latent pattern: the cache rows its decode steps read (live
-        # tokens, all slots) and the key rows its prefills attended, with
-        # how many of them were up-projected to per-head keys and values
-        # (`latent_form`: all of an expanded prefill's, none of an absorbed
-        # one's); real rows, counted on the host.
-        self._latent = {"rows_read": 0, "step_rows_read": 0,
-                        "rows_attended": 0, "rows_expanded": 0,
-                        "prefills": {"expanded": 0, "absorbed": 0},
-                        "form": ""}
-        # A pattern of power retention layers: the sequences its decode
-        # steps moved the state of, its prefills by form ("attention": a
-        # whole prompt, every output from the rows' own keys; "chunked":
-        # from a checkpoint, the state's part beside them), and the
-        # checkpoint boundaries the admitted prompts passed and kept.
-        self._retention = {"rows_stepped": 0, "step_rows_stepped": 0,
-                           "prefills": {"attention": 0, "chunked": 0},
-                           "form": "", "boundaries_passed": 0,
-                           "boundaries_kept": 0}
         page, kv_shd = self.page, self._kv_shd
         # The decode step stays a lambda ON PURPOSE: the benchmark's
         # `decode_tick` and `decode_roofline` readers pick it out of a
@@ -1353,15 +422,15 @@ class LLMEngine:
         # `decode_step` when a benchmark PR points the readers at that
         # name (ROADMAP).
         self._decode_jit = jax.jit(
-            lambda p, pk, pv, state, update: _decode_fn(
+            lambda p, pk, pv, state, update: programs._decode_fn(
                 p, pk, pv, state, update, cfg, page, kv_shd),
             donate_argnums=(1, 2, 3))
 
         def install_kv(pk, pv, ks, vs, pages):
-            return _install_fn(pk, pv, ks, vs, pages, page, kv_shd)
+            return programs._install_fn(pk, pv, ks, vs, pages, page, kv_shd)
         self._install_jit = jax.jit(install_kv, donate_argnums=(0, 1))
 
-        self._install_state_jit = jax.jit(_install_state_fn,
+        self._install_state_jit = jax.jit(programs._install_state_fn,
                                           donate_argnums=(0, 1))
         self._trace_jit = None          # `trace_logits` builds it
 
@@ -1400,10 +469,10 @@ class LLMEngine:
 
     # ------------------------------------------------------------ requests --
     def _dense_only(self, what: str) -> None:
-        if self.cfg.latent:
+        if self._cache_form.pools == 1:
             raise ValueError(
-                f"{what}: a shipped or streamed cache is a K/V pair, and a "
-                "latent pattern caches one row a token in one pool")
+                f"{what}: a shipped or streamed cache is a K/V pair, and "
+                "this configuration caches one row a token in one pool")
         if self.cfg.pattern:
             raise ValueError(
                 f"{what}: keys and values shipped or streamed from elsewhere "
@@ -1418,15 +487,8 @@ class LLMEngine:
         budget = len(req.prompt) + req.params.max_tokens + 1
         return math.ceil(min(budget, self.max_len) / self.page)
 
-    def add_request(self, prompt_tokens: Sequence[int],
-                    params: Optional[SamplingParams] = None, *,
-                    no_cache: bool = False) -> int:
-        if len(prompt_tokens) >= self.max_len:
-            raise ValueError(
-                f"prompt ({len(prompt_tokens)}) >= max_len ({self.max_len})")
-        req = _Request(self._next_id, list(prompt_tokens),
-                       params or SamplingParams())
-        req.no_cache = no_cache
+    def _queue(self, req: _Request) -> int:
+        """A request whose pages the pool can hold joins the waiting."""
         need = self._pages_needed(req)
         if need > self.n_pages - 1:
             raise ValueError(
@@ -1436,6 +498,17 @@ class LLMEngine:
         self._requests[req.req_id] = req
         self._waiting.append(req)
         return req.req_id
+
+    def add_request(self, prompt_tokens: Sequence[int],
+                    params: Optional[SamplingParams] = None, *,
+                    no_cache: bool = False) -> int:
+        if len(prompt_tokens) >= self.max_len:
+            raise ValueError(
+                f"prompt ({len(prompt_tokens)}) >= max_len ({self.max_len})")
+        req = _Request(self._next_id, list(prompt_tokens),
+                       params or SamplingParams())
+        req.no_cache = no_cache
+        return self._queue(req)
 
     def add_external_request(self, kv_blob: dict, first_token: int,
                              params: Optional[SamplingParams] = None, *,
@@ -1462,15 +535,7 @@ class LLMEngine:
         req.no_cache = prompt_tokens is None
         req.kv_blob = kv_blob
         req.first_token = int(first_token)
-        need = self._pages_needed(req)
-        if need > self.n_pages - 1:
-            raise ValueError(
-                f"request needs {need} KV pages but the pool only has "
-                f"{self.n_pages - 1} — raise kv_pages or lower max_tokens")
-        self._next_id += 1
-        self._requests[req.req_id] = req
-        self._waiting.append(req)
-        return req.req_id
+        return self._queue(req)
 
     def _norm_parts(self, parts, length: int, tag: str) -> List[dict]:
         """Validate + key a part list: contiguous spans covering
@@ -1528,10 +593,7 @@ class LLMEngine:
                 f"decode tail needs {need} KV pages but a slot holds "
                 f"{self.pages_per_slot} and the pool {self.n_pages - 1} "
                 f"— lower max_tokens or raise kv_pages/max_len")
-        self._next_id += 1
-        self._requests[req.req_id] = req
-        self._waiting.append(req)
-        return req.req_id
+        return self._queue(req)
 
     def cancel_request(self, req_id: int) -> bool:
         """Retire a request mid-flight (client disconnect, deadline
@@ -1606,15 +668,13 @@ class LLMEngine:
         `steps_queued` of the `steps` left for the device while the step
         before them was still unread (`_next_batch_if_queued`).  A step
         counts when it is read, for the rows that were still live then."""
-        z = self.cfg.latent
-        pooled = self._pk is not None
+        cfg, pooled = self.cfg, self._pk is not None
         per_step = self.max_batch * self.pages_per_slot if pooled else 0
         return {"path": decode_path(
-                    (self.cfg.num_heads, self.cfg.head_dim_), self._pk.shape,
-                    self._tables.shape, z.rank if z else 0)
+                    (cfg.num_heads, cfg.head_dim_), self._pk.shape,
+                    self._tables.shape, self._cache_form.value_lanes(cfg))
                 if pooled else "none",
-                "pool_row": pool_row(*self.cfg.cache_row)
-                if pooled else "none",
+                "pool_row": pool_row(*cfg.cache_row) if pooled else "none",
                 "steps": self._decode_steps,
                 "steps_queued": self._steps_queued,
                 "pages_read": self._pages_read,
@@ -1646,56 +706,15 @@ class LLMEngine:
         return out
 
     def latent_stats(self) -> Dict[str, Any]:
-        """A pattern of latent layers: the bytes of one token's cache row in
-        one layer (its real values; `pool_row_bytes` as the pool pads it)
-        and the pool's row form, the cache rows the decode steps read (a
-        slot's live tokens, summed over the slots; in every latent layer
-        alike), and the key rows the prefills attended, cached and new,
-        with how many of them were up-projected to per-head keys and values
-        and which form the last prefill took."""
-        z = self.cfg.latent
-        if not z:
-            return {"enabled": False}
-        act = jnp.dtype(self.cfg.dtype).itemsize
-        return {"enabled": True, "layers": self.cfg.count("L"),
-                "row_bytes": z.row * act,
-                "pool_row_bytes": self._pk.shape[-1] * act,
-                "pool_row": pool_row(*self.cfg.cache_row),
-                "steps": self._decode_steps, **self._latent,
-                "prefills": dict(self._latent["prefills"])}
+        """This and the two below: what `programs.COUNTED`'s row of that
+        name counts (docs/serving.md has the keys), or {"enabled": False}."""
+        return programs.report(self._counts, "latent", self._decode_steps)
 
     def retention_stats(self) -> Dict[str, Any]:
-        """A pattern of power retention layers: the bytes of one sequence's
-        state over all of them (`row_bytes`: a state row, and a checkpoint
-        row) and phi's `block` and width `D`; the sequences the decode
-        steps read and wrote the state of (`rows_stepped` over `steps`, and
-        the last step's) and by which `path`; the prefills by form and the
-        last one's; the checkpoint boundaries the admitted prompts passed
-        and how many of them were kept (`_PrefixCache.boundaries`)."""
-        z = self.cfg.retention
-        if not z:
-            return {"enabled": False}
-        return {"enabled": True, "layers": self.cfg.count("P"),
-                "row_bytes": state_bytes(self.cfg), "block": z.block,
-                "D": z.expanded, "path": retention.step_path(z),
-                "keep": self._keep, "steps": self._decode_steps,
-                **self._retention,
-                "prefills": dict(self._retention["prefills"])}
+        return programs.report(self._counts, "retention", self._decode_steps)
 
     def routed_stats(self) -> Dict[str, Any]:
-        """What the routed layers' DECODE steps touched, a number a layer:
-        distinct held experts that got a row and (token, expert) rows
-        computed, summed over `steps` decode steps and in the last one.
-        A step reads the weights of the experts it touched and no others."""
-        if not len(self._routed):
-            return {"enabled": False}
-        r = self.cfg.routed
-        return {"enabled": True, "steps": self._decode_steps,
-                "held": r.held, "experts": r.experts, "top_k": r.top_k,
-                "touched": self._routed[:, 0].tolist(),
-                "rows": self._routed[:, 1].tolist(),
-                "step_touched": self._step_routed[:, 0].tolist(),
-                "step_rows": self._step_routed[:, 1].tolist()}
+        return programs.report(self._counts, "routed", self._decode_steps)
 
     def prefill_stats(self) -> Dict[str, Any]:
         """The attention form of the last prefill (`path`: "kernel" or
@@ -1714,43 +733,27 @@ class LLMEngine:
         (`models/transformer.py:row_blocks`; a sequence-parallel prefill
         gives its halves no length): nothing is read back."""
         from ..ops.prefill_attention import kv_blocks
-        row = () if prefix_len is None or self._pk is None \
+        pooled = self._pk is not None
+        row = () if prefix_len is None or not pooled \
             else (self.page, self.pages_per_slot)
-        path = _prefill_path(self.cfg, padded, self._kv_shd, *row) \
-            if self.sp_degree == 1 else "xla"
         table = math.prod(row) if row else 0    # cached rows a suffix sees
         run, dense = kv_blocks(rows, padded, prefix_len or 0, table)
+        if not pooled:                  # no layer attends: no attention form
+            path, run, dense = "none", 0, 0
+        elif self.sp_degree > 1:
+            path = "xla"
+        else:
+            path = programs._prefill_path(self.cfg, padded, self._kv_shd,
+                                          *row)
         rows_run, rows_dense = row_blocks(
             rows if self.sp_degree == 1 else None, padded, every=self._every)
-        self._prefill_ran = {"path": path,
-                             "kv_blocks": run if path == "kernel" else dense,
-                             "row_blocks": rows_run}
-        if self.cfg.latent:
-            # The form, by the rule the program went by; real rows.
-            form = latent_form(table)
-            attended = (prefix_len or 0) + rows
-            expanded = attended if form == "expanded" else 0
-            self._prefill_ran.update(form=form, expanded=expanded)
-            lat = self._latent
-            lat["form"] = form
-            lat["prefills"][form] += 1
-            lat["rows_attended"] += attended
-            lat["rows_expanded"] += expanded
-        if self.cfg.retention:
-            # The form, by the host's mirror of the rule `retention.mixer`
-            # goes by (the state's part is added where the state has read
-            # anything, which on the device is `any(z != 0)` and is not
-            # read back): a prefill from a checkpoint starts from such a
-            # state, a whole prompt from the zero row.
-            form = "chunked" if prefix_len else "attention"
-            self._prefill_ran.update(form=form)
-            self._retention["form"] = form
-            self._retention["prefills"][form] += 1
+        self._prefill_ran = {
+            "path": path, "kv_blocks": run if path == "kernel" else dense,
+            "row_blocks": rows_run,
+            **programs.count(self._counts, "prefill", rows, prefix_len,
+                             table)}
         st = self._prefill_stats
-        if self._pk is None:            # no layer attends: no attention form
-            path, dense = "none", 0
-            self._prefill_ran.update(path=path, kv_blocks=0)
-        else:
+        if pooled:
             st[path + "_calls"] += 1
         st["path"] = path
         st["kv_blocks_run"] += self._prefill_ran["kv_blocks"]
@@ -1804,7 +807,7 @@ class LLMEngine:
                 kv_shd = self._kv_shd
 
                 def prefill(p, t, n):
-                    return _prefill_fn(p, t, n, cfg, kv_shd)
+                    return programs._prefill_fn(p, t, n, cfg, kv_shd)
                 self._prefill_jit[key] = jax.jit(prefill)
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = prompt
@@ -1973,15 +976,13 @@ class LLMEngine:
         self._touched[req.slot] = True
         return True
 
-    def _install(self, slot: int, ks, vs):
-        pages = jnp.asarray(self._tables[slot])
-        self._pk, self._pv = self._install_jit(
-            self._pk, self._pv, ks, vs, pages)
-
     def _install_pages(self, page_ids: Sequence[int], ks, vs):
-        """Install KV into specific pool pages (ks/vs start page-aligned
-        on page_ids[0]; trailing scratch-page writes are masked reads by
-        contract, same as _install)."""
+        """Install KV into specific pool pages, ks/vs starting page-aligned
+        on page_ids[0]: a slot's whole table row, or the pages NEWLY
+        reserved for a suffix (it starts page-aligned at prefix_len, so it
+        maps exactly onto them; the shared prefix pages are already
+        resident and are never written).  Trailing scratch-page writes are
+        masked reads by contract."""
         pages = np.zeros(self.pages_per_slot, np.int32)
         pages[:len(page_ids)] = page_ids
         self._pk, self._pv = self._install_jit(
@@ -2001,13 +1002,6 @@ class LLMEngine:
             self._dev["rec"], self._ckpt, req.slot, end, kept,
             jnp.asarray(rows))
 
-    def _install_new_pages(self, req: _Request, ks, vs):
-        """Install suffix KV into the request's NEWLY reserved pages (the
-        suffix starts page-aligned at prefix_len, so it maps exactly onto
-        them; the shared prefix pages are already resident and are never
-        written)."""
-        self._install_pages(req.pages, ks, vs)
-
     def _run_suffix(self, prompt: Sequence[int], prefix_len: int,
                     pages_row, upto: Optional[int] = None, from_row: int = 0):
         """Jit-cached suffix prefill against resident prefix pages.
@@ -2025,10 +1019,9 @@ class LLMEngine:
         sp = self.sp_degree > 1
         key = ("sp-suffix", Sb) if sp else ("suffix", Sb)
         after = prefix_len              # what `_count_prefill` is told
-        if self.cfg.latent and not prefix_len:
+        if self._cache_form.whole_program and not prefix_len:
             # A whole prompt attends nothing cached, and is given no pages
-            # to gather: another program (and, `latent_form`, the expanded
-            # attention).
+            # to gather: another program.
             key, pages_row, after = ("whole", Sb), None, None
         if self._pk is None:
             pages_row = None            # no pool: one program a bucket
@@ -2038,23 +1031,23 @@ class LLMEngine:
                 every, keep = self._every, self._keep
 
                 def state_prefill(p, pk, pv, pg, t, pl, n, ckpt, row):
-                    return _state_prefill_fn(p, pk, pv, pg, t, pl, n, ckpt,
-                                             row, cfg, page, every,
-                                             keep=keep)
+                    return programs._state_prefill_fn(
+                        p, pk, pv, pg, t, pl, n, ckpt, row, cfg, page, every,
+                        keep=keep)
                 self._prefill_jit[key] = jax.jit(state_prefill)
             elif sp:
                 mesh = self.mesh
 
                 def sp_suffix_prefill(p, pk, pv, pg, t, pl, n):
-                    return self._sp.sp_suffix_prefill_fn(
-                        p, pk, pv, pg, t, pl, n, cfg, page, mesh)
+                    return self._sp.sp_prefill_fn(
+                        p, t, n, cfg, mesh, cached=(pk, pv, pg, pl, page))
                 self._prefill_jit[key] = jax.jit(sp_suffix_prefill)
             else:
                 kv_shd = self._kv_shd
 
                 def suffix_prefill(p, pk, pv, pg, t, pl, n):
-                    return _suffix_prefill_fn(
-                        p, pk, pv, pg, t, pl, n, cfg, page, kv_shd)
+                    return programs._prefill_fn(
+                        p, t, n, cfg, kv_shd, cached=(pk, pv, pg, pl, page))
                 self._prefill_jit[key] = jax.jit(suffix_prefill)
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = suf
@@ -2072,13 +1065,13 @@ class LLMEngine:
         (last-token logits, the experts every row chose or None)."""
         if not (req.prefix_len or self.cfg.pattern):
             logits, ks, vs = self._run_prefill(req.prompt)
-            self._install(req.slot, ks, vs)
+            self._install_pages(self._tables[req.slot], ks, vs)
             return logits, None
         logits, ks, vs, *state = self._run_suffix(
             req.prompt, req.prefix_len, self._tables[req.slot],
             from_row=req.from_row)
         if self._pk is not None:
-            self._install_new_pages(req, ks, vs)
+            self._install_pages(req.pages, ks, vs)
         if self._every:
             self._install_state(req, *state[:2])
         return logits, state[2] if state else None
@@ -2121,7 +1114,7 @@ class LLMEngine:
                 self._prefilling[req.slot] = req
                 continue
             active_before = len(self._slots)
-            programs = len(self._prefill_jit)
+            compiled = len(self._prefill_jit)
             t0 = ph.enter("prefill")
             if req.kv_blob is not None:
                 self._install_external(req)
@@ -2130,16 +1123,14 @@ class LLMEngine:
             ran = {} if req.kv_blob is not None else self._prefill_ran
             if self._every:
                 ran = dict(ran, checkpoints=len(req.new_rows),
-                           recomputed=req.recomputed)
-            if self.cfg.retention:
-                passed = (S - req.prefix_len) // self._every
-                ran = dict(ran, kept=len(req.new_rows), passed=passed)
-                self._retention["boundaries_passed"] += passed
-                self._retention["boundaries_kept"] += len(req.new_rows)
+                           recomputed=req.recomputed, **programs.count(
+                               self._counts, "admit",
+                               (S - req.prefix_len) // self._every,
+                               len(req.new_rows)))
             ph.leave(t0, "prefill", req.req_id.to_bytes(8, "little"),
                      tokens=S, cached_tokens=req.prefix_len,
                      active=active_before, n=ph.n,
-                     new_program=len(self._prefill_jit) - programs, **ran)
+                     new_program=len(self._prefill_jit) - compiled, **ran)
             if self._cache is not None and not req.no_cache:
                 self._cache.hold_row(req.from_row, -1)
                 self._cache.insert(
@@ -2199,10 +1190,10 @@ class LLMEngine:
         ks = jnp.asarray(blob["k"], self.cfg.dtype)
         vs = jnp.asarray(blob["v"], self.cfg.dtype)
         if req.prefix_len:
-            self._install_new_pages(req, ks[:, req.prefix_len:],
-                                    vs[:, req.prefix_len:])
+            self._install_pages(req.pages, ks[:, req.prefix_len:],
+                                vs[:, req.prefix_len:])
         else:
-            self._install(req.slot, ks, vs)
+            self._install_pages(self._tables[req.slot], ks, vs)
 
     def _sample_batch(self, logits_list, params_list) -> List[int]:
         """Sample first tokens for a whole admission wave in ONE
@@ -2417,20 +1408,9 @@ class LLMEngine:
         self._pages_read += pages
         self._step_pages_read = pages
         self._step_state_rows = flight.synced
-        extra = {}
-        if len(self._routed):
-            # After the tokens, what the routed layers touched (`_decode_fn`).
-            self._step_routed = nxt[self.max_batch:].reshape(-1, 2)
-            self._routed += self._step_routed
-            extra["experts"] = int(self._step_routed[:, 0].sum())
-        if self.cfg.latent:
-            rows = int((lengths + 1).sum())     # this step's token included
-            self._latent["rows_read"] += rows
-            self._latent["step_rows_read"] = extra["latent_rows"] = rows
-        if self.cfg.retention:
-            ret = self._retention
-            ret["rows_stepped"] += len(live)
-            ret["step_rows_stepped"] = extra["state_rows"] = len(live)
+        # (After the tokens, what the routed layers touched: `_decode_fn`.)
+        extra = programs.count(self._counts, "decode", lengths,
+                               nxt[self.max_batch:])
         ph.span("decode", flight.t0, ph.to("emit"), batch=len(live),
                 pages=pages, synced=flight.synced,
                 queued=int(flight.queued), **extra)
@@ -2463,7 +1443,7 @@ class LLMEngine:
         t0 = ph.to("ahead" if ahead else "prep", **closing)
         update, synced = self._no_rows, int(self._touched.sum())
         if synced:
-            update = jax.device_put(_pack_rows(
+            update = jax.device_put(programs._pack_rows(
                 self._tables, self._last, self._lengths, active, self._temps,
                 self._touched), self._state_shd)
             self._touched[:] = False
@@ -2530,7 +1510,7 @@ class LLMEngine:
             S = len(req.prompt)
             nxt = min(req.prefilled + self.prefill_chunk, S)
             row = self._tables[slot]
-            programs = len(self._prefill_jit)
+            compiled = len(self._prefill_jit)
             t0 = ph.enter("prefill")
             if req.prefilled == 0:
                 logits, ks, vs = self._run_prefill(req.prompt[:nxt])
@@ -2545,7 +1525,7 @@ class LLMEngine:
             ph.leave(t0, "prefill", req.req_id.to_bytes(8, "little"),
                      tokens=nxt, cached_tokens=req.prefilled, chunked=True,
                      active=len(self._slots), n=ph.n,
-                     new_program=len(self._prefill_jit) - programs,
+                     new_program=len(self._prefill_jit) - compiled,
                      **self._prefill_ran)
             req.prefilled = nxt
             if nxt >= S:
@@ -2611,59 +1591,64 @@ class LLMEngine:
         valid = int(data.get("len", data["k"].shape[1]))
         return kj[li], data["_vj"][li], valid
 
-    def _window_prefetch(self, parts) -> None:
-        self._kv_window.prefetch(
-            [(p["key"], p["handle"]) for p in parts])
-
-    def _ext_decode_step(self, req: _Request) -> int:
-        """One decode token for a paged-context slot: streamed online-
-        softmax attention over the external parts (layers outer, parts
-        inner — the device never holds more than one part), the pool-
-        resident decode tail, and the incoming token itself; the new
-        token's KV appends to the tail pages in one donated update.
+    def _stream_layers(self, toks, pos: int, parts, valid: int, tail=None,
+                       **fields):
+        """Streamed online-softmax attention (layers outer, parts inner —
+        the device never holds more than one part): the rows `toks`, at
+        absolute position `pos`, attend the external `parts`, then what
+        `tail(li)` gives of the pool (k, v, valid, its position) and their
+        own first `valid` rows.  Returns (x, ks, vs (L, rows, KV, D)).
         Raises KVGatherError if a part's bytes cannot be gathered."""
         sa = self._stream_attn
-        S, t = req.ext_len, req.ext_written
-        pos = S + t                       # absolute write/query position
         rec = flight_recorder.recorder()
         win = self._kv_window
         b0, w0, f0 = win.bytes_fetched, win.wait_s, win.fetches
         t0 = rec.begin()
-        self._window_prefetch(req.ext_parts)
-        x = sa.embed(self.params,
-                     np.asarray([[self._last[req.slot]]], np.int32))
-        pages_row = jnp.asarray(np.asarray(req.pages, np.int32))
-        ks_new, vs_new = [], []
+        win.prefetch([(p["key"], p["handle"]) for p in parts])
+        x = sa.embed(self.params, toks)
+        ks, vs = [], []
         for li in range(self.cfg.num_layers):
             q, k, v = sa.qkv(self.params["layers"], li, x, pos)
-            m, l, acc = sa.init(1)
-            for part in req.ext_parts:
-                pk, pv, valid = self._part_layer(part, li)
-                m, l, acc = sa.block(q, pk, pv, valid, pos,
-                                     part["span"][0], m, l, acc)
-            if t > 0:
-                tk, tv = self._tail_gather_jit(self._pk, self._pv,
-                                               jnp.int32(li), pages_row)
-                m, l, acc = sa.block(q, tk, tv, t, pos, S, m, l, acc)
-            m, l, acc = sa.block(q, k, v, 1, pos, pos, m, l, acc)
+            m, l, acc = sa.init(toks.shape[1])
+            for part in parts:
+                pk, pv, n = self._part_layer(part, li)
+                m, l, acc = sa.block(q, pk, pv, n, pos, part["span"][0],
+                                     m, l, acc)
+            if tail is not None:
+                tk, tv, n, at = tail(li)
+                m, l, acc = sa.block(q, tk, tv, n, pos, at, m, l, acc)
+            m, l, acc = sa.block(q, k, v, valid, pos, pos, m, l, acc)
             x = sa.finish(self.params["layers"], li, x, l, acc)
-            ks_new.append(k)
-            vs_new.append(v)
-        logits = sa.logits(self.params, x, 0)
+            ks.append(k)
+            vs.append(v)
         # The span covers prefetch-kick → last layer; gather_wait_us is
         # the BLOCKING portion (prefetch that got there first shows up
         # as bytes with ~zero wait — the gather/compute overlap signal).
-        rec.end("request", "sp:gather", t0,
-                id=req.req_id.to_bytes(8, "little"),
-                parts=len(req.ext_parts),
+        rec.end("request", "sp:gather", t0, parts=len(parts),
                 gather_bytes=win.bytes_fetched - b0,
                 gather_wait_us=int((win.wait_s - w0) * 1e6),
-                fetches=win.fetches - f0)
-        page_id = req.pages[t // self.page]
+                fetches=win.fetches - f0, **fields)
+        return x, jnp.stack(ks), jnp.stack(vs)
+
+    def _ext_decode_step(self, req: _Request) -> int:
+        """One decode token for a paged-context slot: it attends the
+        external parts, the pool-resident decode tail, and itself
+        (`_stream_layers`); the new token's KV appends to the tail pages
+        in one donated update."""
+        S, t = req.ext_len, req.ext_written
+        pages_row = jnp.asarray(np.asarray(req.pages, np.int32))
+
+        def tail(li):
+            return (*self._tail_gather_jit(self._pk, self._pv, jnp.int32(li),
+                                           pages_row), t, S)
+        x, ks, vs = self._stream_layers(
+            np.asarray([[self._last[req.slot]]], np.int32), S + t,
+            req.ext_parts, 1, tail if t > 0 else None,
+            id=req.req_id.to_bytes(8, "little"))
+        logits = self._stream_attn.logits(self.params, x, 0)
         self._pk, self._pv = self._append_tail_jit(
-            self._pk, self._pv, jnp.stack(ks_new)[:, 0],
-            jnp.stack(vs_new)[:, 0], jnp.int32(page_id),
-            jnp.int32(t % self.page))
+            self._pk, self._pv, ks[:, 0], vs[:, 0],
+            jnp.int32(req.pages[t // self.page]), jnp.int32(t % self.page))
         req.ext_written = t + 1
         return int(self._sample_batch([logits], [req.params])[0])
 
@@ -2681,45 +1666,25 @@ class LLMEngine:
         stripe and publishes it into ITS OWN node's arena, so no single
         node's pool (or arena) ever holds the whole context."""
         self._dense_only("prefill_paged_chunk")
-        sa = self._stream_attn
         Sc = len(chunk_tokens)
         if not (0 < Sc <= span):
             raise ValueError(f"chunk of {Sc} tokens vs span {span}")
         ctx = self._norm_parts(
             ctx_parts, pos0, f"pf{self._part_seq}") if ctx_parts else []
         self._part_seq += 1
-        rec = flight_recorder.recorder()
-        win = self._kv_window
-        b0, w0, f0 = win.bytes_fetched, win.wait_s, win.fetches
-        t0 = rec.begin()
-        self._window_prefetch(ctx)
         toks = np.zeros((1, span), np.int32)
         toks[0, :Sc] = chunk_tokens
-        x = sa.embed(self.params, toks)
-        ks_out, vs_out = [], []
-        for li in range(self.cfg.num_layers):
-            q, k, v = sa.qkv(self.params["layers"], li, x, pos0)
-            m, l, acc = sa.init(span)
-            for part in ctx:
-                pk, pv, valid = self._part_layer(part, li)
-                m, l, acc = sa.block(q, pk, pv, valid, pos0,
-                                     part["span"][0], m, l, acc)
-            m, l, acc = sa.block(q, k, v, Sc, pos0, pos0, m, l, acc)
-            x = sa.finish(self.params["layers"], li, x, l, acc)
-            ks_out.append(k)
-            vs_out.append(v)
-        rec.end("request", "sp:gather", t0, parts=len(ctx),
-                gather_bytes=win.bytes_fetched - b0,
-                gather_wait_us=int((win.wait_s - w0) * 1e6),
-                fetches=win.fetches - f0, prefill_chunk=True)
+        x, ks, vs = self._stream_layers(toks, pos0, ctx, Sc,
+                                        prefill_chunk=True)
         # The stripe stays DEVICE-RESIDENT: a same-process consumer
         # (chunk c+1 via the window, or a co-located decode engine)
         # attends to it with zero host copies, and publishing it stages
         # exactly once through the serializer's device plane — the old
         # np.asarray here paid a device->host sync per chunk even when
         # nothing ever left the process.
-        part = {"k": jnp.stack(ks_out), "v": jnp.stack(vs_out), "len": Sc}
-        logits = sa.logits(self.params, x, Sc - 1) if is_last else None
+        part = {"k": ks, "v": vs, "len": Sc}
+        logits = self._stream_attn.logits(self.params, x, Sc - 1) \
+            if is_last else None
         return part, logits
 
     def prefill_paged(self, prompt_tokens: Sequence[int],
@@ -2801,19 +1766,21 @@ class LLMEngine:
                      params: Optional[SamplingParams] = None) -> List[int]:
         """Closed-loop convenience over add_paged_request (the serving
         layer streams the same admission instead): decode a paged
-        handoff to completion; re-raises the typed gather error if a
-        part's host was lost mid-decode."""
-        rid = self.add_paged_request(handoff["parts"], handoff["len"],
-                                     handoff["first"], params,
-                                     prompt_tokens=handoff.get("prompt"))
+        handoff to completion."""
+        return self._run_to_end(self.add_paged_request(
+            handoff["parts"], handoff["len"], handoff["first"], params,
+            prompt_tokens=handoff.get("prompt")))
+
+    def _run_to_end(self, rid: int) -> List[int]:
+        """Step until request `rid` is done; re-raises its typed error (the
+        gather error of a part whose host was lost mid-decode)."""
         while self.has_unfinished():
             for done in self.step():
                 if done.req_id == rid:
                     if done.error is not None:
                         raise done.error
                     return done.out
-        raise RuntimeError(
-            f"paged request {rid} was dropped without finishing")
+        raise RuntimeError(f"request {rid} was dropped without finishing")
 
     # ------------------------------------------------------------ generate --
     def generate(self, prompts: Sequence[Sequence[int]],
@@ -2858,8 +1825,8 @@ class LLMEngine:
             if self._trace_jit is None:
                 cfg, page, kv_shd = self.cfg, self.page, self._kv_shd
                 def decode_logits(p, pk, pv, tb, lt, ln, ac, rec):
-                    return _decode_logits_fn(p, pk, pv, tb, lt, ln, ac, cfg,
-                                             page, kv_shd, rec)
+                    return programs._decode_logits_fn(
+                        p, pk, pv, tb, lt, ln, ac, cfg, page, kv_shd, rec)
                 self._trace_jit = jax.jit(decode_logits,
                                           donate_argnums=(1, 2, 7))
             rows = [logits]
@@ -2955,12 +1922,5 @@ class LLMEngine:
         """Decode-node half: install a shipped prefill and run decode to
         completion (closed-loop convenience over add_external_request —
         the serving layer streams the same admission instead)."""
-        rid = self.add_external_request(kv_blob, first_token, params,
-                                       prompt_tokens=prompt_tokens)
-        req = self._requests[rid]
-        while self.has_unfinished():
-            for done in self.step():
-                if done.req_id == rid:
-                    return done.out
-        raise RuntimeError(
-            f"decode request {req.req_id} was dropped without finishing")
+        return self._run_to_end(self.add_external_request(
+            kv_blob, first_token, params, prompt_tokens=prompt_tokens))

@@ -5,7 +5,7 @@ The reference Ray has no sequence/context parallelism anywhere (SURVEY.md
 TPU we own the whole stack, so the LLM engine gets it natively, in two
 halves that compose into the long-context serving path:
 
-1. **SP prefill** (`sp_prefill_fn` / `sp_suffix_prefill_fn`): the
+1. **SP prefill** (`sp_prefill_fn`, and its suffix form `cached=`): the
    engine's prefill attention with the sequence dim sharded over an
    ``sp`` mesh axis via shard_map — Ring Attention (Liu et al. 2023: KV
    blocks rotate around the axis with running log-sum-exp softmax
@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,8 +48,8 @@ from ..models import transformer as model
 from ..ops.paged_attention import head_rows
 from ..ops.ring_attention import ring_attention, ulysses_attention
 
-__all__ = ["sp_mesh", "sp_prefill_fn", "sp_suffix_prefill_fn",
-           "sp_stripe_pages", "StreamAttn", "validate_sp"]
+__all__ = ["sp_mesh", "sp_prefill_fn", "sp_stripe_pages", "StreamAttn",
+           "validate_sp"]
 
 
 # ---------------------------------------------------------------------------
@@ -114,33 +114,6 @@ def _seq_sharding(mesh: Mesh, rank: int):
     return NamedSharding(mesh, P(*spec))
 
 
-def sp_prefill_fn(params, tokens, length, cfg, mesh: Mesh,
-                  strategy: str = "ring"):
-    """Sequence-parallel twin of engine._prefill_fn: same contract —
-    tokens (1, Sb) padded prompt → (last_logits (V,), ks, vs
-    (L, Sb, KV, D)) — with the attention sharded over the mesh's ``sp``
-    axis.  Sb must be divisible by the sp size (pow-2 buckets are).
-    Heads ride a ``tp`` axis if the mesh has one; only the sequence
-    axis communicates."""
-    S = tokens.shape[1]
-    tokens = jax.lax.with_sharding_constraint(tokens,
-                                              _seq_sharding(mesh, 2))
-    x = model.embed_tokens(params, tokens, cfg)
-    x = jax.lax.with_sharding_constraint(x, _seq_sharding(mesh, 3))
-    cos, sin = model.rope_angles(jnp.arange(0, S, dtype=jnp.float32), cfg)
-    scale = 1.0 / math.sqrt(cfg.head_dim_)
-    attn = ring_attention if strategy == "ring" else ulysses_attention
-
-    def attend(q, k, v):
-        o = attn(q, k, v, mesh, axis_name="sp", causal=True, scale=scale,
-                 batch_axes=(), heads_axis="tp")
-        return o, (k[0], v[0])
-
-    x, (ks, vs) = model.scan_blocks(params["layers"], x, cos, sin, attend,
-                                    cfg)
-    return model.lm_logits(params, x[0, length - 1], cfg), ks, vs
-
-
 def _sp_suffix_shard(q, k, v, ck, cv, prefix_len, *, axis_name: str,
                      n_shards: int, scale: float):
     """shard_map body for SP suffix prefill: q/k/v are the suffix's
@@ -203,43 +176,57 @@ def _sp_suffix_shard(q, k, v, ck, cv, prefix_len, *, axis_name: str,
     return out.astype(q.dtype)
 
 
-def sp_suffix_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
-                         length, cfg, page: int, mesh: Mesh):
-    """Sequence-parallel twin of engine._suffix_prefill_fn (prefix-cache
-    hit suffix prefill): suffix queries sharded over ``sp``, resident
-    prefix pages replicated, ring rotation over the suffix KV.  Always
-    ring — Ulysses would have to split the resident prefix's KV heads
-    across shards, which buys nothing for a memory-resident prefix."""
-    Sb = tokens.shape[1]
-    T = pages.shape[0] * page
-    n = mesh.shape["sp"]
+def sp_prefill_fn(params, tokens, length, cfg, mesh: Mesh,
+                  strategy: str = "ring", cached=None):
+    """Sequence-parallel twin of programs._prefill_fn: same contract —
+    tokens (1, Sb) padded prompt → (last_logits (V,), ks, vs
+    (L, Sb, KV, D)) — with the attention sharded over the mesh's ``sp``
+    axis.  Sb must be divisible by the sp size (pow-2 buckets are).
+    Heads ride a ``tp`` axis if the mesh has one; only the sequence
+    axis communicates.  With `cached` = (pool_k, pool_v, pages,
+    prefix_len, page), its suffix form (a prefix-cache hit): suffix
+    queries sharded over ``sp``, resident prefix pages replicated, ring
+    rotation over the suffix KV.  Always ring — Ulysses would have to
+    split the resident prefix's KV heads across shards, which buys
+    nothing for a memory-resident prefix."""
+    S = tokens.shape[1]
     scale = 1.0 / math.sqrt(cfg.head_dim_)
     tokens = jax.lax.with_sharding_constraint(tokens,
                                               _seq_sharding(mesh, 2))
     x = model.embed_tokens(params, tokens, cfg)
     x = jax.lax.with_sharding_constraint(x, _seq_sharding(mesh, 3))
-    # RoPE at absolute positions prefix_len + i (prefix_len is traced).
-    cos, sin = model.rope_angles(
-        prefix_len + jnp.arange(Sb, dtype=jnp.int32), cfg)
+    if cached is None:
+        cos, sin = model.rope_angles(jnp.arange(0, S, dtype=jnp.float32),
+                                     cfg)
+        attn = ring_attention if strategy == "ring" else ulysses_attention
+        per_layer = ()
 
-    body_shard = functools.partial(_sp_suffix_shard, axis_name="sp",
-                                   n_shards=n, scale=scale)
-    spec = P(None, "sp", None, None)
-    shard = jax.shard_map(
-        body_shard, mesh=mesh,
-        in_specs=(spec, spec, spec, P(None, None, None),
-                  P(None, None, None), P()),
-        out_specs=spec, check_vma=False)
+        def attend(q, k, v):
+            o = attn(q, k, v, mesh, axis_name="sp", causal=True,
+                     scale=scale, batch_axes=(), heads_axis="tp")
+            return o, (k[0], v[0])
+    else:
+        *per_layer, pages, prefix_len, page = cached
+        T = pages.shape[0] * page
+        # RoPE at absolute positions prefix_len + i (prefix_len is traced).
+        cos, sin = model.rope_angles(
+            prefix_len + jnp.arange(S, dtype=jnp.int32), cfg)
+        spec = P(None, "sp", None, None)
+        shard = jax.shard_map(
+            functools.partial(_sp_suffix_shard, axis_name="sp",
+                              n_shards=mesh.shape["sp"], scale=scale),
+            mesh=mesh, in_specs=(spec, spec, spec, P(None, None, None),
+                                 P(None, None, None), P()),
+            out_specs=spec, check_vma=False)
+        heads = (cfg.num_kv_heads, cfg.head_dim_)
 
-    heads = (cfg.num_kv_heads, cfg.head_dim_)
-
-    def attend(q, k, v, pk, pv):            # pk/pv: (N, page, *row)
-        ck = head_rows(pk[pages], *heads).reshape(T, *heads)
-        cv = head_rows(pv[pages], *heads).reshape(T, *heads)
-        return shard(q, k, v, ck, cv, prefix_len), (k[0], v[0])
+        def attend(q, k, v, pk, pv):            # pk/pv: (N, page, *row)
+            ck = head_rows(pk[pages], *heads).reshape(T, *heads)
+            cv = head_rows(pv[pages], *heads).reshape(T, *heads)
+            return shard(q, k, v, ck, cv, prefix_len), (k[0], v[0])
 
     x, (ks, vs) = model.scan_blocks(params["layers"], x, cos, sin, attend,
-                                    cfg, (pool_k, pool_v))
+                                    cfg, per_layer)
     return model.lm_logits(params, x[0, length - 1], cfg), ks, vs
 
 
@@ -301,20 +288,32 @@ class StreamAttn:
         logits = sa.logits(params, x, last_idx)
 
     Only one block is ever device-resident per call, so the device
-    working set is O(part), not O(context).  All jits are cached by
-    operand shape (chunk/part sizes are engine-static, so the cache
-    stays a handful of entries)."""
+    working set is O(part), not O(context).  Each piece is ONE jit, which
+    compiles once an operand shape (chunk/part sizes are engine-static,
+    so that stays a handful of programs)."""
 
     def __init__(self, cfg):
         self.cfg = cfg
-        self.scale = 1.0 / math.sqrt(cfg.head_dim_)
-        self._jits: Dict[Any, Any] = {}
 
-    def _get(self, key, make):
-        fn = self._jits.get(key)
-        if fn is None:
-            fn = self._jits[key] = make()
-        return fn
+        def qkv(layers, i, x, pos0):
+            cos, sin = model.rope_angles(
+                pos0 + jnp.arange(x.shape[1], dtype=jnp.int32), cfg)
+            q, k, v = model.block_qkv(_layer(layers, i), x, cos, sin, cfg)
+            return q[0], k[0], v[0]
+
+        def finish(layers, i, x, l, acc):
+            o = acc / jnp.maximum(l, 1e-30)        # (KV, G, Sq, D)
+            o = o.transpose(2, 0, 1, 3).reshape(
+                1, x.shape[1], -1, cfg.head_dim_).astype(cfg.dtype)
+            return model.block_out(_layer(layers, i), x, o, cfg)
+
+        def logits(params, x, idx):
+            return model.lm_logits(params, x[0, idx], cfg)
+        self._embed = jax.jit(lambda p, t: model.embed_tokens(p, t, cfg))
+        self._qkv, self._finish = jax.jit(qkv), jax.jit(finish)
+        self._block = jax.jit(functools.partial(
+            _stream_block_fn, scale=1.0 / math.sqrt(cfg.head_dim_)))
+        self._logits = jax.jit(logits)
 
     def init(self, sq: int):
         cfg = self.cfg
@@ -325,59 +324,22 @@ class StreamAttn:
         return m, l, acc
 
     def embed(self, params, tokens):
-        cfg = self.cfg
-
-        def make():
-            return jax.jit(lambda p, t: model.embed_tokens(p, t, cfg))
-        return self._get(("embed", tokens.shape[1]), make)(
-            params, jnp.asarray(tokens))
+        return self._embed(params, jnp.asarray(tokens))
 
     def qkv(self, layers, li: int, x, pos0: int):
         """→ (q (Sq, Hq, D), k, v (Sq, KV, D)), rope'd at pos0 + i."""
-        cfg = self.cfg
-
-        def make():
-            def fn(layers, i, x, pos0):
-                cos, sin = model.rope_angles(
-                    pos0 + jnp.arange(x.shape[1], dtype=jnp.int32), cfg)
-                q, k, v = model.block_qkv(_layer(layers, i), x, cos, sin,
-                                          cfg)
-                return q[0], k[0], v[0]
-            return jax.jit(fn)
-        return self._get(("qkv", x.shape[1]), make)(
-            layers, jnp.int32(li), x, jnp.int32(pos0))
+        return self._qkv(layers, jnp.int32(li), x, jnp.int32(pos0))
 
     def block(self, q, k_blk, v_blk, k_valid: int, q_pos0: int,
               k_pos0: int, m, l, acc):
-        def make():
-            return jax.jit(functools.partial(_stream_block_fn,
-                                             scale=self.scale))
-        return self._get(("block", q.shape[0], k_blk.shape[0]), make)(
-            q, k_blk, v_blk, jnp.int32(k_valid), jnp.int32(q_pos0),
-            jnp.int32(k_pos0), m, l, acc)
+        return self._block(q, k_blk, v_blk, jnp.int32(k_valid),
+                           jnp.int32(q_pos0), jnp.int32(k_pos0), m, l, acc)
 
     def finish(self, layers, li: int, x, l, acc):
-        cfg = self.cfg
-
-        def make():
-            def fn(layers, i, x, l, acc):
-                o = acc / jnp.maximum(l, 1e-30)        # (KV, G, Sq, D)
-                o = o.transpose(2, 0, 1, 3).reshape(
-                    1, x.shape[1], -1, cfg.head_dim_).astype(cfg.dtype)
-                return model.block_out(_layer(layers, i), x, o, cfg)
-            return jax.jit(fn)
-        return self._get(("finish", x.shape[1]), make)(
-            layers, jnp.int32(li), x, l, acc)
+        return self._finish(layers, jnp.int32(li), x, l, acc)
 
     def logits(self, params, x, idx: int):
-        cfg = self.cfg
-
-        def make():
-            def fn(params, x, idx):
-                return model.lm_logits(params, x[0, idx], cfg)
-            return jax.jit(fn)
-        return self._get(("logits", x.shape[1]), make)(
-            params, x, jnp.int32(idx))
+        return self._logits(params, x, jnp.int32(idx))
 
 
 # ---------------------------------------------------------------------------
@@ -391,7 +353,8 @@ def _bench_sp_prefill(degree: int, tokens: int, strategy: str,
     import time
 
     from ..models import PRESETS
-    from .engine import _prefill_fn, init_params
+    from ..models.transformer import init_params
+    from .programs import _prefill_fn
     cfg = PRESETS["tiny"]
     params = init_params(cfg, jax.random.key(0))
     toks = jnp.asarray(np.random.default_rng(0).integers(

@@ -649,7 +649,7 @@ def latent_form(cached: int) -> str:
     over cached rows waits for traffic that has them and a chip run that
     sets it.  The expanded rows of a whole prompt go through the blocked
     prefill kernel where the engine's chooser takes it
-    (`llm/engine.py:_prefill_path`: a TPU, 1,024 padded rows or more) and
+    (`llm/programs.py:_prefill_path`: a TPU, 1,024 padded rows or more) and
     no scores are built; `LATENT_FORMS`' built scores are what a suffix, a
     bucket under that and the CPU still run."""
     return "absorbed" if cached else "expanded"

@@ -1,6 +1,6 @@
 """Paged decode attention: one query token per slot against a paged KV pool.
 
-The serving engine's decode step (llm/engine.py:_decode_fn) attends each
+The serving engine's decode step (llm/programs.py:_decode_fn) attends each
 slot's new token to the keys and values its page table holds.  On a TPU this
 is a Pallas kernel that moves only the pages a slot holds: the pool stays in
 HBM, each live page is copied to VMEM once, and all `H // KV` query heads of

@@ -3,8 +3,8 @@ and (on a prefix-cache hit) against the prefix its slot already holds in the
 paged pool — blocked, with an online softmax, so that no S x S score matrix
 is ever made.
 
-The serving engine's prefill bodies call it (llm/engine.py:_prefill_fn,
-_suffix_prefill_fn, and a latent pattern's whole prompt in
+The serving engine's prefill bodies call it (llm/programs.py:_prefill_fn,
+whole or over cached pages, and a latent pattern's whole prompt in
 _latent_prefill_attend).  It is forward only and emits no residuals;
 training's differentiable kernel is ops/flash_attention.py and shares
 nothing with it.

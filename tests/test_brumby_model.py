@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from benchmark.tests.test_brumby import *                   # noqa: F401,F403
 from benchmark.tests.test_brumby import engine, prompt_of, tiny
-from ray_tpu.llm.engine import LLMEngine, SamplingParams, _PrefixCache
+from ray_tpu.llm.engine import LLMEngine, SamplingParams
+from ray_tpu.llm.kv_cache import _PrefixCache
 from ray_tpu.models import retention
 from ray_tpu.models import transformer as T
 
@@ -177,7 +178,7 @@ def test_a_prefill_by_row_blocks_is_the_same_prefill():
     """The row-wise halves by row blocks of a bucket, the mixer over the
     whole of it: the logits, the state and the kept checkpoints of a call
     that is told its length are those of the call over the real rows."""
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
     _, pc = tiny()
     params = T.init_params(pc, jax.random.key(2))
     toks = jax.random.randint(jax.random.key(3), (1, 256), 1, 512)

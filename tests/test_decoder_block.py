@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm import engine as E
+from ray_tpu.llm import programs as E
+from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.llm import sequence_parallel as SP
 from ray_tpu.models import PRESETS
 from ray_tpu.models import transformer as T
@@ -92,10 +93,10 @@ def _prefill_by_rows():
 def _suffix_prefill():
     params, pool, toks, pages, n = _shapes()
     jax.eval_shape(
-        lambda p, pk, pv, pg, t, pl, n: E._suffix_prefill_fn(
-            p, pk, pv, pg, t, pl, n, CFG, PAGE), params, pool, pool, pages,
-        toks, n, n)
-    return E._suffix_prefill_fn
+        lambda p, pk, pv, pg, t, pl, n: E._prefill_fn(
+            p, t, n, CFG, cached=(pk, pv, pg, pl, PAGE)), params, pool, pool,
+        pages, toks, n, n)
+    return E._pair_prefill_attend   # the suffix's own code: one body now
 
 
 def _decode():
@@ -122,15 +123,15 @@ def _sp_suffix_prefill():
     params, pool, toks, pages, n = _shapes()
     mesh = SP.sp_mesh(2)
     jax.eval_shape(
-        lambda p, pk, pv, pg, t, pl, n: SP.sp_suffix_prefill_fn(
-            p, pk, pv, pg, t, pl, n, CFG, PAGE, mesh), params, pool, pool,
-        pages, toks, n, n)
-    return SP.sp_suffix_prefill_fn
+        lambda p, pk, pv, pg, t, pl, n: SP.sp_prefill_fn(
+            p, t, n, CFG, mesh, cached=(pk, pv, pg, pl, PAGE)), params, pool,
+        pool, pages, toks, n, n)
+    return SP._sp_suffix_shard      # the suffix's own code: one body now
 
 
 def _streamed():
-    eng = E.LLMEngine(CFG, max_batch=1, max_len=64, page_size=PAGE,
-                      kv_pages=PAGES, seed=0)
+    eng = LLMEngine(CFG, max_batch=1, max_len=64, page_size=PAGE,
+                    kv_pages=PAGES, seed=0)
     prompt = list(np.random.default_rng(0).integers(1, CFG.vocab_size, 12))
     part, logits = eng.prefill_paged_chunk(prompt, 0, [], span=ROWS,
                                            is_last=True)

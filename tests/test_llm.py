@@ -301,7 +301,7 @@ def test_decode_logits_through_cache_match_float32_forward(path, monkeypatch):
 
     import jax
     import jax.numpy as jnp
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
     from ray_tpu.ops import paged_attention as pa
 
     page = 16
@@ -465,7 +465,7 @@ def test_decode_step_writes_the_pool_in_place():
         assert mem.temp_size_in_bytes < eng._pk.nbytes, mem
 
 
-# ---- the decode step's resident state (llm/engine.py:_decode_fn) ---------
+# ---- the decode step's resident state (llm/programs.py:_decode_fn) ---------
 
 def _engine(mesh_axes, **kw):
     """An engine on one device, or on a forced-CPU mesh of `mesh_axes`."""
@@ -486,7 +486,7 @@ def _check_against_host_rebuilt_steps(eng, seed):
     on the device must be what the mirrors say.  Returns the list the
     checked steps are counted in."""
     import jax
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
 
     cfg, page, kv_shd = eng.cfg, eng.page, eng._kv_shd
     parent_step = jax.jit(
@@ -575,7 +575,7 @@ def test_decode_state_accepts_only_the_marked_rows():
     """`_pack_rows` -> `_accept_rows`: the rows the host marks replace the
     device's, bit for bit (a temperature too); the others stay."""
     import jax
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
     B, P = 5, 7
     rng = np.random.default_rng(1)
     draw = lambda: (rng.integers(0, 99, (B, P)), rng.integers(0, 99, B),
@@ -840,12 +840,12 @@ def _tiny_wide():
 
 @pytest.mark.parametrize("form", ["whole", "suffix"])
 def test_prefill_bodies_through_the_kernel_match_xla(form, prefill_kernel):
-    """`_prefill_fn` / `_suffix_prefill_fn` with the kernel against their
+    """`_prefill_fn`, whole and over cached pages, with the kernel against their
     XLA expression: last-token logits and the rows to install below
     `length` (rows past it are garbage under either)."""
     import jax
     import jax.numpy as jnp
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
 
     cfg, page, rows, length = _tiny_wide(), 16, 256, 150
     rng = np.random.default_rng(0)
@@ -862,8 +862,8 @@ def test_prefill_bodies_through_the_kernel_match_xla(form, prefill_kernel):
         assert eng._admit() == 1
         row = jnp.asarray(eng._tables[next(iter(eng._slots))])
         fn = lambda: jax.jit(
-            lambda p, pk, pv, pg, t, pl, n: E._suffix_prefill_fn(
-                p, pk, pv, pg, t, pl, n, cfg, page))(
+            lambda p, pk, pv, pg, t, pl, n: E._prefill_fn(
+                p, t, n, cfg, cached=(pk, pv, pg, pl, page)))(
             eng.params, eng._pk, eng._pv, row, jnp.asarray(toks), 4 * page,
             length)
     want = [np.asarray(a) for a in fn()]

@@ -24,7 +24,8 @@ import pytest
 import ray_tpu
 from ray_tpu.exceptions import KVGatherError, StreamBrokenError
 from ray_tpu.llm import LLMEngine, LongContextApp, SamplingParams
-from ray_tpu.llm.engine import _KVWindow, _prefill_fn
+from ray_tpu.llm.kv_cache import _KVWindow
+from ray_tpu.llm.programs import _prefill_fn
 from ray_tpu.models import PRESETS
 
 pytestmark = pytest.mark.sp
@@ -48,7 +49,7 @@ def test_sp_prefill_fn_parity(degree, strategy):
     import jax.numpy as jnp
 
     from ray_tpu.llm.sequence_parallel import sp_mesh, sp_prefill_fn
-    from ray_tpu.llm.engine import init_params
+    from ray_tpu.models.transformer import init_params
 
     params = init_params(CFG, jax.random.key(0))
     mesh = sp_mesh(degree)

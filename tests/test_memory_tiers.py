@@ -355,7 +355,7 @@ def test_kv_demote_overflows_to_nvme_parts(tmp_path):
     """Past the host-window byte budget, demoted entries overflow to
     NVMe part files ({k, v, len} npz) and still promote token-exact."""
     from ray_tpu.llm import SamplingParams
-    from ray_tpu.llm.engine import _KVDemoteStore
+    from ray_tpu.llm.kv_cache import _KVDemoteStore
     eng = _tiny_engine(kv_pages=12)
     # Swap in a near-zero host window over a temp dir: every demotion
     # overflows to disk immediately.

@@ -268,7 +268,7 @@ def test_install_then_read_gives_back_the_rows(kv, d):
     """`_install_fn` writes whole pages of rows as the pool holds them; read
     through the slot's page row they are the rows installed, one by one.  A
     latent row (one head of 576 or 160) has ONE pool and None beside it."""
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
     L, page, P_, Sb = 2, 16, 4, 40
     latent = pa.pool_row(kv, d) == "latent"
     shape = pa.pool_shape(L, 9, page, kv, d)
@@ -478,7 +478,7 @@ def test_decode_step_compiles_for_v5e_in_place(mesh_axes, topo, monkeypatch,
     and nothing pool-sized is made beside them."""
     import re
 
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
 
     monkeypatch.setattr(pa, "decode_path", lambda *shapes: "pallas")
     B, page, P_ = 16, 16, 128
@@ -534,7 +534,7 @@ def test_lfm2_decode_step_and_install_compile_for_v5e_without_pool_copies(
 
     from benchmark.families import lfm2_moe
     from benchmark.run import ROOT
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
     from ray_tpu.models import routed
     from ray_tpu.models.transformer import STATEFUL, init_params, zero_state
 
@@ -602,7 +602,7 @@ def test_moonlight_programs_compile_for_v5e_around_one_pool(
 
     from benchmark.families import deepseek_v3
     from benchmark.run import ROOT
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
     from ray_tpu.models import routed
     from ray_tpu.models.transformer import init_params
 
@@ -696,7 +696,7 @@ def test_brumby_programs_compile_for_v5e_around_the_state(
 
     from benchmark.families import brumby
     from benchmark.run import ROOT
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
     from ray_tpu.models import retention
     from ray_tpu.models.transformer import init_params, zero_state
 
@@ -859,7 +859,7 @@ def test_prefill_bodies_compile_for_v5e_without_scores(
     kernel is in the program, and no (H, S, S) or (H, Sb, T + Sb) array, no
     widened K or V and nothing pool-sized is made."""
     import re
-    from ray_tpu.llm import engine as E
+    from ray_tpu.llm import programs as E
 
     # `prefill_path` asks the platform: let it see the described chips.
     monkeypatch.setattr(jax, "devices", lambda *a: topo.devices)
@@ -875,8 +875,8 @@ def test_prefill_bodies_compile_for_v5e_without_scores(
         lowered = jax.jit(prefill).lower(params, toks, S((), jnp.int32))
     else:
         def suffix_prefill(p, pk, pv, pg, t, pl, n):
-            return E._suffix_prefill_fn(p, pk, pv, pg, t, pl, n, cfg, page,
-                                        kv_shd)
+            return E._prefill_fn(p, t, n, cfg, kv_shd,
+                                 cached=(pk, pv, pg, pl, page))
         lowered = jax.jit(suffix_prefill).lower(
             params, pool, pool, S((P_,), jnp.int32), toks,
             S((), jnp.int32), S((), jnp.int32))

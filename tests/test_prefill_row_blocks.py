@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm import engine as E
+from ray_tpu.llm import programs as E
+from ray_tpu.llm.engine import LLMEngine, SamplingParams
 from ray_tpu.models import PRESETS
 from ray_tpu.models import transformer as T
 
@@ -45,9 +46,9 @@ def _forms(model, row_block):
     return {
         "whole": jax.jit(lambda t, n: E._prefill_fn(
             params, t, n, CFG, row_block=row_block)),
-        "suffix": jax.jit(lambda t, n: E._suffix_prefill_fn(
-            params, pk, pv, pages, t, PREFIX, n, CFG, PAGE,
-            row_block=row_block))}
+        "suffix": jax.jit(lambda t, n: E._prefill_fn(
+            params, t, n, CFG, row_block=row_block,
+            cached=(pk, pv, pages, PREFIX, PAGE)))}
 
 
 @pytest.fixture(scope="module")
@@ -131,7 +132,7 @@ def test_engine_counts_row_blocks_and_serves_the_same_tokens():
     """At the real ROW_BLOCK: a 1,030-token prompt in a 2,048-row bucket
     runs 3 of its 4 blocks; its logits are the unblocked form's; buckets
     under 2,048 rows run what they ran."""
-    eng = E.LLMEngine(CFG, max_batch=1, max_len=2048, page_size=64, seed=0)
+    eng = LLMEngine(CFG, max_batch=1, max_len=2048, page_size=64, seed=0)
     rng = np.random.default_rng(1)
     long = rng.integers(1, CFG.vocab_size, 1030).tolist()
     logits, ks, _ = eng._run_prefill(long)
@@ -397,7 +398,7 @@ def test_row_blocks_counts_a_patterns_checkpoint_spacing():
 
 
 def _pattern_engine():
-    return E.LLMEngine(PCFG, max_batch=2, max_len=2048, page_size=32, seed=0,
+    return LLMEngine(PCFG, max_batch=2, max_len=2048, page_size=32, seed=0,
                        prefix_cache=True)
 
 
@@ -410,7 +411,7 @@ def test_engine_counts_a_patterns_row_blocks_and_serves_the_same_tokens(
     rng = np.random.default_rng(1)
     doc = rng.integers(1, PCFG.vocab_size, 1030).tolist()
     ask = doc[:1024] + rng.integers(1, PCFG.vocab_size, 9).tolist()
-    sp = E.SamplingParams(max_tokens=4)
+    sp = SamplingParams(max_tokens=4)
     eng = _pattern_engine()
     assert eng._every == 32
     cold = eng.generate([doc], sp)[0]
