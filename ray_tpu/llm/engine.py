@@ -52,9 +52,9 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .._private import flight_recorder
 from ..exceptions import KVGatherError
-from ..models.transformer import (STATEFUL, TransformerConfig, init_params,
-                                  param_logical_axes, row_blocks, state_bytes,
-                                  state_chunk, zero_state)
+from ..models.transformer import (ROW_BLOCK, TransformerConfig, init_params,
+                                  param_logical_axes, row_blocks, state_axis,
+                                  state_bytes, state_chunk, zero_states)
 from ..ops.paged_attention import (decode_path, head_rows, pool_row,
                                    pool_rows)
 from . import programs
@@ -136,13 +136,16 @@ class _Flight:
 # --------------------------------------------------------------------------
 
 # A pattern with stateful layers keeps a state checkpoint every this many
-# of their chunks (`state_chunk`: 4 x 128 = 512 tokens for Mamba-2's
-# published scan chunk, and for the short convolution's), and
+# of their chunks (`state_chunk`: 4 x 128 = 512 tokens for the hybrid's
+# scan chunk, and for the short convolution's), and no farther apart than
+# `_CKPT_TOKENS` where a chunk divides that (a scan chunk of 256: every 512
+# too, not 1,024: a re-ask needs a boundary inside what it shares), and
 # pads no prefill below `_MIN_STATE_ROWS` rows: under that a prefill's time
 # is the weights' read, and a bucket fewer is a program fewer to warm (a
 # warm-up that reaches the suffix programs through one shared page of 16
 # tokens starts at 24 rows).
 _CKPT_CHUNKS = 4
+_CKPT_TOKENS = 512
 _MIN_STATE_ROWS = 32
 
 
@@ -208,6 +211,8 @@ class LLMEngine:
         kvh, d = cfg.cache_row
         # State checkpoints, every `_every` tokens (0: no recurrent layer).
         self._every = _CKPT_CHUNKS * state_chunk(cfg)
+        if self._every > _CKPT_TOKENS and not _CKPT_TOKENS % state_chunk(cfg):
+            self._every = _CKPT_TOKENS
         if cfg.pattern:
             if mesh is not None or prefill_chunk or (sp_degree or 1) > 1 \
                     or getattr(cfg, "sp_degree", 1) > 1:
@@ -318,8 +323,10 @@ class LLMEngine:
         self._cache = _PrefixCache(self.page, cache_tag, self._every,
                                    range(2, 2 + n_rows)) \
             if prefix_cache else None
-        stateful = [k for k in cfg.kinds if k in STATEFUL]
-        self._ckpt = [zero_state(cfg, k, 2 + n_rows) for k in stateful]
+        # (A tree for each stateful block of the period, a row a checkpoint,
+        # behind the repeats where the period is scanned: `zero_states`.)
+        self._ckpt = zero_states(cfg, 2 + n_rows)
+        self._state_axis = state_axis(cfg)
         # KV offload tier: LRU-evicted prefix-cache pages demote into a
         # bounded host window (NVMe overflow) instead of being freed;
         # hits promote back via device_put.  Pool squeezes (mem_chaos)
@@ -383,8 +390,7 @@ class LLMEngine:
             self._state_shd)
         if self._every:
             # Per slot, the recurrent layers' state: resident with the rest.
-            self._dev["rec"] = [zero_state(cfg, k, max_batch)
-                                for k in stateful]
+            self._dev["rec"] = zero_states(cfg, max_batch)
         # What the host counts for the kinds of layer the configuration has
         # (programs.COUNTED): by name, the `<name>_stats()` below.
         self._counts = programs.counters(cfg, pool=self._pk, keep=self._keep)
@@ -431,7 +437,8 @@ class LLMEngine:
         self._install_jit = jax.jit(install_kv, donate_argnums=(0, 1))
 
         self._install_state_jit = jax.jit(programs._install_state_fn,
-                                          donate_argnums=(0, 1))
+                                          donate_argnums=(0, 1),
+                                          static_argnames="axis")
         self._trace_jit = None          # `trace_logits` builds it
 
         # Chunked in-pool prefill: chunk size is a page multiple so every
@@ -716,6 +723,9 @@ class LLMEngine:
     def routed_stats(self) -> Dict[str, Any]:
         return programs.report(self._counts, "routed", self._decode_steps)
 
+    def mamba_stats(self) -> Dict[str, Any]:
+        return programs.report(self._counts, "mamba", self._decode_steps)
+
     def prefill_stats(self) -> Dict[str, Any]:
         """The attention form of the last prefill (`path`: "kernel" or
         "xla"), how many prefills took each, the key blocks they ran
@@ -751,7 +761,7 @@ class LLMEngine:
             "path": path, "kv_blocks": run if path == "kernel" else dense,
             "row_blocks": rows_run,
             **programs.count(self._counts, "prefill", rows, prefix_len,
-                             table)}
+                             table, min(padded, rows_run * ROW_BLOCK))}
         st = self._prefill_stats
         if pooled:
             st[path + "_calls"] += 1
@@ -993,14 +1003,15 @@ class LLMEngine:
         checkpoints it passed into the rows reserved for them
         (`_reserve`); the prefill's bucket may hold boundaries past the
         prompt, which go to the scratch row."""
-        rows = np.ones(jax.tree.leaves(kept[0])[0].shape[1], np.int32)
+        rows = np.ones(jax.tree.leaves(kept[0])[0].shape[
+            self._state_axis + 1], np.int32)
         for b, row in req.new_rows.items():
             # (Boundary j of the prefill lies in slot (j - 1) % slots: a kind
             # that builds only the last `_keep` has as many slots.)
             rows[((b - req.prefix_len) // self._every - 1) % len(rows)] = row
         self._dev["rec"], self._ckpt = self._install_state_jit(
             self._dev["rec"], self._ckpt, req.slot, end, kept,
-            jnp.asarray(rows))
+            jnp.asarray(rows), axis=self._state_axis)
 
     def _run_suffix(self, prompt: Sequence[int], prefix_len: int,
                     pages_row, upto: Optional[int] = None, from_row: int = 0):
@@ -1055,7 +1066,9 @@ class LLMEngine:
         state = (self._ckpt, from_row) if self.cfg.pattern else ()
         return self._prefill_jit[key](
             self.params, self._pk, self._pv,
-            None if pages_row is None else jnp.asarray(pages_row),
+            # (A copy: the row is a view of `_tables`, which the CPU
+            # backend may still be reading after the slot has been freed.)
+            None if pages_row is None else jnp.asarray(np.array(pages_row)),
             jnp.asarray(toks), prefix_len, S, *state)
 
     def _prefill_slot(self, req: _Request):
@@ -1201,16 +1214,22 @@ class LLMEngine:
         blocking sync per request per tick); the sync cost is stamped as
         a `sample_sync` recorder span so the serving harness sees it."""
         t0 = self.phases.enter("sample_sync")
-        lg = jnp.stack(logits_list)                       # (N, V) f32
-        temps = np.asarray([p.temperature for p in params_list],
-                           np.float32)
+        # (The wave filled up to a power of two with its last row again:
+        # the eager programs below are compiled for a wave's size, and a
+        # tick that admits a size for the first time waits for them; up to
+        # 32 slots that is six sizes, not thirty-two.)
+        n = len(logits_list)
+        fill = (1 << (n - 1).bit_length()) - n
+        lg = jnp.stack(list(logits_list) + list(logits_list[-1:]) * fill)
+        temps = np.asarray([p.temperature for p in params_list]
+                           + [0.0] * fill, np.float32)
         greedy = jnp.argmax(lg, -1).astype(jnp.int32)
         if (temps > 0).any():
             # The one key stream, shared with the decode step, which
             # splits it on the device: this split's first half goes back
             # into the resident state.
             self._dev["rng"], key = jax.random.split(self._dev["rng"])
-            keys = jax.random.split(key, len(params_list))
+            keys = jax.random.split(key, len(temps))
             tj = jnp.asarray(temps)
             sampled = jax.vmap(
                 lambda k, l, t: jax.random.categorical(
@@ -1219,8 +1238,8 @@ class LLMEngine:
         else:
             toks = greedy
         out = np.asarray(toks)                            # the one sync
-        self.phases.leave(t0, "sample_sync", batch=len(params_list))
-        return [int(t) for t in out]
+        self.phases.leave(t0, "sample_sync", batch=n)
+        return [int(t) for t in out[:n]]
 
     def _sample_host(self, logits, params: SamplingParams) -> int:
         return self._sample_batch([logits], [params])[0]

@@ -27,7 +27,8 @@ from ..models.transformer import (ATTEND, LATENT_FORMS, ROW_BLOCK,
                                   decoder_block, embed_tokens, latent_absorb,
                                   latent_expand, latent_form, latent_unabsorb,
                                   lm_logits, over_rows, rope_angles,
-                                  run_pattern, scan_blocks, state_bytes)
+                                  run_pattern, scan_blocks, state_axis,
+                                  state_bytes)
 from ..ops.paged_attention import (head_rows, paged_decode_attention,
                                    paged_latent_attention, pool_row, pool_rows,
                                    pool_shape)
@@ -81,8 +82,12 @@ def _xla_prefill_attention(q, k, v, mask, cfg: TransformerConfig):
     groups = cfg.num_heads // cfg.num_kv_heads
     kr = jnp.repeat(k, groups, axis=2)
     vr = jnp.repeat(v, groups, axis=2)
-    scores = jnp.einsum("bshd,bthd->bhst", q, kr) / jnp.sqrt(
-        jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
+    scores = jnp.einsum("bshd,bthd->bhst", q, kr)
+    if cfg.attention_scale is None:
+        scores = scores / jnp.sqrt(
+            jnp.asarray(cfg.head_dim_, jnp.float32)).astype(q.dtype)
+    else:
+        scores = scores * jnp.asarray(cfg.attention_scale, q.dtype)
     scores = jnp.where(mask[None, None], scores, -1e30)
     p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhst,bthd->bshd", p, vr)
@@ -122,8 +127,9 @@ def _pair_prefill_attend(cfg: TransformerConfig, rows: int, length,
         # pool goes in as it lies; the kernel copies the pages below
         # `prefix_len` of layer `li` and no other.
         from ..ops.prefill_attention import prefill_attention
-        kernel = _per_shard(prefill_attention, kv_sharding,
-                            "hhh.pp..." if cached else "hhh.")
+        kernel = _per_shard(
+            functools.partial(prefill_attention, scale=cfg.attention_scale),
+            kv_sharding, "hhh.pp..." if cached else "hhh.")
         if cached:
             pool = (pool_k, pool_v, pages, prefix_len)
             per_layer = (jnp.arange(pool_k.shape[0], dtype=jnp.int32),)
@@ -136,12 +142,24 @@ def _pair_prefill_attend(cfg: TransformerConfig, rows: int, length,
             return _xla_prefill_attention(q, k, v, mask, cfg)
     else:
         mask = _suffix_mask(rows, T, prefix_len)
-        per_layer = (pool_k, pool_v)
         heads = cfg.cache_row
+        if cfg.repeats > 1:
+            # A scanned period's layer is a traced index: ONE gather of the
+            # slot's pages out of the whole pool (a layer sliced out first
+            # is a copy of it, 67 MB a pool a repeat at Granite's sizes).
+            per_layer = (jnp.arange(pool_k.shape[0], dtype=jnp.int32),)
 
-        def scores(q, k, v, pk, pv):        # pk, pv: (N, page, *row)
-            ck = head_rows(pk[pages], *heads).reshape(T, *heads)
-            cv = head_rows(pv[pages], *heads).reshape(T, *heads)
+            def cached_rows(pool, li):
+                return (pool_k, pool_v)[pool][jnp.full_like(pages, li), pages]
+        else:
+            per_layer = (pool_k, pool_v)
+
+            def cached_rows(pool, *layer):  # pk, pv: (N, page, *row)
+                return layer[pool][pages]
+
+        def scores(q, k, v, *at):
+            ck = head_rows(cached_rows(0, *at), *heads).reshape(T, *heads)
+            cv = head_rows(cached_rows(1, *at), *heads).reshape(T, *heads)
             keys = jnp.concatenate([ck[None], k], axis=1)
             values = jnp.concatenate([cv[None], v], axis=1)
             return over_rows(
@@ -159,7 +177,9 @@ def _pair_decode_attend(cfg: TransformerConfig, kv_sharding, tables, lengths,
     """A decode step's attention over the pair: attend(pools, q, k, v, li)
     writes the token's key and value where they land in layer `li` and
     reads the pages a slot holds -> (o, the pools written)."""
-    paged = _per_shard(paged_decode_attention, kv_sharding, "hpp...")
+    paged = _per_shard(
+        functools.partial(paged_decode_attention, scale=cfg.attention_scale),
+        kv_sharding, "hpp...")
 
     def attend(pools, q, k, v, li):
         pools = written(pools[0], li, k), written(pools[1], li, v)
@@ -253,7 +273,9 @@ def _latent_decode_attend(cfg: TransformerConfig, kv_sharding, tables,
 # such layer; and the events it counts, each (the counters, ...) -> what
 # the event's span carries beside its own fields: "prefill" (real rows,
 # the cached tokens before them or None: a prompt given no pages, the
-# cached rows they see), "decode" (the live slots' lengths, what came back
+# cached rows they see, the rows the row-wise halves ran: the bucket's, or
+# its row blocks that hold a real row), "decode" (the live slots' lengths,
+# what came back
 # behind the step's tokens), "admit" (the checkpoint boundaries an admitted
 # prompt passed, and kept).
 
@@ -283,7 +305,7 @@ def _latent_zero(cfg, pool, **_):
         "prefills": {"expanded": 0, "absorbed": 0}, "form": ""}
 
 
-def _latent_prefill(c, rows, prefix_len, table):
+def _latent_prefill(c, rows, prefix_len, table, ran):
     form = latent_form(table)       # the rule the program went by
     attended = (prefix_len or 0) + rows
     expanded = attended if form == "expanded" else 0
@@ -311,7 +333,7 @@ def _retention_zero(cfg, keep, **_):
         "form": "", "boundaries_passed": 0, "boundaries_kept": 0}
 
 
-def _retention_prefill(c, rows, prefix_len, table):
+def _retention_prefill(c, rows, prefix_len, table, ran):
     # The host's mirror of the rule `retention.mixer` goes by (the state's
     # part is added where the state has read anything, which on the device
     # is `any(z != 0)` and is not read back): a prefill from a checkpoint
@@ -334,14 +356,38 @@ def _retention_admit(c, passed, kept):
     return {"kept": kept, "passed": passed}
 
 
-# Whatever the configuration caches: a routed and a power retention layer
-# have counters and no pool.
+def _mamba_zero(cfg, **_):
+    return cfg.count("M") and {
+        "enabled": True, "layers": cfg.count("M"),
+        "row_bytes": cfg.count("M") * cfg.mamba.state_bytes(
+            jnp.dtype(cfg.dtype).itemsize),
+        "rows_stepped": 0, "step_rows_stepped": 0, "prefill_rows": 0,
+        "prefill_rows_run": 0}
+
+
+def _mamba_prefill(c, rows, prefix_len, table, ran):
+    # A scan runs every row it is given: the bucket's, or the row blocks'.
+    c["prefill_rows"] += rows
+    c["prefill_rows_run"] += ran
+    return {"scan_rows": ran}
+
+
+def _mamba_decode(c, lengths, tail):
+    c["rows_stepped"] += len(lengths)
+    c["step_rows_stepped"] = len(lengths)
+    return {"ssm_rows": len(lengths)}
+
+
+# Whatever the configuration caches: a routed, a power retention and a
+# Mamba-2 layer have counters and no pool.
 COUNTED: Dict[str, Dict[str, Callable]] = {
     "routed": {"zero": _routed_zero, "decode": _routed_decode},
     "latent": {"zero": _latent_zero, "prefill": _latent_prefill,
                "decode": _latent_decode},
     "retention": {"zero": _retention_zero, "prefill": _retention_prefill,
                   "decode": _retention_decode, "admit": _retention_admit},
+    "mamba": {"zero": _mamba_zero, "prefill": _mamba_prefill,
+              "decode": _mamba_decode},
 }
 
 
@@ -495,7 +541,8 @@ def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
         attend, per_layer = prefill_attend(
             cfg, Sb, length, None, cached,
             blocks_to_run(length, Sb, row_block, every), row_block)
-    rec = [{k: c[k][row][None] for k in c} for c in ckpt]
+    at = _on_axis(state_axis(cfg))
+    rec = [{k: c[k][at(row)][at(None)] for k in c} for c in ckpt]
     x, kv, rec, kept, _, chosen = run_pattern(
         params["layers"], x, cos, sin, attend, cfg, rec, per_layer,
         length=length, every=every, row_block=row_block, keep=keep)
@@ -504,14 +551,24 @@ def _state_prefill_fn(params, pool_k, pool_v, pages, tokens, prefix_len,
             chosen)
 
 
-def _install_state_fn(rec, ckpt, slot, end, kept, rows):
+def _on_axis(axis: int):
+    """at(*index) -> the index of a state tree's leaf whose sequences (slots,
+    checkpoint rows, a prefill's one) lie on `axis` (`state_axis`), every
+    axis before it taken whole."""
+    return lambda *index: (*(slice(None),) * axis, *index)
+
+
+def _install_state_fn(rec, ckpt, slot, end, kept, rows, axis: int = 0):
     """Write a prefill's recurrent state into slot `slot` of the resident
     per-slot state `rec`, and the checkpoints it passed into rows `rows`
     (n,) of the pool `ckpt`; a checkpoint nobody keeps goes to row 1, the
-    scratch row."""
-    rec = [{k: r[k].at[slot].set(e[k][0]) for k in r}
+    scratch row.  `axis` (static): `state_axis`, the leaves' axis of slots
+    and rows; a scanned period's repeats lie before it and are written
+    together."""
+    at = _on_axis(axis)
+    rec = [{k: r[k].at[at(slot)].set(e[k][at(0)]) for k in r}
            for r, e in zip(rec, end)]
-    ckpt = [{k: c[k].at[rows].set(kp[k][0]) for k in c}
+    ckpt = [{k: c[k].at[at(rows)].set(kp[k][at(0)]) for k in c}
             for c, kp in zip(ckpt, kept)]
     return rec, ckpt
 
@@ -579,16 +636,13 @@ def _decode_logits_fn(params, pool_k, pool_v, tables, last_tokens, lengths,
     over = decode_attend and decode_attend(cfg, kv_sharding, tables, lengths,
                                            written)
     if cfg.pattern:
-        pools = [pool_k, pool_v]        # written layer by layer, in place
-
-        def attend(*at):
-            o, pools[:] = over(pools, *at)
-            return o, None
+        # The pools are carried from one attention layer to the next and
+        # written layer by layer, in place.
         layer = () if pool_k is None else (
             jnp.arange(pool_k.shape[0], dtype=jnp.int32),)
-        x, _, rec, _, counts, chosen = run_pattern(
-            params["layers"], x, cos, sin, attend, cfg, rec, layer,
-            live=active)
+        x, pools, rec, _, counts, chosen = run_pattern(
+            params["layers"], x, cos, sin, over, cfg, rec, layer,
+            live=active, pools=(pool_k, pool_v))
         return (*pools, lm_logits(params, x[:, 0], cfg), rec, counts, chosen)
 
     def body(carry, layer):

@@ -190,7 +190,7 @@ def sp_prefill_fn(params, tokens, length, cfg, mesh: Mesh,
     split the resident prefix's KV heads across shards, which buys
     nothing for a memory-resident prefix."""
     S = tokens.shape[1]
-    scale = 1.0 / math.sqrt(cfg.head_dim_)
+    scale = cfg.score_scale
     tokens = jax.lax.with_sharding_constraint(tokens,
                                               _seq_sharding(mesh, 2))
     x = model.embed_tokens(params, tokens, cfg)
@@ -312,7 +312,7 @@ class StreamAttn:
         self._embed = jax.jit(lambda p, t: model.embed_tokens(p, t, cfg))
         self._qkv, self._finish = jax.jit(qkv), jax.jit(finish)
         self._block = jax.jit(functools.partial(
-            _stream_block_fn, scale=1.0 / math.sqrt(cfg.head_dim_)))
+            _stream_block_fn, scale=cfg.score_scale))
         self._logits = jax.jit(logits)
 
     def init(self, sq: int):

@@ -887,7 +887,9 @@ class EngineReplica:
         read, key rows the prefills attended and up-projected) and
         `retention` `retention_stats()` (sequences whose state the decode
         steps moved, prefills by form, checkpoint boundaries passed and
-        kept), `{"enabled": False}` for a model without such layers."""
+        kept) and `mamba` `mamba_stats()` (sequences whose SSM state the
+        decode steps moved, the rows the prefills' scans ran beside the
+        real ones), `{"enabled": False}` for a model without such layers."""
         e = self.engine
         return {"ticks": self._ticks, "max_active": self._max_active,
                 "shed": self._shed, "cancelled": self._cancelled,
@@ -907,6 +909,7 @@ class EngineReplica:
                 "routed": e.routed_stats(),
                 "latent": e.latent_stats(),
                 "retention": e.retention_stats(),
+                "mamba": e.mamba_stats(),
                 "tick": self._phases.snapshot()}
 
     async def pid(self) -> int:

@@ -36,6 +36,10 @@ class Mamba2Dims:
     conv_kernel: int = 4
     chunk: int = 128
     norm_eps: float = 1e-5
+    # The type a sequence's SSM state is HELD in between calls (a slot's
+    # row, a checkpoint); the recurrence itself runs in float32 whatever
+    # this says.  Another type is a precision control's.
+    state_dtype: str = "float32"
 
     @property
     def inner(self) -> int:
@@ -55,14 +59,15 @@ class Mamba2Dims:
 
     def state_bytes(self, act_bytes: int = 2) -> int:
         """One sequence's recurrent state in one layer."""
-        return (self.num_heads * self.head_dim * self.state * 4
+        return (self.num_heads * self.head_dim * self.state
+                * jnp.dtype(self.state_dtype).itemsize
                 + (self.conv_kernel - 1) * self.conv_width * act_bytes)
 
 
 def zero_state(dims: Mamba2Dims, batch: int, dtype):
     """One layer's state of `batch` sequences that have read nothing."""
     return {"ssm": jnp.zeros((batch, dims.num_heads, dims.head_dim, dims.state),
-                             jnp.float32),
+                             jnp.dtype(dims.state_dtype)),
             "tail": jnp.zeros((batch, dims.conv_kernel - 1, dims.conv_width),
                               dtype)}
 
@@ -200,7 +205,9 @@ def mixer(lp, u, state, dims: Mamba2Dims, length=None, live=None,
         real = real & live[:, None]
     dt = jnp.where(real[..., None], dt, 0.0)
     A = -jnp.exp(lp["A_log"].astype(f32))
-    y, ssm, kept = ssd(x, dt, A, Bm, Cm, state["ssm"], dims.chunk, every)
+    held = state["ssm"].dtype
+    y, ssm, kept = ssd(x, dt, A, Bm, Cm, state["ssm"].astype(f32),
+                       dims.chunk, every)
     y = y + x.astype(f32) * lp["D"].astype(f32)[:, None]
     # Gate, then norm over each group's share of the inner width.
     y = y.reshape(B, S, dims.inner) * jax.nn.silu(z.astype(f32))
@@ -209,10 +216,12 @@ def mixer(lp, u, state, dims: Mamba2Dims, length=None, live=None,
     y = (yg.reshape(B, S, dims.inner) * lp["norm"].astype(f32)).astype(dt_)
     out = jnp.einsum("bsf,fe->bse", y, lp["w_out"].astype(dt_))
 
-    new = {"ssm": ssm, "tail": tail_after(ext, state["tail"], K, length, live)}
+    new = {"ssm": ssm.astype(held),
+           "tail": tail_after(ext, state["tail"], K, length, live)}
     ckpt = None
     if every:
-        ckpt = {"ssm": kept, "tail": tails_every(ext, state["tail"], K, every)}
+        ckpt = {"ssm": kept.astype(held),
+                "tail": tails_every(ext, state["tail"], K, every)}
     return out, new, ckpt
 
 

@@ -104,8 +104,14 @@ class TransformerConfig:
     # attention layers rotate nothing (position is carried by the recurrent
     # layers); `qk_norm` = an RMS norm with a learned scale over each q and
     # k head before the rotation; `tie_embeddings` = the head is the
-    # embedding table, held once.
+    # embedding table, held once.  `repeats` (a pattern's alone): the stack
+    # is `pattern`, ONE PERIOD, that many times over (`num_layers` counts
+    # them all).  More than one and the period's weights, and whatever the
+    # engine keeps for its stateful layers, are stacked along a leading
+    # repeat axis, and `run_pattern` scans the period over it: one traced
+    # period however deep the stack.
     pattern: str = ""
+    repeats: int = 1
     mamba: Optional[Mamba2Dims] = None
     routed: Optional[RoutedDims] = None
     conv: Optional[ShortConvDims] = None
@@ -114,6 +120,16 @@ class TransformerConfig:
     rope: bool = True
     qk_norm: bool = False
     tie_embeddings: bool = False
+    # Four multipliers a family may publish (Granite 4.0: 12, 0.22, 1 / 64,
+    # 8), each None = absent, and then no operation is added anywhere: the
+    # token's row of the table times `embedding_multiplier`; every residual
+    # half's output times `residual_multiplier` before it is added; the
+    # attention scores times `attention_scale` in the place of 1 / sqrt(head
+    # width); the logits divided by `logit_divisor`.
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    attention_scale: Optional[float] = None
+    logit_divisor: Optional[float] = None
 
     @property
     def head_dim_(self) -> int:
@@ -131,16 +147,29 @@ class TransformerConfig:
         return self.num_kv_heads, self.head_dim_
 
     @property
+    def score_scale(self) -> float:
+        """What the attention scores are multiplied by."""
+        if self.attention_scale is not None:
+            return self.attention_scale
+        return 1.0 / math.sqrt(self.head_dim_)
+
+    @property
+    def period(self) -> str:
+        """One letter a block of ONE period, in order: what `params["layers"]`
+        holds a tree for."""
+        return self.pattern.replace(" ", "")
+
+    @property
     def kinds(self) -> str:
-        """One letter a block, in order."""
-        return self.pattern.replace(" ", "") or "D" * self.num_layers
+        """One letter a block of the whole stack, in order."""
+        return self.period * self.repeats or "D" * self.num_layers
 
     @property
     def pattern_layers(self) -> int:
-        """The layers `pattern` spells: its words, or its letters where it
-        has no spaces."""
-        return len(self.pattern.split() if " " in self.pattern
-                   else self.pattern)
+        """The layers the stack spells: `pattern`'s words, or its letters
+        where it has no spaces, times `repeats`."""
+        return self.repeats * len(
+            self.pattern.split() if " " in self.pattern else self.pattern)
 
     def count(self, kind: str) -> int:
         return self.kinds.count(kind)
@@ -286,10 +315,24 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         if cfg.pattern_layers != L:
             raise ValueError(f"pattern {cfg.pattern!r} has not {L} layers")
         keys = jax.random.split(next(k), len(cfg.kinds))
+        if cfg.repeats > 1:
+            if cfg.routed:
+                raise ValueError("`balance_routers` walks a stack spelled "
+                                 "out: no scanned period of routed layers")
+            # Block j of every repeat in ONE traced initialiser, its leaves
+            # stacked as they come out (repeat r's are block r x period + j
+            # of the stack spelled out, from that block's own key): a
+            # quarter of the program at four repeats.
+            n = len(cfg.period)
+            layers = tuple(
+                jax.vmap(functools.partial(_init_pattern_layer, kind,
+                                           cfg=cfg))(keys[j::n])
+                for j, kind in enumerate(cfg.period))
+        else:
+            layers = tuple(_init_pattern_layer(kind, keys[i], cfg)
+                           for i, kind in enumerate(cfg.kinds))
         params = {"embed": dense(next(k), (cfg.vocab_size, h), h),
-                  "layers": tuple(_init_pattern_layer(kind, keys[i], cfg)
-                                  for i, kind in enumerate(cfg.kinds)),
-                  "ln_f": jnp.ones((h,), jnp.float32)}
+                  "layers": layers, "ln_f": jnp.ones((h,), jnp.float32)}
         head = next(k)
         if not cfg.tie_embeddings:
             params["lm_head"] = dense(head, (h, cfg.vocab_size), h)
@@ -352,8 +395,24 @@ def apply_rope(x, cos, sin):
 
 
 def embed_tokens(params, tokens, cfg: TransformerConfig):
-    """tokens (...) int32 -> their rows of the table (..., E)."""
-    return params["embed"].astype(cfg.dtype)[tokens]
+    """tokens (...) int32 -> their rows of the table (..., E), times the
+    configuration's `embedding_multiplier` where it has one."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    return _times(x, cfg.embedding_multiplier)
+
+
+def _times(y, by: Optional[float]):
+    """y x `by`, the product taken in float32 and rounded once to y's type
+    (a multiplier like 0.22 is no bfloat16); y itself where `by` is None."""
+    if by is None:
+        return y
+    return (y.astype(jnp.float32) * by).astype(y.dtype)
+
+
+def residual(x, y, cfg: TransformerConfig):
+    """x + y, the residual half's output y times the configuration's
+    `residual_multiplier` where it has one."""
+    return x + _times(y, cfg.residual_multiplier)
 
 
 def lm_logits(params, x, cfg: TransformerConfig):
@@ -361,10 +420,16 @@ def lm_logits(params, x, cfg: TransformerConfig):
     logits (..., V), accumulated and returned in float32."""
     x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
     if cfg.tie_embeddings:
-        return jnp.einsum("...e,ve->...v", x, params["embed"].astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
-    return jnp.einsum("...e,ev->...v", x, params["lm_head"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
+        logits = jnp.einsum(
+            "...e,ve->...v", x, params["embed"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.einsum(
+            "...e,ev->...v", x, params["lm_head"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32)
+    if cfg.logit_divisor is not None:
+        logits = logits / cfg.logit_divisor
+    return logits
 
 
 # ---------------------------------------------------------------------------
@@ -401,7 +466,7 @@ def attn_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
     """Output projection of the attention's o (B, S, H, D), and residual."""
     o = constrain(o, ("batch", "seq", "heads", "head_dim"))
     o = jnp.einsum("bshd,hde->bse", o, lp["attn"]["wo"].astype(cfg.dtype))
-    return x + constrain(o, ("batch", "seq", "embed"))
+    return residual(x, constrain(o, ("batch", "seq", "embed")), cfg)
 
 
 def ffn_block(lp, x, cfg: TransformerConfig, constrain=_unconstrained):
@@ -413,7 +478,7 @@ def ffn_block(lp, x, cfg: TransformerConfig, constrain=_unconstrained):
     g = constrain(g, ("batch", "seq", "mlp"))
     d = jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
                    lp["mlp"]["w_down"].astype(dt))
-    return x + constrain(d, ("batch", "seq", "embed"))
+    return residual(x, constrain(d, ("batch", "seq", "embed")), cfg)
 
 
 def block_out(lp, x, o, cfg: TransformerConfig, constrain=_unconstrained):
@@ -587,6 +652,26 @@ def zero_state(cfg: TransformerConfig, kind: str, batch: int):
     if kind == "P":
         return retention.zero_state(cfg.retention, batch)
     return shortconv.zero_state(cfg.conv, cfg.hidden_size, batch, cfg.dtype)
+
+
+def state_axis(cfg: TransformerConfig) -> int:
+    """The axis of a state tree's leaves the sequences lie on: 0, or 1
+    behind the repeats where the period is scanned (`run_pattern`)."""
+    return int(cfg.repeats > 1)
+
+
+def zero_states(cfg: TransformerConfig, batch: int):
+    """`run_pattern`'s `rec` for `batch` sequences that have read nothing:
+    one tree for each STATEFUL block of the period, in order; each leaf
+    (batch, ...), or (repeats, batch, ...) where the period is scanned."""
+    def period():
+        return [zero_state(cfg, k, batch) for k in cfg.period
+                if k in STATEFUL]
+    if not state_axis(cfg):
+        return period()
+    return jax.tree.map(
+        lambda s: jnp.zeros((cfg.repeats, *s.shape), s.dtype),
+        jax.eval_shape(period))
 
 
 def state_bytes(cfg: TransformerConfig) -> int:
@@ -769,7 +854,7 @@ def _stateful_block(mixer, dims):
             h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
             y, state, kept = mixer(lp, h, state, dims(cfg), length=length,
                                    live=live, every=every)
-            return state, (x + y, kept)
+            return state, (residual(x, y, cfg), kept)
         if blocks is None:
             state, (x, kept) = rows(state, x, length)
             return x, state, kept
@@ -855,22 +940,23 @@ def routed_block(lp, x, cfg: TransformerConfig, real=None, blocks=None,
     h, chosen, w, u = over_rows(before, [(x, 1)], (x, *picks, acted), blocks,
                                 row_block)
     y, counts = routed.held_experts(lp, u, chosen, w, r, real)
-    x, = over_rows(lambda x, h, y: (x + routed.combine(lp, h, y, r),),
+    x, = over_rows(lambda x, h, y: (residual(
+        x, routed.combine(lp, h, y, r), cfg),),
                    [(x, 1), (h, 1), (y, 1)], (x,), blocks, row_block)
     return x, counts, chosen
 
 
 def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
                 per_layer=(), length=None, live=None, every: int = 0,
-                row_block: int = ROW_BLOCK, keep: int = 0):
-    """A pattern of kinds, block by block (`layers`: one tree a block).
-    `attend(q, k, v, *at)` as `scan_blocks` takes it, `at` the i-th slice of
-    `per_layer` for the i-th attention layer (an `L` layer: `attend(q, row,
-    w, *at)`, `latent_block`); `rec` the recurrent state, one
-    tree (`zero_state`) for each STATEFUL block in order.  Which rows are
-    real: the first `length` (a prefill's padded bucket), the slots that are
-    `live` (B,) (a decode step); the others move no state and meet no
-    routed expert.  `every`: the stateful mixers' checkpoints; `keep`: how
+                row_block: int = ROW_BLOCK, keep: int = 0, pools=None):
+    """A pattern of kinds, block by block (`layers`: one tree a block of the
+    period).  `attend(q, k, v, *at)` as `scan_blocks` takes it, `at` the
+    i-th slice of `per_layer` for the i-th attention layer (an `L` layer:
+    `attend(q, row, w, *at)`, `latent_block`); `rec` the recurrent state,
+    one tree (`zero_state`) for each STATEFUL block of the period in order.
+    Which rows are real: the first `length` (a prefill's padded bucket), the
+    slots that are `live` (B,) (a decode step); the others move no state
+    and meet no routed expert.  `every`: the stateful mixers' checkpoints; `keep`: how
     many of those a prompt passes the caller keeps, the last so many (0:
     all), which a kind whose state is large builds no others for.  Given
     `length` in a bucket that `by_row_blocks` (blocks of `row_block` rows,
@@ -880,7 +966,54 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
     and x, the kept keys and values, the checkpoints and the chosen experts
     are ZEROS past the last block that ran.  Returns (x, the attention
     layers' `kept` stacked, rec', the stateful blocks' checkpoints, the `E`
-    blocks' counts (n, 2) and chosen experts (n, B, S, K))."""
+    blocks' counts (n, 2) and chosen experts (n, B, S, K)).
+
+    `pools` (a decode step's): something CARRIED from one attention layer
+    to the next, the page pools it writes in place: `attend(pools, q, k, v,
+    *at)` -> (o, the pools after the layer), and what comes back in
+    `kept`'s place is the pools after the last.
+
+    `cfg.repeats` > 1: the period is traced ONCE and `lax.scan`ned over the
+    repeats.  Every leaf of `layers`, of `rec` and of the checkpoints that
+    come back then has the repeats on a leading axis, in front of its batch;
+    `rec` rides the scan's carry and is read and written where it lies, a
+    repeat's slice a trip (as `pools` is); `per_layer`, `kept`, the counts
+    and the chosen experts are flat over ALL the stack's layers of their
+    kind, repeat-major: layer j of repeat r is r x (the period's) + j."""
+    period = functools.partial(
+        _run_period, cos=cos, sin=sin, attend=attend, cfg=cfg, length=length,
+        live=live, every=every, row_block=row_block, keep=keep)
+    R = cfg.repeats
+    if R == 1:
+        return period(layers, x, rec, per_layer, pools)
+
+    def fold(a):                        # (all layers, ...) -> (R, period's,)
+        return a.reshape(R, a.shape[0] // R, *a.shape[1:])
+
+    def trip(carry, at_repeat):
+        x, pools, rec = carry
+        lp, at, r = at_repeat
+        here = jax.tree.map(lambda s: jax.lax.dynamic_index_in_dim(
+            s, r, keepdims=False), rec)
+        x, kept, new, *rest = period(lp, x, here, at, pools)
+        rec = jax.tree.map(
+            lambda s, n: jax.lax.dynamic_update_index_in_dim(s, n, r, 0),
+            rec, new)
+        if pools is not None:
+            pools, kept = kept, None
+        return (x, pools, rec), (kept, *rest)
+    (x, pools, rec), (kept, ckpts, counts, chosen) = jax.lax.scan(
+        trip, (x, pools, rec),
+        (layers, tuple(fold(a) for a in per_layer), jnp.arange(R)))
+    kept, counts, chosen = jax.tree.map(
+        lambda a: a.reshape(-1, *a.shape[2:]), (kept, counts, chosen))
+    return x, (kept if pools is None else pools), rec, ckpts, counts, chosen
+
+
+def _run_period(layers, x, rec, per_layer, pools, *, cos, sin, attend,
+                cfg: TransformerConfig, length, live, every: int,
+                row_block: int, keep: int):
+    """`run_pattern` for the blocks of one period, walked in Python."""
     kept, new, ckpts, counts, chosen = [], [], [], [], []
     real = None
     if length is not None:
@@ -888,19 +1021,20 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
     if live is not None:
         real = jnp.broadcast_to(live[:, None], x.shape[:2])
     by = (blocks_to_run(length, x.shape[1], row_block, every), row_block)
-    for kind, lp in zip(cfg.kinds, layers):
-        if kind == "*":
-            at = tuple(a[len(kept)] for a in per_layer)
-            x, k = attention_block(
-                lp, x, cos, sin, lambda q, k, v: attend(q, k, v, *at), cfg,
-                *by)
-            kept.append(k)
-        elif kind == "L":
-            at = tuple(a[len(kept)] for a in per_layer)
-            x, k = latent_block(
-                lp, x, cos, sin, lambda q, row, w: attend(q, row, w, *at),
+    n_attend = 0
+    for kind, lp in zip(cfg.period, layers):
+        if kind in "*L":
+            at = tuple(a[n_attend] for a in per_layer)
+            n_attend += 1
+            carried = () if pools is None else (pools,)
+            x, k = (attention_block if kind == "*" else latent_block)(
+                lp, x, cos, sin,
+                lambda *a, at=at, carried=carried: attend(*carried, *a, *at),
                 cfg, *by)
-            kept.append(k)
+            if pools is None:
+                kept.append(k)
+            else:                       # this layer's are the next one's
+                pools = k
         elif kind in STATEFUL:
             x, state, ck = _STATEFUL_BLOCK[kind](
                 lp, x, rec[len(new)], cfg, *by, length=length, live=live,
@@ -914,8 +1048,11 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
             x, c, ch = routed_block(lp, x, cfg, real, *by)
             counts.append(c)
             chosen.append(ch)
-    kept = jax.tree.map(lambda *a: jnp.stack(a), *kept) \
-        if kept and kept[0] is not None else None
+    if pools is not None:
+        kept = pools
+    else:
+        kept = jax.tree.map(lambda *a: jnp.stack(a), *kept) \
+            if kept and kept[0] is not None else None
     if not counts:
         return x, kept, new, ckpts, None, None
     return x, kept, new, ckpts, jnp.stack(counts), jnp.stack(chosen)
@@ -960,8 +1097,8 @@ def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
                     w, row[:, :, 0], cfg)(q, causal), None), cfg)[0]
         else:
             x = attention_block(
-                lp, x, cos, sin, lambda q, k, v: (_xla_attention(q, k, v),
-                                                  None), cfg)[0]
+                lp, x, cos, sin, lambda q, k, v: (_xla_attention(
+                    q, k, v, scale=cfg.attention_scale), None), cfg)[0]
         layers.append(lp)
     return dict(params, layers=tuple(layers))
 
@@ -987,22 +1124,23 @@ def scan_blocks(layers, x, cos, sin, attend, cfg: TransformerConfig,
         body, x, (jnp.arange(n) if by_rows else layers, *per_layer))
 
 
-def _xla_attention(q, k, v, causal: bool = True):
+def _xla_attention(q, k, v, causal: bool = True,
+                   scale: Optional[float] = None):
     """Reference dot-product attention (single implementation lives in
     ops/flash_attention.py; XLA fuses it well on its own)."""
     from ..ops.flash_attention import reference_attention
-    return reference_attention(q, k, v, causal=causal)
+    return reference_attention(q, k, v, causal=causal, scale=scale)
 
 
 def _flash_attention(q, k, v, mesh: Optional[Mesh],
-                     rules: LogicalAxisRules):
+                     rules: LogicalAxisRules, scale: Optional[float] = None):
     """The Pallas kernel is a custom call the GSPMD partitioner cannot
     split — left inside a sharded jit it gathers q/k/v onto every chip.
     So on a multi-device mesh run it per shard over the batch and head
     axes (attention is independent across both); every shard sees whole
     sequences."""
     from ..ops.flash_attention import flash_attention
-    attend = functools.partial(flash_attention, causal=True)
+    attend = functools.partial(flash_attention, causal=True, scale=scale)
     if mesh is None or mesh.size == 1:
         return attend(q, k, v)
     q_spec = rules.spec(("batch", None, "heads", None), mesh)
@@ -1014,14 +1152,16 @@ def _flash_attention(q, k, v, mesh: Optional[Mesh],
 
 def _attention(cfg: TransformerConfig, q, k, v, mesh: Optional[Mesh],
                rules: LogicalAxisRules):
+    scale = cfg.attention_scale         # None: each form's 1 / sqrt(D)
     if cfg.attention_impl == "flash":
-        return _flash_attention(q, k, v, mesh, rules)
+        return _flash_attention(q, k, v, mesh, rules, scale)
     if cfg.attention_impl == "ring" and mesh is not None:
         from ..ops.ring_attention import ring_attention
-        return ring_attention(q, k, v, mesh=mesh, axis_name="sp", causal=True)
+        return ring_attention(q, k, v, mesh=mesh, axis_name="sp", causal=True,
+                              scale=scale)
     if cfg.attention_impl not in ("xla", "ring"):
         raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
-    return _xla_attention(q, k, v)
+    return _xla_attention(q, k, v, scale=scale)
 
 
 # ---------------------------------------------------------------------------
