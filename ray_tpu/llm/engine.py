@@ -393,7 +393,8 @@ class LLMEngine:
             self._dev["rec"] = zero_states(cfg, max_batch)
         # What the host counts for the kinds of layer the configuration has
         # (programs.COUNTED): by name, the `<name>_stats()` below.
-        self._counts = programs.counters(cfg, pool=self._pk, keep=self._keep)
+        self._counts = programs.counters(cfg, pool=self._pk, keep=self._keep,
+                                         slots=max_batch)
         # The update of a step before which no slot was touched: marks none.
         self._no_rows = jax.device_put(none, self._state_shd)
         self._prefill_jit = {}

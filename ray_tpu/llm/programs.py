@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..models import retention
+from ..models import mamba2, retention
 from ..models.transformer import (ATTEND, LATENT_FORMS, ROW_BLOCK,
                                   TransformerConfig, blocks_to_run,
                                   decoder_block, embed_tokens, latent_absorb,
@@ -268,7 +268,8 @@ def _latent_decode_attend(cfg: TransformerConfig, kv_sharding, tables,
 # ---- What the host counts for a kind of layer ------------------------------
 # From shapes and lengths it already holds: nothing is read back but the
 # routed counts, which ride behind a step's tokens.  A row of `COUNTED` is
-# "zero": (cfg, pool=, keep=) -> the kind's `<name>_stats()` as it starts
+# "zero": (cfg, pool=, keep=, slots=) -> the kind's `<name>_stats()` as it
+# starts
 # (docs/serving.md has the keys), nothing where the configuration has no
 # such layer; and the events it counts, each (the counters, ...) -> what
 # the event's span carries beside its own fields: "prefill" (real rows,
@@ -356,13 +357,16 @@ def _retention_admit(c, passed, kept):
     return {"kept": kept, "passed": passed}
 
 
-def _mamba_zero(cfg, **_):
+def _mamba_zero(cfg, slots, **_):
+    # `path`: the form the decode step's program is built with, which says
+    # whose state it moves: the live slots' ("pallas"), or every slot's.
     return cfg.count("M") and {
         "enabled": True, "layers": cfg.count("M"),
         "row_bytes": cfg.count("M") * cfg.mamba.state_bytes(
             jnp.dtype(cfg.dtype).itemsize),
-        "rows_stepped": 0, "step_rows_stepped": 0, "prefill_rows": 0,
-        "prefill_rows_run": 0}
+        "path": mamba2.step_path(cfg.mamba, live=True), "slots": slots,
+        "rows_stepped": 0, "step_rows_stepped": 0, "rows_moved": 0,
+        "prefill_rows": 0, "prefill_rows_run": 0}
 
 
 def _mamba_prefill(c, rows, prefix_len, table, ran):
@@ -375,6 +379,7 @@ def _mamba_prefill(c, rows, prefix_len, table, ran):
 def _mamba_decode(c, lengths, tail):
     c["rows_stepped"] += len(lengths)
     c["step_rows_stepped"] = len(lengths)
+    c["rows_moved"] += len(lengths) if c["path"] == "pallas" else c["slots"]
     return {"ssm_rows": len(lengths)}
 
 

@@ -843,9 +843,10 @@ def latent_block(lp, x, cos, sin, attend, cfg: TransformerConfig,
 def _stateful_block(mixer, dims):
     def block(lp, x, state, cfg: TransformerConfig, blocks=None,
               row_block: int = ROW_BLOCK, length=None, live=None,
-              every: int = 0):
+              every: int = 0, **beside):
         """x (B, S, E) from the layer's recurrent `state` -> (x, state',
-        checkpoints); `length`, `live` and `every` are the mixer's.  By row
+        checkpoints); `length`, `live`, `every` and what a kind takes
+        `beside` them (`_beside`) are the mixer's.  By row
         blocks the loop carries the state: a trip is the mixer on its
         block's rows from the state the trip before left, told how many of
         its rows are real, and the checkpoints it passes are its own share
@@ -853,7 +854,7 @@ def _stateful_block(mixer, dims):
         def rows(state, x, length):
             h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
             y, state, kept = mixer(lp, h, state, dims(cfg), length=length,
-                                   live=live, every=every)
+                                   live=live, every=every, **beside)
             return state, (residual(x, y, cfg), kept)
         if blocks is None:
             state, (x, kept) = rows(state, x, length)
@@ -912,11 +913,27 @@ conv_block = _stateful_block(shortconv.mixer, lambda cfg: cfg.conv)
 _STATEFUL_BLOCK = {"M": mamba_block, "C": conv_block, "P": retention_block}
 
 
-def _beside(kind: str, **more):
-    """What `retention_block` takes besides what every stateful block does
-    (the others rotate nothing, and their state is small enough to keep at
-    every boundary)."""
-    return more if kind == "P" else {}
+def _beside(kind: str, cos, sin, keep: int = 0, order=None, repeat=None):
+    """What a kind's block takes besides what every stateful block does:
+    `retention_block` rotates, and keeps only so many of the boundaries it
+    passes (the others' state is small enough to keep at every one); a
+    Mamba-2 step that moves the live slots alone (`carried_whole`) is told
+    their `order` and which `repeat` of the stacked state is its own."""
+    if kind == "P":
+        return {"cos": cos, "sin": sin, "keep": keep}
+    return {"order": order, "repeat": repeat} \
+        if kind == "M" and order is not None else {}
+
+
+def carried_whole(cfg: TransformerConfig, rows: int, length, live,
+                  every: int):
+    """For each STATEFUL block of the period, the leaves of its state that a
+    call of these shapes updates where they lie, whatever is stacked in
+    front of them: a Mamba-2 decode step's "ssm" where `mamba2.step_path`
+    says "pallas", nothing anywhere else."""
+    return [("ssm",) if kind == "M" and mamba2.step_path(
+        cfg.mamba, rows, length, live, every) == "pallas" else ()
+        for kind in cfg.period if kind in STATEFUL]
 
 
 def routed_block(lp, x, cfg: TransformerConfig, real=None, blocks=None,
@@ -977,12 +994,17 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
     repeats.  Every leaf of `layers`, of `rec` and of the checkpoints that
     come back then has the repeats on a leading axis, in front of its batch;
     `rec` rides the scan's carry and is read and written where it lies, a
-    repeat's slice a trip (as `pools` is); `per_layer`, `kept`, the counts
+    repeat's slice a trip (as `pools` is), but for the leaves a block
+    updates in place itself (`carried_whole`: a slice handed to a kernel
+    would be a copy of it), which go to the block whole with the repeat's
+    number; `per_layer`, `kept`, the counts
     and the chosen experts are flat over ALL the stack's layers of their
     kind, repeat-major: layer j of repeat r is r x (the period's) + j."""
+    whole = carried_whole(cfg, x.shape[1], length, live, every)
     period = functools.partial(
         _run_period, cos=cos, sin=sin, attend=attend, cfg=cfg, length=length,
-        live=live, every=every, row_block=row_block, keep=keep)
+        live=live, every=every, row_block=row_block, keep=keep,
+        order=mamba2.live_order(live) if any(whole) else None)
     R = cfg.repeats
     if R == 1:
         return period(layers, x, rec, per_layer, pools)
@@ -993,12 +1015,13 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
     def trip(carry, at_repeat):
         x, pools, rec = carry
         lp, at, r = at_repeat
-        here = jax.tree.map(lambda s: jax.lax.dynamic_index_in_dim(
-            s, r, keepdims=False), rec)
-        x, kept, new, *rest = period(lp, x, here, at, pools)
-        rec = jax.tree.map(
-            lambda s, n: jax.lax.dynamic_update_index_in_dim(s, n, r, 0),
-            rec, new)
+        here = [{k: s[k] if k in w else jax.lax.dynamic_index_in_dim(
+            s[k], r, keepdims=False) for k in sorted(s)}
+            for s, w in zip(rec, whole)]
+        x, kept, new, *rest = period(lp, x, here, at, pools, repeat=r)
+        rec = [{k: n[k] if k in w else jax.lax.dynamic_update_index_in_dim(
+            s[k], n[k], r, 0) for k in sorted(s)}
+            for s, n, w in zip(rec, new, whole)]
         if pools is not None:
             pools, kept = kept, None
         return (x, pools, rec), (kept, *rest)
@@ -1012,8 +1035,9 @@ def run_pattern(layers, x, cos, sin, attend, cfg: TransformerConfig, rec,
 
 def _run_period(layers, x, rec, per_layer, pools, *, cos, sin, attend,
                 cfg: TransformerConfig, length, live, every: int,
-                row_block: int, keep: int):
-    """`run_pattern` for the blocks of one period, walked in Python."""
+                row_block: int, keep: int, order=None, repeat=None):
+    """`run_pattern` for the blocks of one period, walked in Python;
+    `order`, `repeat`: `_beside`'s."""
     kept, new, ckpts, counts, chosen = [], [], [], [], []
     real = None
     if length is not None:
@@ -1038,7 +1062,7 @@ def _run_period(layers, x, rec, per_layer, pools, *, cos, sin, attend,
         elif kind in STATEFUL:
             x, state, ck = _STATEFUL_BLOCK[kind](
                 lp, x, rec[len(new)], cfg, *by, length=length, live=live,
-                every=every, **_beside(kind, cos=cos, sin=sin, keep=keep))
+                every=every, **_beside(kind, cos, sin, keep, order, repeat))
             new.append(state)
             ckpts.append(ck)
         elif kind == "F":
@@ -1087,7 +1111,7 @@ def balance_routers(params, cfg: TransformerConfig, key, batch: int = 2,
         elif kind in STATEFUL:
             x = _STATEFUL_BLOCK[kind](
                 lp, x, zero_state(cfg, kind, batch), cfg,
-                **_beside(kind, cos=cos, sin=sin))[0]
+                **_beside(kind, cos, sin))[0]
         elif kind == "F":
             x = ffn_block(lp, x, cfg)
         elif kind == "L":

@@ -181,7 +181,9 @@ def test_the_mamba_counter_counts_live_rows_and_the_rows_a_scan_ran():
     zero = eng.mamba_stats()
     assert zero == {"enabled": True, "layers": 4,
                     "row_bytes": 4 * (8 * 32 * 16 * 4 + 3 * 288 * 4),
+                    "path": "ssd", "slots": 2,
                     "rows_stepped": 0, "step_rows_stepped": 0,
+                    "rows_moved": 0,
                     "prefill_rows": 0, "prefill_rows_run": 0, "steps": 0}
     eng.generate([prompt_of(cfg, 9, 40), prompt_of(cfg, 10, 75)],
                  SamplingParams(max_tokens=5))
@@ -191,6 +193,8 @@ def test_the_mamba_counter_counts_live_rows_and_the_rows_a_scan_ran():
     assert st["prefill_rows_run"] == 64 + 128 + 3 * 512     # blocks of 2,048
     assert st["steps"] == 4 + 1 and st["rows_stepped"] == 4 * 2 + 1
     assert st["step_rows_stepped"] == 1
+    # The reference path's program moves every slot's state, live or not.
+    assert st["rows_moved"] == (4 + 1) * 2
     dense = LLMEngine(TransformerConfig(
         vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=1,
         num_heads=2, num_kv_heads=2, dtype=jnp.float32), max_batch=1,

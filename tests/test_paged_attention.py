@@ -753,6 +753,96 @@ def test_brumby_programs_compile_for_v5e_around_the_state(
     assert mem.temp_size_in_bytes < layer
 
 
+@pytest.mark.parametrize("shape", [
+    # repeats, slots, heads, head width, state, groups: serve_chat_ssm's
+    # stacked leaf, serve_doc_reask_hybrid's plain one
+    (4, 32, 64, 64, 128, 1), (None, 8, 128, 64, 128, 8)])
+def test_mamba_step_compiles_for_v5e_in_place(shape, topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import mamba2
+    R, B, H, P_, N, G = shape
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    leaf = (B, H, P_, N) if R is None else (R, B, H, P_, N)
+
+    def step(xdt, dec, Bm, Cm, ssm, order, n, r):
+        return mamba2.mamba_step(xdt, dec, Bm, Cm, ssm, order, n,
+                                 None if R is None else r)
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(
+        S((B, H, P_), jnp.bfloat16), S((B, H), jnp.float32),
+        S((B, G, N), jnp.bfloat16), S((B, G, N), jnp.bfloat16),
+        S(leaf, jnp.float32), S((B,), jnp.int32), S((), jnp.int32),
+        S((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mamba_step" in text
+    # The whole leaf, stacked or not, is the kernel's operand and result.
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == math.prod(leaf) * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+def test_granite_decode_step_compiles_for_v5e_with_the_state_where_it_lies(
+        topo, monkeypatch, no_compile_cache):
+    """Granite's decode step at serve_chat_ssm's shapes (all 40 layers, 32
+    slots): nine `mamba_step` calls in the scan's body, each given the whole
+    stacked leaf (4, 32, 64, 64, 128) and giving it back; no copy, slice or
+    update of a state leaf anywhere (a repeat sliced out for the kernel
+    would be 67 MB copied in and out a layer); the pools and the resident
+    state alias their outputs; the temporaries stay where the parent's were
+    (0.08 GB)."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.run import load_cell
+    from ray_tpu.llm import programs as E
+    from ray_tpu.models import mamba2
+    from ray_tpu.models.transformer import init_params, zero_states
+    from tests.test_mamba_step import as_on_a_tpu
+
+    monkeypatch.setattr(mamba2, "step_path", as_on_a_tpu(mamba2.step_path))
+    cell = load_cell("serve_chat_ssm")
+    eng = cell["traffic"]["engine"]
+    cfg = cell["family"].program_config(cell["config"],
+                                        max_seq_len=eng["max_len"])
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    on_chip = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+    B, page = eng["max_batch"], eng["page_size"]
+    P_ = eng["max_len"] // page
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    pools = on_chip(jax.eval_shape(
+        lambda: E.make_pools(cfg, eng["kv_pages"] + 1, page, None)))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    rec = on_chip(jax.eval_shape(lambda: zero_states(cfg, B)))
+    state = {"slots": S((B, P_ + 4), jnp.int32),
+             "rng": S(key.shape, key.dtype), "rec": rec}
+    leaf = rec[0]["ssm"].shape
+    assert leaf == (4, 32, 64, 64, 128) and len(rec) == 9
+
+    def decode_step(p, pk, pv, state, update):
+        return E._decode_fn(p, pk, pv, state, update, cfg, page, None)
+    compiled = jax.jit(decode_step, donate_argnums=(1, 2, 3)).lower(
+        params, *pools, state, S((B, P_ + 5), jnp.int32)).compile()
+    text = compiled.as_text()
+    dims = ",".join(map(str, leaf))
+    calls = re.findall(r"= \(f32\[32,64,64\]\S*, f32\[" + dims
+                       + r"\]\S*\) custom-call\(", text)
+    assert len(calls) == 9 and text.count("mamba_step") >= 9
+    moved = [line.strip()[:160] for line in text.splitlines() if re.search(
+        r"= f32\[(" + dims + "|" + dims[2:] + r")\]\S* "
+        r"(copy|transpose|dynamic-slice|dynamic-update-slice|fusion)\(",
+        line)]
+    assert moved == []
+    mem = compiled.memory_analysis()
+    resident = sum(math.prod(a.shape) * a.dtype.itemsize
+                   for a in jax.tree.leaves((pools, rec)))
+    assert mem.alias_size_in_bytes >= resident
+    assert mem.temp_size_in_bytes < 128 << 20
+
+
 @pytest.mark.parametrize("tokens", [16, 64, 4096])
 @pytest.mark.parametrize("name", ["nemotron_h", "lfm2_moe", "deepseek_v3"])
 def test_grouped_products_compile_for_v5e_at_k_whole(
