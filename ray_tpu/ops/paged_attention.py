@@ -12,9 +12,29 @@ with row `t * KV + h`.  The kernel multiplies all `H` query heads against
 all rows of a chunk on the MXU (the unit is idle in decode) and masks the
 rows of the other KV heads, so no key is ever regrouped in memory.
 
-`paged_decode_attention` runs the kernel on a TPU for shapes it tiles and
-`reference_paged_attention` everywhere else (the CPU test mesh, head widths
-under 128); the reference is also the kernel's parity oracle.
+`paged_decode_attention` runs the kernel on a TPU for shapes it tiles
+(`kernel_tiles`) and `reference_paged_attention` everywhere else (the CPU
+test mesh, rows that fill no whole lane rows, pages under 16 rows, tables
+over scalar memory); the reference is also the kernel's parity oracle.
+
+WHICH ROWS THE KERNEL TAKES.  Rows held by heads, `(page, KV, D)` with `D`
+whole lane rows, as above.  Rows of lanes (`pool_row` below: heads narrower
+than a lane row, a token's `KV * D` values one row of whole lane rows) it
+reads as ONE KV head as wide as the row, `W = KV * D`: Mosaic slices no 64
+lanes out of a row, so each query head is widened to `W` with zeros outside
+its KV head's lanes (`_lane_queries`), the other heads' lanes add exact
+zeros to its scores, and of its `W` sums it keeps its own `D`
+(`_lane_results`); the products run on the matrix unit, idle in decode.  A
+latent row (one row a token, key and value at once) is the same kernel with
+one pool.  THE CHUNK: `start` always issues a whole chunk's copies (past a
+slot's last page the last one is fetched again), so a chunk costs its full
+bytes however short the slot, a dead slot (length 0) included, and its size
+goes by the row's bytes: `_CHUNK_ROWS` rows of one lane row each for heads
+(512 KiB a pool), half that many lane rows for a lanes pool
+(`_lanes_chunk_pages`: 16 pages of 16 rows of 512 lanes; timed on the chip
+at 4-64 pages: short chat contexts beside dead slots want 8, long documents
+64, and 16 is within 0.03 ms a step of either's best), 1,024 rows for a
+latent pool.
 
 THE POOL'S ROW follows the head width (`pool_row`).  A token's keys of one
 layer are `(KV, D)`, and where `D` is a whole number of 128-lane rows the
@@ -99,18 +119,37 @@ def head_rows(x, num_kv_heads: int, head_dim: int):
     return x.reshape(x.shape[:lead] + (num_kv_heads, head_dim))
 
 
+def _own_lanes(H: int, KV: int):
+    """(1, H, KV, 1): whether KV head `k`'s lanes of a row are query head
+    `h`'s own (heads group in order: head h reads KV head h // (H // KV))."""
+    own = (jnp.arange(H) // (H // KV))[:, None] == jnp.arange(KV)  # (H, KV)
+    return own[None, :, :, None]
+
+
+def _lane_queries(q, own):
+    """Queries (B, H, D) as wide as a row of `KV * D` lanes: zeros outside
+    their KV head's lanes, so the other heads' products add exact zeros."""
+    B, H, D = q.shape
+    return jnp.where(own, q[:, :, None], 0).reshape(B, H, own.shape[2] * D)
+
+
+def _lane_results(o, own):
+    """Of each head's row of sums (B, H, KV * D) its own lanes, (B, H, D)."""
+    B, H, W = o.shape
+    KV = own.shape[2]
+    return jnp.where(own, o.reshape(B, H, KV, W // KV), 0).sum(2)
+
+
 def _lanes_attention(q, ck, cv, lengths, scale):
     """The reference over rows of `C = KV * D` lanes, ck / cv (B, T, C), which
     are never split into heads (that would move 64-lane halves of every
     gathered row about): each query head is widened to a whole row with
     zeros outside its KV head's lanes, so the other heads' products add exact
     zeros to its scores, and of its output row it keeps its own lanes."""
-    B, H, D = q.shape
+    H, D = q.shape[1:]
     T, KV = ck.shape[1], ck.shape[2] // D
-    own = (jnp.arange(H) // (H // KV))[:, None] == jnp.arange(KV)  # (H, KV)
-    own = own[None, :, :, None]
-    qw = jnp.where(own, q[:, :, None], 0).reshape(B, H, KV * D)
-    s = jnp.einsum("bhc,btc->bht", qw, ck,
+    own = _own_lanes(H, KV)
+    s = jnp.einsum("bhc,btc->bht", _lane_queries(q, own), ck,
                    preferred_element_type=jnp.float32) * scale
     valid = jnp.arange(T)[None] <= lengths[:, None]               # (B, T)
     s = jnp.where(valid[:, None], s, -1e30)
@@ -119,8 +158,7 @@ def _lanes_attention(q, ck, cv, lengths, scale):
     cv = jnp.where(valid[:, :, None], cv, 0)
     o = jnp.einsum("bht,btc->bhc", p, cv,
                    preferred_element_type=jnp.float32)
-    o = jnp.where(own, o.reshape(B, H, KV, D), 0).sum(2)
-    return o.astype(q.dtype)
+    return _lane_results(o, own).astype(q.dtype)
 
 
 def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
@@ -279,18 +317,18 @@ def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
     jax.lax.fori_loop(0, B, slot_body, jnp.int32(0))
 
 
-def _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
-                         interpret=False):
+def _paged_pair_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
+                       page, kv_heads, chunk_pages, interpret):
+    """`_paged_kernel` over a pair of pools (L, N, page, ...) seen as rows,
+    (L, N, page * kv_heads, width), for queries (B, H, width) -> the same."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, D = q.shape
-    L, N, page, KV, _ = pool_k.shape
-    rows_page = page * KV
-    chunk_pages = max(1, _CHUNK_ROWS // rows_page)
-    R = chunk_pages * rows_page
+    H, width = q.shape[1:]
+    rows = pool_k.shape[:2] + (page * kv_heads, width)
+    R = chunk_pages * page * kv_heads
     kernel = functools.partial(_paged_kernel, scale=scale, page=page,
-                               kv_heads=KV, chunk_pages=chunk_pages)
+                               kv_heads=kv_heads, chunk_pages=chunk_pages)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
@@ -301,8 +339,8 @@ def _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
             in_specs=[vmem, hbm, hbm],
             out_specs=vmem,
             scratch_shapes=[
-                pltpu.VMEM((2, R, D), pool_k.dtype),
-                pltpu.VMEM((2, R, D), pool_v.dtype),
+                pltpu.VMEM((2, R, width), pool_k.dtype),
+                pltpu.VMEM((2, R, width), pool_v.dtype),
                 pltpu.VMEM((H, R), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ]),
@@ -311,7 +349,17 @@ def _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q, pool_k.reshape(L, N, rows_page, D), pool_v.reshape(L, N, rows_page, D))
+      q, pool_k.reshape(rows), pool_v.reshape(rows))
+
+
+def _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
+                         interpret=False):
+    """The kernel over pools of rows held by heads, (L, N, page, KV, D):
+    a page is `page * KV` rows of D lanes."""
+    page, KV = pool_k.shape[2:4]
+    return _paged_pair_pallas(
+        q, pool_k, pool_v, tables, lengths, layer, scale, page, KV,
+        max(1, _CHUNK_ROWS // (page * KV)), interpret)
 
 
 def _paged_latent_pallas(q, pool, tables, lengths, layer, scale,
@@ -351,19 +399,56 @@ def _paged_latent_pallas(q, pool, tables, lengths, layer, scale,
       _wide_queries(q, pool), pool)
 
 
+def _lanes_chunk_pages(page: int, width: int) -> int:
+    """Pages a chunk of a lanes pool holds.  A chunk always costs its full
+    bytes (`start` fetches a short slot's last page again), so it is sized
+    by the row's bytes, not its rows: half of `_CHUNK_ROWS` lane rows (the
+    module docstring has the timings)."""
+    return max(1, _CHUNK_ROWS * _LANES // 2 // width // page)
+
+
+def _paged_lanes_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
+                        interpret=False):
+    """The kernel over a pair of lanes pools (L, N, page, W = KV * D): to
+    the kernel ONE KV head of W lanes, the queries widened to the row
+    (`_lane_queries`) so that every head's product runs over whole lane rows
+    and adds exact zeros outside its KV head's; of the (B, H, W) sums each
+    head keeps its own D lanes."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    page, W = pool_k.shape[2:]
+    own = _own_lanes(q.shape[1], W // q.shape[2])
+    # A pool small enough for VMEM (LFM2's cell: 100 MB) XLA's memory-space
+    # assignment parks there between two layers' calls, a copy in and a copy
+    # out of a whole pool (0.21 ms a step on the chip).  The kernel copies
+    # the live pages itself: hold the pools where they lie.  (The
+    # interpreter knows no memory spaces.)
+    if not interpret:
+        pool_k, pool_v = (pltpu.with_memory_space_constraint(pool, pltpu.HBM)
+                          for pool in (pool_k, pool_v))
+    o = _paged_pair_pallas(
+        _lane_queries(q, own), pool_k, pool_v, tables, lengths, layer, scale,
+        page, 1, _lanes_chunk_pages(page, W), interpret)
+    return _lane_results(o, own)
+
+
 # Page tables ride in scalar memory (1 MiB on a v5e) beside the lengths.
 _TABLE_BYTES = 512 << 10
 
 
 def kernel_tiles(q_shape, pool_shape, tables_shape) -> bool:
     """Whether the Pallas kernel can tile these shapes: a head of whole
-    128-lane rows, pages of whole bf16 sublane tiles, heads that group,
-    page tables that fit scalar memory."""
+    128-lane rows or, narrower, a pool whose rows are lanes (`pool_row`:
+    the KV heads together whole lane rows, which the kernel reads as one
+    head as wide as the row), pages of whole bf16 sublane tiles, heads that
+    group, page tables that fit scalar memory."""
     H, D = q_shape[-2:]
-    if D % _LANES:
-        return False        # (and the pool may hold rows of lanes: `pool_row`)
-    page, KV = pool_shape[-3:-1]
-    return page % 16 == 0 and H % KV == 0 \
+    if pool_shape[-1] == D:                     # rows held by heads
+        (page, KV), whole = pool_shape[-3:-1], D % _LANES == 0
+    else:                                       # rows of KV * D lanes
+        page, width = pool_shape[-2:]
+        KV, whole = width // D, width % _LANES == 0 and width % D == 0
+    return whole and page % 16 == 0 and H % KV == 0 \
         and 4 * math.prod(tables_shape) <= _TABLE_BYTES
 
 
@@ -407,8 +492,8 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths, layer=None, *,
                                          layer, scale=scale)
     if layer is None:
         pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
-    return _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer,
-                                scale)
+    kernel = _paged_lanes_pallas if pool_k.ndim == 4 else _paged_decode_pallas
+    return kernel(q, pool_k, pool_v, tables, lengths, layer, scale)
 
 
 def paged_latent_attention(q, pool, tables, lengths, layer=None, *,

@@ -138,6 +138,44 @@ def test_a_scanned_period_is_traced_once_and_one_repeat_not_scanned(what):
     assert loops["scanned"] == loops["once"] + 1
 
 
+def test_a_scanned_periods_narrow_attention_through_the_paged_kernel(
+        monkeypatch):
+    """A period with an attention layer of 64-wide heads (KV 2 x 64: the
+    pool's row is 128 lanes), scanned twice: the decode step's logits with
+    the paged kernel in the scan's body (the chooser steered here, as
+    tests/test_llm.py does: there is no TPU; the kernel interpreted, two
+    pages a chunk, the stacked pool and the repeat's traced layer number)
+    against the plain function's, over a prompt that ends inside its fifth
+    page and 2 x page + 3 decoded tokens beside two dead slots."""
+    import functools
+
+    from ray_tpu.ops import paged_attention as pa
+
+    cfg, pc = tiny()
+    narrow = dataclasses.replace(pc, pattern=PERIOD, repeats=2, num_layers=6,
+                                 num_heads=4, num_kv_heads=2, head_dim=64)
+    prompt, tokens = prompt_of(cfg, 3, 75), prompt_of(cfg, 4, 35)
+    traces = []
+    for path in ("reference", "pallas"):
+        if path == "pallas":
+            calls, real = [], pa._paged_lanes_pallas
+            def lanes_kernel(*a, **k):
+                calls.append(a[1].shape)
+                return real(*a, **k, interpret=True)
+            monkeypatch.setattr(pa, "_paged_lanes_pallas", lanes_kernel)
+            monkeypatch.setattr(pa, "decode_path", lambda *shapes: "pallas")
+            monkeypatch.setattr(pa, "_lanes_chunk_pages",
+                                lambda page, width: 2)
+        eng = engine(narrow, 1, max_batch=3)
+        assert eng.decode_stats()["pool_row"] == "lanes"
+        assert eng._pk.shape == (2, 97, 16, 128)
+        traces.append(eng.trace_logits(prompt, tokens)["logits"])
+    assert calls == [(2, 97, 16, 128)]          # one site: the scan's body
+    assert traces[0].shape == (36, cfg["vocab_size"])
+    np.testing.assert_allclose(traces[1], traces[0], rtol=2e-4, atol=2e-5)
+    assert np.abs(traces[0]).max() > 1e-2
+
+
 @pytest.mark.parametrize("field", ["embedding_multiplier",
                                    "residual_multiplier", "attention_scale",
                                    "logit_divisor"])

@@ -3,7 +3,9 @@ interpret mode on the CPU, against the module's plain function, against a
 float32 softmax written here; then the kernel and the engine's decode step
 compiled for a described v5e (no chip: the TPU's compiler is installed)."""
 
+import functools
 import math
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +16,21 @@ from ray_tpu.ops import paged_attention as pa
 
 D = 128
 BF16_TOL = 2e-2     # outputs are O(1) averages of bf16 values rounded to bf16
+
+
+class _Tpu:
+    """What `decode_path` asks of a device, answering as a chip would."""
+    platform = "tpu"
+
+
+def as_on_a_tpu(fn):
+    """`fn` (the chooser) answering as it would in a process whose devices
+    are TPUs."""
+    @functools.wraps(fn)
+    def asked(*a, **k):
+        with mock.patch.object(jax, "devices", lambda *b: [_Tpu]):
+            return fn(*a, **k)
+    return asked
 
 
 def _case(groups, page, lengths, *, kv=2, n_slots_pages=6, seed=0,
@@ -36,9 +53,9 @@ def _case(groups, page, lengths, *, kv=2, n_slots_pages=6, seed=0,
     return q, pk, pv, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
 
 
-def _oracle(q, pk, pv, tables, lengths):
-    """softmax(q K^T / sqrt(D)) V in float32, slot by slot, head by head,
-    over positions 0..length of the slot's own pages."""
+def _oracle(q, pk, pv, tables, lengths, scale=None):
+    """softmax(q K^T / sqrt(D)) V (or x `scale`) in float32, slot by slot,
+    head by head, over positions 0..length of the slot's own pages."""
     q, pk, pv = (np.asarray(a, np.float32) for a in (q, pk, pv))
     tables, lengths = np.asarray(tables), np.asarray(lengths)
     B, H, D = q.shape
@@ -49,7 +66,7 @@ def _oracle(q, pk, pv, tables, lengths):
         k = pk[tables[b]].reshape(-1, kv, D)[:n]
         v = pv[tables[b]].reshape(-1, kv, D)[:n]
         for h in range(H):
-            s = k[:, h // (H // kv)] @ q[b, h] / math.sqrt(D)
+            s = k[:, h // (H // kv)] @ q[b, h] * (scale or 1 / math.sqrt(D))
             p = np.exp(s - s.max())
             out[b, h] = (p / p.sum()) @ v[:, h // (H // kv)]
     return out
@@ -62,6 +79,15 @@ def _kernel(q, pk, pv, tables, lengths, layer=None):
         pk, pv, layer = pk[None], pv[None], 0
     return pa._paged_decode_pallas(q, pk, pv, tables, lengths, layer,
                                    1 / math.sqrt(D), interpret=True)
+
+
+def _lanes_kernel(q, pk, pv, tables, lengths, layer=None, scale=None):
+    """The kernel over a pool whose rows are lanes, interpreted."""
+    if layer is None:
+        pk, pv, layer = pk[None], pv[None], 0
+    return pa._paged_lanes_pallas(
+        q, pk, pv, tables, lengths, layer,
+        scale or 1 / math.sqrt(q.shape[-1]), interpret=True)
 
 
 def _lengths(page, pages):
@@ -132,8 +158,16 @@ def test_kernel_cases(what, small_chunks):
     assert np.abs(ref - want).max() < tol
 
 
-@pytest.mark.parametrize("impl", ["kernel", "reference", "lanes"])
-def test_reads_live_pages_only(impl, small_chunks):
+@pytest.fixture
+def small_lane_chunks(monkeypatch):
+    """Two pages a chunk of a lanes pool (the rule gives the cells' rows
+    of 512 lanes 16)."""
+    monkeypatch.setattr(pa, "_lanes_chunk_pages", lambda page, width: 2)
+
+
+@pytest.mark.parametrize("impl", ["kernel", "reference", "lanes",
+                                  "lanes_kernel"])
+def test_reads_live_pages_only(impl, small_chunks, small_lane_chunks):
     """Every page no slot holds is NaN, and the rows of a slot's last page
     past its length are huge: the output is what it was before.  (The old
     gather multiplied dead values by zero and could not pass this.)"""
@@ -141,8 +175,9 @@ def test_reads_live_pages_only(impl, small_chunks):
     small_chunks(page, kv)
     lengths = [0, page - 1, page, 3 * page + 5, 6 * page - 1]
     q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv,
-                                    d=64 if impl == "lanes" else D)
-    fn = _kernel if impl == "kernel" else pa.reference_paged_attention
+                                    d=64 if impl.startswith("lanes") else D)
+    fn = {"kernel": _kernel, "lanes_kernel": _lanes_kernel}.get(
+        impl, pa.reference_paged_attention)
     clean = np.asarray(fn(q, pk, pv, tables, lens), np.float32)
     held = np.zeros(pk.shape[0], bool)
     tail = np.zeros(pk.shape[:2], bool)
@@ -198,10 +233,28 @@ def test_the_pools_row_follows_the_head_width():
     wide = jnp.zeros((3, 5, 2, 128))
     assert pa.pool_rows(wide, 2, 128) is wide
     assert pa.head_rows(wide, 2, 128) is wide
-    # neither kernel takes them; the chooser reads no pool row as heads
-    assert not pa.kernel_tiles((32, 64), (2, 3073, 16, 512), (8, 256))
+    # the kernel takes rows of whole lane rows as ONE head as wide as the
+    # row (LFM2's and Granite's cells, stacked or one layer's); the chooser
+    # reads no pool row as heads
+    assert pa.kernel_tiles((32, 64), (2, 3073, 16, 512), (8, 256))
+    assert pa.kernel_tiles((32, 64), (4, 4097, 16, 512), (32, 128))
+    assert pa.kernel_tiles((4, 64), (9, 64, 128), (4, 4))
+    assert pa.kernel_tiles((12, 96), (9, 16, 384), (4, 4))      # 4 x 96
     assert pa.decode_path((32, 64), (2, 3073, 16, 512), (8, 256)) \
-        == "reference"
+        == "reference"                                          # no TPU
+    on_chip = as_on_a_tpu(pa.decode_path)
+    assert on_chip((32, 64), (2, 3073, 16, 512), (8, 256)) \
+        == on_chip((32, 64), (4, 4097, 16, 512), (32, 128)) == "pallas"
+    assert on_chip((3, 64), (9, 16, 3, 64), (4, 4)) == "reference"
+    # and what still does not tile: rows of 64 or 192 lanes (held by heads,
+    # `pool_row`), a page of 8 rows, heads that do not group, a table over
+    # scalar memory
+    assert not pa.kernel_tiles((4, 64), (9, 16, 1, 64), (4, 4))
+    assert not pa.kernel_tiles((6, 64), (9, 16, 3, 64), (4, 4))
+    assert not pa.kernel_tiles((6, 64), (2, 9, 16, 192), (4, 4))
+    assert not pa.kernel_tiles((32, 64), (2, 3073, 8, 512), (8, 256))
+    assert not pa.kernel_tiles((12, 64), (2, 3073, 16, 512), (8, 256))
+    assert not pa.kernel_tiles((32, 64), (2, 3073, 16, 512), (256, 2048))
     # the third form: a latent layer's ONE row a token (512 + 64 values),
     # one KV head that is no whole number of lane rows, padded to them
     assert pa.pool_row(1, 576) == pa.pool_row(1, 160) == "latent"
@@ -218,15 +271,19 @@ def test_the_pools_row_follows_the_head_width():
         == "reference"                                          # no TPU
 
 
+@pytest.mark.parametrize("impl", ["chosen", "kernel"])
 @pytest.mark.parametrize("page", [16, 64])
 @pytest.mark.parametrize("groups", [1, 4])
 @pytest.mark.parametrize("kv", [2, 8])
-def test_narrow_heads_match_float32(kv, groups, page):
-    """D 64: the pool's rows are lanes, and the plain function reads them as
-    they lie (lengths 0, page - 1, page, several pages, the last)."""
+def test_narrow_heads_match_float32(kv, groups, page, impl,
+                                    small_lane_chunks):
+    """D 64: the pool's rows are lanes, and the plain function (what the
+    chooser takes on the CPU) and the kernel (interpreted) read them as they
+    lie (lengths 0, page - 1, page, several pages, the last)."""
     args = _case(groups, page, _lengths(page, 6), kv=kv, d=64)
     assert args[1].shape == (31, page, kv * 64)
-    got = np.asarray(pa.paged_decode_attention(*args), np.float32)
+    fn = pa.paged_decode_attention if impl == "chosen" else _lanes_kernel
+    got = np.asarray(fn(*args), np.float32)
     assert np.abs(got - _oracle(*args)).max() < BF16_TOL
     # and against the same keys held by heads, which take the other form
     heads = [pa.head_rows(pool, kv, 64) for pool in args[1:3]]
@@ -235,9 +292,11 @@ def test_narrow_heads_match_float32(kv, groups, page):
     assert np.abs(got - np.asarray(split, np.float32)).max() < BF16_TOL
 
 
-@pytest.mark.parametrize("what", ["inactive", "shared", "stacked", "float32"])
-def test_narrow_head_cases(what):
-    page, kv, kwargs, layer = 16, 8, {}, None
+@pytest.mark.parametrize("impl", ["chosen", "kernel"])
+@pytest.mark.parametrize("what", ["inactive", "shared", "stacked", "float32",
+                                  "scale", "real_chunk"])
+def test_narrow_head_cases(what, impl, monkeypatch):
+    page, kv, kwargs, layer, scale = 16, 8, {}, None, None
     lengths = _lengths(page, 6)
     if what == "inactive":
         lengths = [0, 40, 0, 17]
@@ -246,18 +305,31 @@ def test_narrow_head_cases(what):
         lengths = [70, 3 * page, 3 * page + 20]
     elif what == "float32":
         kwargs["dtype"] = jnp.float32
+    elif what == "scale":
+        scale = 1 / 64                      # Granite's: the caller's, not
+    elif what == "real_chunk":              # 1 / sqrt(D)
+        # the cells' row of 512 lanes at the rule's own chunk: slots that
+        # end inside a chunk, on its last row, and two chunks on
+        chunk = pa._lanes_chunk_pages(page, kv * 64)
+        assert chunk == 16
+        kwargs["n_slots_pages"] = 2 * chunk + 4
+        lengths = [0, (chunk - 1) * page + 3, chunk * page - 1, chunk * page,
+                   (2 * chunk + 4) * page - 1]
+    if what != "real_chunk":
+        monkeypatch.setattr(pa, "_lanes_chunk_pages", lambda page, width: 2)
     q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv, d=64, **kwargs)
     if what == "inactive":
         tables = tables.at[0].set(0).at[2].set(0)
-    want = _oracle(q, pk, pv, tables, lens)
+    want = _oracle(q, pk, pv, tables, lens, scale)
     if what == "stacked":
         # The engine's form: (L, N, page, KV * D) and a traced layer index.
         other = jnp.full_like(pk, jnp.nan)
         pk, pv, layer = (jnp.stack([other, pk, other]),
                          jnp.stack([other, pv, other]), jnp.int32(1))
-    got = np.asarray(jax.jit(pa.paged_decode_attention)(
+    fn = pa.paged_decode_attention if impl == "chosen" else _lanes_kernel
+    got = np.asarray(jax.jit(functools.partial(fn, scale=scale))(
         q, pk, pv, tables, lens, layer), np.float32)
-    assert np.isfinite(got).all()
+    assert np.isfinite(got).all() and got.shape == q.shape
     assert np.abs(got - want).max() < (1e-4 if what == "float32"
                                        else BF16_TOL)
 
@@ -430,6 +502,38 @@ def test_kernel_compiles_for_v5e(shape, topo, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("shape", [
+    # B, P, N, attention layers, scale: serve_chat_ssm (Granite: 32 / 8
+    # heads of 64), serve_doc_reask_moe (LFM2: the same heads)
+    (32, 128, 4097, 4, 1 / 64),
+    (8, 256, 3073, 2, 1 / 8),
+])
+def test_lanes_kernel_compiles_for_v5e(shape, topo, monkeypatch,
+                                       no_compile_cache):
+    """`paged_decode_attention` as a chip's chooser takes it, over a pool
+    whose rows are 512 lanes: the kernel, both pools where they lie (no
+    gather of a slot's table, no copy, no slice), the widened queries and
+    the kept lanes the only things beside it."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+    B, P, N, L, scale = shape
+    monkeypatch.setattr(pa, "decode_path", as_on_a_tpu(pa.decode_path))
+    one = SingleDeviceSharding(topo.devices[0])
+    S = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)
+    pool = S(pa.pool_shape(L, N, 16, 8, 64), jnp.bfloat16)
+    assert pool.shape == (L, N, 16, 512)
+    compiled = jax.jit(functools.partial(pa.paged_decode_attention,
+                                         scale=scale)).lower(
+        S((B, 32, 64), jnp.bfloat16), pool, pool, S((B, P), jnp.int32),
+        S((B,), jnp.int32), S((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    assert "gather" not in text
+    assert not re.search(rf"bf16\[({B},{P * 16}|{B * P},16),512\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def _cell(topo, mesh_axes, page=16):
     """The serving cells' widths (two layers) placed on one described chip
     or a two-chip `mesh_axes` mesh: cfg, kv_sharding, a ShapeDtypeStruct
@@ -525,7 +629,10 @@ def test_lfm2_decode_step_and_install_compile_for_v5e_without_pool_copies(
     both pools alias their outputs, no copy or transpose gives a pool half
     (the `(L, N, page, 8, 64)` pool had eight in the step, four in the
     install, and 0.95 GiB of scratch), and the temporaries stay under a
-    quarter of one."""
+    quarter of one.  The step's two attention layers are the paged kernel
+    (the chooser answering as on a chip): nothing as large as the slots'
+    whole tables, (8, 4096, 512), is gathered, selected or multiplied, and
+    no pool is copied to VMEM and back around them."""
     import json
     import os
     import re
@@ -539,6 +646,7 @@ def test_lfm2_decode_step_and_install_compile_for_v5e_without_pool_copies(
     from ray_tpu.models.transformer import STATEFUL, init_params, zero_state
 
     monkeypatch.setattr(routed, "grouped_path", lambda: "megablox")
+    monkeypatch.setattr(pa, "decode_path", as_on_a_tpu(pa.decode_path))
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "lfm2-24b-a2b-l9.json")) as f:
         cfg = lfm2_moe.program_config(json.load(f))
@@ -567,6 +675,14 @@ def test_lfm2_decode_step_and_install_compile_for_v5e_without_pool_copies(
     n_params = len(jax.tree.leaves(params))
     assert aliases[0] == n_params and aliases[1] == n_params + 1
     assert "gmm" in step.as_text()
+    assert len(re.findall(r"custom-call\(.*paged_decode_attention",
+                          step.as_text())) == L
+    assert not re.search(rf"\[{B},{P_ * page},512\]", step.as_text())
+    # nor is a pool parked in VMEM between the two layers' kernels (100 MB
+    # fit, and XLA copied one in and out, 0.21 ms a step on the chip, until
+    # the wrapper held both in HBM)
+    assert [line for line in step.as_text().splitlines()
+            if "copy-start(" in line and "3073" in line] == []
 
     def install_kv(pk, pv, ks, vs, pages):
         return E._install_fn(pk, pv, ks, vs, pages, page, None)
@@ -788,9 +904,11 @@ def test_granite_decode_step_compiles_for_v5e_with_the_state_where_it_lies(
     slots): nine `mamba_step` calls in the scan's body, each given the whole
     stacked leaf (4, 32, 64, 64, 128) and giving it back; no copy, slice or
     update of a state leaf anywhere (a repeat sliced out for the kernel
-    would be 67 MB copied in and out a layer); the pools and the resident
-    state alias their outputs; the temporaries stay where the parent's were
-    (0.08 GB)."""
+    would be 67 MB copied in and out a layer); the period's one attention
+    layer is the paged kernel over the stacked lanes pool, and nothing as
+    large as the slots' whole tables, (32, 2048, 512), is gathered, selected
+    or multiplied; the pools and the resident state alias their outputs; the
+    temporaries stay where the parent's were (0.08 GB)."""
     import re
 
     from jax.sharding import SingleDeviceSharding
@@ -799,9 +917,10 @@ def test_granite_decode_step_compiles_for_v5e_with_the_state_where_it_lies(
     from ray_tpu.llm import programs as E
     from ray_tpu.models import mamba2
     from ray_tpu.models.transformer import init_params, zero_states
-    from tests.test_mamba_step import as_on_a_tpu
+    from tests.test_mamba_step import as_on_a_tpu as backend_a_tpu
 
-    monkeypatch.setattr(mamba2, "step_path", as_on_a_tpu(mamba2.step_path))
+    monkeypatch.setattr(mamba2, "step_path", backend_a_tpu(mamba2.step_path))
+    monkeypatch.setattr(pa, "decode_path", as_on_a_tpu(pa.decode_path))
     cell = load_cell("serve_chat_ssm")
     eng = cell["traffic"]["engine"]
     cfg = cell["family"].program_config(cell["config"],
@@ -836,6 +955,10 @@ def test_granite_decode_step_compiles_for_v5e_with_the_state_where_it_lies(
         r"(copy|transpose|dynamic-slice|dynamic-update-slice|fusion)\(",
         line)]
     assert moved == []
+    assert pools[0].shape == (4, 4097, page, 512)
+    assert len(re.findall(r"custom-call\(.*paged_decode_attention",
+                          text)) == 1
+    assert not re.search(rf"\[{B},{P_ * page},512\]", text)
     mem = compiled.memory_analysis()
     resident = sum(math.prod(a.shape) * a.dtype.itemsize
                    for a in jax.tree.leaves((pools, rec)))
