@@ -687,10 +687,17 @@ class LLMEngine:
         counts when it is read, for the rows that were still live then."""
         cfg, pooled = self.cfg, self._pk is not None
         per_step = self.max_batch * self.pages_per_slot if pooled else 0
-        return {"path": (self._cache_form.decode_form or decode_path(
-                    (cfg.num_heads, cfg.head_dim_), self._pk.shape,
-                    self._tables.shape, self._cache_form.value_lanes(cfg)))
-                if pooled else "none",
+        form = self._cache_form.decode_form
+        if not pooled:
+            path = "none"
+        elif form:
+            path = form(cfg, self._pk, self.max_batch,
+                        self.pages_per_slot * self.page)
+        else:
+            path = decode_path(
+                (cfg.num_heads, cfg.head_dim_), self._pk.shape,
+                self._tables.shape, self._cache_form.value_lanes(cfg))
+        return {"path": path,
                 "pool_row": pool_row(*cfg.cache_row) if pooled else "none",
                 "steps": self._decode_steps,
                 "steps_queued": self._steps_queued,

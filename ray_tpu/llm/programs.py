@@ -29,12 +29,16 @@ from ..models.transformer import (ATTEND, LATENT_FORMS, ROW_BLOCK,
                                   lm_logits, over_rows, rope_angles,
                                   run_pattern, scan_blocks, state_axis,
                                   state_bytes)
-from ..ops.paged_attention import (head_rows, paged_decode_attention,
+from ..ops.paged_attention import (head_rows, heads_chunk_pages,
+                                   paged_decode_attention,
                                    paged_latent_attention, pool_row, pool_rows,
                                    pool_shape)
 from ..ops.sparse_attention import (gathered_attention, index_scores,
-                                    masked_attention, pick_positions,
-                                    select_mask, sparse_path)
+                                    masked_attention,
+                                    paged_attention_over_picks,
+                                    pick_positions, positions_of,
+                                    select_chunk_pages, select_mask,
+                                    sparse_path)
 
 
 # ---- What the forms share --------------------------------------------------
@@ -357,29 +361,54 @@ def _sparse_prefill_attend(cfg: TransformerConfig, rows: int, length,
     return attend, per_layer
 
 
+def _sparse_decode_path(cfg: TransformerConfig, pool_k, slots: int,
+                        table_rows: int) -> str:
+    """The form `sparse_path` gives the decode step of an engine of `slots`
+    slots of `table_rows` tokens over the key pool `pool_k`: the shapes
+    `_sparse_decode_attend` hands it, from what the host holds
+    (`Cache.decode_form`)."""
+    layers, n_pages, page = pool_k.shape[:3]
+    return sparse_path(
+        1, (slots, cfg.num_heads, cfg.head_dim_), pool_k.shape,
+        pool_shape(layers, n_pages, page, *cfg.indexer.row),
+        (slots, table_rows // page))
+
+
 def _sparse_decode_attend(cfg: TransformerConfig, kv_sharding, tables,
                           lengths, written):
     """One query row a slot: the token's key, value and index key written
-    where they land, the slot's index keys read through its page row and
-    scored, and the `top_k` rows picked gathered out of the pools where
-    they lie (ops/sparse_attention.py, "gathered").  `pools` may carry a
-    third member, (layers, B, K) int32: the positions every layer picked,
-    -1 where it picked fewer (`Cache.picks`: the check's)."""
+    where they land, then (ops/sparse_attention.py: `sparse_path`, from
+    the shapes) "paged": the slot's index keys scored by its live pages,
+    the cut, and the paged kernel over the rows it marks, all three pools
+    read where they lie; or "gathered", the plain form: the slot's index
+    keys read through its page row and scored, and the `top_k` rows picked
+    gathered out of the pools.  `pools` may carry a third member, (layers,
+    B, K) int32: the positions every layer picked, -1 where it picked fewer
+    (`Cache.picks`: the check's), from the selection the step attended
+    under."""
     z, heads = cfg.indexer, cfg.cache_row
 
     def attend(pools, q, k, v, qi, ki, wi, li):
         pool_k, (pool_v, pool_i), *picked = pools
         pool_k, pool_v = written(pool_k, li, k), written(pool_v, li, v)
         pool_i = written(pool_i, li, ki, z.row)
-        # (The rows as they lie, a lane row each: split into the key and
-        # its zeros they are relaid, 33 MB a layer, 0.27 ms on a v5e.)
-        index = pool_i[jnp.full_like(tables, li), tables]
-        at, valid = pick_positions(
-            qi[:, 0], wi[:, 0, 0],
-            index.reshape(tables.shape[0], -1, index.shape[-1]), lengths,
-            z.top_k)
-        o = gathered_attention(q[:, 0], pool_k, pool_v, tables, at, valid,
-                               li, cfg.score_scale, heads)
+        if sparse_path(1, q[:, 0].shape, pool_k.shape, pool_i.shape,
+                       tables.shape) == "paged":
+            o, seen = paged_attention_over_picks(
+                q[:, 0], qi[:, 0], wi[:, 0, 0], pool_k, pool_v, pool_i,
+                tables, lengths, li, z.top_k, cfg.score_scale)
+            at, valid = positions_of(seen, z.top_k) if picked else (None,) * 2
+        else:
+            # (The rows as they lie, a lane row each: split into the key
+            # and its zeros they are relaid, 33 MB a layer, 0.27 ms on a
+            # v5e.)
+            index = pool_i[jnp.full_like(tables, li), tables]
+            at, valid = pick_positions(
+                qi[:, 0], wi[:, 0, 0],
+                index.reshape(tables.shape[0], -1, index.shape[-1]), lengths,
+                z.top_k)
+            o = gathered_attention(q[:, 0], pool_k, pool_v, tables, at,
+                                   valid, li, cfg.score_scale, heads)
         picked = [p.at[li].set(jnp.where(valid, at, -1)) for p in picked]
         return o[:, None], (pool_k, (pool_v, pool_i), *picked)
     return attend
@@ -503,24 +532,37 @@ def _mamba_decode(c, lengths, tail):
     return {"ssm_rows": len(lengths)}
 
 
-def _sparse_zero(cfg, slots, table_rows, **_):
+def _sparse_zero(cfg, slots, table_rows, pool=None, **_):
     # `index_rows_read`: what the selection needs, every live token's index
-    # key.  `index_rows_scanned`: what the step's PROGRAM reads, every
-    # slot's whole table of `table_rows` rows, `index_pool_row_bytes` each
-    # (`_sparse_decode_attend`: the gather goes by the table, live or not).
+    # key.  What the step's PROGRAM reads, by its form (`path["decode"]`):
+    # `index_rows_scanned`, index rows of `index_pool_row_bytes` each, and
+    # `kv_rows_read`, rows of a key and a value (`row_bytes`).  "gathered"
+    # goes by the table: every slot's `table_rows` index rows, live or not
+    # (`scanned_a_step`), and the rows it picked, `topk` a slot.  "paged"
+    # goes by the lengths: every slot's live pages rounded up to the
+    # kernels' chunks (`chunk_rows`: the index pass's, the attention's), a
+    # dead slot one chunk, in both passes.
     z = cfg.indexer
+    if not z:
+        return z
     act = jnp.dtype(cfg.dtype).itemsize
     kvh, d = cfg.cache_row
-    return z and {
+    path = sparse_path(1) if pool is None else _sparse_decode_path(
+        cfg, pool, slots, table_rows)
+    page = 1 if pool is None else pool.shape[2]
+    return {
         "enabled": True, "layers": cfg.count("S"), "topk": z.top_k,
         "row_bytes": 2 * kvh * d * act, "index_row_bytes": z.width * act,
         "index_pool_row_bytes": z.row[0] * z.row[1] * act,
-        "scanned_a_step": slots * table_rows,
+        "scanned_a_step": slots * table_rows, "slots": slots, "page": page,
+        "picked_a_step": slots * min(z.top_k, table_rows),
+        "chunk_rows": [page * select_chunk_pages(page),
+                       page * heads_chunk_pages(page, kvh)],
         "rows_visible": 0, "rows_selected": 0, "index_rows_read": 0,
-        "index_rows_scanned": 0,
+        "index_rows_scanned": 0, "kv_rows_read": 0,
         "step_rows_visible": 0, "step_rows_selected": 0,
         "prefill_pairs_visible": 0, "prefill_pairs_selected": 0,
-        "path": {"decode": sparse_path(1), "prefill": sparse_path(ROW_BLOCK)}}
+        "path": {"decode": path, "prefill": sparse_path(ROW_BLOCK)}}
 
 
 def _sparse_prefill(c, rows, prefix_len, table, ran):
@@ -541,7 +583,17 @@ def _sparse_decode(c, lengths, tail):
     c["rows_visible"] += rows
     c["rows_selected"] += picked
     c["index_rows_read"] += rows
-    c["index_rows_scanned"] += c["scanned_a_step"]
+    if c["path"]["decode"] == "paged":
+        live = (lengths.astype(np.int64) // c["page"] + 1) * c["page"]
+        dead = c["slots"] - len(lengths)        # a chunk each, as the live
+
+        def covered(chunk):     # rows a pass covers: whole chunks a slot
+            return int((-(-live // chunk)).sum() + dead) * chunk
+        scanned, read = map(covered, c["chunk_rows"])
+    else:
+        scanned, read = c["scanned_a_step"], c["picked_a_step"]
+    c["index_rows_scanned"] += scanned
+    c["kv_rows_read"] += read
     c["step_rows_visible"], c["step_rows_selected"] = rows, picked
     return {"sparse_rows": picked}
 
@@ -602,8 +654,9 @@ class Cache(NamedTuple):
     blocks, row_block)` -> (attend, per_layer) as `_pair_prefill_attend`
     has it; `decode_attend(cfg, kv_sharding, tables, lengths, written)` ->
     attend(pools, *a layer's q and new rows, li) -> (o, the pools written);
-    `value_lanes(cfg)`: `decode_path`'s, and `decode_form` the step's
-    attention path where it is not one of the paged kernel's.  `picks(cfg,
+    `value_lanes(cfg)`: `decode_path`'s, and `decode_form(cfg, pool_k,
+    slots, table_rows)` the step's attention path where `decode_path`
+    alone does not say it.  `picks(cfg,
     slots, table)`: for a form that SELECTS what it attends, the buffer a traced decode step
     carries with its pools and fills with what every layer picked
     (`_decode_logits_fn`'s `expose`; its prefill_attend takes `expose`
@@ -615,7 +668,7 @@ class Cache(NamedTuple):
     prefill_attend: Optional[Callable] = None
     decode_attend: Optional[Callable] = None
     value_lanes: Callable = lambda cfg: 0
-    decode_form: str = ""
+    decode_form: Optional[Callable] = None
     beside: Callable = lambda cfg: ()
     picks: Optional[Callable] = None
 
@@ -633,7 +686,7 @@ CACHES: Dict[str, Cache] = {
                     kernel_over_pages=False, whole_program=True,
                     prefill_attend=_sparse_prefill_attend,
                     decode_attend=_sparse_decode_attend,
-                    decode_form=sparse_path(1),
+                    decode_form=_sparse_decode_path,
                     beside=lambda cfg: (cfg.indexer.row,),
                     picks=lambda cfg, slots, table: jnp.full(
                         (cfg.count("S"), slots,

@@ -140,7 +140,14 @@ def _lane_results(o, own):
     return jnp.where(own, o.reshape(B, H, KV, W // KV), 0).sum(2)
 
 
-def _lanes_attention(q, ck, cv, lengths, scale):
+def _valid(T: int, lengths, seen):
+    """(B, T): the rows a slot attends, those up to its length and, of
+    them, those `seen` (B, T) marks (None: all)."""
+    valid = jnp.arange(T)[None] <= lengths[:, None]
+    return valid if seen is None else valid & seen
+
+
+def _lanes_attention(q, ck, cv, lengths, scale, seen=None):
     """The reference over rows of `C = KV * D` lanes, ck / cv (B, T, C), which
     are never split into heads (that would move 64-lane halves of every
     gathered row about): each query head is widened to a whole row with
@@ -151,7 +158,7 @@ def _lanes_attention(q, ck, cv, lengths, scale):
     own = _own_lanes(H, KV)
     s = jnp.einsum("bhc,btc->bht", _lane_queries(q, own), ck,
                    preferred_element_type=jnp.float32) * scale
-    valid = jnp.arange(T)[None] <= lengths[:, None]               # (B, T)
+    valid = _valid(T, lengths, seen)                              # (B, T)
     s = jnp.where(valid[:, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     # Dead rows may hold anything (NaN included): select, never multiply.
@@ -162,7 +169,7 @@ def _lanes_attention(q, ck, cv, lengths, scale):
 
 
 def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
-                              *, scale: Optional[float] = None):
+                              *, scale: Optional[float] = None, seen=None):
     """Plain `jax.numpy` form of `paged_decode_attention` (same signature):
     gathers every slot's whole table and masks what is past its length."""
     B, H, D = q.shape
@@ -171,7 +178,8 @@ def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
     if pool_k.ndim == 2 + len(pages):           # rows of lanes: (.., KV * D)
         rows = (B, -1, pool_k.shape[-1])
         return _lanes_attention(q, pool_k[pages].reshape(rows),
-                                pool_v[pages].reshape(rows), lengths, scale)
+                                pool_v[pages].reshape(rows), lengths, scale,
+                                seen)
     page, KV = pool_k.shape[-3:-1]
     T = tables.shape[1] * page
     ck = pool_k[pages].reshape(B, T, KV, D)
@@ -179,7 +187,7 @@ def reference_paged_attention(q, pool_k, pool_v, tables, lengths, layer=None,
     qg = q.reshape(B, KV, H // KV, D)
     s = jnp.einsum("bkgd,btkd->bkgt", qg, ck,
                    preferred_element_type=jnp.float32) * scale
-    valid = jnp.arange(T)[None] <= lengths[:, None]               # (B, T)
+    valid = _valid(T, lengths, seen)                              # (B, T)
     s = jnp.where(valid[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     # Dead rows may hold anything (NaN included): select, never multiply.
@@ -209,16 +217,24 @@ def reference_latent_attention(q, pool, tables, lengths, layer=None, *,
 def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
                   q_ref, *refs,
                   scale: float, page: int, kv_heads: int, chunk_pages: int,
-                  value_lanes: int = 0):
+                  value_lanes: int = 0, selects: bool = False):
     """All slots of one layer.  Work is the list of (slot, chunk) pairs in
     order; while one chunk is computed the next one's pages are in flight,
     across slot boundaries too.  `refs`: k_hbm, v_hbm, o_ref, kbuf, vbuf,
     bias_scr, sem; with `value_lanes` (a latent pool) there is one pool and
     one buffer, k_hbm, o_ref, kbuf, bias_scr, sem: a copied row is the key
-    and, in its first `value_lanes` lanes, the value."""
+    and, in its first `value_lanes` lanes, the value.  With `selects` the
+    first of `refs` is `seen_ref` (B * chunks a table, R) float32, whole in
+    VMEM, a row for each chunk of each slot's table: of a slot's rows up to
+    its length the kernel attends those marked other than 0 and no others
+    (every live page is still copied); the buffers are then (2, chunk_pages,
+    rows_page, width) and ONE wait a pool covers a chunk's copies (12,000
+    waits a layer at 8 x 12k tokens were 0.04 ms of 0.44 on a v5e)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if selects:
+        seen_ref, *refs = refs
     if value_lanes:
         k_hbm, o_ref, kbuf, bias_scr, sem = refs
     else:
@@ -238,7 +254,8 @@ def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
         return (n_pages(b) + chunk_pages - 1) // chunk_pages
 
     def page_copies(pid, buf, j):
-        dst = pl.ds(j * rows_page, rows_page)
+        # (`selects`: the buffers are (2, chunk_pages, rows_page, width).)
+        dst = j if selects else pl.ds(j * rows_page, rows_page)
         keys = pltpu.make_async_copy(k_hbm.at[li, pid], kbuf.at[buf, dst],
                                      sem.at[0, buf])
         if value_lanes:
@@ -259,6 +276,15 @@ def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
                 cp.start()
 
     def wait(buf):
+        if selects:
+            # ONE wait a pool for the chunk's copies: it counts the bytes
+            # of what it names, here the whole buffer.
+            some = pl.ds(0, chunk_pages)
+            pltpu.make_async_copy(k_hbm.at[li, some], kbuf.at[buf],
+                                  sem.at[0, buf]).wait()
+            pltpu.make_async_copy(v_hbm.at[li, some], vbuf.at[buf],
+                                  sem.at[1, buf]).wait()
+            return
         for j in range(chunk_pages):
             for cp in page_copies(0, buf, j):    # a wait reads no source
                 cp.wait()
@@ -291,14 +317,23 @@ def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
             wait(buf)
             k = kbuf[buf]                                     # (R, D)
             v = k[:, :value_lanes] if value_lanes else vbuf[buf]
+            if selects:
+                k, v = k.reshape(R, -1), v.reshape(R, -1)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)           # (H, R)
             s = s * scale + bias_scr[...]
             live = row_tok + c * (chunk_pages * page) <= length
+            if selects:
+                live &= seen_ref[pl.ds(b * (seen_ref.shape[0] // B) + c, 1),
+                                 :] != 0
             s = jnp.where(live, s, -1e30)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
+            if selects:
+                # A chunk may hold no row that is seen: until one is, the
+                # maximum stands at the mask's own value and exp gives 1.
+                p = jnp.where(live, p, 0.0)
             alpha = jnp.exp(m - m_new)
             l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
             acc = alpha * acc + jax.lax.dot_general(
@@ -317,10 +352,31 @@ def _paged_kernel(layer_ref, tables_ref, lengths_ref,      # scalar prefetch
     jax.lax.fori_loop(0, B, slot_body, jnp.int32(0))
 
 
+def _chunk_marks(seen, tokens: int, kv_heads: int):
+    """seen (B, T) bool -> (B * chunks, tokens * kv_heads) float32: a row
+    for each chunk of `tokens` tokens of each slot's table (filled with
+    zeros to whole chunks), a token's mark once for each of its KV heads'
+    rows, as a chunk holds them.  The repeat is a product with a 0 / 1
+    matrix (exact): XLA's own interleaves lanes through two relayouts of
+    the whole array, 0.04 ms a layer at 8 x 16,384 x 4 on a v5e."""
+    B, T = seen.shape
+    fill = -T % tokens
+    rows = jnp.pad(seen, ((0, 0), (0, fill))).reshape(-1, tokens)
+    if kv_heads == 1:
+        return rows.astype(jnp.float32)
+    each = jnp.arange(tokens * kv_heads)[None] // kv_heads \
+        == jnp.arange(tokens)[:, None]
+    return jnp.dot(rows.astype(jnp.bfloat16), each.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+
+
 def _paged_pair_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
-                       page, kv_heads, chunk_pages, interpret):
+                       page, kv_heads, chunk_pages, interpret, seen=None):
     """`_paged_kernel` over a pair of pools (L, N, page, ...) seen as rows,
-    (L, N, page * kv_heads, width), for queries (B, H, width) -> the same."""
+    (L, N, page * kv_heads, width), for queries (B, H, width) -> the same.
+    `seen` (B, T) bool or None: the cached tokens a slot attends, of those
+    up to its length (T the table's tokens, filled to whole chunks; a row
+    of the kernel is a token's one KV head, so each mark is repeated)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -328,38 +384,51 @@ def _paged_pair_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
     rows = pool_k.shape[:2] + (page * kv_heads, width)
     R = chunk_pages * page * kv_heads
     kernel = functools.partial(_paged_kernel, scale=scale, page=page,
-                               kv_heads=kv_heads, chunk_pages=chunk_pages)
+                               kv_heads=kv_heads, chunk_pages=chunk_pages,
+                               selects=seen is not None)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    marks, chunk = (), (R,)
+    if seen is not None:
+        chunk = (chunk_pages, page * kv_heads)
+        marks = (_chunk_marks(seen, chunk_pages * page, kv_heads),)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(),
-            in_specs=[vmem, hbm, hbm],
+            in_specs=[vmem] * (1 + len(marks)) + [hbm, hbm],
             out_specs=vmem,
             scratch_shapes=[
-                pltpu.VMEM((2, R, width), pool_k.dtype),
-                pltpu.VMEM((2, R, width), pool_v.dtype),
+                pltpu.VMEM((2, *chunk, width), pool_k.dtype),
+                pltpu.VMEM((2, *chunk, width), pool_v.dtype),
                 pltpu.VMEM((H, R), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        name="paged_decode_attention",
+        # (Under marks it traces by a name of its own: a reader can tell
+        # the attention that selects from the one that reads every row.)
+        name="paged_decode_attention" if seen is None
+        else "sparse_decode_attention",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q, pool_k.reshape(rows), pool_v.reshape(rows))
+      q, *marks, pool_k.reshape(rows), pool_v.reshape(rows))
+
+
+def heads_chunk_pages(page: int, kv_heads: int) -> int:
+    """Pages a chunk of a pool of rows held by heads holds."""
+    return max(1, _CHUNK_ROWS // (page * kv_heads))
 
 
 def _paged_decode_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
-                         interpret=False):
+                         interpret=False, seen=None):
     """The kernel over pools of rows held by heads, (L, N, page, KV, D):
     a page is `page * KV` rows of D lanes."""
     page, KV = pool_k.shape[2:4]
     return _paged_pair_pallas(
         q, pool_k, pool_v, tables, lengths, layer, scale, page, KV,
-        max(1, _CHUNK_ROWS // (page * KV)), interpret)
+        heads_chunk_pages(page, KV), interpret, seen)
 
 
 def _paged_latent_pallas(q, pool, tables, lengths, layer, scale,
@@ -408,7 +477,7 @@ def _lanes_chunk_pages(page: int, width: int) -> int:
 
 
 def _paged_lanes_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
-                        interpret=False):
+                        interpret=False, seen=None):
     """The kernel over a pair of lanes pools (L, N, page, W = KV * D): to
     the kernel ONE KV head of W lanes, the queries widened to the row
     (`_lane_queries`) so that every head's product runs over whole lane rows
@@ -428,7 +497,7 @@ def _paged_lanes_pallas(q, pool_k, pool_v, tables, lengths, layer, scale,
                           for pool in (pool_k, pool_v))
     o = _paged_pair_pallas(
         _lane_queries(q, own), pool_k, pool_v, tables, lengths, layer, scale,
-        page, 1, _lanes_chunk_pages(page, W), interpret)
+        page, 1, _lanes_chunk_pages(page, W), interpret, seen)
     return _lane_results(o, own)
 
 
@@ -472,7 +541,7 @@ def decode_path(q_shape, pool_shape, tables_shape,
 
 
 def paged_decode_attention(q, pool_k, pool_v, tables, lengths, layer=None, *,
-                           scale: Optional[float] = None):
+                           scale: Optional[float] = None, seen=None):
     """Attention of one new token per slot over the pages the slot holds.
 
     q (B, H, D) after RoPE; pool_k / pool_v (N, page, KV, D), or the stacked
@@ -482,18 +551,20 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths, layer=None, *,
     lengths (B,) tokens already cached: positions 0..lengths[b] are attended
     (the new token's key is at index lengths[b], written by the caller).
     Slot b reads pages tables[b, 0 .. lengths[b] // page] and no other.
-    Returns (B, H, D) in q's dtype.
+    `seen` (B, P * page) bool: of those positions a slot attends the ones it
+    marks (an attention that SELECTS, ops/sparse_attention.py; every live
+    page is read all the same).  Returns (B, H, D) in q's dtype.
 
     On a TPU, for shapes `kernel_tiles` accepts, this is the Pallas kernel;
     otherwise `reference_paged_attention`."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if decode_path(q.shape, pool_k.shape, tables.shape) != "pallas":
         return reference_paged_attention(q, pool_k, pool_v, tables, lengths,
-                                         layer, scale=scale)
+                                         layer, scale=scale, seen=seen)
     if layer is None:
         pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
     kernel = _paged_lanes_pallas if pool_k.ndim == 4 else _paged_decode_pallas
-    return kernel(q, pool_k, pool_v, tables, lengths, layer, scale)
+    return kernel(q, pool_k, pool_v, tables, lengths, layer, scale, seen=seen)
 
 
 def paged_latent_attention(q, pool, tables, lengths, layer=None, *,

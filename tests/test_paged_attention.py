@@ -53,9 +53,10 @@ def _case(groups, page, lengths, *, kv=2, n_slots_pages=6, seed=0,
     return q, pk, pv, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
 
 
-def _oracle(q, pk, pv, tables, lengths, scale=None):
+def _oracle(q, pk, pv, tables, lengths, scale=None, seen=None):
     """softmax(q K^T / sqrt(D)) V (or x `scale`) in float32, slot by slot,
-    head by head, over positions 0..length of the slot's own pages."""
+    head by head, over positions 0..length of the slot's own pages (of
+    them, those `seen` (B, T) marks)."""
     q, pk, pv = (np.asarray(a, np.float32) for a in (q, pk, pv))
     tables, lengths = np.asarray(tables), np.asarray(lengths)
     B, H, D = q.shape
@@ -63,8 +64,9 @@ def _oracle(q, pk, pv, tables, lengths, scale=None):
     out = np.zeros((B, H, D), np.float32)
     for b in range(B):
         n = lengths[b] + 1
-        k = pk[tables[b]].reshape(-1, kv, D)[:n]
-        v = pv[tables[b]].reshape(-1, kv, D)[:n]
+        at = slice(None) if seen is None else np.asarray(seen)[b, :n]
+        k = pk[tables[b]].reshape(-1, kv, D)[:n][at]
+        v = pv[tables[b]].reshape(-1, kv, D)[:n][at]
         for h in range(H):
             s = k[:, h // (H // kv)] @ q[b, h] * (scale or 1 / math.sqrt(D))
             p = np.exp(s - s.max())
@@ -72,22 +74,40 @@ def _oracle(q, pk, pv, tables, lengths, scale=None):
     return out
 
 
-def _kernel(q, pk, pv, tables, lengths, layer=None):
+def _kernel(q, pk, pv, tables, lengths, layer=None, seen=None):
     """The Pallas kernel itself, interpreted (on a TPU the public function
     would choose it)."""
     if layer is None:
         pk, pv, layer = pk[None], pv[None], 0
     return pa._paged_decode_pallas(q, pk, pv, tables, lengths, layer,
-                                   1 / math.sqrt(D), interpret=True)
+                                   1 / math.sqrt(D), interpret=True,
+                                   seen=seen)
 
 
-def _lanes_kernel(q, pk, pv, tables, lengths, layer=None, scale=None):
+def _lanes_kernel(q, pk, pv, tables, lengths, layer=None, scale=None,
+                  seen=None):
     """The kernel over a pool whose rows are lanes, interpreted."""
     if layer is None:
         pk, pv, layer = pk[None], pv[None], 0
     return pa._paged_lanes_pallas(
         q, pk, pv, tables, lengths, layer,
-        scale or 1 / math.sqrt(q.shape[-1]), interpret=True)
+        scale or 1 / math.sqrt(q.shape[-1]), interpret=True, seen=seen)
+
+
+def _marks(tables, lengths, page, seed=0):
+    """(B, T) bool: about a third of each slot's positions up to its length,
+    the slot's FIRST chunk of two pages left wholly unmarked where it has a
+    later one (the kernel's running maximum then starts on a chunk that
+    holds nothing it attends), marks past the length set (they are not
+    attended) and at least one position a slot."""
+    B, T = tables.shape[0], tables.shape[1] * page
+    seen = np.random.default_rng(seed).random((B, T)) < 0.33
+    for b, n in enumerate(np.asarray(lengths)):
+        if n >= 2 * page:
+            seen[b, :2 * page] = False
+        seen[b, n] = True
+        seen[b, n + 1:] = True
+    return jnp.asarray(seen)
 
 
 def _lengths(page, pages):
@@ -118,10 +138,15 @@ def test_kernel_matches_reference_and_float32(groups, page, small_chunks):
 
 
 @pytest.mark.parametrize("what", ["inactive", "shared", "stacked", "real_chunk",
-                                  "float32"])
-def test_kernel_cases(what, small_chunks):
+                                  "float32", "seen", "seen_stacked",
+                                  "seen_lanes"])
+def test_kernel_cases(what, small_chunks, small_lane_chunks):
     page, kv, kwargs, layer = 16, 2, {}, None
     lengths = _lengths(page, 6)
+    if what.startswith("seen"):
+        # An attention that SELECTS (ops/sparse_attention.py): of a slot's
+        # positions up to its length the kernel attends those marked.
+        return _seen_case(what, page, lengths, small_chunks)
     if what == "inactive":
         # As the engine hands them over: table row 0 (the scratch page),
         # length 0.  They cost one page and give a finite row.
@@ -158,6 +183,48 @@ def test_kernel_cases(what, small_chunks):
     assert np.abs(ref - want).max() < tol
 
 
+def _seen_case(what, page, lengths, small_chunks):
+    kv = 4
+    small_chunks(page, kv)
+    lanes = what == "seen_lanes"
+    q, pk, pv, tables, lens = _case(2, page, lengths, kv=kv,
+                                    d=64 if lanes else D)
+    seen = _marks(tables, lens, page)
+    scale = 1 / math.sqrt(q.shape[-1])
+    want = _oracle(q, pk, pv, tables, lens, scale, seen)
+    layer = None
+    if what == "seen_stacked":
+        other = jnp.full_like(pk, jnp.nan)
+        pk, pv, layer = (jnp.stack([other, pk, other]),
+                         jnp.stack([other, pv, other]), jnp.int32(1))
+    fn = _lanes_kernel if lanes else _kernel
+    got = np.asarray(jax.jit(lambda *a: fn(*a, layer=layer, seen=seen))(
+        q, pk, pv, tables, lens), np.float32)
+    ref = np.asarray(pa.reference_paged_attention(
+        q, pk, pv, tables, lens, layer, seen=seen), np.float32)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < BF16_TOL
+    assert np.abs(ref - want).max() < BF16_TOL
+    # and it is not the attention over every row
+    assert np.abs(got - _oracle(q, pk[1] if layer is not None else pk,
+                                pv[1] if layer is not None else pv,
+                                tables, lens, scale)).max() > 10 * BF16_TOL
+
+
+@pytest.mark.parametrize("tokens,kv,T", [(32, 4, 96), (32, 4, 100),
+                                         (512, 4, 16384), (16, 1, 48)])
+def test_chunk_marks_repeat_a_tokens_mark_for_its_kv_heads(tokens, kv, T):
+    """A row a chunk of a slot's table, filled with zeros to whole chunks,
+    each token's mark once for each of its KV heads' rows."""
+    seen = jax.random.bernoulli(jax.random.key(0), 0.3, (3, T))
+    got = np.asarray(pa._chunk_marks(seen, tokens, kv))
+    fill = -T % tokens
+    want = np.repeat(np.pad(np.asarray(seen), ((0, 0), (0, fill))), kv, 1)
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(
+        got, want.reshape(3 * (T + fill) // tokens, tokens * kv))
+
+
 @pytest.fixture
 def small_lane_chunks(monkeypatch):
     """Two pages a chunk of a lanes pool (the rule gives the cells' rows
@@ -166,7 +233,8 @@ def small_lane_chunks(monkeypatch):
 
 
 @pytest.mark.parametrize("impl", ["kernel", "reference", "lanes",
-                                  "lanes_kernel"])
+                                  "lanes_kernel", "kernel_seen",
+                                  "reference_seen"])
 def test_reads_live_pages_only(impl, small_chunks, small_lane_chunks):
     """Every page no slot holds is NaN, and the rows of a slot's last page
     past its length are huge: the output is what it was before.  (The old
@@ -176,8 +244,10 @@ def test_reads_live_pages_only(impl, small_chunks, small_lane_chunks):
     lengths = [0, page - 1, page, 3 * page + 5, 6 * page - 1]
     q, pk, pv, tables, lens = _case(4, page, lengths, kv=kv,
                                     d=64 if impl.startswith("lanes") else D)
-    fn = {"kernel": _kernel, "lanes_kernel": _lanes_kernel}.get(
-        impl, pa.reference_paged_attention)
+    fn = {"kernel": _kernel, "lanes_kernel": _lanes_kernel,
+          "kernel_seen": _kernel}.get(impl, pa.reference_paged_attention)
+    if impl.endswith("_seen"):
+        fn = functools.partial(fn, seen=_marks(tables, lens, page))
     clean = np.asarray(fn(q, pk, pv, tables, lens), np.float32)
     held = np.zeros(pk.shape[0], bool)
     tail = np.zeros(pk.shape[:2], bool)
@@ -1085,10 +1155,14 @@ def test_masked_prefill_kernel_compiles_for_v5e(rows, topo, no_compile_cache):
 def test_sparse_programs_compile_for_v5e(topo, monkeypatch, no_compile_cache):
     """serve_doc_reask_sparse's three programs at its widths (two layers,
     eight experts, a pool of 4,097 pages): the decode step keeps its three
-    pools in place and builds nothing as large as a pool (the slots' whole
-    index tables and the picked rows, 33 MB each, are what it gathers); a whole prompt of
-    4,096 rows goes through the masked prefill kernel and builds no float32
-    scores; a suffix over cached pages gathers the slot's table."""
+    pools in place and reads them through its two kernels a layer (the
+    chooser answering as on a chip: `index_select`, then the paged kernel
+    under the marks, `sparse_decode_attention`): no gather of a slot's whole index table, (8, 16,384,
+    128), none of the picked rows, no pool copied or parked in VMEM round
+    the kernels; a whole prompt of 4,096 rows goes through the masked
+    prefill kernel and builds no float32 scores; a suffix over cached pages
+    gathers the slot's table."""
+    import re
     from jax.sharding import SingleDeviceSharding
     from benchmark.families import keye_vl2
     from benchmark.run import load_cell
@@ -1098,6 +1172,7 @@ def test_sparse_programs_compile_for_v5e(topo, monkeypatch, no_compile_cache):
     from ray_tpu.ops import prefill_attention as pfa
     monkeypatch.setattr(routed, "grouped_path", lambda: "megablox")
     monkeypatch.setattr(pfa, "prefill_path", as_on_a_tpu(pfa.prefill_path))
+    monkeypatch.setattr(pa, "decode_path", as_on_a_tpu(pa.decode_path))
     config = dict(load_cell("serve_doc_reask_sparse")["config"],
                   num_hidden_layers=2, num_experts=8, num_local_experts=8)
     cfg = keye_vl2.program_config(config, max_seq_len=16384)
@@ -1115,6 +1190,16 @@ def test_sparse_programs_compile_for_v5e(topo, monkeypatch, no_compile_cache):
             params, *pools, state, S((B, P + 5))).compile()
     assert step.memory_analysis().temp_size_in_bytes < pool_bytes // 2
     assert step.memory_analysis().alias_size_in_bytes > 2 * pool_bytes
+    text = step.as_text()
+    for kernel in ("index_select", "sparse_decode_attention"):
+        assert len(re.findall(rf"custom-call\(.*{kernel}", text)) == 2
+    # the slots' whole index tables, the picked rows of 4 x 128
+    assert not re.search(rf"bf16\[{B},{P * page},128\]", text)
+    assert not re.search(rf"bf16\[{B},2048,4,128\]", text)
+    for pool in jax.tree.leaves(pools):
+        assert _pool_sized_copies(step, pool) == []
+    assert [line for line in text.splitlines()
+            if "copy-start(" in line and "4097" in line] == []
     whole = jax.jit(lambda p, pk, pv, t, n: E._state_prefill_fn(
         p, pk, pv, None, t, 0, n, [], 0, cfg, page, 0)).lower(
             params, *pools, S((1, 4096)), S(())).compile()
