@@ -123,12 +123,14 @@ class _Flight:
     """A decode step that has been dispatched and not read yet: what it
     returns (the next tokens, a pattern's routed counts after them), whom
     it ran for (slot -> request), the slot rows the host wrote before it,
-    and whether it left while the step before it was still unread."""
+    whether it left while the step before it was still unread, and its
+    number among the programs sent (`TickPhases.sent`)."""
     nxt: Any
     batch: Dict[int, _Request]
     t0: int
     synced: int
     queued: bool = False
+    sent: int = 0
 
 
 # --------------------------------------------------------------------------
@@ -842,7 +844,9 @@ class LLMEngine:
         toks = np.zeros((1, Sb), np.int32)
         toks[0, :S] = prompt
         self._count_prefill(S, Sb)
-        return self._prefill_jit[key](self.params, jnp.asarray(toks), S)
+        out = self._prefill_jit[key](self.params, jnp.asarray(toks), S)
+        self.phases.sent()
+        return out
 
     # ------------------------------------------------------ page refcounts --
     def _alloc_page(self) -> int:
@@ -869,8 +873,11 @@ class LLMEngine:
         overwrite them)."""
         idx = jnp.asarray(np.asarray(pages, np.int32))
         heads = (self.cfg.num_kv_heads, self.cfg.head_dim_)
-        kk = np.asarray(head_rows(self._pk[:, idx], *heads))
-        vv = np.asarray(head_rows(self._pv[:, idx], *heads))
+        kk = head_rows(self._pk[:, idx], *heads)
+        vv = head_rows(self._pv[:, idx], *heads)
+        sent = self.phases.sent()
+        kk, vv = np.asarray(kk), np.asarray(vv)
+        self.phases.seen(sent)
         self._demote.put(key, kk, vv, len(pages))
 
     def _try_promote(self, req: _Request, c: int, shared: List[int],
@@ -1021,6 +1028,7 @@ class LLMEngine:
         pages[:held] = page_ids[:held]
         self._pk, self._pv = self._install_jit(
             self._pk, self._pv, ks, vs, jnp.asarray(pages))
+        self.phases.sent()
 
     def _install_state(self, req: _Request, end, kept) -> None:
         """A prefill's recurrent state into the request's slot, and the
@@ -1036,6 +1044,7 @@ class LLMEngine:
         self._dev["rec"], self._ckpt = self._install_state_jit(
             self._dev["rec"], self._ckpt, req.slot, end, kept,
             jnp.asarray(rows), axis=self._state_axis)
+        self.phases.sent()
 
     def _run_suffix(self, prompt: Sequence[int], prefix_len: int,
                     pages_row, upto: Optional[int] = None, from_row: int = 0,
@@ -1091,12 +1100,14 @@ class LLMEngine:
         toks[0, :S] = suf
         self._count_prefill(S, Sb, after)
         state = (self._ckpt, from_row) if self.cfg.pattern else ()
-        return self._prefill_jit[key](
+        out = self._prefill_jit[key](
             self.params, self._pk, self._pv,
             # (A copy: the row is a view of `_tables`, which the CPU
             # backend may still be reading after the slot has been freed.)
             None if pages_row is None else jnp.asarray(np.array(pages_row)),
             jnp.asarray(toks), prefix_len, S, *state)
+        self.phases.sent()
+        return out
 
     def _prefill_slot(self, req: _Request, expose: bool = False):
         """Run a reserved request's prefill and install what it leaves:
@@ -1286,7 +1297,9 @@ class LLMEngine:
             toks = jnp.where(tj > 0, sampled.astype(jnp.int32), greedy)
         else:
             toks = greedy
+        sent = self.phases.sent()       # the eager programs above, as one
         out = np.asarray(toks)                            # the one sync
+        self.phases.seen(sent)
         self.phases.leave(t0, "sample_sync", batch=n)
         return [int(t) for t in out[:n]]
 
@@ -1388,7 +1401,14 @@ class LLMEngine:
         and `dispatch`), the decode step's `prep`, `dispatch` and `wait`,
         then `emit` again (and `ahead`, where the next step leaves at the
         end of the call: the next call's `prep` and `dispatch` are then
-        empty); it hands back to the replica's loop in `hop`."""
+        empty); it hands back to the replica's loop in `hop`.  And of each
+        phase, the part in which the device had nothing to run is counted
+        beside it (`TickPhases.empty_ns`): every call on this path that
+        enqueues a program is followed by `ph.sent()`, and the two blocking
+        read-backs (`wait`'s of the step, `sample_sync`'s of an admission's
+        first tokens) by `ph.seen()` with the number the program read got:
+        a new send needs its `sent()`, or the account calls a busy device
+        empty."""
         ph = self.phases
         ph.in_step = True
         done: List[_Request] = []
@@ -1468,6 +1488,7 @@ class LLMEngine:
             self.hand_first(self.take_tick_events())
         ph.to("wait")
         nxt = np.asarray(flight.nxt)
+        ph.seen(flight.sent)
         lengths = self._lengths[list(live)]
         pages = int((lengths // self.page + 1).sum()) \
             if self._pk is not None else 0
@@ -1521,7 +1542,7 @@ class LLMEngine:
             ph.to("dispatch")
         self._pk, self._pv, self._dev, nxt = self._decode_jit(
             self.params, self._pk, self._pv, self._dev, update)
-        return _Flight(nxt, batch, t0, synced, queued)
+        return _Flight(nxt, batch, t0, synced, queued, ph.sent())
 
     def _next_batch_if_ahead(self) -> Dict[int, _Request]:
         """Whom the NEXT decode step is for, if it may leave now, before
@@ -1674,6 +1695,7 @@ class LLMEngine:
         t0 = rec.begin()
         win.prefetch([(p["key"], p["handle"]) for p in parts])
         x = sa.embed(self.params, toks)
+        self.phases.sent()              # the first of the eager programs
         ks, vs = [], []
         for li in range(self.cfg.num_layers):
             q, k, v = sa.qkv(self.params["layers"], li, x, pos)
@@ -1696,7 +1718,9 @@ class LLMEngine:
                 gather_bytes=win.bytes_fetched - b0,
                 gather_wait_us=int((win.wait_s - w0) * 1e6),
                 fetches=win.fetches - f0, **fields)
-        return x, jnp.stack(ks), jnp.stack(vs)
+        out = x, jnp.stack(ks), jnp.stack(vs)
+        self.phases.sent()              # ... and the last
+        return out
 
     def _ext_decode_step(self, req: _Request) -> int:
         """One decode token for a paged-context slot: it attends the
@@ -1717,6 +1741,7 @@ class LLMEngine:
         self._pk, self._pv = self._append_tail_jit(
             self._pk, self._pv, ks[:, 0], vs[:, 0],
             jnp.int32(req.pages[t // self.page]), jnp.int32(t % self.page))
+        self.phases.sent()
         req.ext_written = t + 1
         return int(self._sample_batch([logits], [req.params])[0])
 

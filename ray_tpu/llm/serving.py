@@ -59,8 +59,13 @@ batched device→host sample pull), ``step:emit``, ``step:ahead`` (a
 later decode step sent off before the call that will read it, at the end of
 ``step()`` or behind a step still unread, while no caller waits for the
 lock), ``tick:fan_out`` — whose cumulative nanoseconds
-``debug_stats()["tick"]`` also serves.  A tick's first tokens are fanned
-out from inside it, before its decode step is read (``_hand_first``).
+``debug_stats()["tick"]`` also serves (``ns``), and beside them
+``empty_ns``: of each leaf's time, the part in which the device was KNOWN
+EMPTY, everything the engine had sent it read back and nothing sent since
+(``sent``, ``seen``; a lower bound of the device's idle time, also on any
+profile's host plane as the annotation ``ray_tpu/device:empty``:
+``tick_phases.py``).  A tick's first tokens are fanned out from inside it,
+before its decode step is read (``_hand_first``).
 
 A request's own account of its time: the replica snapshots those counters
 when a request is enqueued (S0), when its first token is put on its stream
@@ -69,7 +74,9 @@ phase, so the differences part the two stretches exactly, and the stream's
 terminal dict carries them as ``timing``, recorder on or off:
 ``request_id``, ``lock_wait_ns`` (entry → S0), ``first_ns`` (S0 → S1),
 ``total_ns`` (S0 → S2), ``first`` and ``rest`` (ns by leaf, which sum to
-``first_ns`` and ``total_ns - first_ns``), ``ticks`` and ``stops`` (ticks
+``first_ns`` and ``total_ns - first_ns``), ``first_empty`` and
+``rest_empty`` (of those, the ns in which the device was known empty, by
+leaf; a leaf that reads 0 is left out), ``ticks`` and ``stops`` (ticks
 begun, and those of them that admitted, between S1 and S2),
 ``prompt_tokens``, ``cached_tokens``, ``recomputed``.  A request that is
 cancelled, expires, is shed or fails has none.
@@ -126,12 +133,17 @@ def _timing(req, t_in: int, s0: dict, s1: dict, s2: dict) -> Dict[str, Any]:
     """A finished request's record, from the `TickPhases` snapshots at its
     enqueue (S0), first token (S1) and end (S2) and the stamp `t_in` of
     its entry: two snapshots differ by exactly the time between them."""
+    def empty(a: dict, b: dict) -> Dict[str, int]:
+        return {p: ns for p in LEAVES
+                if (ns := b["empty_ns"][p] - a["empty_ns"][p])}
     return {"request_id": req.req_id,
             "lock_wait_ns": s0["t"] - t_in,
             "first_ns": s1["t"] - s0["t"],
             "total_ns": s2["t"] - s0["t"],
             "first": {p: s1["ns"][p] - s0["ns"][p] for p in LEAVES},
             "rest": {p: s2["ns"][p] - s1["ns"][p] for p in LEAVES},
+            "first_empty": empty(s0, s1),
+            "rest_empty": empty(s1, s2),
             "ticks": s2["n"] - s1["n"],
             "stops": s2["admitting"] - s1["admitting"],
             "prompt_tokens": len(req.prompt),

@@ -4,7 +4,9 @@ steps goes.
 Every instant of a running `EngineReplica` belongs to exactly one LEAF
 phase: the replica's decode loop and the engine's `step()` call
 `TickPhases.to(<leaf>)` at each boundary, which closes the open phase and
-opens the next at ONE monotonic stamp.  That one call feeds three sinks:
+opens the next at ONE monotonic stamp.  That one call feeds three sinks,
+and books a fourth fact with the same stamp (the device's known-empty
+time, below):
 
   counters    cumulative ns per leaf (`snapshot()`, served as
               `EngineReplica.debug_stats()["tick"]`): exact window totals
@@ -68,6 +70,35 @@ records its spans as before and counts as the loop's `turn`.
 else's prompt: those in which `_admit` gave a request a slot or a chunked
 prefill advanced.  The window's `STOP` nanoseconds over it are what one
 admission costs a stream that is decoding.
+
+When the chip had nothing to run.  `ns` counts a leaf's host time whether
+it hid under a running step or starved the chip; `empty_ns` says which.
+The device runs what one process sends it in order, so two sequence numbers
+are enough: `sent()`, called after every call on the serving path that
+enqueued a program (a decode step, a prefill, an install, the sampler's
+eager programs; a `device_put` of rows is no program), counts them, and
+`seen(number)`, called after a blocking read-back has returned, says what
+`sent()` had returned when the program read was enqueued (a `_Flight`
+keeps its number).  The device is KNOWN EMPTY from the stamp at which a
+read-back returns with `seen == sent`, everything sent has been read, until
+the return of the next call that enqueues a program; a step read while
+another is queued behind it opens nothing.  `to()` books the open
+interval's part of the closing leaf to `empty_ns[<leaf>]` with the stamp it
+takes anyway (one branch a call); `sent()` and `seen()` take a stamp only
+where they close or open an interval.  So `empty_ns[leaf] <= ns[leaf]`
+between any two snapshots, `snapshot()` serves both (and the two numbers)
+from one sequence bracket, and a reply's `timing` carries its own share
+(`first_empty`, `rest_empty`).  A `jax.profiler.TraceAnnotation` named
+`ray_tpu/device:empty` is held open over each interval, as
+`ray_tpu/tick:<leaf>` is over a leaf: the program's belief on the host
+plane of any profile, beside the device plane's truth.  The account is a
+LOWER bound of the device's idle time, by design: a program that ended
+before it was read (the read-back's late return inside `wait`, a step sent
+ahead that ends while `admit` runs) left the chip idle for a stretch the
+host cannot know; gaps inside a program and anything sent from outside the
+serving path (a reference check's programs) are not seen at all.  A
+profile's idle share is the upper bound, and the difference is what the
+read-back and the launch cost.
 """
 
 from __future__ import annotations
@@ -100,13 +131,22 @@ class TickPhases:
         self.n = 0                      # ticks begun
         self.admitting = 0              # of them, ticks that admitted
         self.ns: Dict[str, int] = dict.fromkeys(LEAVES, 0)
+        # Of `ns`, the part in which the device was known empty.
+        self.empty_ns: Dict[str, int] = dict.fromkeys(LEAVES, 0)
         self.in_tick = False            # a replica's loop drives this tick
         self.in_step = False            # inside LLMEngine.step()
         self._open: Tuple[Optional[str], int] = (None, 0)    # leaf, since
-        # Odd while `to()` moves time from the open phase into `ns`:
-        # `snapshot()` on the other thread reads between two even values.
+        # Programs enqueued, and of them how many are known to have ended
+        # (read back, or enqueued before one that was).
+        self._sent = 0
+        self._seen = 0
+        self._empty: Optional[int] = None   # known empty since; None: not
+        # Odd while a stamp is taken and time moves into the counters:
+        # `snapshot()` on the other thread reads between two even values,
+        # its own stamp among them.
         self._seq = 0
         self._note: Optional[TraceAnnotation] = None
+        self._empty_note: Optional[TraceAnnotation] = None
         self._tick: Tuple[int, int, int] = (0, 0, 0)
 
     # ------------------------------------------------------------ leaves --
@@ -114,11 +154,15 @@ class TickPhases:
         """Close the open phase and open `leaf` (None: nothing) at one
         stamp, which is returned.  `closing` are arguments of the span of
         the phase that closes."""
-        now = clocks.mono_ns()
         cur, since = self._open
         self._seq += 1
+        now = clocks.mono_ns()
         if cur is not None:
             self.ns[cur] += now - since
+        if self._empty is not None:
+            if cur is not None:
+                self.empty_ns[cur] += now - self._empty
+            self._empty = now
         self._open = (leaf, now)
         self._seq += 1
         if cur is not None:
@@ -157,6 +201,35 @@ class TickPhases:
         flight_recorder.recorder().span_at("request", name, t0, t1,
                                            n=self.n, **args)
 
+    # ------------------------------------------------------------ device --
+    def sent(self) -> int:
+        """After a call that enqueued a program (or several): the device
+        has work from here on.  Returns the count, which `seen` takes once
+        the program's result has been read."""
+        self._sent += 1
+        if self._empty is not None:
+            self._seq += 1
+            cur = self._open[0]
+            if cur is not None:
+                self.empty_ns[cur] += clocks.mono_ns() - self._empty
+            self._empty = None
+            self._seq += 1
+            self._empty_note.__exit__(None, None, None)
+        return self._sent
+
+    def seen(self, number: int) -> None:
+        """After a blocking read of what program `number` returned: it and
+        every program before it have ended.  Nothing sent since: the
+        device is known empty from now on."""
+        if number > self._seen:
+            self._seen = number
+        if self._seen == self._sent and self._empty is None:
+            self._seq += 1
+            self._empty = clocks.mono_ns()
+            self._seq += 1
+            self._empty_note = TraceAnnotation("ray_tpu/device:empty")
+            self._empty_note.__enter__()
+
     # -------------------------------------------------------------- tick --
     def tick_begin(self, enqueued: int, active: int, waiting: int) -> None:
         """The loop holds the lock: `turn` closes under the NEW tick's
@@ -175,15 +248,24 @@ class TickPhases:
     def snapshot(self) -> Dict[str, Any]:
         """Ticks begun, those of them that admitted, and cumulative ns per
         leaf with the open phase counted up to the stamp `t`: two snapshots
-        bracket a window exactly, `sum(ns)` apart by their `t`s."""
+        bracket a window exactly, `sum(ns)` apart by their `t`s.  Beside
+        `ns`, never inside it: `empty_ns`, the part of each leaf in which
+        the device was known empty (the open interval counted up to `t`
+        too), and the two numbers it rests on, `sent` and `seen`."""
         while True:
             seq = self._seq
             ns = dict(self.ns)
+            empty_ns = dict(self.empty_ns)
             cur, since = self._open
+            empty = self._empty
+            seen, sent = self._seen, self._sent
+            t = clocks.mono_ns()
             if not seq % 2 and seq == self._seq:
                 break
             time.sleep(0)               # let the thread inside `to()` finish
-        t = clocks.mono_ns()
         if cur is not None:
             ns[cur] += t - since
-        return {"n": self.n, "admitting": self.admitting, "t": t, "ns": ns}
+            if empty is not None:
+                empty_ns[cur] += t - empty
+        return {"n": self.n, "admitting": self.admitting, "t": t, "ns": ns,
+                "empty_ns": empty_ns, "sent": sent, "seen": seen}

@@ -295,8 +295,8 @@ def _check_timing(rec, n_tokens, shipped=False):
     t = end["timing"]
     assert set(t) == {
         "request_id", "lock_wait_ns", "first_ns", "total_ns", "first",
-        "rest", "ticks", "stops", "prompt_tokens", "cached_tokens",
-        "recomputed"}
+        "rest", "first_empty", "rest_empty", "ticks", "stops",
+        "prompt_tokens", "cached_tokens", "recomputed"}
     assert tuple(t["first"]) == tuple(t["rest"]) == LEAVES
     ints = [t["lock_wait_ns"], t["first_ns"], t["total_ns"], t["ticks"],
             t["stops"], *t["first"].values(), *t["rest"].values()]
@@ -592,7 +592,8 @@ def test_old_spans_keep_names_arguments_and_extents(run):
 
 @pytest.mark.parametrize("reader", ["queue_wait", "decode_batch",
                                     "prefill_rate", "ttft_outside",
-                                    "span_median", "tick_phase"])
+                                    "span_median", "tick_phase",
+                                    "device_empty", "reply_empty"])
 def test_benchmark_readers_read_these_spans_and_counters(run, reader):
     sys.path.insert(0, ROOT)
     try:
@@ -604,7 +605,10 @@ def test_benchmark_readers_read_these_spans_and_counters(run, reader):
            "stats_before": run["stats"][0], "stats_after": run["stats"][-1]}
     args = {"span_median": {"name": "request:lock_wait"},
             "tick_phase": {"phases": "all",
-                           "minus": ["idle", "wait", "sample_sync"]}
+                           "minus": ["idle", "wait", "sample_sync"]},
+            "device_empty": {"phases": "all", "minus": ["idle"],
+                             "per": "tick"},
+            "reply_empty": {"of": "reply", "band": [40, 60]}
             }.get(reader, {})
     value = read(ctx, args)
     assert value is not None and value >= 0
@@ -620,6 +624,22 @@ def test_benchmark_readers_read_these_spans_and_counters(run, reader):
         # a program that counts no phases (the parent commit): nothing
         old = {k: {} for k in ("stats_before", "stats_after")}
         assert read(old, args) is None and read({}, args) is None
+    if reader == "device_empty":
+        tick = run["stats"][-1]["tick"]
+        busy = sum(tick["empty_ns"].values()) - tick["empty_ns"]["idle"]
+        assert 0 < value == pytest.approx(busy / 1e6 / tick["n"])
+        # at most the host's time in the same leaves, which `tick_phase`
+        # reads; and of the whole run, `idle` included, a share
+        assert value <= importlib.import_module(
+            "benchmark.readers.tick_phase").read(ctx, {
+                "phases": "all", "minus": ["idle"]})
+        assert 0 < read(ctx, {"phases": "all", "per": "window"}) <= 100
+    if reader == "reply_empty":
+        kept = [r["finish"]["timing"] for r in sorted(
+            run["records"], key=lambda r: r["token_times"][-1] - r["due"])
+            [5:7]]
+        assert value == pytest.approx(sum(
+            sum(t["rest_empty"].values()) for t in kept) / 2e6)
     if reader == "span_median":
         assert read({"spans": []}, args) is None
         assert read(ctx, {"name": "no:such"}) is None
